@@ -1,0 +1,774 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once, in ONE process, through the entry points a user
+calls, at the full width of the flagship model (histogram GBDT at Higgs
+width: 28 features, 256 bins, depth 6; three trees, weights from a seed):
+
+    device -> native build -> feed -> train -> kernels -> checkpoint
+           -> serve -> mesh (two or more devices)
+
+    libsvm bytes on disk -> DeviceStagingIter -> GBDT (fit_streamed: sparse
+    Pallas kernel; fit: dense Pallas kernel) -> checkpoint -> ScoringServer
+
+and checks at every step that what came out is right by the repo's own
+means: staged content against an independent host parse, each forest
+against the same fit on XLA scatter, each kernel against its XLA reference,
+/score against predict_batch, sharded against single-device.
+
+It claims no speed.  It exits non-zero at the first failure and prints
+nothing on standard output then.  On success standard output is two lines,
+each one JSON object: the full summary (phases, kernels, versions, cache;
+also written to ``summary.json`` under ``--out``), and last the verdict with
+exactly these keys, the device as JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Without a TPU it fails in the first phase.  ``--rehearse-cpu`` (which also
+needs ``JAX_PLATFORMS=cpu`` in the environment) walks the same phases at
+tiny sizes with interpreted kernels, to debug the script itself off the
+chip; its summary says ``"rehearsal": true`` and proves nothing about a
+device.
+
+    python chip_smoke.py [--expect-devices N] [--out DIR]
+    JAX_PLATFORMS=cpu python chip_smoke.py --rehearse-cpu
+"""
+from __future__ import annotations
+
+import argparse
+import contextlib
+import functools
+import json
+import os
+import sys
+import time
+import traceback
+import urllib.request
+from pathlib import Path
+
+import numpy as np
+
+HERE = Path(__file__).resolve().parent
+
+# Flagship width at both sizes; rows, depth and trees are what is cut.  Row
+# counts leave the last batch short (padding is part of the path) and divide
+# by eight, so a mesh fit sees every row the one-device fit saw.
+FEATURES = 28
+FULL = dict(rows=8 * 16384 + 1000, batch_size=16384, bins=256, depth=6,
+            trees=3, kernel_rows=65536, score_rows=(1, 7, 64, 300))
+TINY = dict(rows=2 * 512 + 104, batch_size=512, bins=32, depth=3,
+            trees=2, kernel_rows=1024, score_rows=(1, 7, 40))
+
+
+T0 = time.monotonic()
+
+
+def log(msg: str) -> None:
+    print(f"[smoke +{time.monotonic() - T0:7.1f}s] {msg}", file=sys.stderr,
+          flush=True)
+
+
+class Failure(Exception):
+    """A check the smoke makes did not hold."""
+
+
+def require(cond, msg: str) -> None:
+    if not cond:
+        raise Failure(msg)
+
+
+def rel_err(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
+
+
+def cache_entries(cache_dir: str) -> int:
+    if not os.path.isdir(cache_dir):
+        return 0
+    return sum(1 for n in os.listdir(cache_dir) if not n.endswith("-atime"))
+
+
+class CompileMeter:
+    """What JAX itself reports about getting executables: seconds spent
+    tracing, lowering and compiling (or fetching from the persistent cache),
+    how many programs, how many came from the cache.  This is the set-up
+    share of a phase's wall clock; the rest is the phase running."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.seconds, self.programs, self.cache_hits = 0.0, 0, 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_) -> None:
+        if event.startswith("/jax/core/compile/"):
+            self.seconds += seconds
+            self.programs += event.endswith("backend_compile_duration")
+
+    def _event(self, event: str, **_) -> None:
+        self.cache_hits += event == "/jax/compilation_cache/cache_hits"
+
+    def snapshot(self) -> tuple:
+        return self.seconds, self.programs, self.cache_hits
+
+    def since(self, mark: tuple) -> dict:
+        return {"compile_seconds": round(self.seconds - mark[0], 2),
+                "programs": self.programs - mark[1],
+                "cache_hits": self.cache_hits - mark[2]}
+
+
+# ---- phases -----------------------------------------------------------------
+
+def phase_device(ctx: dict) -> dict:
+    import jax
+    import jaxlib
+    args = ctx["args"]
+    backend = jax.default_backend()
+    if args.rehearse_cpu:
+        require(os.environ.get("JAX_PLATFORMS") == "cpu" and backend == "cpu",
+                "--rehearse-cpu needs JAX_PLATFORMS=cpu in the environment "
+                f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}, "
+                f"backend {backend!r})")
+    else:
+        require(backend == "tpu",
+                f"no TPU: jax.default_backend() is {backend!r} "
+                f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}); "
+                "--rehearse-cpu walks the phases off the chip")
+    devices = jax.devices()
+    if args.expect_devices is not None:
+        require(len(devices) == args.expect_devices,
+                f"--expect-devices {args.expect_devices}: JAX found "
+                f"{len(devices)} {backend} device(s)")
+    try:
+        import libtpu
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = None
+    ctx["device"] = {"platform": devices[0].platform,
+                     "kind": devices[0].device_kind, "count": len(devices)}
+    ctx["versions"] = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                       "libtpu": libtpu_version,
+                       "python": sys.version.split()[0]}
+    return dict(ctx["device"])
+
+
+def phase_native(ctx: dict) -> dict:
+    # importing the package builds cpp/ into build/libdmlctpu.so when the
+    # library is missing or older than the sources (an incremental ninja
+    # run under the build lock), so a fresh checkout compiles it right here
+    import dmlc_core_tpu
+    info = dmlc_core_tpu.native_build_info()
+    want = HERE / "build" / "libdmlctpu.so"
+    require(Path(info["library"]) == want,
+            f"loaded {info['library']}, not this checkout's {want} "
+            "(unset DMLCTPU_LIBRARY_PATH)")
+    from dmlc_core_tpu import compile_cache, telemetry
+    require(telemetry.enabled(), "native runtime built without telemetry: "
+            "the smoke reads its counters")
+    cache_dir = compile_cache.configure()
+    ctx["cache"] = {"dir": cache_dir,
+                    "from_env": bool(os.environ.get(
+                        "JAX_COMPILATION_CACHE_DIR")),
+                    "entries_before": cache_entries(cache_dir)}
+    if ctx["device"]["platform"] == "tpu":
+        hbm = telemetry.resource_sample().get("resource.hbm_bytes_limit")
+        require(hbm, "the TPU reported no memory limit "
+                "(telemetry.resource_sample)")
+        info["hbm_bytes_limit"] = int(hbm)
+    ctx["native"] = info
+    return info
+
+
+def write_dataset(path: Path, rows: int, seed: int = 12):
+    """Higgs-shaped libsvm from a seed: 28 float features a row, a label
+    trees can learn and a linear model cannot."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((rows, FEATURES)).astype(np.float32)
+    # the staging convention reads a stored 0 as an absent entry; keep every
+    # value printable as non-zero at six decimals
+    x = np.where(np.abs(x) < 1e-3, np.float32(1e-3), x)
+    y = ((x[:, 0] > 0) ^ (x[:, 1] > 0.3) ^ (x[:, 2] > 1.0)).astype(np.float32)
+    fmt = "%d " + " ".join(f"{j}:%.6f" for j in range(FEATURES))
+    with open(path, "w") as f:
+        np.savetxt(f, np.column_stack([y, x]), fmt=fmt)
+
+
+def host_parse(uri: str):
+    """The reference the staged content is held against: one pass of the
+    host Parser, no staging, no device."""
+    from dmlc_core_tpu import Parser
+    labels, index, value, counts = [], [], [], []
+    with Parser(uri, format="libsvm") as parser:
+        for block in parser:
+            labels.append(block.label)
+            index.append(block.index.astype(np.int64))
+            value.append(block.values_or_ones())
+            counts.append(np.diff(block.offset).astype(np.int64))
+    return (np.concatenate(labels), np.concatenate(index),
+            np.concatenate(value), np.concatenate(counts))
+
+
+def content_sums(label, index, value, rows: int, nnz: int) -> dict:
+    v = np.asarray(value, np.float64)
+    return {"rows": int(rows), "nnz": int(nnz),
+            "sum_label": float(np.sum(np.asarray(label, np.float64))),
+            "sum_value": float(np.sum(v)),
+            "sum_index_value": float(np.sum(np.asarray(index, np.float64) * v))}
+
+
+def staged_sums(batches) -> dict:
+    """The same sums over an epoch of staged batches, read back from the
+    devices they sit on."""
+    return content_sums(
+        np.concatenate([np.asarray(b.label) for b in batches]),
+        np.concatenate([np.asarray(b.index) for b in batches]),
+        np.concatenate([np.asarray(b.value) for b in batches]),
+        sum(int(b.num_rows) for b in batches),
+        sum(int(np.asarray(b.row_ptr)[-1]) for b in batches))
+
+
+def require_same_sums(got: dict, want: dict, what: str) -> None:
+    for k, w in want.items():
+        g = got[k]
+        same = g == w if isinstance(w, int) else abs(g - w) <= 1e-9 * max(
+            abs(w), 1.0)
+        require(same, f"{what}: {k} staged {g!r} != host parse {w!r}")
+
+
+def require_on_devices(batch, platform: str, what: str) -> None:
+    import jax
+    for leaf in jax.tree.leaves(batch):
+        require(isinstance(leaf, jax.Array),
+                f"{what}: a staged leaf is {type(leaf).__name__}, "
+                "not a jax.Array")
+        require(all(d.platform == platform for d in leaf.devices()),
+                f"{what}: a staged leaf sits on "
+                f"{sorted(d.platform for d in leaf.devices())}, not {platform}")
+
+
+def phase_feed(ctx: dict) -> dict:
+
+    from dmlc_core_tpu import DeviceStagingIter
+    from dmlc_core_tpu.models import QuantileBinner
+    size, platform = ctx["size"], ctx["device"]["platform"]
+    uri = str(ctx["out"] / "higgs_shaped.libsvm")
+    t0 = time.monotonic()
+    write_dataset(Path(uri), size["rows"])
+    label, index, value, counts = host_parse(uri)
+    want = content_sums(label, index, value, len(label), len(index))
+    require(want["rows"] == size["rows"], f"host parse saw {want['rows']} "
+            f"rows, wrote {size['rows']}")
+    write_parse_s = time.monotonic() - t0
+
+    # Every batch is held until the epoch is over and only then read back:
+    # the leaves were zero-copy views over native arenas that return to the
+    # pool once JAX lets go of them, so a put that aliased host memory or a
+    # DMA still in flight would show up here as another batch's bytes.
+    stage_opts = dict(batch_size=size["batch_size"], num_workers=4)
+    ctx["stage_opts"] = stage_opts
+    t0 = time.monotonic()
+    it = DeviceStagingIter(uri, **stage_opts)
+    batches = list(it)
+    it.close()
+    stage_s = time.monotonic() - t0
+    for b in batches:
+        require_on_devices(b, platform, "text feed")
+    require_same_sums(staged_sums(batches), want, "text feed")
+    require(it.max_index == FEATURES - 1,
+            f"max_index {it.max_index}, want {FEATURES - 1}")
+
+    # The binned cache's hit path puts arena views with donate=True.  Same
+    # check, against the text epoch just verified: bin codes lane for lane.
+    binner = QuantileBinner(num_bins=size["bins"], missing_aware=True)
+    binner.fit_sparse(index, value, FEATURES)
+    cache = str(ctx["out"] / "higgs_shaped.bincache")
+    binned_it = DeviceStagingIter(uri, bin_cache=cache, binner=binner,
+                                  **stage_opts)
+    list(binned_it)                      # builds the cache
+    hit = list(binned_it)                # served from it
+    require(len(hit) == len(batches), f"bincache epoch has {len(hit)} "
+            f"batches, text epoch {len(batches)}")
+    for i, (b, t) in enumerate(zip(hit, batches)):
+        require_on_devices(b, platform, "bincache feed")
+        want_bin = np.asarray(binner.transform_entries(t.index, t.value))
+        live = np.asarray((t.value != 0))
+        require(np.array_equal(np.asarray(b.emask), live)
+                and np.array_equal(np.asarray(b.ebin)[live], want_bin[live])
+                and np.array_equal(np.asarray(b.index), np.asarray(t.index))
+                and np.array_equal(np.asarray(b.label), np.asarray(t.label)),
+                f"bincache batch {i} differs from the text batch")
+
+    ctx.update(uri=uri, binner=binner, host=(label, index, value, counts),
+               first_batch=batches[0])
+    return {"rows": want["rows"], "nnz": want["nnz"],
+            "batches": len(batches),
+            "write_and_host_parse_seconds": round(write_parse_s, 2),
+            "first_epoch_seconds": round(stage_s, 2),
+            "bincache_batches": len(hit)}
+
+
+def logloss_accuracy(prob, label):
+    p = np.clip(np.asarray(prob, np.float64), 1e-7, 1 - 1e-7)
+    y = np.asarray(label) > 0.5
+    loss = float(-np.mean(np.where(y, np.log(p), np.log1p(-p))))
+    return loss, float(np.mean((p > 0.5) == y))
+
+
+def same_structure(a: dict, b: dict) -> bool:
+    return all(np.array_equal(np.asarray(a[k]), np.asarray(b[k]))
+               for k in ("feature", "threshold", "default_right"))
+
+
+def phase_train(ctx: dict) -> dict:
+    import jax
+
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.models import GBDT, QuantileBinner
+    size, uri, binner = ctx["size"], ctx["uri"], ctx["binner"]
+    label, index, value, counts = ctx["host"]
+    # On one chip the route is left to "auto" and must come out as the
+    # kernel; the rehearsal has to ask for the (interpreted) kernel by name.
+    # With several chips and no mesh declared "auto" keeps XLA scatter
+    # (pallas_call has no partitioning rule): there the kernels run in the
+    # mesh phase, under histogram_mesh.
+    routed = "pallas" if ctx["args"].rehearse_cpu else "auto"
+    expect = ("pallas" if ctx["args"].rehearse_cpu
+              or ctx["device"]["count"] == 1 else "xla")
+    config = dict(num_features=FEATURES, num_trees=size["trees"],
+                  max_depth=size["depth"], num_bins=size["bins"],
+                  missing_aware=True)
+    levels = size["trees"] * size["depth"] if expect == "pallas" else 0
+    out = {"histogram": routed, "expected_route": expect}
+
+    # (a) disk to forest: fit_streamed re-stages the file every pass
+    def streamed(histogram):
+        model = GBDT(histogram=histogram, **config)
+        t0 = time.monotonic()
+        forest = model.fit_streamed(uri, binner,
+                                    staging_options=ctx["stage_opts"])
+        jax.block_until_ready(forest)
+        return model, forest, time.monotonic() - t0
+
+    before = telemetry.counter_get("gbdt.hist_sparse_pallas")
+    model, forest, fit_s = streamed(routed)
+    require(set(model.level_backends(sparse=True)) == {expect},
+            f"fit_streamed: histogram={routed!r} resolved levels to "
+            f"{model.level_backends(sparse=True)}, want {expect}")
+    kernel_levels = telemetry.counter_get("gbdt.hist_sparse_pallas") - before
+    require(kernel_levels == levels, f"fit_streamed ran the sparse kernel "
+            f"on {kernel_levels} levels, want {levels}")
+    log(f"  fit_streamed({routed}) {fit_s:.1f}s")
+    forest_xla, xla_s = forest, 0.0
+    if expect == "pallas":
+        _, forest_xla, xla_s = streamed("xla")
+        log(f"  fit_streamed(xla) {xla_s:.1f}s")
+    scores = {}
+    for name, f in (("kernel", forest), ("xla", forest_xla)):
+        prob = model.predict_staged(f, uri, binner, **ctx["stage_opts"])
+        require(prob.shape == (size["rows"],) and np.isfinite(prob).all(),
+                f"predict_staged gave shape {prob.shape} / non-finite values")
+        scores[name] = logloss_accuracy(prob, label)
+    loss, acc = scores["kernel"]
+    require(acc > 0.8, f"fit_streamed training accuracy {acc:.4f} <= 0.8")
+    require(abs(loss - scores["xla"][0]) <= 1e-3,
+            f"fit_streamed loss {loss:.6f} vs XLA-scatter fit "
+            f"{scores['xla'][0]:.6f}")
+    out["streamed"] = {
+        "levels": model.level_backends(sparse=True), "loss": round(loss, 6),
+        "loss_xla": round(scores["xla"][0], 6), "accuracy": round(acc, 4),
+        "same_structure_as_xla": same_structure(forest, forest_xla),
+        "fit_seconds": round(fit_s, 2), "xla_fit_seconds": round(xla_s, 2)}
+    ctx.update(model=model, forest=forest, config=config)
+
+    # (b) the binned dense matrix (every row of this file has all 28)
+    require((counts == FEATURES).all(), "dataset rows are not all dense")
+    dense_binner = QuantileBinner(num_bins=size["bins"], missing_aware=True)
+    bins = dense_binner.fit_transform(value.reshape(-1, FEATURES))
+    y = jax.numpy.asarray(label)
+
+    def dense(histogram):
+        m = GBDT(histogram=histogram, **config)
+        t0 = time.monotonic()
+        f = m.fit(bins, y)
+        jax.block_until_ready(f)
+        return m, f, time.monotonic() - t0
+
+    dmodel, dforest, fit_s = dense(routed)
+    require(set(dmodel.level_backends()) == {expect},
+            f"fit: histogram={routed!r} resolved levels to "
+            f"{dmodel.level_backends()}, want {expect}")
+    log(f"  fit({routed}) {fit_s:.1f}s")
+    dforest_xla, xla_s = dforest, 0.0
+    if expect == "pallas":
+        _, dforest_xla, xla_s = dense("xla")
+        log(f"  fit(xla) {xla_s:.1f}s")
+    dloss = float(dmodel.loss(dforest, bins, y))
+    dloss_xla = float(dmodel.loss(dforest_xla, bins, y))
+    _, dacc = logloss_accuracy(dmodel.predict(dforest, bins), label)
+    require(dacc > 0.8, f"fit training accuracy {dacc:.4f} <= 0.8")
+    require(abs(dloss - dloss_xla) <= 1e-3,
+            f"fit loss {dloss:.6f} vs XLA-scatter fit {dloss_xla:.6f}")
+    out["dense"] = {
+        "levels": dmodel.level_backends(), "loss": round(dloss, 6),
+        "loss_xla": round(dloss_xla, 6), "accuracy": round(dacc, 4),
+        "same_structure_as_xla": same_structure(dforest, dforest_xla),
+        "fit_seconds": round(fit_s, 2), "xla_fit_seconds": round(xla_s, 2)}
+    ctx.update(dense_bins=np.asarray(bins), dense_loss=dloss)
+    return out
+
+
+def run_kernel(name: str, n_nodes, fn, args, reference, interpreted: bool):
+    """Lower, compile and run one jitted kernel call; hold it to its XLA
+    reference.  ``tpu_custom_call`` in the lowered module is Mosaic: the
+    interpreter lowers to plain XLA ops instead."""
+    import jax
+    lowered = fn.lower(*args)
+    mosaic = "tpu_custom_call" in lowered.as_text()
+    got = jax.block_until_ready(lowered.compile()(*args))
+    err = max(rel_err(g, w) for g, w in zip(jax.tree.leaves(got),
+                                            jax.tree.leaves(reference)))
+    row = {"kernel": name, "n_nodes": n_nodes, "interpret": not mosaic,
+           "rel_err": float(f"{err:.3g}")}
+    log(f"  {row}")
+    require(mosaic != interpreted,
+            f"{name} n_nodes={n_nodes}: "
+            + ("ran interpreted, not compiled by Mosaic" if not mosaic
+               else "compiled by Mosaic in a CPU rehearsal"))
+    require(err <= 1e-4, f"{name} n_nodes={n_nodes}: relative error "
+            f"{err:.3g} against the XLA reference")
+    return row
+
+
+def phase_kernels(ctx: dict) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.models import SparseLinearModel
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    size = ctx["size"]
+    interpreted = ps.pallas_interpret()
+    require(interpreted == ctx["args"].rehearse_cpu,
+            f"pallas_interpret() is {interpreted} on "
+            f"{ctx['device']['platform']}")
+    rows, B, F = size["kernel_rows"], size["bins"], FEATURES
+    rng = np.random.default_rng(5)
+    table = []
+
+    # segment_sum, forward and its custom VJP, inside one SGD step on a
+    # staged batch
+    batch = ctx["first_batch"]
+
+    def weights():      # non-zero, so the forward sum has something to add
+        return {"w": jnp.asarray(np.random.default_rng(7).standard_normal(
+            F).astype(np.float32)), "b": jnp.float32(0.1)}
+
+    reference = SparseLinearModel(num_features=F).train_step(weights(), batch)
+    m = SparseLinearModel(num_features=F, sdot_backend="pallas")
+    table.append(run_kernel("segment_sum(train_step)", None,
+                            jax.jit(m.train_step), (weights(), batch),
+                            reference, interpreted))
+
+    bins = jnp.asarray(rng.integers(0, B, (rows, F)).astype(np.int32))
+    gh = jnp.asarray(rng.standard_normal((rows, 2)).astype(np.float32))
+    # the sparse kernel sees the same matrix as COO entries, bin 0 left
+    # empty as transform_entries leaves it
+    rid = np.repeat(np.arange(rows, dtype=np.int32), F)
+    fi = np.tile(np.arange(F, dtype=np.int32), rows)
+    eb = rng.integers(1, B, rows * F).astype(np.int32)
+    em = np.ones(rows * F, bool)
+    layout = ps.sparse_hist_layout(rid, fi, eb, em, F, B)
+    log(f"  sparse layout: {layout.nnz_live} entries, "
+        f"max {layout.max_tiles} blocks a key tile")
+    caps = {"histogram_gh": ps.HIST_NODE_LIMIT,
+            "histogram_gh_sparse": ps.SPARSE_HIST_NODE_LIMIT}
+    for n in (1, 32, "cap"):
+        for name in ("histogram_gh", "histogram_gh_sparse"):
+            nn = caps[name] if n == "cap" else n
+            rel = jnp.asarray(rng.integers(0, nn, rows).astype(np.int32))
+            if name == "histogram_gh":
+                fn = jax.jit(functools.partial(
+                    ps.histogram_gh, n_nodes=nn, num_bins=B, force="pallas"))
+                args = (bins, rel, gh)
+                reference = ps.histogram_gh(bins, rel, gh, nn, B, force="xla")
+            else:
+                def sparse(gkey, lrid, w, ts, tc, rel, gh, nn=nn):
+                    return ps.histogram_gh_sparse_kernel(
+                        gkey, rel[lrid], gh[lrid] * w[:, None], ts, tc,
+                        nn, F, B, layout.max_tiles)
+                fn = jax.jit(sparse)
+                args = (layout.gkey, layout.rid, layout.w, layout.tstart,
+                        layout.tcount, rel, gh)
+                reference = ps.histogram_gh_sparse(
+                    jnp.asarray(rid), jnp.asarray(fi), jnp.asarray(eb),
+                    jnp.asarray(em), rel, gh, nn, F, B, force="xla")
+            t0 = time.monotonic()
+            jax.block_until_ready(reference)
+            log(f"  XLA reference for {name} n_nodes={nn}: "
+                f"{time.monotonic() - t0:.1f}s")
+            table.append(run_kernel(name, nn, fn, args, reference,
+                                    interpreted))
+    ctx["kernels"] = table
+    return {"calls": len(table), "node_caps": caps}
+
+
+def phase_checkpoint(ctx: dict) -> dict:
+
+    from dmlc_core_tpu import checkpoint
+    forest, model = ctx["forest"], ctx["model"]
+    uri = str(ctx["out"] / "forest.ckpt")
+    leaves = checkpoint.save(forest, uri)
+    back = checkpoint.load(uri, like=model.init())
+    require(sorted(back) == sorted(forest), "checkpoint keys differ")
+    for k in forest:
+        a, b = np.asarray(forest[k]), np.asarray(back[k])
+        require(a.dtype == b.dtype and np.array_equal(a, b),
+                f"checkpoint leaf {k!r} did not round-trip")
+    ctx["forest"] = back      # what is served is what was restored
+    return {"leaves": leaves, "bytes": os.path.getsize(uri)}
+
+
+def post_score(port: int, rows: list) -> dict:
+    body = json.dumps({"rows": [{"index": i, "value": v}
+                                for i, v in rows]}).encode()
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/score", data=body,
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as resp:
+        return json.loads(resp.read())
+
+
+def phase_serve(ctx: dict) -> dict:
+
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.serving import (ScoringIterator, ScoringServer,
+                                       pack_snapshot, push_snapshot)
+    size, model, forest, binner = (ctx["size"], ctx["model"], ctx["forest"],
+                                   ctx["binner"])
+    _, index, value, _ = ctx["host"]
+    index = index.reshape(-1, FEATURES)
+    value = value.reshape(-1, FEATURES)
+    requests, at = [], 0
+    for n in size["score_rows"]:
+        requests.append([(index[r].tolist(), value[r].tolist())
+                         for r in range(at, at + n)])
+        at += n
+    payload = pack_snapshot("gbdt", ctx["config"], forest, binner=binner)
+    packer = ScoringIterator(max_batch=4096)
+    worst = 0.0
+    with ScoringServer(host="127.0.0.1", port=0, http_port=0) as server:
+        verdict = push_snapshot("127.0.0.1", server.port, payload, seq=1)
+        require(verdict.get("ok"), f"snapshot push refused: {verdict}")
+        retraces = []
+        for sweep in range(2):        # the first sweep compiles each bucket
+            for rows in requests:
+                reply = post_score(server.http_port, rows)
+                require(reply.get("model") == verdict["digest"],
+                        f"/score answered from model {reply.get('model')}, "
+                        f"pushed {verdict['digest']}")
+                batch, n = packer.pack(rows)
+                want = np.asarray(model.predict_batch(forest, batch,
+                                                      binner))[:n]
+                got = np.asarray(reply["scores"], np.float32)
+                require(got.shape == want.shape,
+                        f"/score returned {got.shape} scores for {n} rows")
+                diff = float(np.max(np.abs(got - want)))
+                worst = max(worst, diff)
+                require(diff <= 1e-6, f"/score differs from predict_batch "
+                        f"by {diff:.3g} on a {n}-row request")
+            retraces.append(telemetry.counter_get("models.predict_retrace"))
+    require(retraces[1] == retraces[0],
+            f"warm /score requests retraced predict "
+            f"{retraces[1] - retraces[0]} time(s)")
+    return {"requests": 2 * len(requests),
+            "rows_per_request": list(size["score_rows"]),
+            "max_abs_diff": worst, "snapshot_bytes": len(payload),
+            "predict_retrace_after_warmup": retraces[1] - retraces[0],
+            "predict_retrace_total": retraces[1]}
+
+
+def phase_mesh(ctx: dict) -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from dmlc_core_tpu import DeviceStagingIter
+    from dmlc_core_tpu.models import GBDT
+    from dmlc_core_tpu.parallel import MeshPlan
+    size, binner = ctx["size"], ctx["binner"]
+    n = jax.device_count()
+    routed = "pallas" if ctx["args"].rehearse_cpu else "auto"
+    plan = MeshPlan.build()
+    require(plan.num_shards == n, f"MeshPlan spans {plan.num_shards} of "
+            f"{n} devices")
+    out = {"plan": plan.describe()}
+
+    # the same file, staged row-sharded: nothing piled on device 0
+    it = DeviceStagingIter(ctx["uri"], sharding=plan.data_sharding(),
+                           **ctx["stage_opts"])
+    batches = list(it)
+    it.close()
+    for b in batches:
+        for name in ("label", "weight", "index", "value"):
+            leaf = getattr(b, name)
+            shards = leaf.addressable_shards
+            require(len({s.device for s in shards}) == n
+                    and all(s.data.shape[0] * n == leaf.shape[0]
+                            for s in shards),
+                    f"staged {name} [{leaf.shape[0]}] is laid out as "
+                    f"{[(str(s.device), s.data.shape[0]) for s in shards]}")
+    label, index, value, _ = ctx["host"]
+    require_same_sums(
+        staged_sums(batches),
+        content_sums(label, index, value, len(label), len(index)),
+        "sharded feed")
+    out["sharded_batches"] = len(batches)
+
+    # both reduction routes on the level histogram's own payload
+    payload = 32 * FEATURES * size["bins"] * 2
+    x = np.random.default_rng(3).standard_normal(
+        (n, payload)).astype(np.float32)
+    xs = jax.device_put(x.reshape(-1), plan.data_sharding())
+    routes = {}
+    for strategy, op in (("flat", "all_reduce"),
+                         ("hier", "collective_permute")):
+        fn = jax.jit(plan.shard_map(
+            functools.partial(plan.allreduce, strategy=strategy),
+            in_specs=plan.row_spec, out_specs=P(), check_replication=False))
+        text = fn.lower(xs).as_text()
+        require(op in text, f"{strategy} allreduce lowered without {op}")
+        err = rel_err(fn(xs), x.sum(axis=0))
+        require(err <= 1e-5, f"{strategy} allreduce off by {err:.3g}")
+        routes[strategy] = {"op": op, "rel_err": float(f"{err:.3g}")}
+    out["allreduce"] = {"payload_bytes": payload * 4,
+                        "auto_strategy": plan.strategy_for(payload * 4),
+                        **routes}
+
+    # the same GBDT with the kernel under shard_map, one fit per route
+    require(size["rows"] % n == 0,
+            f"{size['rows']} rows do not divide over {n} devices")
+    bins = jax.device_put(ctx["dense_bins"], plan.data_sharding())
+    y = jax.device_put(label, plan.data_sharding())
+    fits = {}
+    level_bytes = [2 ** d * FEATURES * size["bins"] * 8
+                   for d in range(size["depth"])]
+    for collective in ("auto", "flat"):
+        p = MeshPlan.build(collective=collective)
+        m = GBDT(histogram=routed, histogram_mesh=p, **ctx["config"])
+        require(set(m.level_backends()) == {"pallas"},
+                f"mesh fit resolved levels to {m.level_backends()}")
+        t0 = time.monotonic()
+        forest = jax.block_until_ready(m.fit(bins, y))
+        loss = float(m.loss(forest, bins, y))
+        require(abs(loss - ctx["dense_loss"]) <= 1e-3,
+                f"mesh fit ({collective}) loss {loss:.6f} vs one-device "
+                f"fit {ctx['dense_loss']:.6f}")
+        fits[collective] = {
+            "loss": round(loss, 6), "seconds": round(time.monotonic() - t0, 2),
+            "level_strategies": [p.strategy_for(b) for b in level_bytes]}
+    if not ctx["args"].rehearse_cpu:
+        require("hier" in fits["auto"]["level_strategies"],
+                "no level of the auto fit took the ppermute ring")
+    out["fit"] = fits
+
+    # the sparse kernel under shard_map, on one staged batch
+    first = batches[0]
+    sm = GBDT(histogram=routed, histogram_mesh=plan, **ctx["config"])
+    require(set(sm.level_backends(sparse=True)) == {"pallas"},
+            f"mesh fit_batch resolved {sm.level_backends(sparse=True)}")
+    f_mesh = jax.device_get(sm.fit_batch(first, binner))
+    f_one = GBDT(histogram="xla", **ctx["config"]).fit_batch(
+        ctx["first_batch"], binner)
+    p_mesh = np.asarray(sm.predict_batch(f_mesh, ctx["first_batch"], binner))
+    p_one = np.asarray(sm.predict_batch(f_one, ctx["first_batch"], binner))
+    y0 = np.asarray(ctx["first_batch"].label)
+    l_mesh, l_one = (logloss_accuracy(p, y0)[0] for p in (p_mesh, p_one))
+    require(abs(l_mesh - l_one) <= 1e-3, f"mesh fit_batch loss {l_mesh:.6f} "
+            f"vs one-device XLA fit_batch {l_one:.6f}")
+    out["fit_batch"] = {"loss": round(l_mesh, 6), "loss_xla": round(l_one, 6)}
+
+    # every family's sharded step against its single-device run
+    with contextlib.redirect_stdout(sys.stderr):
+        import __graft_entry__
+        __graft_entry__.dryrun_multichip(n)
+    out["dryrun_multichip"] = n
+    return out
+
+
+PHASES = [("device", phase_device), ("native", phase_native),
+          ("feed", phase_feed), ("train", phase_train),
+          ("kernels", phase_kernels), ("checkpoint", phase_checkpoint),
+          ("serve", phase_serve), ("mesh", phase_mesh)]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny sizes, interpreted kernels, CPU backend "
+                         "(needs JAX_PLATFORMS=cpu); proves nothing about "
+                         "a device")
+    ap.add_argument("--expect-devices", type=int, default=None,
+                    help="fail unless JAX finds exactly this many devices")
+    ap.add_argument("--out", default=str(HERE / "chiprun_out" / "chip_smoke"),
+                    help="directory for the data, the checkpoint and "
+                         "summary.json (nothing is written elsewhere)")
+    args = ap.parse_args()
+    out = Path(args.out).resolve()
+    out.mkdir(parents=True, exist_ok=True)
+    ctx = {"args": args, "out": out,
+           "size": TINY if args.rehearse_cpu else FULL}
+    summary = {"ok": False, "rehearsal": args.rehearse_cpu, "phases": {}}
+    failed = None
+    meter = CompileMeter()
+    try:
+        for name, fn in PHASES:
+            if name == "mesh" and ctx["device"]["count"] < 2:
+                summary["phases"][name] = {"ok": True, "skipped": "1 device"}
+                continue
+            log(f"phase {name} ...")
+            t0, mark = time.monotonic(), meter.snapshot()
+            try:
+                detail = fn(ctx)
+            except Exception as exc:  # noqa: BLE001 — reported, then exit 1
+                traceback.print_exc(file=sys.stderr)
+                failed = f"{name}: {type(exc).__name__}: {exc}"
+                summary["phases"][name] = {
+                    "ok": False, "seconds": round(time.monotonic() - t0, 2),
+                    "error": failed[-2000:]}
+                break
+            summary["phases"][name] = {
+                "ok": True, "seconds": round(time.monotonic() - t0, 2),
+                **meter.since(mark), **detail}
+            log(f"phase {name} ok: {summary['phases'][name]}")
+    finally:
+        # the generated inputs are large and reproducible from the seed
+        for p in out.glob("higgs_shaped.*"):
+            p.unlink()
+    for key in ("device", "versions", "kernels"):
+        if key in ctx:
+            summary[key] = ctx[key]
+    if "cache" in ctx:
+        cache = ctx["cache"]
+        cache["entries_added"] = (cache_entries(cache["dir"])
+                                  - cache.pop("entries_before"))
+        summary["cache"] = cache
+    # set-up (native build, getting executables) apart from the rest
+    setup = {"native_build_seconds": ctx.get("native", {}).get(
+        "build_seconds", 0.0), **meter.since((0.0, 0, 0))}
+    summary["setup"] = setup
+    summary["seconds"] = round(time.monotonic() - T0, 2)
+    summary["steady_seconds"] = round(
+        summary["seconds"] - setup["native_build_seconds"]
+        - setup["compile_seconds"], 2)
+    summary["ok"] = failed is None
+    (out / "summary.json").write_text(json.dumps(summary, indent=1) + "\n")
+    if failed is not None:
+        log(f"FAILED in phase {failed}")
+        return 1
+    print(json.dumps(summary))
+    # the last line is the verdict alone: "ok" and the device, nothing more
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

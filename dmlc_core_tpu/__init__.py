@@ -11,9 +11,10 @@ Layers (mirrors SURVEY.md §1, rebuilt TPU-first):
     the DMLC_* env bootstrap onto jax.distributed;
   * `tracker`: dmlc-submit job launch + rabit-compatible rendezvous.
 """
-from . import (checkpoint, data, faultinject, io, models, ops, parallel,
-               telemetry, timer)
-from ._native import NativeError, version as native_version
+from . import (checkpoint, compile_cache, data, faultinject, io, models, ops,
+               parallel, telemetry, timer)
+from ._native import (NativeError, build_info as native_build_info,
+                      version as native_version)
 from .data import (BinnedBatch, BinnedRowIter, BinnedStagingIter,
                    DeviceStagingIter, PaddedBatch, Parser, RecordBatch,
                    RecordStagingIter, RowBlock, build_bin_cache)
@@ -22,9 +23,9 @@ from .io import (FileInfo, InputSplit, RecordIOReader, RecordIOWriter,
 
 __version__ = "0.1.0"
 __all__ = [
-    "checkpoint", "data", "faultinject", "io", "models", "ops", "parallel",
-    "telemetry", "timer",
-    "NativeError", "native_version",
+    "checkpoint", "compile_cache", "data", "faultinject", "io", "models",
+    "ops", "parallel", "telemetry", "timer",
+    "NativeError", "native_build_info", "native_version",
     "DeviceStagingIter", "PaddedBatch", "Parser", "RowBlock",
     "RecordBatch", "RecordStagingIter",
     "BinnedBatch", "BinnedRowIter", "BinnedStagingIter", "build_bin_cache",

@@ -7,18 +7,21 @@ pythonic wrappers live in `dmlc_core_tpu.io` and `dmlc_core_tpu.data`.
 
 Resolution order for the library path:
   1. $DMLCTPU_LIBRARY_PATH
-  2. <repo>/build/libdmlctpu.so
+  2. <repo>/build/libdmlctpu.so — built with cmake+ninja when it is missing
+     or older than cpp/ (``build/`` is not committed, so a checkout and a
+     copied working tree must not end up running different libraries)
   3. alongside this package (wheel layout)
-If absent, it is built on demand with cmake+ninja (dev convenience).
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import time
 from pathlib import Path
 
 _REPO_ROOT = Path(__file__).resolve().parent.parent
+_REPO_SO = _REPO_ROOT / "build" / "libdmlctpu.so"
 
 
 class RowBlockC(ctypes.Structure):
@@ -34,14 +37,6 @@ class RowBlockC(ctypes.Structure):
         ("index", ctypes.POINTER(ctypes.c_uint64)),
         ("value", ctypes.POINTER(ctypes.c_float)),
     ]
-
-
-def _candidate_paths():
-    env = os.environ.get("DMLCTPU_LIBRARY_PATH")
-    if env:
-        yield Path(env)
-    yield _REPO_ROOT / "build" / "libdmlctpu.so"
-    yield Path(__file__).resolve().parent / "libdmlctpu.so"
 
 
 def _lock_handle():
@@ -76,51 +71,80 @@ def _build_direct(build_dir: Path, so: Path) -> None:
                            f"rc={proc.returncode}):\n{proc.stderr[-2000:]}")
 
 
-def _build_native() -> Path:
-    build_dir = _REPO_ROOT / "build"
-    so = build_dir / "libdmlctpu.so"
+def _repo_so_current() -> bool:
+    """build/libdmlctpu.so exists and no native source is newer than it."""
+    if not _REPO_SO.exists():
+        return False
+    built = _REPO_SO.stat().st_mtime
+    sources = [_REPO_ROOT / "CMakeLists.txt"]
+    for sub in ("cpp/src", "cpp/include"):
+        sources += (p for p in (_REPO_ROOT / sub).rglob("*") if p.is_file())
+    return all(p.stat().st_mtime <= built for p in sources)
+
+
+def _build_native() -> float | None:
+    """Bring build/libdmlctpu.so up to date with cpp/ (an incremental ninja
+    build: only what changed recompiles); returns the seconds it took, None
+    when another process got there first."""
     import fcntl
     import shutil
     with _lock_handle() as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
-        if so.exists():  # another process built it while we waited
-            return so
+        if _repo_so_current():  # another process built it while we waited
+            return None
+        t0 = time.monotonic()
+        build_dir = _REPO_SO.parent
         if shutil.which("cmake") is None or shutil.which("ninja") is None:
-            _build_direct(build_dir, so)
-            return so
-        for cmd in (["cmake", "-B", str(build_dir), "-G", "Ninja",
-                     "-DCMAKE_BUILD_TYPE=Release"],
-                    ["ninja", "-C", str(build_dir), "dmlctpu"]):
-            proc = subprocess.run(cmd, cwd=_REPO_ROOT, capture_output=True,
-                                  text=True)
-            if proc.returncode != 0:
-                # surface the compiler/linker output: an opaque import
-                # failure here makes EVERY Python entry point undiagnosable
-                raise RuntimeError(
-                    f"native build failed ({' '.join(cmd[:2])}, "
-                    f"rc={proc.returncode}):\n{proc.stderr[-2000:]}")
-    return so
+            _build_direct(build_dir, _REPO_SO)
+        else:
+            for cmd in (["cmake", "-B", str(build_dir), "-G", "Ninja",
+                         "-DCMAKE_BUILD_TYPE=Release"],
+                        ["ninja", "-C", str(build_dir), "dmlctpu"]):
+                proc = subprocess.run(cmd, cwd=_REPO_ROOT,
+                                      capture_output=True, text=True)
+                if proc.returncode != 0:
+                    # surface the compiler/linker output: an opaque import
+                    # failure here makes EVERY Python entry point
+                    # undiagnosable
+                    raise RuntimeError(
+                        f"native build failed ({' '.join(cmd[:2])}, "
+                        f"rc={proc.returncode}):\n{proc.stderr[-2000:]}")
+        # a source ninja had no reason to rebuild for (a touched header
+        # nothing includes) leaves the old mtime: stamp the library so the
+        # next import does not come back here
+        os.utime(_REPO_SO)
+        return time.monotonic() - t0
 
 
-def _load() -> ctypes.CDLL:
+def _load():
+    """-> (CDLL, path, build seconds or None when nothing was built)."""
     import fcntl
-    # Shared lock around the exists-check + dlopen: a concurrent rebuild
-    # relinks the .so non-atomically, and CDLL on the half-written file
-    # fails with an invalid-ELF OSError.  Held only while loading; released
-    # before _build_native takes its exclusive lock (flock via a second fd
-    # in the same process would otherwise self-deadlock).
+    env = os.environ.get("DMLCTPU_LIBRARY_PATH")
+    wheel_so = Path(__file__).resolve().parent / "libdmlctpu.so"
+    in_checkout = (_REPO_ROOT / "CMakeLists.txt").exists()
+    build_seconds = None
+    if env and Path(env).exists():
+        path = Path(env)
+    elif in_checkout:
+        path = _REPO_SO
+        if not _repo_so_current():
+            build_seconds = _build_native()
+    elif wheel_so.exists():
+        path = wheel_so
+    else:
+        raise OSError("libdmlctpu.so not found: set DMLCTPU_LIBRARY_PATH or "
+                      "import from a source checkout (which builds it)")
+    # Shared lock around dlopen: a concurrent rebuild relinks the .so
+    # non-atomically, and CDLL on the half-written file fails with an
+    # invalid-ELF OSError.  Taken only after _build_native released its
+    # exclusive lock (flock via a second fd in the same process would
+    # otherwise self-deadlock).
     with _lock_handle() as lock:
         fcntl.flock(lock, fcntl.LOCK_SH)
-        for path in _candidate_paths():
-            if path.exists():
-                return ctypes.CDLL(str(path))
-    so = _build_native()
-    with _lock_handle() as lock:
-        fcntl.flock(lock, fcntl.LOCK_SH)
-        return ctypes.CDLL(str(so))
+        return ctypes.CDLL(str(path)), path, build_seconds
 
 
-_LIB = _load()
+_LIB, _LIB_PATH, _BUILD_SECONDS = _load()
 
 # ---- signatures -------------------------------------------------------------
 _LIB.DmlcTpuGetLastError.argtypes = []
@@ -265,6 +289,14 @@ def lib() -> ctypes.CDLL:
 
 def version() -> str:
     return _LIB.DmlcTpuVersion().decode()
+
+
+def build_info() -> dict:
+    """Which library this process loaded, and whether importing the package
+    had to (re)build it: ``{"library", "version", "built", "build_seconds"}``."""
+    return {"library": str(_LIB_PATH), "version": version(),
+            "built": _BUILD_SECONDS is not None,
+            "build_seconds": round(_BUILD_SECONDS or 0.0, 2)}
 
 
 def set_default_parse_threads(nthread: int) -> None:

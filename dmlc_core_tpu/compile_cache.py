@@ -1,0 +1,43 @@
+"""Where compiled XLA programs are kept between runs.
+
+A GBDT fit compiles one program per tree level and serving one per request
+bucket; without a persistent cache every process start pays all of them
+again.  ``configure()`` is called by each entry point that compiles
+(``chip_smoke.py``, the examples, ``bench.py``'s device child, the scoring
+server's ``main``) before its first jit — never at package import, so a
+library user's own cache settings are left alone.
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+_CHECKOUT = Path(__file__).resolve().parent.parent
+
+
+def configure() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    The directory is placed from outside: where ``JAX_COMPILATION_CACHE_DIR``
+    is set JAX reads it itself and no directory is set in code; otherwise
+    the cache lives at ``<checkout>/.jax_cache`` — a fixed path, because the
+    path is part of what a run has to find again.  Every program is kept,
+    however quick its compile: with JAX's one-second floor a program near
+    the floor is cached on one run and not on the next, and a warm start
+    could not be told from a cold one by counting entries.
+
+    A process held to the CPU platform (``JAX_PLATFORMS=cpu``: the tests, a
+    rehearsal) caches nothing.  An XLA:CPU executable is tied to the CPU
+    that compiled it — its loader logs the whole feature list on every hit
+    and warns of SIGILL — and this directory travels with the checkout.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_CHECKOUT / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    if jax.config.jax_platforms == "cpu":
+        jax.config.update("jax_enable_compilation_cache", False)
+    return jax.config.jax_compilation_cache_dir

@@ -289,19 +289,8 @@ def _device_put_maybe_donated(leaves, shardings=None, donate: bool = True):
     repacker hands each batch fresh arena views), so donation is safe;
     arena recycling stays correct because jax holds the leaf references
     until the async transfer completes, which pins the arena finalizer.
-    Falls back to a plain put when ``donate`` is off
-    (``DMLCTPU_BINCACHE_DONATE=0``) or the installed jax predates the
-    ``donate=`` keyword."""
-    if donate:
-        try:
-            if shardings is None:
-                return jax.device_put(leaves, donate=True)
-            return jax.device_put(leaves, shardings, donate=True)
-        except TypeError:  # jax without device_put(donate=)
-            pass
-    if shardings is None:
-        return jax.device_put(leaves)
-    return jax.device_put(leaves, shardings)
+    ``donate`` is off under ``DMLCTPU_BINCACHE_DONATE=0``."""
+    return jax.device_put(leaves, shardings, donate=donate)
 
 
 def _multihost_rounds(native, payload_len: int, pack):

@@ -420,6 +420,8 @@ def main(argv=None) -> None:
                         help=f"bin cache directory (or ${CACHE_DIR_ENV})")
     args = parser.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    from dmlc_core_tpu.parallel import pin_host_only
+    pin_host_only()
     worker = StagingWorker(host=args.host, port=args.port,
                            cache_dir=args.cache_dir)
     print(f"DATASERVICE_READY {worker.host}:{worker.port}", flush=True)

@@ -43,7 +43,8 @@ import numpy as np
 
 from .. import telemetry
 from .common import count_predict_retrace
-from ..ops.pallas_segment import (histogram_gh, histogram_gh_sparse_kernel,
+from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
+                                  histogram_gh, histogram_gh_sparse_kernel,
                                   segment_sum, sparse_hist_layout)
 
 
@@ -595,52 +596,37 @@ class GBDT:
         self._grad_hess = (_logistic_grad_hess if objective == "logistic"
                            else _squared_grad_hess)
 
-    # "auto" caps the Pallas histogram at this many nodes per level.  The
-    # histogram-as-matmul kernel's compare work is independent of n_nodes
-    # (O(rows*F*bins)); what grows with depth is its MXU M axis and its
-    # VMEM blocks (A tile [ROW, 2*n_pad], out tile [2*n_pad, KEY_TILE]) —
-    # both linear in n_nodes regardless of num_bins, so the cap is on
-    # n_nodes, not n_nodes*num_bins.  Measured on TPU v5e at 256 bins the
-    # kernel beats XLA scatter-add at every level through n_nodes=512
-    # (2.2-8.2x, see ops.histogram_gh); the cap marks the edge of measured
-    # territory (~2 MB of VMEM tiles) rather than an observed crossover.
-    _PALLAS_NODE_LIMIT = 512
-
-    def _hist_impl(self, n_nodes: int) -> str:
+    def _hist_impl(self, n_nodes: int,
+                   node_limit: int = HIST_NODE_LIMIT) -> str:
         """Histogram backend for a level with ``n_nodes`` nodes.  Resolved
         lazily (never in __init__: touching jax.default_backend() there
         would initialize the backend as a constructor side effect, breaking
         construct-before-jax.distributed.initialize programs).  Explicit
-        "xla"/"pallas" always wins; "auto" = the Pallas kernel on a
-        SINGLE-device TPU inside its measured-win envelope (it beat XLA
-        scatter-add at every measured level, 2.2-8.2x — see
-        ops.histogram_gh), XLA elsewhere.  Multi-device
-        meshes stay on XLA by default: the sharded fit path relies on
-        ``segment_sum`` being GSPMD-partitionable so the compiler inserts
-        the histogram psum (the rabit-allreduce analogue); ``pallas_call``
-        has no partitioning rule, so GSPMD cannot route a row-sharded fit
-        into the kernel.  The supported multi-device kernel route is the
-        explicit one: construct with ``histogram_mesh=(mesh, axis)`` and
-        ``_level_histogram`` runs the kernel per-device under shard_map
-        with an explicit psum (proven by tests/test_pallas.py's
-        shardmap_psum case; fit parity by test_gbdt.py's
-        sharded_pallas_fit case).  Off-TPU pallas interpret mode is a
-        correctness tool, not an execution path."""
+        "xla"/"pallas" always wins; "auto" = the Pallas kernel on a TPU
+        while the level fits the kernel's node cap
+        (``ops.pallas_segment.HIST_NODE_LIMIT``), XLA scatter-add elsewhere.
+        Without ``histogram_mesh`` that holds on a SINGLE device only: the
+        sharded fit path relies on ``segment_sum`` being GSPMD-partitionable
+        so the compiler inserts the histogram psum (the rabit-allreduce
+        analogue), and ``pallas_call`` has no partitioning rule.  The
+        multi-device kernel route is the explicit one: construct with
+        ``histogram_mesh=`` and ``_level_histogram`` runs the kernel
+        per-device under shard_map with the plan's allreduce."""
         if self.histogram != "auto":
             return self.histogram
-        if self.histogram_mesh is not None:
-            # explicit shard_map route declared: multi-device no longer
-            # disqualifies the kernel — only backend and the measured
-            # node-limit envelope do
-            if (jax.default_backend() == "tpu"
-                    and n_nodes <= self._PALLAS_NODE_LIMIT):
-                return "pallas"
-            return "xla"
-        if (jax.default_backend() == "tpu"
-                and jax.device_count() == 1
-                and n_nodes <= self._PALLAS_NODE_LIMIT):
+        if (jax.default_backend() == "tpu" and n_nodes <= node_limit
+                and (self.histogram_mesh is not None
+                     or jax.device_count() == 1)):
             return "pallas"
         return "xla"
+
+    def level_backends(self, sparse: bool = False) -> list:
+        """The histogram backend ("pallas" or "xla") each level of a fit
+        resolves to on this process's devices, root level first —
+        ``sparse=True`` for the COO builders (``fit_batch`` /
+        ``fit_streamed``), else the dense ``fit``."""
+        impl = self._hist_impl_sparse if sparse else self._hist_impl
+        return [impl(2 ** d) for d in range(self.max_depth)]
 
     def _level_histogram(self, bins_i: jax.Array, rel: jax.Array,
                          gh: jax.Array, n_nodes: int) -> jax.Array:
@@ -719,33 +705,10 @@ class GBDT:
                                       bins_i, rel, gh)
         return histogram_gh(bins_i, rel, gh, n_nodes, B, force=impl)
 
-    # The sparse-kernel analogue of _PALLAS_NODE_LIMIT.  The sparse
-    # kernel's compare work is O(nnz * KEY_TILE) — independent of n_nodes
-    # AND of F (the feature-sorted span table means a key tile never sees
-    # another feature's entries) — so, exactly like the dense kernel, the
-    # only thing that grows with depth is the MXU M axis and the VMEM
-    # tiles (A [NNZ_TILE, 2*n_pad], out [2*n_pad, KEY_TILE]).  Same cap,
-    # same rationale: the edge of measured territory, not a crossover.
-    _SPARSE_PALLAS_NODE_LIMIT = 512
-
     def _hist_impl_sparse(self, n_nodes: int) -> str:
-        """Sparse-histogram backend for a level: `_hist_impl`'s resolution
-        rule against the sparse node cap.  Explicit "xla"/"pallas" wins;
-        "auto" = the kernel on a single-device TPU (or any TPU mesh when
-        the explicit ``histogram_mesh`` shard_map route is declared)
-        within the cap, XLA scatter elsewhere."""
-        if self.histogram != "auto":
-            return self.histogram
-        if self.histogram_mesh is not None:
-            if (jax.default_backend() == "tpu"
-                    and n_nodes <= self._SPARSE_PALLAS_NODE_LIMIT):
-                return "pallas"
-            return "xla"
-        if (jax.default_backend() == "tpu"
-                and jax.device_count() == 1
-                and n_nodes <= self._SPARSE_PALLAS_NODE_LIMIT):
-            return "pallas"
-        return "xla"
+        """Sparse-histogram backend for a level: `_hist_impl`'s rule
+        against the sparse kernel's node cap."""
+        return self._hist_impl(n_nodes, SPARSE_HIST_NODE_LIMIT)
 
     def _sparse_layout_enabled(self, streamed: bool = False) -> bool:
         """Whether this fit's configuration can route any level through the
@@ -753,15 +716,9 @@ class GBDT:
         build a layout.  Checked *before* entry arrays exist (streamed
         fits use it to decide whether pass 0 should accumulate the global
         entry arrays the sort needs)."""
-        if self.histogram == "xla":
-            return False
         if streamed and self.histogram_mesh is not None:
             return False
-        if self.histogram == "auto" and not any(
-                self._hist_impl_sparse(2 ** d) == "pallas"
-                for d in range(self.max_depth)):
-            return False
-        return True
+        return "pallas" in self.level_backends(sparse=True)
 
     def _sparse_fit_layout(self, row_id, findex, ebin, emask, rows: int,
                            streamed: bool = False):

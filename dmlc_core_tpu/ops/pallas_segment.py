@@ -16,8 +16,8 @@ stages) and scatter serialization dominates, and it exists as the template
 for fusing more per-entry math into the reduction.
 
 ``segment_sum(..., force=...)`` picks the implementation; the default
-keeps XLA's scatter.  On non-TPU backends the kernel runs in interpret
-mode (tests exercise it on the CPU mesh).
+keeps XLA's scatter.  On non-TPU backends the kernels run in interpret
+mode (``pallas_interpret``; tests exercise them on the CPU mesh).
 
 Sparse histogram
 ----------------
@@ -62,6 +62,14 @@ def check_force(force, what: str = "backend") -> None:
     if force not in VALID_FORCE:
         raise ValueError(f"unknown {what} force={force!r} "
                          f"(want one of {VALID_FORCE})")
+
+
+def pallas_interpret() -> bool:
+    """Whether the three kernels here run through the Pallas interpreter
+    (a correctness tool: plain XLA ops, no Mosaic) instead of being
+    compiled for the chip.  The one place that decides it: interpreted
+    exactly when the default backend is not a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _seg_kernel(row_id_ref, contrib_ref, out_ref):
@@ -163,6 +171,22 @@ _segment_sum_pallas_diff.defvjp(_segment_sum_fwd, _segment_sum_bwd)
 
 
 _KEY_TILE = 512    # (feature, bin) key lanes per out tile
+
+# Most nodes per level the two histogram kernels are routed to by
+# ``GBDT(histogram="auto")``.  Their compare work does not depend on
+# n_nodes; what grows with it is the MXU M axis and the VMEM tiles — the A
+# tile [rows-or-entries per step, 2*n_pad], its mask and one-hot
+# temporaries, and the double-buffered out tile [2*n_pad, KEY_TILE] — so
+# each cap is the largest power of two at which Mosaic fits the kernel into
+# its 16 MiB of scoped VMEM on a v5e (PR 21, libtpu 0.0.34).  Above it the
+# compiler refuses: the dense kernel at 1024 nodes asks for 25.81M, the
+# sparse kernel (1024-entry steps against the dense kernel's 512-row ones)
+# for 21.24M at 512 — "RESOURCE_EXHAUSTED: Ran out of memory in memory
+# space vmem ... exceeded scoped vmem limit".  chip_smoke.py compiles and
+# checks both kernels at exactly these values, so a cap that stops
+# compiling fails there and not in somebody's depth-10 fit.
+HIST_NODE_LIMIT = 512
+SPARSE_HIST_NODE_LIMIT = 256
 
 
 def _hist_kernel(nb: int, fpt: int, q: int, n_pad: int,
@@ -306,18 +330,18 @@ def histogram_gh(bins: jax.Array, rel: jax.Array, gh: jax.Array,
     MXU at f32 (HIGHEST) precision — scatter-free, nothing materialized
     at [rows, F] granularity, compare work O(rows*F*bins) independent of
     n_nodes, and an M axis wide enough to use the systolic array.
-    Measured on TPU v5e (rows=100k, F=28, 256 bins) vs the XLA path:
-    2.2x at n_nodes=1, 3.6x at 32, 8.2x at 64, 2.6x at 512; max abs
-    err vs scatter-add <= 4e-6 (accumulation order only), so the
-    backends stay drop-in interchangeable.  Interpret mode off-TPU is a
-    correctness tool, not an execution path.
+    Against the XLA path on a v5e (rows=65,536, F=28, 256 bins, PR 21's
+    chip_smoke.py): max error <= 7e-7 of the largest bucket at 1, 32 and
+    512 nodes (accumulation order only), so the backends stay drop-in
+    interchangeable.  Its speed against XLA scatter has no measurement on
+    the current installation.  Interpret mode off-TPU is a correctness
+    tool, not an execution path.
     """
     check_force(force, "histogram backend")
     if force == "pallas":
-        interpret = jax.default_backend() != "tpu"
         return _histogram_gh_pallas(
             jnp.asarray(bins, jnp.int32).T, jnp.asarray(rel, jnp.int32),
-            gh, n_nodes, num_bins, interpret).astype(gh.dtype)
+            gh, n_nodes, num_bins, pallas_interpret()).astype(gh.dtype)
     rows, F = bins.shape
     feat_cols = jnp.arange(F, dtype=jnp.int32)
     keys = ((rel[:, None] * F + feat_cols[None, :]) * num_bins
@@ -565,8 +589,7 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
 
 def histogram_gh_sparse_kernel(gkey, rel_e, gh_e, tstart, tcount,
                                n_nodes: int, num_features: int,
-                               num_bins: int, max_tiles: int,
-                               interpret: bool | None = None) -> jax.Array:
+                               num_bins: int, max_tiles: int) -> jax.Array:
     """Raw kernel entry over pre-gathered per-entry arrays:
     ``rel_e = rel[layout.rid]`` (per level) and
     ``gh_e = gh[layout.rid] * layout.w[:, None]`` (per tree).  The GBDT
@@ -574,11 +597,9 @@ def histogram_gh_sparse_kernel(gkey, rel_e, gh_e, tstart, tcount,
     loop and — under ``histogram_mesh`` — so the call can sit inside a
     ``shard_map`` body next to its psum.  ``histogram_gh_sparse`` wraps it
     for one-shot use."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _histogram_gh_sparse_pallas(gkey, rel_e, gh_e, tstart, tcount,
                                        n_nodes, num_features, num_bins,
-                                       max_tiles, interpret)
+                                       max_tiles, pallas_interpret())
 
 
 def histogram_gh_sparse(row_id, findex, ebin, emask, rel, gh,
@@ -655,8 +676,7 @@ def segment_sum(contrib: jax.Array, row_id: jax.Array, num_segments: int,
     """
     check_force(force, "segment-sum backend")
     if force == "pallas":
-        interpret = jax.default_backend() != "tpu"
         out = _segment_sum_pallas_diff(contrib, row_id, num_segments,
-                                       interpret)
+                                       pallas_interpret())
         return out.astype(contrib.dtype)
     return jax.ops.segment_sum(contrib, row_id, num_segments=num_segments)

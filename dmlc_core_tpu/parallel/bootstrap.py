@@ -59,3 +59,16 @@ def init_from_env(coordinator_port_offset: int = 1) -> DmlcEnvInfo:
         process_id=info.task_id,
     )
     return info
+
+
+def pin_host_only() -> None:
+    """Declare this process host-only: a data-service staging worker, the
+    tracker, the ``dmlc-submit`` parent.  None of them ever needs an
+    accelerator, but each imports JAX (a binner's cuts are a ``jnp`` array,
+    the resource sampler asks for ``jax.devices()``), and on a TPU host the
+    first backend call would open the chips the trainer next to it owns —
+    one process per chip.  Pinned through ``jax.config`` rather than the
+    environment, which the ranks and workers started from here inherit.
+    Call at the top of ``main``, before anything can touch a backend."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")

@@ -29,24 +29,11 @@ _OPS: dict[str, Callable] = {
 
 def shard_map_compat(fn, mesh, in_specs, out_specs,
                      check_replication: bool = True):
-    """``jax.shard_map`` across jax versions: the stable location on
-    >= 0.8, the experimental fallback before.  With
-    ``check_replication=False`` the static replication check is disabled
-    under whichever flag this jax spells it (``check_vma`` stable /
-    ``check_rep`` experimental) — needed when an op's output replication
+    """``jax.shard_map`` with the static replication check switchable:
+    ``check_replication=False`` is needed when an op's output replication
     is real but not statically inferable (all_gather, pallas_call)."""
-    try:
-        from jax import shard_map  # jax >= 0.8 stable location
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-    kwargs = {}
-    if not check_replication:
-        import inspect
-        params = inspect.signature(shard_map).parameters
-        kwargs = {("check_vma" if "check_vma" in params
-                   else "check_rep"): False}
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kwargs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 def allreduce(x: jax.Array, op: str = "sum", axis_name: str = "data") -> jax.Array:

@@ -277,6 +277,8 @@ def main() -> None:
     p.add_argument("--http-port", type=int, default=None,
                    help="HTTP /score port (0 = ephemeral)")
     args = p.parse_args()
+    from .. import compile_cache
+    compile_cache.configure()
     srv = ScoringServer(host=args.host, port=args.port,
                         http_port=args.http_port)
     print(f"SCORING_READY {srv.port} {srv.http_port}", flush=True)

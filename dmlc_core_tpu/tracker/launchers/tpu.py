@@ -1,8 +1,12 @@
 """--cluster=tpu: launch ranks onto a TPU VM slice (the BASELINE north star).
 
-One rank per TPU VM host.  Hosts come from --host-file (the slice's worker
-hostnames, e.g. from `gcloud compute tpus tpu-vm list-...`) or, absent that,
-from TPU_WORKER_HOSTNAMES in the environment.  Rank assignment is
+One rank per TPU VM host, never more: a host's chips belong to one process
+(JAX opens every local chip at its first backend call, and a second process
+on the host then fails or hangs), so ``-n`` above the host count is refused.
+The one process drives all local chips through a ``MeshPlan``.  Hosts come
+from --host-file (the slice's worker hostnames, e.g. from `gcloud compute
+tpus tpu-vm list-...`) or, absent that, from TPU_WORKER_HOSTNAMES in the
+environment.  Rank assignment is
 topology-aware: the host list is kept in slice order (worker-0 …
 worker-N-1 matches the physical ICI layout), so DMLC_TASK_ID == TPU worker
 id and jax.distributed's process ids line up with ICI neighbours.
@@ -17,14 +21,11 @@ one (the tracker still serves the full rabit protocol for those).
 """
 from __future__ import annotations
 
-import logging
 import os
 import subprocess
 
 from ..submit import submit
 from ._threads import RankThreads
-
-LOGGER = logging.getLogger("dmlc_tpu.tpu")
 
 
 def slice_hosts(args) -> list:
@@ -41,8 +42,11 @@ def slice_hosts(args) -> list:
 def run(args) -> None:
     hosts = slice_hosts(args)
     if args.num_workers > len(hosts):
-        LOGGER.info("%d workers on %d hosts: multiple ranks per host",
-                    args.num_workers, len(hosts))
+        raise SystemExit(
+            f"dmlc-submit --cluster=tpu: {args.num_workers} workers on "
+            f"{len(hosts)} host(s) — one rank per TPU host: its one process "
+            "owns every local chip (use MeshPlan to drive them); list more "
+            "hosts with --host-file or TPU_WORKER_HOSTNAMES")
     ranks = RankThreads()
 
     def spawn_all(num_workers: int, num_servers: int, envs: dict) -> None:
@@ -71,7 +75,7 @@ def run(args) -> None:
                 raise RuntimeError(f"tpu worker {task_id} on {host} exited {proc.returncode}")
 
         for task_id in range(num_workers):
-            host, port = hosts[task_id % len(hosts)]
+            host, port = hosts[task_id]
             ranks.spawn(one, task_id, host, port)
 
     tracker = submit(args.num_workers, 0, spawn_all, host_ip=args.host_ip,

@@ -83,9 +83,11 @@ def submit(num_workers: int, num_servers: int, fun_submit: Callable,
 
 
 def main(argv=None) -> None:
+    from ..parallel import pin_host_only
     from . import launchers
     from .opts import parse
 
+    pin_host_only()
     args = parse(argv)
     logging.basicConfig(level=getattr(logging, args.log_level))
     launcher = launchers.get(args.cluster)

@@ -27,13 +27,6 @@ _here = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(_here))
 sys.path.insert(0, _here)  # for `from train_linear import synth_dataset`
 
-# honor JAX_PLATFORMS even where a site hook pre-imports jax with its own
-# platform preference (a no-op in standard environments)
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--data", default="/tmp/train_linear_synth.libsvm")
@@ -73,9 +66,11 @@ def main() -> None:
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from dmlc_core_tpu import compile_cache
     from dmlc_core_tpu.data import DeviceStagingIter
     from dmlc_core_tpu.models import SparseLinearModel
 
+    compile_cache.configure()
     pid, nprocs = jax.process_index(), jax.process_count()
     mesh = Mesh(np.asarray(jax.devices()), ("data",))
     sharding = NamedSharding(mesh, P("data"))

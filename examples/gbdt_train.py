@@ -23,13 +23,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# honor JAX_PLATFORMS even where a site hook pre-imports jax with its own
-# platform preference (a no-op in standard environments)
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def synth_dataset(path: str, rows: int = 50_000, dim: int = 32) -> None:
     """Sparse rows whose label is a nonlinear (XOR-style) feature rule —
     unlearnable by the linear model, easy for trees."""
@@ -87,9 +80,12 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from dmlc_core_tpu import compile_cache
     from dmlc_core_tpu.data import DeviceStagingIter
     from dmlc_core_tpu.models import GBDT, QuantileBinner
     from dmlc_core_tpu.ops.sparse import csr_to_dense, csr_to_dense_missing
+
+    compile_cache.configure()
 
     stage_kw = dict(num_workers=args.num_workers)
     if args.prefetch_depth is not None:

@@ -20,11 +20,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def synth_dataset(path: str, rows: int = 20_000, per_field: int = 8) -> int:
     """Two-field interaction problem: y = 1 iff (user + item) is even."""
     import numpy as np
@@ -48,8 +43,11 @@ def main() -> None:
     ap.add_argument("--num-factors", type=int, default=8)
     args = ap.parse_args()
 
+    from dmlc_core_tpu import compile_cache
     from dmlc_core_tpu.data import DeviceStagingIter, Parser
     from dmlc_core_tpu.models import FieldAwareFactorizationMachine
+
+    compile_cache.configure()
 
     tmp = None
     if args.data is None:

@@ -24,13 +24,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# honor JAX_PLATFORMS even where a site hook pre-imports jax with its own
-# platform preference (a no-op in standard environments)
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def synth_dataset(path: str, rows: int = 200_000, dim: int = 1000) -> None:
     """Sparse binary problem with a planted weight vector."""
     import numpy as np
@@ -59,8 +52,11 @@ def main() -> None:
     import jax
     import numpy as np
 
+    from dmlc_core_tpu import compile_cache
     from dmlc_core_tpu.data import DeviceStagingIter
     from dmlc_core_tpu.models import SparseLinearModel
+
+    compile_cache.configure()
 
     data = args.data
     if data is None:

@@ -119,6 +119,12 @@ def test_tpu_localhost_and_remote_shape(monkeypatch, tmp_path):
     assert sorted(by_host) == ["tpu-w0", "tpu-w1"]
     assert "export TPU_WORKER_ID=1" in by_host["tpu-w1"]["cmd"][6]
     assert "export TPU_WORKER_ID=0" in by_host["tpu-w0"]["cmd"][6]
+    # more ranks than hosts would put two processes on one host's chips
+    calls.clear()
+    args = parse(["--cluster=tpu", "-n", "3", "--", "python", "step.py"])
+    with pytest.raises(SystemExit, match="one rank per TPU host"):
+        tpu.run(args)
+    assert not calls
 
 
 @pytest.mark.parametrize("flavor,version_text", [

@@ -189,8 +189,7 @@ def test_histogram_gh_shardmap_psum_matches_global():
 
     # replication check off: pallas_call's out_shape carries no varying-axes
     # annotation, so the static replication check cannot see through it; the
-    # psum makes the output replicated regardless.  shard_map_compat spells
-    # the flag (check_vma/check_rep) for whichever jax is installed.
+    # psum makes the output replicated regardless.
     from dmlc_core_tpu.parallel.collective import shard_map_compat
     sharded = jax.jit(shard_map_compat(
         local_hist, mesh, in_specs=(P("data"), P("data"), P("data")),
