@@ -28,6 +28,16 @@ def configure() -> str:
     the floor is cached on one run and not on the next, and a warm start
     could not be told from a cold one by counting entries.
 
+    A program's debug information — the ``jax.named_scope`` path and the
+    source line of every instruction — is part of the key
+    (``jax_compilation_cache_include_metadata_in_key``).  JAX leaves it out
+    by default, and an executable fetched from a directory that an older
+    checkout filled then carries that checkout's metadata: a profile of it
+    shows no scope the program has gained since, and the per-scope device
+    times (doc/observability.md, "Device scope contract") read nothing on
+    exactly the warm runs.  The price: an edit that shifts the lines of a
+    file recompiles that file's programs once.
+
     A process held to the CPU platform (``JAX_PLATFORMS=cpu``: the tests, a
     rehearsal) caches nothing.  An XLA:CPU executable is tied to the CPU
     that compiled it — its loader logs the whole feature list on every hit
@@ -38,6 +48,7 @@ def configure() -> str:
                           str(_CHECKOUT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if jax.config.jax_platforms == "cpu":
         jax.config.update("jax_enable_compilation_cache", False)
     return jax.config.jax_compilation_cache_dir

@@ -574,9 +574,10 @@ class BinnedBatch:
         """COO row id per nonzero (fuses under jit); padding lanes map to
         row ``batch_size - 1`` (their emask is False, so masked compute is
         unaffected)."""
-        k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
-        r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
-        return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
+        with jax.named_scope("batch.row_ids"):
+            k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
+            r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
+            return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
 
 
 jax.tree_util.register_dataclass(
@@ -1191,8 +1192,7 @@ class BinnedStagingIter:
 
     # -- staging --------------------------------------------------------------
     def _stage(self, w: dict) -> BinnedBatch:
-        with telemetry.span("h2d.stage_binned"), \
-                jax.profiler.TraceAnnotation("dmlctpu.stage_binned"):
+        with telemetry.span("h2d.stage_binned"):
             with_qid = w["qid"] is not None
             num_rows = np.int32(w["num_rows"])
             leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
@@ -1290,4 +1290,5 @@ class BinnedStagingIter:
                 host_iter.close()
 
         yield from _staged_iter(produce_device, 2,
-                                depth_gauge="h2d.queue_depth")
+                                depth_gauge="h2d.queue_depth",
+                                device_feed=True)

@@ -76,7 +76,8 @@ def _observability_scope():
     return scope
 
 
-def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None):
+def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None,
+                 device_feed: bool = False):
     """Drive ``produce(emit)`` on a background thread, yielding emitted items
     up to ``prefetch`` ahead of the consumer.
 
@@ -89,6 +90,12 @@ def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None):
     ``depth_gauge`` names a telemetry gauge kept at the queue's occupancy —
     pipeline state for the flight recorder (a stall with the gauge pinned at
     ``prefetch`` means the consumer wedged; pinned at 0, the producer).
+
+    ``device_feed`` marks the last queue of a feed, the one whose items are
+    device batches: the consumer's blocked time in its ``get`` is the span
+    ``feed.wait`` and the counter ``h2d.consumer_wait_us`` — what the
+    training loop waited for the whole pipeline (host queues are internal
+    hand-offs, already counted as ``h2d.wait_us`` by their own consumer).
     """
     q: queue.Queue = queue.Queue(maxsize=max(prefetch, 1))
     sentinel = object()
@@ -131,7 +138,14 @@ def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None):
     reached_end = False
     try:
         while True:
-            item = q.get()
+            if device_feed:
+                t0 = time.monotonic()
+                with telemetry.span("feed.wait"):
+                    item = q.get()
+                telemetry.counter_add("h2d.consumer_wait_us",
+                                      int((time.monotonic() - t0) * 1e6))
+            else:
+                item = q.get()
             if depth_gauge is not None:
                 telemetry.gauge_set(depth_gauge, q.qsize())
             if item is sentinel:
@@ -378,9 +392,10 @@ class PaddedBatch:
         Padding lanes map to row ``batch_size - 1`` (their value is 0, so
         segment reductions are unaffected).
         """
-        k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
-        r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
-        return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
+        with jax.named_scope("batch.row_ids"):
+            k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
+            r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
+            return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
 
 
 jax.tree_util.register_dataclass(
@@ -726,8 +741,7 @@ class RecordStagingIter:
         }
 
     def _stage(self, w: dict) -> RecordBatch:
-        with telemetry.span("h2d.stage_records"), \
-                jax.profiler.TraceAnnotation("dmlctpu.stage_records"):
+        with telemetry.span("h2d.stage_records"):
             def put(arr):
                 if self._sharding is not None:
                     return jax.device_put(arr, self._sharding)
@@ -930,7 +944,8 @@ class RecordStagingIter:
             finally:
                 host_iter.close()
 
-        yield from _staged_iter(produce, 2, depth_gauge="h2d.queue_depth")
+        yield from _staged_iter(produce, 2, depth_gauge="h2d.queue_depth",
+                                device_feed=True)
 
 
 class DeviceStagingIter:
@@ -1121,11 +1136,10 @@ class DeviceStagingIter:
 
     # ---- staging ------------------------------------------------------------
     def _stage(self, w: dict) -> PaddedBatch:
-        # visible as one span per staged batch in jax profiler / xplane
-        # traces AND in the dmlctpu telemetry trace (shared steady-clock
-        # epoch with the native parse/pack spans)
-        with telemetry.span("h2d.stage_batch"), \
-                jax.profiler.TraceAnnotation("dmlctpu.stage_batch"):
+        # one span per staged batch, in the dmlctpu telemetry trace (shared
+        # steady-clock epoch with the native parse/pack spans) and, as
+        # ``dmlctpu.h2d.stage_batch``, in a running jax profiler trace
+        with telemetry.span("h2d.stage_batch"):
             return self._stage_inner(w)
 
     def _stage_inner(self, w: dict) -> PaddedBatch:
@@ -1429,4 +1443,5 @@ class DeviceStagingIter:
                 host_iter.close()
 
         yield from _staged_iter(produce_device, 2,
-                                depth_gauge="h2d.queue_depth")
+                                depth_gauge="h2d.queue_depth",
+                                device_feed=True)

@@ -347,14 +347,11 @@ class DataServiceIter:
     def _stage(self, w: dict):
         """Identical to BinnedStagingIter._stage — one donated device_put of
         the repacked host batch (bit-identity hinges on sharing this path)."""
-        import jax
-
         from dmlc_core_tpu.data.binned_cache import (BinnedBatch,
                                                      cuts_digest_of)
         from dmlc_core_tpu.data.staging import (_device_put_maybe_donated,
                                                 _replicated_sharding)
-        with telemetry.span("h2d.stage_binned"), \
-                jax.profiler.TraceAnnotation("dmlctpu.stage_binned"):
+        with telemetry.span("h2d.stage_binned"):
             with_qid = w["qid"] is not None
             num_rows = np.int32(w["num_rows"])
             leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
@@ -412,7 +409,8 @@ class DataServiceIter:
         try:
             with telemetry.span("dataservice.epoch"):
                 yield from _staged_iter(produce_device, 2,
-                                        depth_gauge="h2d.queue_depth")
+                                        depth_gauge="h2d.queue_depth",
+                                        device_feed=True)
         finally:
             telemetry.clear_trace_context()
             self._epoch += 1

@@ -8,6 +8,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
+
 
 def count_predict_retrace() -> None:
     """Bump ``models.predict_retrace`` as a TRACE-TIME side effect.
@@ -18,7 +20,6 @@ def count_predict_retrace() -> None:
     predict recompiles.  Steady-state serving must hold it at zero; see
     doc/serving.md.
     """
-    from .. import telemetry
     try:
         telemetry.counter_add("models.predict_retrace", 1)
     except Exception:  # counting must never break tracing
@@ -86,16 +87,20 @@ class SGDModelMixin:
 
     def loss(self, params: dict, batch) -> jax.Array:
         from ..ops.sparse import padded_row_mean
-        m = self.margins(params, batch)
-        if self.objective == "logistic":
-            per_row = logistic_nll(m, batch.label)  # {-1,1} or {0,1}
-        else:
-            per_row = 0.5 * (m - batch.label) ** 2
-        data_loss = padded_row_mean(per_row, batch.weight)
-        if self.l2 > 0.0:
-            data_loss = data_loss + 0.5 * self.l2 * sum(
-                jnp.sum(t ** 2) for t in self._l2_terms(params))
-        return data_loss
+        # the model's own scopes (``ffm.gather`` ...) nest inside this one;
+        # under ``value_and_grad`` the backward ops keep the forward path,
+        # as ``transpose(jvp(sgd.loss))/...``
+        with jax.named_scope("sgd.loss"):
+            m = self.margins(params, batch)
+            if self.objective == "logistic":
+                per_row = logistic_nll(m, batch.label)  # {-1,1} or {0,1}
+            else:
+                per_row = 0.5 * (m - batch.label) ** 2
+            data_loss = padded_row_mean(per_row, batch.weight)
+            if self.l2 > 0.0:
+                data_loss = data_loss + 0.5 * self.l2 * sum(
+                    jnp.sum(t ** 2) for t in self._l2_terms(params))
+            return data_loss
 
     def predict(self, params: dict, batch) -> jax.Array:
         m = self.margins(params, batch)
@@ -119,9 +124,8 @@ class SGDModelMixin:
         padded = pad_batch_to_bucket(batch, row_bucket, nnz_bucket)
         return self._predict_padded(params, padded)[:batch.batch_size]
 
-    @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
     def train_step(self, params: dict, batch) -> Tuple[dict, jax.Array]:
-        """One SGD step; returns (new_params, loss).
+        """One SGD step; returns (new_params, loss).  ``params`` is donated.
 
         Under jit with replicated params and a data-sharded batch, the
         grad reduction lowers to a psum over the mesh — the
@@ -129,8 +133,18 @@ class SGDModelMixin:
         exactly ``place_params`` + ``batch_sharding`` — the plan owns
         placement; GSPMD still owns the reduction (explicit routes use
         ``grad_allreduce``).
+
+        The host's part of a step (the dispatch of the jitted
+        ``_train_step``, or the wait for a full device queue) is the span
+        ``sgd.step``.
         """
+        with telemetry.span("sgd.step"):
+            return self._train_step(params, batch)
+
+    @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    def _train_step(self, params: dict, batch) -> Tuple[dict, jax.Array]:
         loss, grads = jax.value_and_grad(self.loss)(params, batch)
-        new_params = jax.tree.map(
-            lambda p, g: p - self.learning_rate * g, params, grads)
+        with jax.named_scope("sgd.update"):
+            new_params = jax.tree.map(
+                lambda p, g: p - self.learning_rate * g, params, grads)
         return new_params, loss

@@ -72,19 +72,24 @@ class FieldAwareFactorizationMachine(SGDModelMixin):
         # their clamped target contributes nothing anyway)
         fld = jnp.clip(batch.field, 0, A - 1)
 
-        linear = csr_matvec(params["w"], idx, val, rid, B,
-                            force=self.sdot_backend)
-        # [nnz, A, K]: entry k's factor rows toward EVERY target field
-        ve = params["v"][idx] * val[:, None, None]
-        # accumulate by (row, source field) -> S[r, a, b, :]
-        S = jax.ops.segment_sum(
-            ve, rid * A + fld, num_segments=B * A
-        ).reshape(B, A, A, self.num_factors)
-        cross = jnp.einsum("rabk,rbak->r", S, S)
-        # self-pair diagonal (i == j): x_k^2 * |v[f_k, fl_k]|^2
-        v_self = params["v"][idx, fld]                           # [nnz, K]
-        diag = jax.ops.segment_sum(
-            (val ** 2) * jnp.sum(v_self ** 2, axis=-1), rid, num_segments=B)
+        with jax.named_scope("ffm.linear"):
+            linear = csr_matvec(params["w"], idx, val, rid, B,
+                                force=self.sdot_backend)
+        with jax.named_scope("ffm.gather"):
+            # [nnz, A, K]: entry k's factor rows toward EVERY target field
+            ve = params["v"][idx] * val[:, None, None]
+        with jax.named_scope("ffm.reduce"):
+            # accumulate by (row, source field) -> S[r, a, b, :]
+            S = jax.ops.segment_sum(
+                ve, rid * A + fld, num_segments=B * A
+            ).reshape(B, A, A, self.num_factors)
+            cross = jnp.einsum("rabk,rbak->r", S, S)
+        with jax.named_scope("ffm.diag"):
+            # self-pair diagonal (i == j): x_k^2 * |v[f_k, fl_k]|^2
+            v_self = params["v"][idx, fld]                       # [nnz, K]
+            diag = jax.ops.segment_sum(
+                (val ** 2) * jnp.sum(v_self ** 2, axis=-1), rid,
+                num_segments=B)
         return linear + 0.5 * (cross - diag) + params["b"]
 
     def _l2_terms(self, params: dict) -> tuple:

@@ -651,8 +651,6 @@ class GBDT:
         columns are computed independently with the row-reduction order
         unchanged, and chunking an elementwise cross-device reduce
         reorders nothing (tests/test_meshplan.py pins this).
-        ``mesh.overlap_occupancy`` publishes the structural overlap
-        fraction (K-1)/K in permille at trace time.
         """
         from jax.sharding import PartitionSpec as P
 
@@ -687,11 +685,6 @@ class GBDT:
                 outs.append(plan.allreduce(pending))
                 return jnp.concatenate(outs, axis=1)
 
-            try:
-                telemetry.gauge_set("mesh.overlap_occupancy",
-                                    (K - 1) * 1000 // K)
-            except Exception:
-                pass
             # replication check off: pallas_call's out_shape carries no
             # varying-axes annotation, so the static check cannot see
             # through it; the allreduce replicates the output
@@ -982,27 +975,35 @@ class GBDT:
         grad_hess = grad_hess or self._grad_hess
         eval_loss_fn = eval_loss_fn or self._objective_loss
         for t_idx in range(self.num_trees):
-            g, h = grad_hess(margin, label)
-            w_t, col_mask = self._tree_sampling(root_key, t_idx, w)
-            ck = jax.random.fold_in(root_key, 1_000_000 + t_idx)
-            f, t, d, sg, sc, leaf, leaf_rel = build_tree(g * w_t, h * w_t,
-                                                         col_mask, ck)
-            margin = margin + leaf[leaf_rel]
-            feats.append(f)
-            thrs.append(t)
-            dirs.append(d)
-            sgains.append(sg)
-            scovers.append(sc)
-            leaves.append(leaf)
-            if have_eval:
-                ev_m = ev_m + eval_margin(f, t, d, leaf)
-                loss = float(eval_loss_fn(ev_m, eval_label, eval_weight))
-                if loss < best_loss:
-                    best_loss, best_t, since_best = loss, t_idx + 1, 0
-                elif early_stopping_rounds > 0:
-                    since_best += 1
-                    if since_best >= early_stopping_rounds:
-                        break
+            with telemetry.span("gbdt.tree"):
+                # these run op by op, each its own program: a profile finds
+                # them as the programs that are not jit(_build_tree); the
+                # scope names them once the round is one program
+                with jax.named_scope("gbdt.boost"):
+                    g, h = grad_hess(margin, label)
+                    w_t, col_mask = self._tree_sampling(root_key, t_idx, w)
+                    ck = jax.random.fold_in(root_key, 1_000_000 + t_idx)
+                    g, h = g * w_t, h * w_t
+                f, t, d, sg, sc, leaf, leaf_rel = build_tree(g, h,
+                                                             col_mask, ck)
+                with jax.named_scope("gbdt.boost"):
+                    margin = margin + leaf[leaf_rel]
+                feats.append(f)
+                thrs.append(t)
+                dirs.append(d)
+                sgains.append(sg)
+                scovers.append(sc)
+                leaves.append(leaf)
+                if have_eval:
+                    ev_m = ev_m + eval_margin(f, t, d, leaf)
+                    loss = float(eval_loss_fn(ev_m, eval_label,
+                                              eval_weight))
+                    if loss < best_loss:
+                        best_loss, best_t, since_best = loss, t_idx + 1, 0
+                    elif early_stopping_rounds > 0:
+                        since_best += 1
+                        if since_best >= early_stopping_rounds:
+                            break
         # truncation at the best round only when stopping was requested:
         # an eval_set alone is monitoring, not a pruning instruction
         stop_on = have_eval and early_stopping_rounds > 0
@@ -1126,6 +1127,7 @@ class GBDT:
             col_mask = jnp.ones(self.num_features, bool)
         return w_t, col_mask
 
+    @telemetry.span("gbdt.stack")
     def _stack_forest(self, params, feats, thrs, dirs, sgains, scovers,
                       leaves, trees_used: int, total: int) -> dict:
         """Null-pad the per-tree lists to ``total`` static slots (trees past
@@ -1194,36 +1196,42 @@ class GBDT:
         root_key = jax.random.PRNGKey(self.seed)
         feats, thrs, dirs, sgains, scovers, leaves = [], [], [], [], [], []
         for r in range(self.num_trees):
-            p = jax.nn.softmax(margin, axis=1)
-            if have_eval:
-                ev_round = []
-            for k in range(K):
-                t_idx = r * K + k
-                g = (p[:, k] - onehot[:, k])
-                h = jnp.maximum(p[:, k] * (1.0 - p[:, k]), 1e-16)
-                w_t, col_mask = self._tree_sampling(root_key, t_idx, w)
-                ck = jax.random.fold_in(root_key, 1_000_000 + t_idx)
-                f, t, d, sg, sc, leaf, leaf_rel = build_tree(
-                    g * w_t, h * w_t, col_mask, ck)
-                margin = margin.at[:, k].add(leaf[leaf_rel])
-                feats.append(f)
-                thrs.append(t)
-                dirs.append(d)
-                sgains.append(sg)
-                scovers.append(sc)
-                leaves.append(leaf)
+            with telemetry.span("gbdt.tree"):   # one round: K trees
+                with jax.named_scope("gbdt.boost"):
+                    p = jax.nn.softmax(margin, axis=1)
                 if have_eval:
-                    ev_round.append(eval_margin(f, t, d, leaf))
-            if have_eval:
-                ev_m = ev_m + jnp.stack(ev_round, axis=1)
-                loss = float(self._objective_loss(ev_m, eval_label,
-                                                  eval_weight))
-                if loss < best_loss:
-                    best_loss, best_round, since_best = loss, r + 1, 0
-                elif early_stopping_rounds > 0:
-                    since_best += 1
-                    if since_best >= early_stopping_rounds:
-                        break
+                    ev_round = []
+                for k in range(K):
+                    t_idx = r * K + k
+                    with jax.named_scope("gbdt.boost"):
+                        g = (p[:, k] - onehot[:, k])
+                        h = jnp.maximum(p[:, k] * (1.0 - p[:, k]), 1e-16)
+                        w_t, col_mask = self._tree_sampling(root_key, t_idx,
+                                                            w)
+                        ck = jax.random.fold_in(root_key, 1_000_000 + t_idx)
+                        g, h = g * w_t, h * w_t
+                    f, t, d, sg, sc, leaf, leaf_rel = build_tree(
+                        g, h, col_mask, ck)
+                    with jax.named_scope("gbdt.boost"):
+                        margin = margin.at[:, k].add(leaf[leaf_rel])
+                    feats.append(f)
+                    thrs.append(t)
+                    dirs.append(d)
+                    sgains.append(sg)
+                    scovers.append(sc)
+                    leaves.append(leaf)
+                    if have_eval:
+                        ev_round.append(eval_margin(f, t, d, leaf))
+                if have_eval:
+                    ev_m = ev_m + jnp.stack(ev_round, axis=1)
+                    loss = float(self._objective_loss(ev_m, eval_label,
+                                                      eval_weight))
+                    if loss < best_loss:
+                        best_loss, best_round, since_best = loss, r + 1, 0
+                    elif early_stopping_rounds > 0:
+                        since_best += 1
+                        if since_best >= early_stopping_rounds:
+                            break
         stop_on = have_eval and early_stopping_rounds > 0
         trees_used = (best_round * K if stop_on else len(feats))
         return self._stack_forest(params, feats, thrs, dirs, sgains,
@@ -1244,7 +1252,11 @@ class GBDT:
         """
         F, B = self.num_features, self.num_bins
         rows = bins.shape[0]
-        bins_i = bins.astype(jnp.int32)
+        # every phase of the level loop carries a scope (doc/observability.md,
+        # "Device scope contract"): a profile and the benchmark's per-layer
+        # metrics find device time by these names, not by %fusion.N
+        with jax.named_scope("gbdt.cast"):
+            bins_i = bins.astype(jnp.int32)
 
         node = jnp.zeros(rows, jnp.int32)  # heap id of each row's node
         mono = self.monotone_constraints is not None
@@ -1260,7 +1272,6 @@ class GBDT:
         for depth in range(self.max_depth):
             first = 2 ** depth - 1          # heap id of the level's first node
             n_nodes = 2 ** depth
-            rel = node - first              # [rows] in [0, n_nodes)
             # fused histogram build: ONE reduction over rows x features
             # carrying (grad, hess) lanes together — the key array (the
             # bandwidth bottleneck) is read once, not once per statistic.
@@ -1268,72 +1279,77 @@ class GBDT:
             # contraction kernel on TPU while the level is shallow
             # (scatter-free; see ops.histogram_gh for the layout and the
             # HBM-footprint contrast), XLA scatter-add otherwise.
-            gh = jnp.stack([grad, hess], axis=-1)  # [rows, 2]
-            hist = self._level_histogram(bins_i, rel, gh, n_nodes)
-            hist_g = hist[..., 0]
-            hist_h = hist[..., 1]
-            # left cumulative mass for "go right if bin > b" at each cut b
-            gl = jnp.cumsum(hist_g, axis=2)
-            hl = jnp.cumsum(hist_h, axis=2)
-            g_tot = gl[:, :, -1:]
-            h_tot = hl[:, :, -1:]
-            lam = self.lambda_
+            with jax.named_scope("gbdt.hist"):
+                rel = node - first          # [rows] in [0, n_nodes)
+                gh = jnp.stack([grad, hess], axis=-1)  # [rows, 2]
+                hist = self._level_histogram(bins_i, rel, gh, n_nodes)
+            with jax.named_scope("gbdt.split"):
+                hist_g = hist[..., 0]
+                hist_h = hist[..., 1]
+                # left cumulative mass for "go right if bin > b" at each cut
+                gl = jnp.cumsum(hist_g, axis=2)
+                hl = jnp.cumsum(hist_h, axis=2)
+                g_tot = gl[:, :, -1:]
+                h_tot = hl[:, :, -1:]
+                lam = self.lambda_
 
-            def split_gain(gl_, hl_):
-                gr_ = g_tot - gl_
-                hr_ = h_tot - hl_
-                g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
-                     - g_tot ** 2 / (h_tot + lam))          # [nodes, F, B]
-                ok = ((hl_ >= self.min_child_weight) &
-                      (hr_ >= self.min_child_weight))
-                return jnp.where(ok, g, -jnp.inf)
+                def split_gain(gl_, hl_):
+                    gr_ = g_tot - gl_
+                    hr_ = h_tot - hl_
+                    g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
+                         - g_tot ** 2 / (h_tot + lam))      # [nodes, F, B]
+                    ok = ((hl_ >= self.min_child_weight) &
+                          (hr_ >= self.min_child_weight))
+                    return jnp.where(ok, g, -jnp.inf)
 
-            if self.missing_aware:
-                # evaluate every cut twice from the same histograms:
-                # missing (bin 0) mass on the left (its natural cumsum
-                # side) vs on the right.  dir axis: 0 = left, 1 = right
-                # (argmax ties resolve to left, the XGBoost default).
-                dirs = [(gl, hl),
-                        (gl - hist_g[:, :, 0:1], hl - hist_h[:, :, 0:1])]
-            else:
-                dirs = [(gl, hl)]
-            gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
-            if mono:
-                wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
-                gain = self._apply_monotone(gain, wl, wr, lo, hi)
-            gain = self._collapse_dir_ties(gain)
-            node_mask = self._level_feature_mask(col_mask, col_key, depth,
-                                                 active)
-            split_f, split_b, split_d, split_g = self._pick_splits(gain,
-                                                                   node_mask)
-            if mono:
-                lo, hi = self._child_bounds(split_f, split_b, split_d,
-                                            wl, wr, lo, hi)
-            if active is not None:
-                active = self._next_active(active, split_f, split_b)
+                if self.missing_aware:
+                    # evaluate every cut twice from the same histograms:
+                    # missing (bin 0) mass on the left (its natural cumsum
+                    # side) vs on the right.  dir axis: 0 = left, 1 = right
+                    # (argmax ties resolve to left, the XGBoost default).
+                    dirs = [(gl, hl),
+                            (gl - hist_g[:, :, 0:1], hl - hist_h[:, :, 0:1])]
+                else:
+                    dirs = [(gl, hl)]
+                gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
+                if mono:
+                    wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
+                    gain = self._apply_monotone(gain, wl, wr, lo, hi)
+                gain = self._collapse_dir_ties(gain)
+                node_mask = self._level_feature_mask(col_mask, col_key,
+                                                     depth, active)
+                split_f, split_b, split_d, split_g = self._pick_splits(
+                    gain, node_mask)
+                if mono:
+                    lo, hi = self._child_bounds(split_f, split_b, split_d,
+                                                wl, wr, lo, hi)
+                if active is not None:
+                    active = self._next_active(active, split_f, split_b)
             features.append(split_f)
             thresholds.append(split_b)
             defaults.append(split_d)
             gains.append(split_g)
             covers.append(h_tot[:, 0, 0])   # node hessian mass (any f)
             # route rows: children of heap node n are 2n+1 (left), 2n+2
-            row_bin = bins_i[jnp.arange(rows), split_f[rel]]
-            go_right = row_bin > split_b[rel]
-            if self.missing_aware:
-                go_right = jnp.where(row_bin == 0,
-                                     split_d[rel] == 1, go_right)
-            node = 2 * node + 1 + go_right.astype(jnp.int32)
+            with jax.named_scope("gbdt.route"):
+                row_bin = bins_i[jnp.arange(rows), split_f[rel]]
+                go_right = row_bin > split_b[rel]
+                if self.missing_aware:
+                    go_right = jnp.where(row_bin == 0,
+                                         split_d[rel] == 1, go_right)
+                node = 2 * node + 1 + go_right.astype(jnp.int32)
 
         # leaf weights: -G/(H + lambda) per leaf, shrunken (clamped into the
         # node's propagated bounds first under monotone constraints)
         n_leaves = 2 ** self.max_depth
-        leaf_rel = node - (n_leaves - 1)
-        gh_leaf = jax.ops.segment_sum(jnp.stack([grad, hess], axis=-1),
-                                      leaf_rel, num_segments=n_leaves)
-        leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
-        if mono:
-            leaf_w = jnp.clip(leaf_w, lo, hi)
-        leaf = self.learning_rate * leaf_w
+        with jax.named_scope("gbdt.leaf"):
+            leaf_rel = node - (n_leaves - 1)
+            gh_leaf = jax.ops.segment_sum(jnp.stack([grad, hess], axis=-1),
+                                          leaf_rel, num_segments=n_leaves)
+            leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
+            if mono:
+                leaf_w = jnp.clip(leaf_w, lo, hi)
+            leaf = self.learning_rate * leaf_w
         # leaf_rel doubles as each row's final leaf assignment, so fit()
         # can update margins without re-routing every row through the tree
         return (jnp.concatenate(features), jnp.concatenate(thresholds),
@@ -1566,6 +1582,7 @@ class GBDT:
 
     # ---- public API ---------------------------------------------------------
 
+    @telemetry.span("gbdt.fit")
     def fit(self, bins: jax.Array, label: jax.Array,
             weight: Optional[jax.Array] = None,
             eval_set: Optional[tuple] = None,
@@ -1662,6 +1679,7 @@ class GBDT:
         return (rid.astype(jnp.int32), fi.astype(jnp.int32),
                 binner.transform_entries(fi, batch.value), emask)
 
+    @telemetry.span("gbdt.fit")
     def fit_batch(self, batch, binner: QuantileBinner,
                   weight: Optional[jax.Array] = None,
                   eval_set=None, early_stopping_rounds: int = 0) -> dict:
@@ -1725,6 +1743,7 @@ class GBDT:
             eval_weight=eval_weight,
             early_stopping_rounds=early_stopping_rounds)
 
+    @telemetry.span("gbdt.fit")
     def fit_streamed(self, batches, binner: QuantileBinner,
                      eval_set=None, early_stopping_rounds: int = 0,
                      staging_options: Optional[dict] = None) -> dict:
@@ -1791,18 +1810,19 @@ class GBDT:
         want_layout = self._sparse_layout_enabled(streamed=True)
         labels, weights, qids, offsets = [], [], [], [0]
         ent = ([], [], [], []) if want_layout else None
-        for b in replay():
-            if want_layout:
-                rid_b, fi_b, eb_b, em_b = self._entry_bins(b, binner)
-                ent[0].append(np.asarray(rid_b, np.int64) + offsets[-1])
-                ent[1].append(np.asarray(fi_b))
-                ent[2].append(np.asarray(eb_b))
-                ent[3].append(np.asarray(em_b))
-            labels.append(np.asarray(b.label, np.float32))
-            weights.append(np.asarray(b.weight, np.float32))
-            if b.qid is not None:
-                qids.append(np.asarray(b.qid))
-            offsets.append(offsets[-1] + int(b.label.shape[0]))
+        with telemetry.span("gbdt.stream_pass"):
+            for b in replay():
+                if want_layout:
+                    rid_b, fi_b, eb_b, em_b = self._entry_bins(b, binner)
+                    ent[0].append(np.asarray(rid_b, np.int64) + offsets[-1])
+                    ent[1].append(np.asarray(fi_b))
+                    ent[2].append(np.asarray(eb_b))
+                    ent[3].append(np.asarray(em_b))
+                labels.append(np.asarray(b.label, np.float32))
+                weights.append(np.asarray(b.weight, np.float32))
+                if b.qid is not None:
+                    qids.append(np.asarray(b.qid))
+                offsets.append(offsets[-1] + int(b.label.shape[0]))
         if not labels:
             raise ValueError("fit_streamed: the batch source is empty")
         label = jnp.asarray(np.concatenate(labels))
@@ -1820,8 +1840,11 @@ class GBDT:
             ent = None  # only the sorted layout stays resident
 
         def stream():
-            for i, b in enumerate(replay()):
-                yield offsets[i], b
+            # one pass over the batches: a re-parse unless the source is
+            # resident; max_depth + 1 of these a tree
+            with telemetry.span("gbdt.stream_pass"):
+                for i, b in enumerate(replay()):
+                    yield offsets[i], b
 
         def batch_entries(b):
             return self._entry_bins(b, binner)

@@ -58,6 +58,17 @@ _NNZ_TILE = 1024   # entries per inner step
 VALID_FORCE = (None, "xla", "pallas")
 
 
+# The kernels' names in a device trace: the TPU compiler names a Pallas
+# call's instruction ``%<name>.<n>``, and without ``name=`` takes the jitted
+# wrapper's, so renaming a wrapper would rename the kernel.  The dense
+# histogram's is matched by ``^%_histogram_gh_pallas`` in the benchmark's
+# ``layer_metrics/hist_*.json`` (tests/test_chip_names.py compiles it and
+# checks): change it only together with those files.
+SEGMENT_SUM_KERNEL = "_segment_sum_pallas"
+DENSE_HIST_KERNEL = "_histogram_gh_pallas"
+SPARSE_HIST_KERNEL = "_histogram_gh_sparse_pallas"
+
+
 def check_force(force, what: str = "backend") -> None:
     if force not in VALID_FORCE:
         raise ValueError(f"unknown {what} force={force!r} "
@@ -137,6 +148,7 @@ def _segment_sum_pallas(contrib: jax.Array, row_id: jax.Array,
         out_specs=pl.BlockSpec((lanes, _ROW_TILE), lambda rt, nt: (0, rt)),
         out_shape=jax.ShapeDtypeStruct((lanes, rows_pad), jnp.float32),
         interpret=interpret,
+        name=SEGMENT_SUM_KERNEL,
     )(row_id_p, contrib_p)
     res = out[:, :num_segments]
     return res[0] if contrib.ndim == 1 else res.T
@@ -284,10 +296,13 @@ def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
     f_pad8 = pl.cdiv(f_pad, 8) * 8
     n_pad = pl.cdiv(n_nodes, 8) * 8
     m_pad = 2 * n_pad
-    bins_p = jnp.zeros((f_pad8, rows_pad), jnp.int32).at[:F, :rows].set(bins_t)
-    rel_p = jnp.full((1, rows_pad), n_pad, jnp.int32).at[0, :rows].set(rel)
-    gh_p = jnp.zeros((2, rows_pad), jnp.float32).at[:, :rows].set(
-        gh.astype(jnp.float32).T)
+    with jax.named_scope("ops.hist_layout"):
+        bins_p = jnp.zeros((f_pad8, rows_pad), jnp.int32
+                           ).at[:F, :rows].set(bins_t)
+        rel_p = jnp.full((1, rows_pad), n_pad, jnp.int32
+                         ).at[0, :rows].set(rel)
+        gh_p = jnp.zeros((2, rows_pad), jnp.float32).at[:, :rows].set(
+            gh.astype(jnp.float32).T)
     if q == 1:
         bins_index = lambda kt, rt: ((kt * fpt) // 8, rt)   # noqa: E731
     else:
@@ -303,10 +318,12 @@ def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
         out_specs=pl.BlockSpec((m_pad, _KEY_TILE), lambda kt, rt: (0, kt)),
         out_shape=jax.ShapeDtypeStruct((m_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name=DENSE_HIST_KERNEL,
     )(bins_p, rel_p, gh_p)
-    return (out.reshape(2, n_pad, f_pad, nb)
-            [:, :n_nodes, :F, :num_bins]
-            .transpose(1, 2, 3, 0))                     # [n, F, B, 2]
+    with jax.named_scope("ops.hist_layout"):
+        return (out.reshape(2, n_pad, f_pad, nb)
+                [:, :n_nodes, :F, :num_bins]
+                .transpose(1, 2, 3, 0))                 # [n, F, B, 2]
 
 
 def histogram_gh(bins: jax.Array, rel: jax.Array, gh: jax.Array,
@@ -339,8 +356,10 @@ def histogram_gh(bins: jax.Array, rel: jax.Array, gh: jax.Array,
     """
     check_force(force, "histogram backend")
     if force == "pallas":
+        with jax.named_scope("ops.hist_layout"):
+            bins_t = jnp.asarray(bins, jnp.int32).T
         return _histogram_gh_pallas(
-            jnp.asarray(bins, jnp.int32).T, jnp.asarray(rel, jnp.int32),
+            bins_t, jnp.asarray(rel, jnp.int32),
             gh, n_nodes, num_bins, pallas_interpret()).astype(gh.dtype)
     rows, F = bins.shape
     feat_cols = jnp.arange(F, dtype=jnp.int32)
@@ -554,9 +573,10 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
     n_pad = pl.cdiv(n_nodes, 8) * 8
     m_pad = 2 * n_pad
     nblocks = nnz_pad // _NNZ_TILE
-    gkey2 = gkey.reshape(1, nnz_pad)
-    rel2 = rel_e.astype(jnp.int32).reshape(1, nnz_pad)
-    gh2 = gh_e.astype(jnp.float32).T            # [2, nnz_pad]
+    with jax.named_scope("ops.hist_layout"):
+        gkey2 = gkey.reshape(1, nnz_pad)
+        rel2 = rel_e.astype(jnp.int32).reshape(1, nnz_pad)
+        gh2 = gh_e.astype(jnp.float32).T        # [2, nnz_pad]
 
     # block index of entry inputs at step (kt, et): clamped so skipped
     # steps (et >= tcount[kt]) re-address an in-range block — a repeated
@@ -581,10 +601,12 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, k_pad), jnp.float32),
         interpret=interpret,
+        name=SPARSE_HIST_KERNEL,
     )(tstart, tcount, gkey2, rel2, gh2)
-    return (out.reshape(2, n_pad, f_pad, nb)
-            [:, :n_nodes, :num_features, :num_bins]
-            .transpose(1, 2, 3, 0))             # [n, F, B, 2]
+    with jax.named_scope("ops.hist_layout"):
+        return (out.reshape(2, n_pad, f_pad, nb)
+                [:, :n_nodes, :num_features, :num_bins]
+                .transpose(1, 2, 3, 0))         # [n, F, B, 2]
 
 
 def histogram_gh_sparse_kernel(gkey, rel_e, gh_e, tstart, tcount,

@@ -247,10 +247,13 @@ class MeshPlan:
             telemetry.counter_add("mesh.collective_bytes", nbytes)
         except Exception:
             pass
-        if strat == "flat" or self.num_shards <= 1:
-            return _OPS[op](
-                x, self.axes if len(self.axes) > 1 else self.axes[0])
-        return self._hier_allreduce(x, op)
+        # every plan-routed reduction under one scope, flat or hierarchical:
+        # collective time is read off a device trace by this name
+        with jax.named_scope("mesh.allreduce"):
+            if strat == "flat" or self.num_shards <= 1:
+                return _OPS[op](
+                    x, self.axes if len(self.axes) > 1 else self.axes[0])
+            return self._hier_allreduce(x, op)
 
     def _hier_allreduce(self, x: jax.Array, op: str) -> jax.Array:
         """Ring reduce-scatter (chip) → tree (host) → ring allgather.

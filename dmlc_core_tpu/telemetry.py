@@ -21,6 +21,7 @@ import contextlib
 import ctypes
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
@@ -232,12 +233,31 @@ def record_span(name: str, ts_us: int, dur_us: int) -> None:
                                                  int(dur_us)))
 
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation("dmlctpu.<name>")`` in a process that
+    has imported jax, else None.  Never imports jax: the tracker, the
+    dataservice workers and load-generating children stay JAX-free."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation("dmlctpu." + name)
+
+
 @contextlib.contextmanager
 def span(name: str) -> Iterator[None]:
-    """Context manager recording its body as a span when tracing is on."""
+    """One span, two sinks.  The body is recorded into the native ring
+    (steady clock; ``trace_dump()``, Perfetto) when tracing is on, and — in
+    a process where jax is already imported — also as the profiler
+    annotation ``dmlctpu.<name>``, which a running ``jax.profiler`` trace
+    puts on the device trace's clock (a flag test while none runs)."""
+    note = _profiler_annotation(name)
     t0 = now_us()
     try:
-        yield
+        if note is None:
+            yield
+        else:
+            with note:
+                yield
     finally:
         record_span(name, t0, now_us() - t0)
 

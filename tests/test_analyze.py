@@ -7,9 +7,11 @@ contract tables ship in lockstep with the code.
 
 No jax / native library needed: the analyzer is pure text analysis.
 """
+import json
+import sys
 from pathlib import Path
 
-import sys
+import pytest
 
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO / "scripts"))
@@ -194,6 +196,78 @@ def test_tracespans_green_tree(tmp_path):
         "doc/observability.md": doc,
     })
     assert tracespans.check(root) == []
+
+
+SCOPE_DOC = ("### Trace span contract\n\n"
+             "| span | where | meaning |\n|---|---|---|\n"
+             "| `good.span` | `work.py` | test |\n\n"
+             "### Device scope contract\n\n"
+             "| scope | where | covers | find it as |\n|---|---|---|---|\n"
+             "| `good.scope` | `work.py` | test | `jit(f)/good.scope/add` |\n"
+             "| `stale.scope` | `work.py` | never opened | |\n")
+SCOPE_SRC = ("import jax\nfrom . import telemetry\n"
+             "def g(x):\n"
+             "    with telemetry.span(\"good.span\"):\n"
+             "        with jax.named_scope(\"good.scope\"):\n"
+             "            x = x + 1\n"
+             "        with jax.named_scope(\"ghost.scope\"):\n"
+             "            x = x + 1\n"
+             "        with jax.named_scope(\"BadShape\"):\n"
+             "            return x + 1\n")
+
+
+def _scope_tree(tmp_path, metric_args):
+    metric = {"name": "m", "layer": "l", "reader": "trace_scope",
+              "args": metric_args}
+    other = {"name": "o", "layer": "l", "reader": "trace_events",
+             "args": {"pattern": "unlisted\\.name"}}
+    return _tree(tmp_path, {
+        "dmlc_core_tpu/work.py": SCOPE_SRC,
+        "doc/observability.md": SCOPE_DOC,
+        "benchmark/layer_metrics/m.json": json.dumps(metric),
+        "benchmark/layer_metrics/o.json": json.dumps(other),
+    })
+
+
+@pytest.mark.parametrize("path,needle,fragment", [
+    ("dmlc_core_tpu/work.py", "ghost.scope",
+     '"ghost.scope" is opened here but missing'),
+    ("dmlc_core_tpu/work.py", "BadShape", "dotted-lowercase"),
+    ("doc/observability.md", "stale.scope", "stale contract row"),
+])
+def test_device_scopes_both_directions(tmp_path, path, needle, fragment):
+    root = _scope_tree(tmp_path, {"scope": "good\\.scope"})
+    findings = tracespans.check(root)
+    text = SCOPE_SRC if path.endswith(".py") else SCOPE_DOC
+    _find(findings, path, _line(text, needle), fragment)
+    assert not any("good.scope" in f.message or "good.span" in f.message
+                   for f in findings), [f.render() for f in findings]
+    assert len(findings) == 3, [f.render() for f in findings]
+
+
+@pytest.mark.parametrize("args,unknown", [
+    ({"scope": "good\\.scope|^jit\\((?!_build_tree\\))"}, []),
+    ({"scope": "good\\.scope|lost\\.scope"}, ["lost.scope"]),
+    ({"what": "unscoped_pct", "scoped": ["good\\.scope", "gone\\.scope"]},
+     ["gone.scope"]),
+])
+def test_layer_metrics_select_only_contract_scopes(tmp_path, args, unknown):
+    findings = [f for f in tracespans.check(_scope_tree(tmp_path, args))
+                if f.path.startswith("benchmark/")]
+    assert [f.path for f in findings] == [
+        "benchmark/layer_metrics/m.json"] * len(unknown)
+    for name, f in zip(unknown, findings):
+        assert f'selects the scope "{name}"' in f.message
+
+
+def test_scopes_without_a_contract_table_are_a_finding(tmp_path):
+    root = _tree(tmp_path, {
+        "dmlc_core_tpu/work.py": SCOPE_SRC,
+        "doc/observability.md": SCOPE_DOC.split("### Device")[0],
+    })
+    findings = tracespans.check(root)
+    assert any('no "Device scope contract" table' in f.message
+               for f in findings), [f.render() for f in findings]
 
 
 def test_repo_is_green():

@@ -1,0 +1,115 @@
+"""What the benchmark finds in a device trace by name is still there after
+the TPU's compiler: the dense histogram kernel's instruction, and the scopes
+of the tree program.  Compiled here for a described v5e, not run (one file,
+one topology fixture: only one process may hold the TPU's library)."""
+import json
+import os
+import re
+from pathlib import Path
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from dmlc_core_tpu.models import GBDT
+from dmlc_core_tpu.ops import pallas_segment
+
+LAYER_METRICS = Path(__file__).resolve().parents[1] / "benchmark" / "layer_metrics"
+ROWS, FEATURES, BINS = 65536, 28, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def quiet_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one; keep it out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def on(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def instructions(compiled) -> list:
+    """``%name`` of every instruction of the compiled module, as a device
+    trace names its events."""
+    return re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = ", compiled.as_text(),
+                      re.M)
+
+
+def op_names(compiled) -> set:
+    return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+
+
+@pytest.mark.parametrize("n_nodes", [16, 32, 64])
+def test_dense_histogram_kernel_keeps_the_name_the_benchmark_matches(
+        one_chip, quiet_cache, n_nodes):
+    pattern = json.loads((LAYER_METRICS / "hist_ms_per_round.json")
+                         .read_text())["args"]["pattern"]
+    roofline = json.loads((LAYER_METRICS / "hist_roofline.json")
+                          .read_text())["args"]["pattern"]
+    assert pattern == roofline
+
+    def level(bins, rel, gh):
+        return pallas_segment._histogram_gh_pallas(
+            bins.T, rel, gh, n_nodes, BINS, False)
+
+    compiled = jax.jit(level).lower(
+        on(one_chip, (ROWS, FEATURES), jnp.int32),
+        on(one_chip, (ROWS,), jnp.int32),
+        on(one_chip, (ROWS, 2), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernels = [n for n in instructions(compiled) if re.search(pattern, n)]
+    assert len(kernels) == 1, (pattern, kernels)
+    assert kernels[0].startswith("%" + pallas_segment.DENSE_HIST_KERNEL)
+    assert any("ops.hist_layout" in n for n in op_names(compiled))
+
+
+def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(
+        one_chip, quiet_cache, monkeypatch):
+    """``gbdt.route`` and ``ops.hist_layout`` are in the ``op_name`` of
+    instructions of the compiled tree program, which is what a device
+    trace's ``tf_op`` carries; and the kernel is there under its name."""
+    # the model asks the attached backend (the CPU, here) whether to
+    # interpret its kernels; this compile is for the described chip
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+    model = GBDT(num_features=FEATURES, num_trees=1, max_depth=2,
+                 num_bins=BINS, missing_aware=True, histogram="pallas")
+    compiled = model._build_tree.lower(
+        model, on(one_chip, (ROWS, FEATURES), jnp.uint8),
+        on(one_chip, (ROWS,), jnp.float32), on(one_chip, (ROWS,), jnp.float32),
+        on(one_chip, (FEATURES,), jnp.bool_),
+        on(one_chip, (2,), jnp.uint32)).compile()
+    names = op_names(compiled)
+    for scope in ("gbdt.route", "ops.hist_layout", "gbdt.hist", "gbdt.split",
+                  "gbdt.leaf"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    kernels = [n for n in instructions(compiled)
+               if n.startswith("%" + pallas_segment.DENSE_HIST_KERNEL)]
+    assert len(kernels) == model.max_depth
+    kernel_ops = [n for n in names if n.endswith("/pallas_call")]
+    assert kernel_ops and all("/gbdt.hist/" in n for n in kernel_ops)

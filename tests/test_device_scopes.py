@@ -1,0 +1,205 @@
+"""The program names its device work and its host spans: every scope a
+``trace_scope`` layer metric reads is in the lowered programs (forward and
+backward), ``telemetry.span`` lands in both sinks, ``telemetry`` itself never
+imports jax, and a scope is part of the compile-cache key."""
+import contextlib
+import glob
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dmlc_core_tpu import compile_cache, telemetry
+from dmlc_core_tpu.data.staging import PaddedBatch
+from dmlc_core_tpu.models import GBDT
+from dmlc_core_tpu.models.ffm import FieldAwareFactorizationMachine
+from dmlc_core_tpu.parallel import MeshPlan
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "scripts"))
+
+from analyze import tracespans  # noqa: E402
+
+
+def named_scopes() -> list:
+    """Every scope the benchmark's ``trace_scope`` metrics select by, as
+    the analyzer harvests them (program selectors left out)."""
+    return sorted({scope for _path, scope in tracespans.metric_scopes(ROOT)})
+
+
+def paths_of(lowered) -> set:
+    """The scope paths (``jit(f)/a.b/op``) of a lowered program's ops."""
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+@pytest.fixture(scope="module")
+def programs() -> dict:
+    """Scope paths of the three device programs, lowered for the CPU at
+    tiny sizes: a whole GBDT fit (depth 2, the Pallas route interpreted, so
+    that the kernel wrapper's layout ops are there), one FFM ``_train_step``
+    and a plan-routed reduction."""
+    model = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=16,
+                 missing_aware=True, histogram="pallas")
+    bins = jnp.zeros((64, 4), jnp.uint8)
+    fit = jax.jit(lambda b, y: model.fit(b, y)).lower(bins, jnp.zeros(64))
+    tree = model._build_tree.lower(model, bins, jnp.zeros(64), jnp.zeros(64),
+                                   jnp.ones(4, bool), jax.random.PRNGKey(0))
+
+    rows, fields, features = 8, 3, 32
+    ffm = FieldAwareFactorizationMachine(num_features=features,
+                                         num_fields=fields)
+    nnz = rows * fields
+    batch = PaddedBatch(
+        label=jnp.zeros(rows), weight=jnp.ones(rows),
+        row_ptr=jnp.arange(rows + 1, dtype=jnp.int32) * fields,
+        index=jnp.zeros(nnz, jnp.int32), value=jnp.ones(nnz),
+        num_rows=jnp.asarray(np.int32(rows)),
+        field=jnp.asarray(np.tile(np.arange(fields, dtype=np.int32), rows)))
+    step = ffm._train_step.lower(ffm, ffm.init(0), batch)
+
+    plan = MeshPlan.build()
+    reduce = jax.jit(plan.shard_map(
+        lambda v: plan.allreduce(v, "sum"), in_specs=plan.row_spec,
+        out_specs=P(), check_replication=False)).lower(
+            jnp.zeros(plan.num_shards * 4))
+    return {"fit": paths_of(fit), "tree": paths_of(tree),
+            "step": paths_of(step), "reduce": paths_of(reduce)}
+
+
+def carries(paths: set, scope: str, under: str = "") -> bool:
+    part = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
+    return any(part.search(p) and under in p for p in paths)
+
+
+@pytest.mark.parametrize("scope", named_scopes())
+def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
+    where = {"gbdt": "fit", "ops": "fit", "batch": "step", "ffm": "step",
+             "sgd": "step", "mesh": "reduce"}[scope.split(".")[0]]
+    assert carries(programs[where], scope), (
+        f"no op of the {where} program carries the scope {scope}")
+    if scope.startswith("gbdt.") and scope != "gbdt.boost":
+        # the tree program is what the chip runs; the whole-fit lowering
+        # above only adds the driver's eager ops to it
+        assert carries(programs["tree"], scope, under="jit(_build_tree)")
+
+
+@pytest.mark.parametrize("scope", [s for s in named_scopes()
+                                   if s.startswith(("ffm.", "sgd.loss"))])
+def test_backward_ops_keep_the_forward_scope(programs, scope):
+    """One pattern on a scope takes a gather together with its scatter
+    twin: the backward pass sits under ``transpose(jvp(sgd.loss))/<scope>``."""
+    step = programs["step"]
+    assert carries(step, scope, under="jit(_train_step)/jvp(sgd.loss)")
+    assert carries(step, scope,
+                   under="jit(_train_step)/transpose(jvp(sgd.loss))")
+
+
+def test_no_metric_names_an_unknown_scope_prefix():
+    assert named_scopes(), "the benchmark names no scope at all"
+    assert {s.split(".")[0] for s in named_scopes()} <= {
+        "gbdt", "ops", "batch", "ffm", "sgd", "mesh"}
+
+
+def test_span_lands_in_the_profiler_trace_and_in_the_native_ring(tmp_path):
+    telemetry.trace_start()
+    jax.profiler.start_trace(str(tmp_path))
+    with telemetry.span("test.two_sinks"):
+        jnp.zeros(8).block_until_ready()
+    jax.profiler.stop_trace()
+    telemetry.trace_stop()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    names = {e.name for line in host.lines for e in line.events}
+    assert "dmlctpu.test.two_sinks" in names
+    if telemetry.enabled():
+        ring = {ev["name"] for ev in telemetry.trace_dump()["traceEvents"]}
+        assert "test.two_sinks" in ring
+
+
+def test_telemetry_itself_never_imports_jax():
+    """``telemetry.span`` looks jax up in ``sys.modules`` and never imports
+    it.  The package's ``__init__`` does import jax (data, models), so the
+    module is loaded here under a bare package, as a JAX-free process would
+    have to load it."""
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('dmlc_core_tpu')\n"
+        f"pkg.__path__ = [{str(ROOT / 'dmlc_core_tpu')!r}]\n"
+        "sys.modules['dmlc_core_tpu'] = pkg\n"
+        "from dmlc_core_tpu import telemetry\n"
+        "with telemetry.span('test.no_jax'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'telemetry imported jax'\n"
+        "print('clean')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "clean"
+
+
+@pytest.fixture
+def cache_in(tmp_path):
+    """The persistent compile cache on, in ``tmp_path``, as
+    ``compile_cache.configure()`` sets it up — except that the CPU platform
+    is allowed to cache for the length of the test."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_include_metadata_in_key")
+    before = {n: getattr(jax.config, n) for n in names}
+    compile_cache.configure()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+    hits = []
+
+    def listener(event, **_):
+        hits.append(event == "/jax/compilation_cache/cache_hits")
+
+    jax.monitoring.register_event_listener(listener)
+    try:
+        yield lambda: sum(hits)
+    finally:
+        jax.monitoring.unregister_event_listener(listener)
+        for n, v in before.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+
+
+def run_scoped_or_not(scoped: bool) -> None:
+    """One program, with or without a scope, always called from this very
+    line: with metadata in the key the caller's line is part of it too."""
+    def double_plus_one(x):
+        with (jax.named_scope("test.scope") if scoped
+              else contextlib.nullcontext()):
+            return x * 2.0 + 1.0
+    jax.jit(double_plus_one)(jnp.arange(8.0)).block_until_ready()
+
+
+def test_a_scope_is_part_of_the_compile_cache_key(cache_in):
+    """What the parent commit compiled must not be handed to the change:
+    it would carry the parent's metadata, and the scopes would be gone from
+    the profile on exactly the warm runs."""
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    jnp.arange(8.0).block_until_ready()     # its own programs, out of the way
+    base = cache_in()
+    hits = []
+    for scoped in (False, False, True, True):
+        run_scoped_or_not(scoped)
+        hits.append(cache_in() - base)
+    # compiled, fetched, compiled (a scope more), fetched
+    assert hits == [0, 1, 1, 2]
+    # and JAX's default would have handed the unscoped executable over
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    run_scoped_or_not(False)
+    run_scoped_or_not(True)
+    assert cache_in() - base == 3
