@@ -47,6 +47,13 @@ from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
                                   histogram_gh, histogram_gh_sparse_kernel,
                                   segment_sum, sparse_hist_layout)
 
+# Most nodes a level for which `GBDT._route_level` finds a row's split by
+# comparing its node id with every node of the level; beyond it, by one
+# gather a row from the level's packed table.  On a v5e the compares cost
+# 10.5M rows 36 ms at 4,096 nodes and 96 ms at 8,192, the gather 76 ms
+# whatever the table's size (PERF.md, PR 26).
+_ROUTE_SELECT_NODES = 4096
+
 
 class QuantileBinner:
     """Per-feature quantile binning to uint8 codes (XGBoost-hist's sketch).
@@ -1332,11 +1339,10 @@ class GBDT:
             covers.append(h_tot[:, 0, 0])   # node hessian mass (any f)
             # route rows: children of heap node n are 2n+1 (left), 2n+2
             with jax.named_scope("gbdt.route"):
-                row_bin = bins_i[jnp.arange(rows), split_f[rel]]
-                go_right = row_bin > split_b[rel]
-                if self.missing_aware:
-                    go_right = jnp.where(row_bin == 0,
-                                         split_d[rel] == 1, go_right)
+                # the same expression as ops.histogram_gh's kernel layout:
+                # XLA keeps one transpose of the bins for the whole tree
+                go_right = self._route_level(bins_i.T, rel, split_f, split_b,
+                                             split_d)
                 node = 2 * node + 1 + go_right.astype(jnp.int32)
 
         # leaf weights: -G/(H + lambda) per leaf, shrunken (clamped into the
@@ -1355,6 +1361,45 @@ class GBDT:
         return (jnp.concatenate(features), jnp.concatenate(thresholds),
                 jnp.concatenate(defaults), jnp.concatenate(gains),
                 jnp.concatenate(covers), leaf, leaf_rel)
+
+    def _route_level(self, bins_t: jax.Array, rel: jax.Array,
+                     split_f: jax.Array, split_b: jax.Array,
+                     split_d: jax.Array) -> jax.Array:
+        """Which rows of a level go to their node's right child: bool [rows].
+
+        bins_t: i32 [F, rows], feature-major; rel: [rows] in [0, n_nodes);
+        split_f / split_b / split_d: the level's [n_nodes] split tables.
+        Dense compare-and-select with rows on the lanes, integers only: a
+        per-row gather (``bins[arange(rows), split_f[rel]]``) took 22 ns a
+        row a level on a v5e whatever the table's size, a quarter of a
+        round at 10.5M rows (PERF.md, PR 26).  The node's three entries
+        travel as one word, so the pass over the nodes selects once (past
+        ``_ROUTE_SELECT_NODES`` nodes one gather of that word is cheaper);
+        the row's bin is then the one term of a sum over the features that
+        its node's feature leaves standing.
+        """
+        F = bins_t.shape[0]
+        n_nodes = split_f.shape[0]
+        # split_b reaches num_bins, the null split's sentinel
+        bbits = self.num_bins.bit_length()
+        if F.bit_length() + bbits + 1 > 31:
+            raise ValueError(f"{F} features do not pack beside "
+                             f"{self.num_bins} bins into an int32 word")
+        word = (split_f << (bbits + 1)) | (split_d << bbits) | split_b
+        if n_nodes > _ROUTE_SELECT_NODES:
+            word = word[rel]
+        elif n_nodes > 1:
+            ids = jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
+            word = jnp.sum(jnp.where(rel[None, :] == ids, word[:, None], 0),
+                           axis=0)                          # [rows]
+        feat = jnp.arange(F, dtype=jnp.int32)[:, None]
+        row_bin = jnp.sum(jnp.where((word >> (bbits + 1))[None, :] == feat,
+                                    bins_t, 0), axis=0)
+        go_right = row_bin > (word & ((1 << bbits) - 1))
+        if self.missing_aware:
+            go_right = jnp.where(row_bin == 0, ((word >> bbits) & 1) == 1,
+                                 go_right)
+        return go_right
 
     @functools.partial(jax.jit, static_argnums=0)
     def _tree_margins(self, feature: jax.Array, threshold: jax.Array,

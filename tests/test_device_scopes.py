@@ -90,6 +90,45 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
         assert carries(programs["tree"], scope, under="jit(_build_tree)")
 
 
+def gathers_under(lowered, scope: str) -> list:
+    """The index operand's shape, as a tuple, of every ``stablehlo.gather``
+    of a lowered program whose location carries ``scope``."""
+    text = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    found = []
+    for dims, loc in re.findall(
+            r'"stablehlo\.gather"\(.*: \(tensor<[^>]*>, tensor<((?:\d+x)*)i32>\)'
+            r' -> \S+ loc\((#loc\d+)\)', text):
+        if carries({names[loc]}, scope):
+            found.append(tuple(int(d) for d in dims.split("x") if d))
+    return found
+
+
+def test_route_gathers_nothing_a_row():
+    """``gbdt.route`` is still a scope of the lowered tree program, and no
+    gather under it takes an index a row (PR 26: four of them, 10.5M
+    indices each, were a quarter of a boosting round on the chip).  The
+    same reading finds them when the old routing is put back."""
+    from test_gbdt import gather_routed
+
+    rows, features = 192, 4
+    kw = dict(num_features=features, num_trees=1, max_depth=3, num_bins=16,
+              missing_aware=True, histogram="pallas")
+    args = (jnp.zeros((rows, features), jnp.uint8), jnp.zeros(rows),
+            jnp.zeros(rows), jnp.ones(features, bool), jax.random.PRNGKey(0))
+
+    def lowered(model):
+        return model._build_tree.lower(model, *args)
+
+    tree = lowered(GBDT(**kw))
+    assert carries(paths_of(tree), "gbdt.route", under="jit(_build_tree)")
+    by_row = [shape for shape in gathers_under(tree, "gbdt.route")
+              if int(np.prod(shape)) >= rows]
+    assert by_row == []
+    old = gathers_under(lowered(gather_routed(**kw)), "gbdt.route")
+    assert sum(int(np.prod(shape)) >= rows for shape in old) >= kw["max_depth"]
+
+
 @pytest.mark.parametrize("scope", [s for s in named_scopes()
                                    if s.startswith(("ffm.", "sgd.loss"))])
 def test_backward_ops_keep_the_forward_scope(programs, scope):

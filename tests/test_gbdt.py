@@ -1324,3 +1324,167 @@ def test_gbdt_histogram_env_knob(monkeypatch):
         GBDT(num_features=3)
     monkeypatch.delenv("DMLCTPU_GBDT_HISTOGRAM")
     assert GBDT(num_features=3).histogram == "auto"
+
+
+def route_level_by_gather(self, bins_t, rel, split_f, split_b, split_d):
+    """The level's routing as it stood before PR 26, kept as the reference:
+    one gather per row for its node's feature, bin threshold and default
+    direction, and one for the row's bin on that feature."""
+    rows = bins_t.shape[1]
+    row_bin = bins_t.T[jnp.arange(rows), split_f[rel]]
+    go_right = row_bin > split_b[rel]
+    if self.missing_aware:
+        go_right = jnp.where(row_bin == 0, split_d[rel] == 1, go_right)
+    return go_right
+
+
+def gather_routed(**kw) -> GBDT:
+    """A model whose tree program routes by `route_level_by_gather`."""
+    model = GBDT(**kw)
+    model._route_level = route_level_by_gather.__get__(model)
+    return model
+
+
+TREE_OUTPUTS = ("feature", "threshold", "default_right", "split_gain",
+                "split_cover", "leaf", "leaf_rel")
+
+
+def tree_inputs(seed: int, rows: int, features: int, num_bins: int) -> tuple:
+    """`_build_tree`'s arguments from a seed: bins over every code (0, the
+    missing bin, among them), normal gradients, positive hessians, every
+    column allowed."""
+    rng = np.random.default_rng(seed)
+    bins = jnp.asarray(rng.integers(0, num_bins, size=(rows, features)),
+                       jnp.uint8)
+    grad = jnp.asarray(rng.normal(size=rows), jnp.float32)
+    hess = jnp.asarray(rng.uniform(0.05, 1.0, size=rows), jnp.float32)
+    return (bins, grad, hess, jnp.ones(features, bool), jax.random.PRNGKey(3))
+
+
+def assert_trees_equal(got, want) -> None:
+    for name, a, b in zip(TREE_OUTPUTS, got, want, strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+ROUTE_CASES = [
+    # missing_aware, max_depth, F, rows, extra constructor arguments
+    (False, 1, 1, 333, {}),
+    (True, 1, 5, 333, {}),
+    (True, 1, 130, 333, {}),
+    (False, 3, 5, 333, {}),
+    (True, 3, 28, 333, {}),
+    (False, 3, 130, 333, {}),
+    (False, 6, 1, 1237, {}),
+    (False, 6, 28, 1237, {}),
+    (True, 6, 28, 1237, {}),
+    (True, 6, 130, 1237, {}),
+    (False, 8, 5, 2999, {}),
+    (True, 8, 28, 2999, {}),
+    (True, 3, 5, 333, {"monotone_constraints": [1, -1, 0, 0, 0]}),
+    (True, 6, 5, 1237, {"interaction_constraints": [[0, 1], [2, 3, 4]]}),
+]
+
+
+@pytest.mark.parametrize(
+    "missing_aware,max_depth,features,rows,extra", ROUTE_CASES,
+    ids=[f"miss{int(m)}-d{d}-F{f}" + "".join(f"-{k.split('_')[0]}" for k in e)
+         for m, d, f, _r, e in ROUTE_CASES])
+def test_route_by_select_builds_the_gather_routed_tree(
+        missing_aware, max_depth, features, rows, extra):
+    """`_route_level`'s compare-and-select is integer arithmetic on the same
+    bins, so every output of the tree program — splits, leaves and each
+    row's leaf — equals, bit for bit, the tree built with the per-row
+    gathers in its place."""
+    num_bins = 16
+    args = tree_inputs(1000 * max_depth + features, rows, features, num_bins)
+    kw = dict(num_features=features, max_depth=max_depth, num_bins=num_bins,
+              missing_aware=missing_aware, histogram="xla", **extra)
+    got = GBDT(**kw)._build_tree(*args)
+    assert_trees_equal(got, gather_routed(**kw)._build_tree(*args))
+    thr = np.asarray(got[1])
+    assert (thr < num_bins).any(), "no real split: the case routes nothing"
+    if max_depth >= 6:
+        # deep levels run out of rows: null splits (threshold == num_bins,
+        # everything left) are routed too
+        assert (thr == num_bins).any()
+    leaves = np.unique(np.asarray(got[6]))
+    assert len(leaves) > 1 and leaves.min() >= 0
+    assert leaves.max() < 2 ** max_depth
+
+
+def test_route_by_select_null_splits_keep_rows_left():
+    """Constant features offer no cut and `min_child_weight` refuses the
+    deeper ones: the null split's sentinel threshold ``num_bins`` (one more
+    than any bin code, the widest value the packed word carries) sends every
+    row of its node left, as the gathers did."""
+    rng = np.random.default_rng(7)
+    rows, features, num_bins = 517, 5, 256
+    cols = rng.integers(0, num_bins, size=(rows, features))
+    cols[:, [0, 2, 4]] = 255                  # constant, at the top code
+    bins = jnp.asarray(cols, jnp.uint8)
+    grad = jnp.asarray(rng.normal(size=rows), jnp.float32)
+    hess = jnp.ones(rows, jnp.float32)
+    kw = dict(num_features=features, max_depth=4, num_bins=num_bins,
+              missing_aware=True, min_child_weight=90.0, histogram="xla")
+    args = (bins, grad, hess, jnp.ones(features, bool), jax.random.PRNGKey(0))
+    got = GBDT(**kw)._build_tree(*args)
+    assert_trees_equal(got, gather_routed(**kw)._build_tree(*args))
+    feature, thr = np.asarray(got[0]), np.asarray(got[1])
+    null = thr == num_bins
+    assert null.any() and not null.all()
+    assert not np.isin(feature[~null], [0, 2, 4]).any()
+    # a null node's right child (heap 2n + 2) holds no row at any depth
+    leaf_rel = np.asarray(got[6])
+    node = leaf_rel + 2 ** 4 - 1
+    path = {int(n) for n in np.unique(node)}
+    for _ in range(4):
+        path |= {(n - 1) // 2 for n in path}
+    for n in np.flatnonzero(null):
+        assert 2 * int(n) + 2 not in path
+
+
+def test_route_by_select_multiclass_forest_is_identical():
+    """`_boost_multi` builds one tree a class a round through the same tree
+    program and updates its margins from `leaf_rel`: whole forests equal."""
+    rng = np.random.default_rng(19)
+    x = rng.uniform(-1, 1, size=(701, 4)).astype(np.float32)
+    y = np.where(x[:, 0] + x[:, 1] > 0.4, 2,
+                 np.where(x[:, 0] * x[:, 2] > 0, 1, 0)).astype(np.float32)
+    bins = QuantileBinner(num_bins=32).fit_transform(x)
+    kw = dict(num_features=4, num_trees=3, max_depth=3, num_bins=32,
+              learning_rate=0.4, objective="softmax", num_class=3,
+              histogram="xla")
+    got = GBDT(**kw).fit(bins, jnp.asarray(y))
+    want = gather_routed(**kw).fit(bins, jnp.asarray(y))
+    assert np.asarray(got["feature"]).shape[0] == 3 * 3
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), key)
+
+
+def test_route_past_the_select_limit_gathers_the_word(monkeypatch):
+    """Levels with more nodes than ``_ROUTE_SELECT_NODES`` look the packed
+    word up by one gather a row (the compares grow with the level, the
+    gather does not); the tree is the same on both sides of the switch."""
+    from dmlc_core_tpu.models import gbdt as gbdt_module
+
+    features, num_bins = 5, 16
+    args = tree_inputs(11, 1237, features, num_bins)
+    kw = dict(num_features=features, max_depth=5, num_bins=num_bins,
+              missing_aware=True, histogram="xla")
+    want = gather_routed(**kw)._build_tree(*args)
+    assert gbdt_module._ROUTE_SELECT_NODES >= 2 ** 4
+    assert_trees_equal(GBDT(**kw)._build_tree(*args), want)
+    monkeypatch.setattr(gbdt_module, "_ROUTE_SELECT_NODES", 2)
+    # a new model traces anew: its levels of 4, 8 and 16 nodes gather
+    assert_trees_equal(GBDT(**kw)._build_tree(*args), want)
+
+
+def test_route_word_refuses_what_does_not_pack():
+    """Feature id, default direction and bin threshold share one int32."""
+    model = GBDT(num_features=3, num_bins=256)
+    with pytest.raises(ValueError, match="int32 word"):
+        model._route_level(
+            jax.ShapeDtypeStruct((1 << 21, 8), jnp.int32), None,
+            jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32),
+            jnp.zeros(1, jnp.int32))
