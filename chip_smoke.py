@@ -43,6 +43,7 @@ import os
 import sys
 import time
 import traceback
+import urllib.error
 import urllib.request
 from pathlib import Path
 
@@ -491,12 +492,12 @@ def phase_kernels(ctx: dict) -> dict:
                 args = (bins, rel, gh)
                 reference = ps.histogram_gh(bins, rel, gh, nn, B, force="xla")
             else:
-                def sparse(gkey, lrid, w, ts, tc, rel, gh, nn=nn):
+                def sparse(gkey, lrid, ts, tc, rel, gh, nn=nn):
                     return ps.histogram_gh_sparse_kernel(
-                        gkey, rel[lrid], gh[lrid] * w[:, None], ts, tc,
+                        gkey, rel[lrid], gh[lrid].T, ts, tc,
                         nn, F, B, layout.max_tiles)
                 fn = jax.jit(sparse)
-                args = (layout.gkey, layout.rid, layout.w, layout.tstart,
+                args = (layout.gkey, layout.rid, layout.tstart,
                         layout.tcount, rel, gh)
                 reference = ps.histogram_gh_sparse(
                     jnp.asarray(rid), jnp.asarray(fi), jnp.asarray(eb),
@@ -533,8 +534,17 @@ def post_score(port: int, rows: list) -> dict:
     req = urllib.request.Request(
         f"http://127.0.0.1:{port}/score", data=body,
         headers={"Content-Type": "application/json"})
-    with urllib.request.urlopen(req, timeout=120) as resp:
-        return json.loads(resp.read())
+    t0 = time.monotonic()
+    try:
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            reply = json.loads(resp.read())
+    except urllib.error.HTTPError as e:
+        # the server's own account of the failure is in the body
+        raise Failure(f"/score answered {e.code} to a {len(rows)}-row "
+                      f"request after {time.monotonic() - t0:.1f}s: "
+                      f"{e.read()[:2000].decode('utf-8', 'replace')}")
+    log(f"  /score {len(rows)} rows {time.monotonic() - t0:.2f}s")
+    return reply
 
 
 def phase_serve(ctx: dict) -> dict:

@@ -53,7 +53,8 @@ from .._native import check, lib, NativeError
 from .. import telemetry
 from .staging import (DeviceStagingIter, _StagedBatchOwnedC,
                       _device_put_maybe_donated, _observability_scope,
-                      _pick_virtual_parts, _replicated_sharding, _staged_iter)
+                      _pick_virtual_parts, _replicated_sharding, _staged_iter,
+                      csr_row_ids)
 
 LOGGER = logging.getLogger("dmlc_core_tpu.binned_cache")
 
@@ -574,10 +575,7 @@ class BinnedBatch:
         """COO row id per nonzero (fuses under jit); padding lanes map to
         row ``batch_size - 1`` (their emask is False, so masked compute is
         unaffected)."""
-        with jax.named_scope("batch.row_ids"):
-            k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
-            r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
-            return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
+        return csr_row_ids(self.row_ptr, self.index.shape[0])
 
 
 jax.tree_util.register_dataclass(

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import functools
 import logging
 import queue
 import threading
@@ -354,6 +355,24 @@ def _multihost_rounds(native, payload_len: int, pack):
         native.close()
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def csr_row_ids(row_ptr: jax.Array, nnz_pad: int) -> jax.Array:
+    """COO row id of each of ``nnz_pad`` lanes under the CSR ``row_ptr``
+    (``[batch + 1]``, non-decreasing, ``row_ptr[0] == 0``): the count of
+    rows 1..batch that start at or before the lane, i.e.
+    ``searchsorted(row_ptr, lane, side="right") - 1``, clamped to the last
+    row for the padding lanes.  One mark a row scattered at its first lane
+    and one running sum over the lanes: O(lanes), where the binary search
+    is a gather a lane a round — at 2.18e8 lanes over 1.18M rows 0.06 s
+    against 105.6 s on a v5e (PERF.md, PR 27).  One program, so that the
+    scope reaches the device trace from an op-by-op caller too."""
+    with jax.named_scope("batch.row_ids"):
+        batch = row_ptr.shape[0] - 1
+        starts = jnp.zeros(nnz_pad, jnp.int32).at[row_ptr[1:]].add(
+            1, mode="drop")     # a row that starts past the last lane
+        return jnp.minimum(jnp.cumsum(starts), batch - 1).astype(jnp.int32)
+
+
 @dataclass
 class PaddedBatch:
     """Static-shape CSR batch (a pytree; arrays live on device after staging).
@@ -392,10 +411,7 @@ class PaddedBatch:
         Padding lanes map to row ``batch_size - 1`` (their value is 0, so
         segment reductions are unaffected).
         """
-        with jax.named_scope("batch.row_ids"):
-            k = jnp.arange(self.index.shape[0], dtype=self.row_ptr.dtype)
-            r = jnp.searchsorted(self.row_ptr, k, side="right") - 1
-            return jnp.minimum(r, self.batch_size - 1).astype(jnp.int32)
+        return csr_row_ids(self.row_ptr, self.index.shape[0])
 
 
 jax.tree_util.register_dataclass(

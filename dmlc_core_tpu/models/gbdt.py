@@ -55,6 +55,103 @@ from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
 _ROUTE_SELECT_NODES = 4096
 
 
+def counter_add(name: str, n: int) -> None:
+    """Add to a native telemetry counter (without the native runtime the
+    models stay pure-JAX usable)."""
+    try:
+        telemetry.counter_add(name, n)
+    except Exception:
+        pass
+
+
+def _first_lane(lo: jax.Array, hi: jax.Array, rounds: int, before
+                ) -> jax.Array:
+    """For every span ``[lo, hi)`` of lanes the first lane at which
+    ``before(lane)`` no longer holds (``hi`` if it holds throughout), by
+    ``rounds`` halvings — enough for the longest span.  ``before`` must hold
+    on a prefix of each span and is only asked about lanes inside it."""
+    def halve(_, span):
+        lo, hi = span
+        mid = (lo + hi) // 2
+        go = (lo < hi) & before(jnp.where(lo < hi, mid, lo))
+        return jnp.where(go, mid + 1, lo), jnp.where(go | (lo >= hi), hi, mid)
+
+    return jax.lax.fori_loop(0, rounds, halve, (lo, hi))[0]
+
+
+# Entries from which `_bin_entries` bins by sorting them, not by a bisection
+# an entry.  At 2.18e8 entries the sorts win by eight times on a v5e (2.5 s
+# against 20.2 s, inside every `fit_batch`), but a sort costs the TPU's
+# compiler 9.8 s a shape at 2^14 lanes, 39.7 s at 2^17 and about 54 s at
+# 2^20, where the bisection compiles in 1.6 s (PERF.md, PR 27).  A
+# scoring server's first request of each bucket compiles what it runs inside
+# the server's 30 s wait, so the threshold lies above a request's bucket
+# (1,024 rows of up to 16,384 entries each): 2^24 entries, which the
+# bisection bins in about 1.5 s.
+_BIN_BY_SORT_ENTRIES = 1 << 24
+
+
+def _bin_entries(cuts: jax.Array, index: jax.Array, value: jax.Array
+                 ) -> jax.Array:
+    """``1 + #{c: cuts[index[k], c] <= value[k]}`` for every entry k, 0 for
+    a NaN value; ``cuts``: [features, C], non-decreasing along C.  Two
+    forms with one result, chosen by the number of entries
+    (`_BIN_BY_SORT_ENTRIES`).  A feature id outside ``[0, features)`` is
+    clamped, as a gather's index is."""
+    if value.shape[0] < _BIN_BY_SORT_ENTRIES:
+        return _bin_by_bisection(cuts, index, value)
+    return _bin_by_sort(cuts, index, value)
+
+
+@jax.jit
+def _bin_by_bisection(cuts, index, value):
+    """A vectorized binary search — ``ceil(log2(C+1))`` rounds of one
+    gather an entry each, instead of materializing the [nnz, C] per-entry
+    cut matrix."""
+    C = cuts.shape[1]
+    fi = jnp.clip(index.astype(jnp.int32), 0, cuts.shape[0] - 1)
+    v = value.astype(jnp.float32)
+    lo = jnp.zeros(v.shape, jnp.int32)
+    hi = jnp.full(v.shape, C, jnp.int32)
+    for _ in range(max(1, int(np.ceil(np.log2(C + 1))))):
+        mid = (lo + hi) // 2
+        cut = cuts[fi, jnp.minimum(mid, C - 1)]
+        go = (cut <= v) & (mid < hi)  # searchsorted side="right"
+        lo = jnp.where(go, mid + 1, lo)
+        hi = jnp.where(go, hi, mid)
+    # NaN entries read as missing (code 0), matching the dense transform
+    return jnp.where(jnp.isnan(v), 0, lo + 1).astype(jnp.int32)
+
+
+@jax.jit
+def _bin_by_sort(cuts, index, value):
+    """The entries are sorted by (feature, value), each of the
+    ``features * C`` cuts is placed among its feature's values by bisection
+    (a gather a *cut* a round), a running count of the cuts placed at or
+    before each lane is the code, and a second sort puts the codes back in
+    entry order."""
+    features, num_cuts = cuts.shape
+    n = value.shape[0]
+    v = value.astype(jnp.float32)
+    key = jnp.clip(index.astype(jnp.int32), 0, features - 1)
+    key_s, v_s, lane = jax.lax.sort(
+        (key, v, jnp.arange(n, dtype=jnp.int32)), num_keys=2)
+    cut_f = jnp.repeat(jnp.arange(features, dtype=jnp.int32), num_cuts)
+    cut_v = cuts.astype(jnp.float32).reshape(-1)
+    # the first lane that does not sort before the cut
+    place = _first_lane(
+        jnp.zeros(cut_f.shape, jnp.int32), jnp.full(cut_f.shape, n),
+        n.bit_length(), lambda lane: (key_s[lane] < cut_f) | (
+            (key_s[lane] == cut_f) & (v_s[lane] < cut_v)))
+    # every cut of a lower feature lies at or before a lane, none of a
+    # higher one does, and a cut of the lane's own feature does when it is
+    # at or below the lane's value
+    placed = jnp.cumsum(jnp.zeros(n, jnp.int32).at[place].add(1, mode="drop"))
+    _, code = jax.lax.sort((lane, 1 + placed - key_s * num_cuts), num_keys=1)
+    # NaN entries read as missing (code 0), matching the dense transform
+    return jnp.where(jnp.isnan(v), 0, code).astype(jnp.int32)
+
+
 class QuantileBinner:
     """Per-feature quantile binning to uint8 codes (XGBoost-hist's sketch).
 
@@ -182,27 +279,12 @@ class QuantileBinner:
                           ) -> jax.Array:
         """Bin COO entries: code of ``value[k]`` under feature
         ``index[k]``'s cuts, in ``[1, num_bins)`` (0 stays reserved for
-        missing = absent).  Jittable: a vectorized binary search —
-        ``ceil(log2(C+1))`` rounds of one gather each, instead of
-        materializing the [nnz, C] per-entry cut matrix."""
+        missing = absent).  Jittable; see `_bin_entries`."""
         if not self.missing_aware:
             raise ValueError("transform_entries requires missing_aware=True")
         if self.cuts is None:
             raise RuntimeError("transform_entries before fit")
-        cuts = self.cuts
-        C = cuts.shape[1]
-        fi = index.astype(jnp.int32)
-        v = value.astype(jnp.float32)
-        lo = jnp.zeros(v.shape, jnp.int32)
-        hi = jnp.full(v.shape, C, jnp.int32)
-        for _ in range(max(1, int(np.ceil(np.log2(C + 1))))):
-            mid = (lo + hi) // 2
-            cut = cuts[fi, jnp.minimum(mid, C - 1)]
-            go = (cut <= v) & (mid < hi)  # searchsorted side="right"
-            lo = jnp.where(go, mid + 1, lo)
-            hi = jnp.where(go, hi, mid)
-        # NaN entries read as missing (code 0), matching the dense transform
-        return jnp.where(jnp.isnan(v), 0, lo + 1).astype(jnp.int32)
+        return _bin_entries(self.cuts, index, value)
 
     # ---- streaming (bounded-memory, mergeable) sketch -----------------------
     #
@@ -724,67 +806,59 @@ class GBDT:
                            streamed: bool = False):
         """The once-per-fit feature-sorted entry layout, or None when no
         level of this fit can resolve to the sparse Pallas kernel (the
-        scatter path needs no layout).  Built host-side — ``findex`` is
-        static across every level and tree, so the sort amortizes over
-        ``num_trees * max_depth`` level passes; the one-time cost is
-        published as ``gbdt.entry_sort_us``.  Sharded over the
-        ``histogram_mesh`` axis when declared (streamed fits keep the
-        kernel single-device: their batch slicing is row-offset based and
-        never mesh-sharded)."""
+        scatter path needs no layout).  ``findex`` is static across every
+        level and tree, so one device sort (``ops.sparse_hist_layout``)
+        amortizes over ``num_trees * max_depth`` level passes; what it
+        costs is the span ``gbdt.entry_sort`` and the counter
+        ``gbdt.entry_sort_us`` (to the sort's end on the device: the host
+        reads the run starts back).  Sharded over the ``histogram_mesh``
+        axis when declared (streamed fits keep the kernel single-device:
+        their batch slicing is row-offset based and never mesh-sharded)."""
         if not self._sparse_layout_enabled(streamed):
             return None
         num_shards = (1 if self.mesh_plan is None
                       else self.mesh_plan.num_shards)
         t0 = time.monotonic()
-        layout = sparse_hist_layout(row_id, findex, ebin, emask,
-                                    self.num_features, self.num_bins,
-                                    num_shards=num_shards, rows=rows)
-        try:
-            telemetry.counter_add("gbdt.entry_sort_us",
-                                  int((time.monotonic() - t0) * 1e6))
-        except Exception:  # no native runtime: models stay pure-JAX usable
-            pass
+        with telemetry.span("gbdt.entry_sort"):
+            layout = jax.block_until_ready(sparse_hist_layout(
+                row_id, findex, ebin, emask, self.num_features,
+                self.num_bins, num_shards=num_shards, rows=rows))
+        counter_add("gbdt.entry_sort_us", int((time.monotonic() - t0) * 1e6))
         return layout
 
     def _level_histogram_sparse(self, layout, rel: jax.Array,
-                                gh_row: jax.Array, gh_e, n_nodes: int):
+                                gh_row: jax.Array, gh_e, rel_e, n_nodes: int):
         """Sparse per-level [nodes, F, bins, 2] via the Pallas kernel.
 
-        Single-device: entry gathers against the feature-sorted layout
-        (``gh_e`` pre-gathered per tree by the caller; only ``rel``
-        changes per level) feed one kernel call.  With ``histogram_mesh``
-        the packed per-shard layout slices ride ``shard_map`` ``P(axis)``
-        in_specs, each device runs the kernel on its local rows' entries,
-        and the plan's allreduce (flat psum or hierarchical by payload)
-        combines the shards — the same rabit-histogram-allreduce shape
-        as the dense `_level_histogram` route (the per-tree gh gather
-        moves inside the shard_map body there, since gh is only
-        device-local under the mesh)."""
+        Single-device: the entry gathers against the feature-sorted layout
+        (``gh_e`` once a tree, ``rel_e`` once a level, both by the caller
+        under its scope ``gbdt.entry_gather``) feed one kernel call.  With
+        ``histogram_mesh`` the packed per-shard layout slices ride
+        ``shard_map`` ``P(axis)`` in_specs, each device runs the kernel on
+        its local rows' entries, and the plan's allreduce (flat psum or
+        hierarchical by payload) combines the shards — the same
+        rabit-histogram-allreduce shape as the dense `_level_histogram`
+        route (both gathers move inside the shard_map body there, since
+        rows are only device-local under the mesh)."""
         F, B = self.num_features, self.num_bins
-        try:
-            telemetry.counter_add("gbdt.hist_sparse_pallas", 1)
-        except Exception:
-            pass
         if self.mesh_plan is not None:
             from jax.sharding import PartitionSpec as P
 
             plan = self.mesh_plan
             mt = layout.max_tiles
 
-            def local(gk, rid_l, w_l, ts, tc, rel_l, gh_l):
-                rel_e = rel_l[rid_l]
-                ghe = gh_l[rid_l].astype(jnp.float32) * w_l[:, None]
-                h = histogram_gh_sparse_kernel(gk, rel_e, ghe, ts, tc,
-                                               n_nodes, F, B, mt)
+            def local(gk, rid_l, ts, tc, rel_l, gh_l):
+                h = histogram_gh_sparse_kernel(
+                    gk, rel_l[rid_l], gh_l[rid_l].astype(jnp.float32).T,
+                    ts, tc, n_nodes, F, B, mt)
                 return plan.allreduce(h)
 
             spec = plan.row_spec
             return plan.shard_map(local,
-                                  in_specs=(spec,) * 7, out_specs=P(),
+                                  in_specs=(spec,) * 6, out_specs=P(),
                                   check_replication=False)(
-                layout.gkey, layout.rid, layout.w,
-                layout.tstart, layout.tcount, rel, gh_row)
-        rel_e = rel[layout.rid]
+                layout.gkey, layout.rid, layout.tstart, layout.tcount,
+                rel, gh_row)
         return histogram_gh_sparse_kernel(
             layout.gkey, rel_e, gh_e, layout.tstart, layout.tcount,
             n_nodes, F, B, layout.max_tiles)
@@ -1432,56 +1506,49 @@ class GBDT:
         forests from identical data — only how the histogram was
         accumulated differs.  Returns
         ``(split_f, split_b, split_d, split_g, lo, hi, active)``."""
-        lam = self.lambda_
-        mono = self.monotone_constraints is not None
-        miss = gh_node[:, None, :] - jnp.sum(hist, axis=2)   # [n, F, 2]
-        gl = jnp.cumsum(hist, axis=2)                   # present mass
-        g_tot = gh_node[:, 0][:, None, None]            # [n, 1, 1]
-        h_tot = gh_node[:, 1][:, None, None]
+        with jax.named_scope("gbdt.split"):
+            lam = self.lambda_
+            mono = self.monotone_constraints is not None
+            miss = gh_node[:, None, :] - jnp.sum(hist, axis=2)   # [n, F, 2]
+            gl = jnp.cumsum(hist, axis=2)                   # present mass
+            g_tot = gh_node[:, 0][:, None, None]            # [n, 1, 1]
+            h_tot = gh_node[:, 1][:, None, None]
 
-        def split_gain(gl_, hl_):
-            gr_ = g_tot - gl_
-            hr_ = h_tot - hl_
-            g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
-                 - g_tot ** 2 / (h_tot + lam))
-            ok = ((hl_ >= self.min_child_weight) &
-                  (hr_ >= self.min_child_weight))
-            return jnp.where(ok, g, -jnp.inf)
+            def split_gain(gl_, hl_):
+                gr_ = g_tot - gl_
+                hr_ = h_tot - hl_
+                g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
+                     - g_tot ** 2 / (h_tot + lam))
+                ok = ((hl_ >= self.min_child_weight) &
+                      (hr_ >= self.min_child_weight))
+                return jnp.where(ok, g, -jnp.inf)
 
-        # dir 0: missing left (GL gains the missing mass); dir 1: right
-        dirs = [(gl[..., 0] + miss[:, :, None, 0],
-                 gl[..., 1] + miss[:, :, None, 1]),
-                (gl[..., 0], gl[..., 1])]
-        gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
-        if mono:
-            wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
-            gain = self._apply_monotone(gain, wl, wr, lo, hi)
-        gain = self._collapse_dir_ties(gain)
-        node_mask = self._level_feature_mask(col_mask, col_key, depth,
-                                             active)
-        split_f, split_b, split_d, split_g = self._pick_splits(gain,
-                                                               node_mask)
-        if mono:
-            lo, hi = self._child_bounds(split_f, split_b, split_d,
-                                        wl, wr, lo, hi)
-        if active is not None:
-            active = self._next_active(active, split_f, split_b)
+            # dir 0: missing left (GL gains the missing mass); dir 1: right
+            dirs = [(gl[..., 0] + miss[:, :, None, 0],
+                     gl[..., 1] + miss[:, :, None, 1]),
+                    (gl[..., 0], gl[..., 1])]
+            gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
+            if mono:
+                wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
+                gain = self._apply_monotone(gain, wl, wr, lo, hi)
+            gain = self._collapse_dir_ties(gain)
+            node_mask = self._level_feature_mask(col_mask, col_key, depth,
+                                                 active)
+            split_f, split_b, split_d, split_g = self._pick_splits(gain,
+                                                                   node_mask)
+            if mono:
+                lo, hi = self._child_bounds(split_f, split_b, split_d,
+                                            wl, wr, lo, hi)
+            if active is not None:
+                active = self._next_active(active, split_f, split_b)
         return split_f, split_b, split_d, split_g, lo, hi, active
 
-    @staticmethod
-    def _sparse_entries(row_id, findex, ebin, emask):
-        """Pre-cast entry arrays for `_build_tree_sparse`, computed ONCE
-        per fit: the int32 casts and the broadcastable f32 emask are
-        invariant across every tree of the batch (only the (grad, hess)
-        values change), so re-deriving them per tree was pure waste."""
-        return (row_id.astype(jnp.int32), findex.astype(jnp.int32),
-                jnp.asarray(ebin, jnp.int32), emask,
-                emask.astype(jnp.float32)[:, None])
-
-    def _build_tree_sparse(self, entries, grad: jax.Array, hess: jax.Array,
-                           col_mask: jax.Array, col_key: jax.Array,
-                           layout=None):
-        """One tree from COO entries — O(nnz) histogram work per level.
+    @functools.partial(jax.jit, static_argnums=0)
+    def _build_tree_sparse(self, entries, layout, grad: jax.Array,
+                           hess: jax.Array, col_mask: jax.Array,
+                           col_key: jax.Array):
+        """One tree from COO entries — O(nnz) histogram work per level, one
+        jitted program a tree as `_build_tree` is.
 
         The sparse formulation of `_build_tree`: present entries
         accumulate their row's (grad, hess) into [nodes, features, bins]
@@ -1493,33 +1560,42 @@ class GBDT:
 
         Histogram accumulation routes through the ``histogram=`` backend
         knob per level (`_hist_impl_sparse`): XLA keeps the flattened-key
-        scatter-add; "pallas" runs the feature-sorted one-hot-contraction
-        kernel against ``layout`` (the once-per-fit sorted entry layout
-        from `_sparse_fit_layout` — the old docstring objection that
-        unsorted COO entries make the kernel pay a full
-        nnz x (nodes*features*bins) compare cost dissolves because
-        ``findex`` never changes across levels or trees, so one sort
-        serves the whole fit).  On kernel levels the node totals and leaf
-        sums ride the multi-lane pallas ``segment_sum``; under
-        ``histogram_mesh`` they stay on XLA scatter so GSPMD inserts
-        their psum.
+        scatter-add over ``entries``; "pallas" runs the feature-sorted
+        one-hot-contraction kernel against ``layout`` (the once-per-fit
+        sorted entry layout from `_sparse_fit_layout`: ``findex`` never
+        changes across levels or trees, so one sort serves the whole fit).
+        On kernel levels the node totals and leaf sums ride the multi-lane
+        pallas ``segment_sum``; under ``histogram_mesh`` they stay on XLA
+        scatter so GSPMD inserts their psum.
 
-        entries: the `_sparse_entries` tuple (pre-cast once per fit;
-        emask 0 marks padding lanes); grad/hess: [rows] weight-scaled.
-        Returns the same 7-tuple as `_build_tree`.
+        entries: ``(row_id, findex, ebin, emask)`` int32/bool entry arrays
+        (emask 0 marks padding lanes), or None when every level runs the
+        kernel on one device and the layout's ``rows_ascend`` holds: the
+        layout's live entries are then the only copy, and rows are routed
+        by them (`_route_layout`; a (row, feature) pair then has one entry
+        at most, so the bin found is `_route_sparse`'s maximum over it).
+        grad/hess: [rows] weight-scaled.  Returns the same 7-tuple as
+        `_build_tree`.
         """
         F, B = self.num_features, self.num_bins
         rows = grad.shape[0]
         mono = self.monotone_constraints is not None
-        rid, fi, ebin, emask, emw = entries
         mesh = self.histogram_mesh is not None
         gh_row = jnp.stack([grad, hess], axis=-1)          # [rows, 2]
+        impls = [self._hist_impl_sparse(2 ** d) if layout is not None
+                 else "xla" for d in range(self.max_depth)]
         # entry-level (grad, hess) lanes, gathered once per TREE (the
         # values change with the margins, so this is the hoist floor):
         # scatter levels want unsorted gh_k, kernel levels the sorted gh_e
         gh_k = gh_e = None
-        if layout is not None and not mesh:
-            gh_e = gh_row[layout.rid] * layout.w[:, None]
+        if "pallas" in impls and not mesh:
+            with jax.named_scope("gbdt.entry_gather"):
+                # one gather of a row's pair, then lanes first for the
+                # kernel: two gathers of one lane each took 7.6 s against
+                # 3.0 s at 2.18e8 entries on a v5e (PERF.md, PR 27)
+                gh_e = gh_row[layout.rid].T
+        if entries is not None:
+            rid, fi, ebin, emask = entries
 
         node = jnp.zeros(rows, jnp.int32)
         lo = jnp.full(1, -jnp.inf)
@@ -1527,25 +1603,32 @@ class GBDT:
         active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
                   if self._interaction_groups is not None else None)
         features, thresholds, defaults, gains, covers = [], [], [], [], []
-        for depth in range(self.max_depth):
+        for depth, impl in enumerate(impls):
             first = 2 ** depth - 1
             n_nodes = 2 ** depth
             rel = node - first
-            impl = (self._hist_impl_sparse(n_nodes)
-                    if layout is not None else "xla")
+            rel_e = None
             if impl == "pallas":
-                hist = self._level_histogram_sparse(layout, rel, gh_row,
-                                                    gh_e, n_nodes)
+                if not mesh:
+                    with jax.named_scope("gbdt.entry_gather"):
+                        rel_e = rel[layout.rid]
+                with jax.named_scope("gbdt.hist"):
+                    hist = self._level_histogram_sparse(
+                        layout, rel, gh_row, gh_e, rel_e, n_nodes)
             else:
-                if gh_k is None:
-                    gh_k = gh_row[rid] * emw   # padding lanes carry 0 mass
-                keys = (rel[rid] * F + fi) * B + ebin
-                hist = jax.ops.segment_sum(
-                    gh_k, keys, num_segments=n_nodes * F * B
-                ).reshape(n_nodes, F, B, 2)                 # bin 0 is empty
-            gh_node = segment_sum(
-                gh_row, rel, num_segments=n_nodes,
-                force="pallas" if impl == "pallas" and not mesh else None)
+                with jax.named_scope("gbdt.hist"):
+                    if gh_k is None:    # padding lanes carry 0 mass
+                        gh_k = (gh_row[rid]
+                                * emask.astype(jnp.float32)[:, None])
+                    keys = (rel[rid] * F + fi) * B + ebin
+                    hist = jax.ops.segment_sum(
+                        gh_k, keys, num_segments=n_nodes * F * B
+                    ).reshape(n_nodes, F, B, 2)             # bin 0 is empty
+            with jax.named_scope("gbdt.node_totals"):
+                gh_node = segment_sum(
+                    gh_row, rel, num_segments=n_nodes,
+                    force="pallas" if impl == "pallas" and not mesh
+                    else None)
             (split_f, split_b, split_d, split_g,
              lo, hi, active) = self._level_splits_from_hist(
                 hist, gh_node, depth, col_mask, col_key, lo, hi, active)
@@ -1554,25 +1637,55 @@ class GBDT:
             defaults.append(split_d)
             gains.append(split_g)
             covers.append(gh_node[:, 1])
-            go_right = self._route_sparse(fi, ebin, emask, rid,
-                                          split_f[rel], split_b[rel],
-                                          split_d[rel], rows)
-            node = 2 * node + 1 + go_right.astype(jnp.int32)
+            with jax.named_scope("gbdt.route"):
+                if entries is None:
+                    go_right = self._route_layout(layout, rel, split_f,
+                                                  split_b, split_d)
+                else:
+                    go_right = self._route_sparse(fi, ebin, emask, rid,
+                                                  split_f[rel], split_b[rel],
+                                                  split_d[rel], rows)
+                node = 2 * node + 1 + go_right.astype(jnp.int32)
 
         n_leaves = 2 ** self.max_depth
-        leaf_rel = node - (n_leaves - 1)
-        leaf_force = ("pallas" if layout is not None and not mesh
-                      and self._hist_impl_sparse(n_leaves) == "pallas"
-                      else None)
-        gh_leaf = segment_sum(gh_row, leaf_rel, num_segments=n_leaves,
-                              force=leaf_force)
-        leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
-        if mono:
-            leaf_w = jnp.clip(leaf_w, lo, hi)
-        leaf = self.learning_rate * leaf_w
+        with jax.named_scope("gbdt.leaf"):
+            leaf_rel = node - (n_leaves - 1)
+            leaf_force = ("pallas" if layout is not None and not mesh
+                          and self._hist_impl_sparse(n_leaves) == "pallas"
+                          else None)
+            gh_leaf = segment_sum(gh_row, leaf_rel, num_segments=n_leaves,
+                                  force=leaf_force)
+            leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
+            if mono:
+                leaf_w = jnp.clip(leaf_w, lo, hi)
+            leaf = self.learning_rate * leaf_w
         return (jnp.concatenate(features), jnp.concatenate(thresholds),
                 jnp.concatenate(defaults), jnp.concatenate(gains),
                 jnp.concatenate(covers), leaf, leaf_rel)
+
+    @staticmethod
+    def _route_layout(layout, rel, split_f, split_b, split_d):
+        """One level of routing by the feature-sorted layout alone: bool
+        [rows], which rows go to their node's right child.  A row's bin on
+        its node's split feature is looked up where it lies: the feature's
+        run of entries is in strictly ascending row order (the caller has
+        checked the layout's ``rows_ascend``), so the row is found in it by
+        bisection — a gather a *row* a round, 0.66 s a level
+        at 1.18M rows on a v5e, where the maximum over every entry
+        (`_route_sparse`) took 5.8 s for 2.18e8 of them (PERF.md, PR 27).
+        No entry of the row in the run: the cell is absent, and the row
+        follows the node's default direction."""
+        rows = rel.shape[0]
+        feat, thr, right = split_f[rel], split_b[rel], split_d[rel] == 1
+        lo, end = layout.fstart[feat], layout.fstart[feat + 1]
+        row = jnp.arange(rows, dtype=jnp.int32)
+        # the first lane of the run at or past the row
+        at = _first_lane(lo, end, layout.run_bits,
+                         lambda lane: layout.rid[lane] < row)
+        lane = jnp.minimum(at, layout.rid.shape[0] - 1)
+        found = (at < end) & (layout.rid[lane] == row)
+        row_bin = jnp.where(found, layout.gkey[lane] & (layout.nb - 1), 0)
+        return jnp.where(row_bin == 0, right, row_bin > thr)
 
     @staticmethod
     def _route_sparse(fi, ebin, emask, rid, row_feat, row_thr, row_dir,
@@ -1746,12 +1859,21 @@ class GBDT:
                              "both the GBDT and the QuantileBinner")
         label = batch.label.astype(jnp.float32)
         w = (batch.weight if weight is None else weight).astype(jnp.float32)
-        row_id, findex, ebin, emask = self._entry_bins(batch, binner)
-        # invariant across every tree: the pre-cast entry tuple and (for
-        # the pallas backend) the feature-sorted layout, built exactly once
-        entries = self._sparse_entries(row_id, findex, ebin, emask)
-        layout = self._sparse_fit_layout(row_id, findex, ebin, emask,
-                                         rows=int(label.shape[0]))
+        # invariant across every tree: the entry arrays (one row_ids() a
+        # fit) and, for the pallas backend, the feature-sorted layout
+        entries = self._entry_bins(batch, binner)
+        layout = self._sparse_fit_layout(*entries, rows=int(label.shape[0]))
+        kernel_levels = self.level_backends(sparse=True).count("pallas")
+        if (layout is not None and self.mesh_plan is None
+                and kernel_levels == self.max_depth and layout.rows_ascend):
+            entries = None      # the layout holds every live entry
+
+        def build_tree(g, h, col_mask, col_key):
+            if kernel_levels:
+                counter_add("gbdt.hist_sparse_pallas", kernel_levels)
+            return self._build_tree_sparse(entries, layout, g, h, col_mask,
+                                           col_key)
+
         eval_margin = eval_label = eval_weight = None
         if eval_set is not None:
             # eval_set: a held-out PaddedBatch (weight-0 rows excluded
@@ -1771,9 +1893,7 @@ class GBDT:
                 eval_w=(eval_set.weight if eval_set is not None else None),
                 have_eval=eval_set is not None)
             return self._boost(
-                label, w,
-                lambda g, h, cm, ck: self._build_tree_sparse(
-                    entries, g, h, cm, ck, layout=layout),
+                label, w, build_tree,
                 eval_margin=eval_margin, eval_label=eval_label,
                 eval_weight=eval_weight,
                 early_stopping_rounds=early_stopping_rounds,
@@ -1781,9 +1901,7 @@ class GBDT:
         driver = (self._boost_multi if self.objective == "softmax"
                   else self._boost)
         return driver(
-            label, w,
-            lambda g, h, cm, ck: self._build_tree_sparse(
-                entries, g, h, cm, ck, layout=layout),
+            label, w, build_tree,
             eval_margin=eval_margin, eval_label=eval_label,
             eval_weight=eval_weight,
             early_stopping_rounds=early_stopping_rounds)
@@ -1914,8 +2032,7 @@ class GBDT:
             gh_row = jnp.stack([grad, hess], axis=-1)      # [rows, 2]
             # per-TREE hoist for kernel levels: the sorted entry gather of
             # this tree's (grad, hess); only rel changes across levels
-            gh_e = (gh_row[layout.rid] * layout.w[:, None]
-                    if layout is not None else None)
+            gh_e = (gh_row[layout.rid].T if layout is not None else None)
             node = jnp.zeros(rows, jnp.int32)
             lo = jnp.full(1, -jnp.inf)
             hi = jnp.full(1, jnp.inf)
@@ -1937,8 +2054,9 @@ class GBDT:
                         node = route_pass(node, prev, 2 ** (depth - 1) - 1)
                         prev = None
                     rel = node - first
+                    counter_add("gbdt.hist_sparse_pallas", 1)
                     hist4 = self._level_histogram_sparse(
-                        layout, rel, gh_row, gh_e, n_nodes)
+                        layout, rel, gh_row, gh_e, rel[layout.rid], n_nodes)
                     gh_node = segment_sum(gh_row, rel,
                                           num_segments=n_nodes,
                                           force="pallas")

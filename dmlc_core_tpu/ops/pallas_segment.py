@@ -30,7 +30,7 @@ would compare every entry tile against every key tile (full
 ``nnz x (F * bins)`` compare cost, which is why the scatter path used to
 be the only sparse backend).  The fix is that ``findex`` never changes:
 :func:`sparse_hist_layout` sorts the entries by feature ONCE per staged
-batch (host-side, amortized over ``num_trees x max_depth`` level passes)
+batch (a device sort, amortized over ``num_trees x max_depth`` level passes)
 and records, per key tile, the contiguous block span of entries whose
 keys can land in that tile.  The kernel grid is then
 ``(key tiles, max blocks per tile)`` with the span table scalar-
@@ -41,6 +41,7 @@ no full-F factor (a tile only ever sees its own features' entries).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -388,6 +389,12 @@ def _sparse_geometry(num_features: int, num_bins: int) -> tuple[int, int]:
     return nb, num_kt
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["gkey", "rid", "tstart", "tcount", "fstart", "nnz_live"],
+    meta_fields=["num_features", "num_bins", "num_shards", "nb", "num_kt",
+                 "max_tiles", "nnz_pad", "run_bits", "rows_ascend"])
+@dataclasses.dataclass(frozen=True)
 class SparseHistLayout:
     """Feature-sorted COO layout for :func:`histogram_gh_sparse`.
 
@@ -395,10 +402,15 @@ class SparseHistLayout:
     the expensive part of the sparse kernel — sorting the entries by
     feature and computing, per key tile, which contiguous span of
     ``_NNZ_TILE`` entry blocks can contribute to it — happens ONCE per
-    staged batch (host-side numpy) and is reused for the whole fit.
-    Masked (``emask == 0``) entries are dropped outright during the sort;
-    the padding lanes that fill the last block carry ``w == 0`` AND
-    ``gkey == -1``, so they are doubly inert in the kernel.
+    staged batch (:func:`sparse_hist_layout`, a device sort) and is reused
+    for the whole fit.  Masked (``emask == 0``) entries sort past the live
+    ones and are dropped; the padding lanes that fill the last block carry
+    ``gkey == -1``, which matches no key of any tile, so whatever row their
+    ``rid`` (0) points at adds nothing.
+
+    The live entries carry everything the tree builder needs of them —
+    feature ``gkey // nb``, bin ``gkey % nb``, row ``rid`` — so a fit whose
+    levels all run the kernel keeps no other copy of the entries.
 
     With ``num_shards > 1`` the layout is built per row-shard (entries
     bucketed to the shard owning their row, row ids localized) and packed
@@ -406,46 +418,100 @@ class SparseHistLayout:
     ``shard_map`` with ``P(axis)`` in_specs hands each device — the
     multi-chip psum route (`gbdt._level_histogram` mirror).
 
-    Fields: ``gkey``/``rid``/``w`` are ``[num_shards * nnz_pad]`` packed
-    per-entry arrays (global key ``fi * nb + ebin``, row id — shard-local
-    when sharded — and 0/1 live weight); ``tstart``/``tcount`` are
-    ``[num_shards * num_kt]`` per-key-tile entry-block spans;
-    ``max_tiles`` is the grid's inner extent (max span over all tiles and
-    shards)."""
+    A pytree: ``gkey``/``rid`` are ``[num_shards * nnz_pad]`` packed
+    per-entry arrays (global key ``fi * nb + ebin`` and row id, shard-local
+    when sharded); ``tstart``/``tcount`` are ``[num_shards * num_kt]``
+    per-key-tile entry-block spans; ``fstart`` is ``[num_shards * (F + 1)]``,
+    the lane at which each feature's run of entries begins in its shard's
+    slice (and, last, where the live entries end).  Within a run the
+    entries keep their input order; ``rows_ascend`` says whether row ids
+    strictly ascend in every run, as they do when the input is row-major (a
+    CSR batch's is) and no row holds a feature twice: only then is a row's
+    entry on a feature the one lane that a bisection of the run finds.
+    ``nnz_live`` counts the live entries.  The rest
+    is static, and what depends on the data is rounded up (`_round_up_some`)
+    so that nearly equal data sets share their compiled programs — a
+    program that takes a layout is compiled for its static fields:
+    ``nnz_pad`` (lanes a shard, from the fullest shard's entries),
+    ``max_tiles`` (the grid's inner extent, from the largest span over all
+    tiles and shards) and ``run_bits`` (bisection rounds that find a lane
+    in the longest run)."""
 
-    __slots__ = ("num_features", "num_bins", "num_shards", "nb", "num_kt",
-                 "max_tiles", "nnz_pad", "nnz_live", "gkey", "rid", "w",
-                 "tstart", "tcount")
+    num_features: int
+    num_bins: int
+    num_shards: int
+    nb: int
+    num_kt: int
+    max_tiles: int
+    nnz_pad: int
+    run_bits: int
+    rows_ascend: bool
+    gkey: jax.Array
+    rid: jax.Array
+    tstart: jax.Array
+    tcount: jax.Array
+    fstart: jax.Array
+    nnz_live: jax.Array
 
-    def __init__(self, **kw):
-        for k in self.__slots__:
-            setattr(self, k, kw[k])
+
+def _round_up_some(n: int, granule: int, parts: int) -> int:
+    """``n`` rounded up to a multiple of ``granule`` and of about one
+    ``parts``-th of its own magnitude: two counts that differ by a little
+    (two seeds' draws of one data set) round to the same number, at the
+    price of at most ``1 / parts`` more."""
+    step = max(granule, 1 << max(max(n, 1).bit_length() - parts.bit_length(),
+                                 0))
+    return max(-(-max(n, 1) // step) * step, granule)
 
 
-def _sparse_layout_shard(rid: np.ndarray, fi: np.ndarray, eb: np.ndarray,
-                         nb: int, num_kt: int, num_features: int):
-    """Sort one shard's live entries by feature; per-key-tile block spans.
+@functools.partial(jax.jit, static_argnames=("num_features", "num_bins",
+                                             "nb", "num_shards", "local"))
+def _layout_sort(row_id, findex, ebin, emask, num_features: int,
+                 num_bins: int, nb: int, num_shards: int, local: int):
+    """Live entries sorted by (owning shard, feature), dead ones last.
 
-    np.argsort(kind="stable") keeps within-feature entries in input order,
-    so the layout — and the kernel's accumulation order — is a pure
-    function of the entry stream (feature-sort determinism test)."""
-    order = np.argsort(fi, kind="stable")
-    fi_s = fi[order]
-    gkey = (fi_s * nb + eb[order]).astype(np.int32)
-    rid_s = rid[order].astype(np.int32)
-    starts = np.zeros(num_features + 1, np.int64)
-    np.cumsum(np.bincount(fi_s, minlength=num_features), out=starts[1:])
-    tstart = np.zeros(num_kt, np.int32)
-    tcount = np.zeros(num_kt, np.int32)
-    for kt in range(num_kt):
-        # features whose key range [f*nb, (f+1)*nb) intersects this tile
-        flo = min((kt * _KEY_TILE) // nb, num_features)
-        fhi = min(-(-((kt + 1) * _KEY_TILE) // nb), num_features)
-        s, e = int(starts[flo]), int(starts[fhi])
-        if e > s:
-            tstart[kt] = s // _NNZ_TILE
-            tcount[kt] = -(-e // _NNZ_TILE) - tstart[kt]
-    return rid_s, gkey, tstart, tcount
+    The sort is stable, so within a feature the entries keep their input
+    order and the layout — and the kernel's accumulation order — is a pure
+    function of the entry stream (feature-sort determinism test).  Returns
+    the sorted ``gkey`` and shard-local ``rid``, ``starts`` (the sorted
+    position at which each (shard, feature) run begins, ``F + 1`` a shard:
+    the last is where the shard's live entries end), whether a live entry
+    is out of range, and whether row ids strictly ascend within every
+    run."""
+    rid = row_id.astype(jnp.int32)
+    fi = findex.astype(jnp.int32)
+    eb = jnp.asarray(ebin, jnp.int32)
+    live = emask.astype(bool)
+    bad = jnp.any(live & ((fi < 0) | (fi >= num_features)
+                          | (eb < 0) | (eb >= num_bins)))
+    f1 = num_features + 1
+    owner = rid // local if num_shards > 1 else 0
+    key = jnp.where(live, owner * f1 + fi, num_shards * f1)
+    key, gkey, rid = jax.lax.sort(
+        (key, fi * nb + eb, rid - owner * local), num_keys=1, is_stable=True)
+    starts = jnp.searchsorted(
+        key, jnp.arange(num_shards * f1, dtype=jnp.int32), side="left")
+    same_run = (key[1:] == key[:-1]) & (key[1:] < num_shards * f1)
+    ascend = jnp.all(~same_run | (rid[1:] > rid[:-1]))
+    return gkey, rid, starts.astype(jnp.int32), bad, ascend
+
+
+@functools.partial(jax.jit, static_argnames=("num_shards", "nnz_pad"))
+def _layout_pack(gkey, rid, offset, count, num_shards: int, nnz_pad: int):
+    """The sorted entries as ``num_shards`` slices of ``nnz_pad`` lanes:
+    shard ``s`` is ``[offset[s], offset[s] + count[s])`` of the sorted
+    arrays, then ``gkey == -1`` / ``rid == 0`` filler."""
+    lane = jnp.arange(nnz_pad, dtype=jnp.int32)
+    if num_shards == 1:     # a slice or a pad of the sorted arrays, no gather
+        def fit(a):
+            n = a.shape[0]
+            return a[:nnz_pad] if n >= nnz_pad else jnp.pad(a, (0, nnz_pad - n))
+        ok = lane < count[0]
+        return jnp.where(ok, fit(gkey), -1), jnp.where(ok, fit(rid), 0)
+    src = jnp.minimum(offset[:, None] + lane[None, :], gkey.shape[0] - 1)
+    ok = lane[None, :] < count[:, None]
+    return (jnp.where(ok, gkey[src], -1).reshape(-1),
+            jnp.where(ok, rid[src], 0).reshape(-1))
 
 
 def sparse_hist_layout(row_id, findex, ebin, emask,
@@ -457,53 +523,56 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
     row_id/findex/ebin/emask: [nnz] COO entry arrays (any int/bool dtypes;
     device or host).  ``num_shards > 1`` buckets entries by the row shard
     that owns them (``rows`` must then divide evenly — shard_map's
-    even-sharding rule) and localizes row ids to the shard."""
-    fi = np.asarray(findex).astype(np.int64)
-    eb = np.asarray(ebin).astype(np.int64)
-    em = np.asarray(emask).astype(bool)
-    rid = np.asarray(row_id).astype(np.int64)
-    if em.any():
-        fl, el = fi[em], eb[em]
-        if fl.min() < 0 or fl.max() >= num_features:
-            raise ValueError("findex out of range for live entries")
-        if el.min() < 0 or el.max() >= num_bins:
-            raise ValueError("ebin out of range for live entries")
+    even-sharding rule) and localizes row ids to the shard.
+
+    The entries are sorted on the device (one stable ``lax.sort`` by
+    feature that carries key and row id); the host reads back only the
+    ``num_shards * (F + 1)`` run starts, from which it sizes the packed
+    arrays and works out the per-key-tile block spans."""
     nb, num_kt = _sparse_geometry(num_features, num_bins)
-    if num_shards == 1:
-        parts = [(rid[em], fi[em], eb[em])]
-    else:
+    local = 0
+    if num_shards > 1:
         if rows is None or rows % num_shards:
             raise ValueError("sharded layout needs rows divisible by "
                              f"num_shards (rows={rows}, "
                              f"num_shards={num_shards})")
         local = rows // num_shards
-        owner = rid // local
-        parts = []
-        for s in range(num_shards):
-            sel = em & (owner == s)
-            parts.append((rid[sel] - s * local, fi[sel], eb[sel]))
-    built = [_sparse_layout_shard(r, f, e, nb, num_kt, num_features)
-             for r, f, e in parts]
-    n_live = [len(b[0]) for b in built]
-    nnz_pad = max(pl.cdiv(max(max(n_live), 1), _NNZ_TILE) * _NNZ_TILE,
-                  _NNZ_TILE)
-    gkey_p = np.full(num_shards * nnz_pad, -1, np.int32)
-    rid_p = np.zeros(num_shards * nnz_pad, np.int32)
-    w_p = np.zeros(num_shards * nnz_pad, np.float32)
-    for s, (rid_s, gkey, _, _) in enumerate(built):
-        gkey_p[s * nnz_pad:s * nnz_pad + len(gkey)] = gkey
-        rid_p[s * nnz_pad:s * nnz_pad + len(rid_s)] = rid_s
-        w_p[s * nnz_pad:s * nnz_pad + len(rid_s)] = 1.0
-    tstart = np.concatenate([b[2] for b in built])
-    tcount = np.concatenate([b[3] for b in built])
+    gkey, rid, starts, bad, ascend = _layout_sort(
+        jnp.asarray(row_id), jnp.asarray(findex), jnp.asarray(ebin),
+        jnp.asarray(emask), num_features, num_bins, nb, num_shards, local)
+    starts = np.asarray(starts).astype(np.int64)
+    if bool(bad):
+        raise ValueError("findex or ebin out of range for live entries")
+    f1 = num_features + 1
+    # [shard, F + 1]: where each feature's run begins in the sorted arrays
+    # and, last, where the shard's live entries end
+    runs = starts.reshape(num_shards, f1)
+    offset = runs[:, 0]
+    local_runs = runs - offset[:, None]             # the same, shard-local
+    count = local_runs[:, num_features]             # live entries a shard
+    nnz_pad = _round_up_some(int(count.max()), _NNZ_TILE, 64)
+    # features whose key range [f*nb, (f+1)*nb) intersects each key tile
+    kt = np.arange(num_kt, dtype=np.int64)
+    flo = np.minimum(kt * _KEY_TILE // nb, num_features)
+    fhi = np.minimum(-(-(kt + 1) * _KEY_TILE // nb), num_features)
+    begin, stop = local_runs[:, flo], local_runs[:, fhi]
+    some = stop > begin
+    tstart = np.where(some, begin // _NNZ_TILE, 0)
+    tcount = np.where(some, -(-stop // _NNZ_TILE) - tstart, 0)
+    gkey_p, rid_p = _layout_pack(
+        gkey, rid, jnp.asarray(offset, jnp.int32),
+        jnp.asarray(count, jnp.int32), num_shards, nnz_pad)
     return SparseHistLayout(
         num_features=num_features, num_bins=num_bins,
         num_shards=num_shards, nb=nb, num_kt=num_kt,
-        max_tiles=max(int(tcount.max()) if tcount.size else 0, 1),
-        nnz_pad=nnz_pad, nnz_live=sum(n_live),
-        gkey=jnp.asarray(gkey_p), rid=jnp.asarray(rid_p),
-        w=jnp.asarray(w_p),
-        tstart=jnp.asarray(tstart), tcount=jnp.asarray(tcount))
+        max_tiles=_round_up_some(int(tcount.max()), 1, 16),
+        nnz_pad=nnz_pad, nnz_live=jnp.asarray(int(count.sum()), jnp.int32),
+        run_bits=int(np.diff(local_runs, axis=1).max()).bit_length(),
+        rows_ascend=bool(ascend),
+        fstart=jnp.asarray(local_runs.reshape(-1), jnp.int32),
+        gkey=gkey_p, rid=rid_p,
+        tstart=jnp.asarray(tstart.reshape(-1), jnp.int32),
+        tcount=jnp.asarray(tcount.reshape(-1), jnp.int32))
 
 
 def _sparse_hist_kernel(n_pad: int, tstart_ref, tcount_ref,
@@ -532,7 +601,7 @@ def _sparse_hist_kernel(n_pad: int, tstart_ref, tcount_ref,
     @pl.when(et < tcount_ref[kt])
     def _accum():
         # A: [NNZ_TILE, 2*n_pad] node-masked (grad, hess) lanes.  Padding
-        # entries carry gh == 0 (w-zeroed by the caller) AND gkey == -1.
+        # entries carry gkey == -1: their row of B is all zero.
         node_ids = jax.lax.broadcasted_iota(jnp.int32, (_NNZ_TILE, n_pad), 1)
         rel_col = jnp.broadcast_to(rel_ref[...].reshape(_NNZ_TILE, 1),
                                    (_NNZ_TILE, n_pad))
@@ -563,8 +632,10 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
                                 num_features: int, num_bins: int,
                                 max_tiles: int, interpret: bool) -> jax.Array:
     """One shard's kernel call.  gkey/rel_e: [nnz_pad] int32 (nnz_pad a
-    multiple of _NNZ_TILE); gh_e: [nnz_pad, 2] f32, already entry-gathered
-    and w-masked; tstart/tcount: [num_kt] int32 block spans.  Returns
+    multiple of _NNZ_TILE); gh_e: [2, nnz_pad] f32, (grad, hess) already
+    gathered onto the entries, lanes first as the kernel reads them (an
+    ``[nnz_pad, 2]`` array would have to be transposed here at every
+    level); tstart/tcount: [num_kt] int32 block spans.  Returns
     [n_nodes, F, num_bins, 2] f32."""
     nnz_pad = gkey.shape[0]
     nb, num_kt = _sparse_geometry(num_features, num_bins)
@@ -576,7 +647,7 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
     with jax.named_scope("ops.hist_layout"):
         gkey2 = gkey.reshape(1, nnz_pad)
         rel2 = rel_e.astype(jnp.int32).reshape(1, nnz_pad)
-        gh2 = gh_e.astype(jnp.float32).T        # [2, nnz_pad]
+        gh2 = gh_e.astype(jnp.float32)
 
     # block index of entry inputs at step (kt, et): clamped so skipped
     # steps (et >= tcount[kt]) re-address an in-range block — a repeated
@@ -614,7 +685,7 @@ def histogram_gh_sparse_kernel(gkey, rel_e, gh_e, tstart, tcount,
                                num_bins: int, max_tiles: int) -> jax.Array:
     """Raw kernel entry over pre-gathered per-entry arrays:
     ``rel_e = rel[layout.rid]`` (per level) and
-    ``gh_e = gh[layout.rid] * layout.w[:, None]`` (per tree).  The GBDT
+    ``gh_e = gh[layout.rid].T`` (per tree, lanes first).  The GBDT
     builder calls this directly so the gh gather hoists out of the level
     loop and — under ``histogram_mesh`` — so the call can sit inside a
     ``shard_map`` body next to its psum.  ``histogram_gh_sparse`` wraps it
@@ -668,7 +739,7 @@ def histogram_gh_sparse(row_id, findex, ebin, emask, rel, gh,
                 f"layout built for F={layout.num_features}/"
                 f"B={layout.num_bins}, called with F={num_features}/"
                 f"B={num_bins}")
-        gh_e = gh[layout.rid].astype(jnp.float32) * layout.w[:, None]
+        gh_e = gh[layout.rid].astype(jnp.float32).T
         rel_e = jnp.asarray(rel, jnp.int32)[layout.rid]
         out = histogram_gh_sparse_kernel(
             layout.gkey, rel_e, gh_e, layout.tstart, layout.tcount,

@@ -22,6 +22,7 @@ bound (the subprocess contract the hot-swap test drives).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import socket
 import threading
@@ -31,6 +32,8 @@ from .. import faultinject, telemetry
 from ..dataservice import protocol
 from .engine import ScoringEngine
 from .queue import MicroBatchQueue
+
+LOGGER = logging.getLogger("dmlc_core_tpu.serving")
 
 #: /score request validation bounds (malformed beyond these -> 400)
 MAX_ROWS_PER_REQUEST = 1024
@@ -150,8 +153,11 @@ class ScoringServer:
                 try:
                     scores, digest, seq = fut.result(timeout=30)
                 except Exception as exc:
-                    return (500, json.dumps({"error": str(exc)}),
-                            "application/json")
+                    LOGGER.error("/score failed", exc_info=exc)
+                    # the type too: a wait that ran out has no message
+                    return (500, json.dumps(
+                        {"error": f"{type(exc).__name__}: {exc}"}),
+                        "application/json")
             return (200, json.dumps({
                 "scores": [float(s) for s in scores.reshape(-1)]
                 if scores.ndim == 1

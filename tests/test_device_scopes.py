@@ -51,6 +51,15 @@ def programs() -> dict:
     fit = jax.jit(lambda b, y: model.fit(b, y)).lower(bins, jnp.zeros(64))
     tree = model._build_tree.lower(model, bins, jnp.zeros(64), jnp.zeros(64),
                                    jnp.ones(4, bool), jax.random.PRNGKey(0))
+    # the sparse tree as fit_batch hands it over when every level runs the
+    # kernel: the feature-sorted layout and no other copy of the entries
+    from dmlc_core_tpu.ops.pallas_segment import sparse_hist_layout
+    rid = jnp.repeat(jnp.arange(64, dtype=jnp.int32), 2)
+    layout = sparse_hist_layout(rid, rid % 4, 1 + rid % 15,
+                                jnp.ones(128, bool), 4, 16)
+    sparse_tree = model._build_tree_sparse.lower(
+        model, None, layout, jnp.zeros(64), jnp.zeros(64), jnp.ones(4, bool),
+        jax.random.PRNGKey(0))
 
     rows, fields, features = 8, 3, 32
     ffm = FieldAwareFactorizationMachine(num_features=features,
@@ -70,7 +79,13 @@ def programs() -> dict:
         out_specs=P(), check_replication=False)).lower(
             jnp.zeros(plan.num_shards * 4))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
+            "sparse_tree": paths_of(sparse_tree),
             "step": paths_of(step), "reduce": paths_of(reduce)}
+
+
+# scopes that only one of the two tree programs opens
+DENSE_ONLY = {"gbdt.cast"}
+SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
 
 
 def carries(paths: set, scope: str, under: str = "") -> bool:
@@ -82,12 +97,40 @@ def carries(paths: set, scope: str, under: str = "") -> bool:
 def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
     where = {"gbdt": "fit", "ops": "fit", "batch": "step", "ffm": "step",
              "sgd": "step", "mesh": "reduce"}[scope.split(".")[0]]
+    if scope in SPARSE_ONLY:
+        where = "sparse_tree"
     assert carries(programs[where], scope), (
         f"no op of the {where} program carries the scope {scope}")
     if scope.startswith("gbdt.") and scope != "gbdt.boost":
-        # the tree program is what the chip runs; the whole-fit lowering
-        # above only adds the driver's eager ops to it
-        assert carries(programs["tree"], scope, under="jit(_build_tree)")
+        # the tree programs are what the chip runs; the whole-fit lowering
+        # above only adds the driver's eager ops to the dense one
+        if scope not in SPARSE_ONLY:
+            assert carries(programs["tree"], scope, under="jit(_build_tree)")
+        if scope not in DENSE_ONLY:
+            # split finding is a jitted function of its own inside the
+            # tree: its ops carry the scope under the call site's path
+            nested = scope == "gbdt.split"
+            assert carries(programs["sparse_tree"], scope, under=""
+                           if nested else "jit(_build_tree_sparse)")
+
+
+def test_sparse_tree_runs_the_kernels_under_their_scopes(programs):
+    """One program a tree: the sparse histogram kernel under ``gbdt.hist``
+    with its layout ops, the Pallas segment sums under ``gbdt.node_totals``
+    and ``gbdt.leaf``, split finding under the nested jit's ``gbdt.split``."""
+    paths = programs["sparse_tree"]
+    tree = "jit(_build_tree_sparse)/"
+    for call in ("gbdt.hist/jit(_histogram_gh_sparse_pallas)",
+                 "gbdt.node_totals/jit(_segment_sum_pallas)",
+                 "gbdt.leaf/jit(_segment_sum_pallas)",
+                 "jit(_level_splits_from_hist)",
+                 "gbdt.entry_gather/gather", "gbdt.route/while"):
+        assert tree + call in paths, call
+    assert carries(paths, "ops.hist_layout")
+    assert carries(paths, "gbdt.split")
+    # the rows are found in their feature's run by bisection: no gather
+    # under the route takes an index an entry
+    assert not carries(paths, "gbdt.route", under="scatter")
 
 
 def gathers_under(lowered, scope: str) -> list:

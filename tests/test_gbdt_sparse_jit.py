@@ -1,0 +1,354 @@
+"""The sparse tree builder as one jitted program a tree (PR 27), held
+against the op-by-op builder it replaced, and the device-built entry layout
+held against the host sort it replaced."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dmlc_core_tpu.models import GBDT
+from dmlc_core_tpu.ops.pallas_segment import (_KEY_TILE, _NNZ_TILE,
+                                              _round_up_some, segment_sum,
+                                              sparse_hist_layout)
+
+from test_gbdt import _sparse_identity_fixture
+
+
+def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
+                            col_key):
+    """`GBDT._build_tree_sparse` as it stood before PR 27, kept as the
+    reference: every level's gathers, scatters and kernel call dispatched
+    op by op (the layout's weight lane ``w`` went with the host sort, so the
+    per-tree entry gather is no longer multiplied by it)."""
+    F, B = self.num_features, self.num_bins
+    rows = grad.shape[0]
+    mono = self.monotone_constraints is not None
+    rid, fi, ebin, emask = entries
+    emw = emask.astype(jnp.float32)[:, None]
+    mesh = self.histogram_mesh is not None
+    gh_row = jnp.stack([grad, hess], axis=-1)
+    gh_k = gh_e = None
+    if layout is not None and not mesh:
+        gh_e = gh_row[layout.rid].T
+    node = jnp.zeros(rows, jnp.int32)
+    lo = jnp.full(1, -jnp.inf)
+    hi = jnp.full(1, jnp.inf)
+    active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
+              if self._interaction_groups is not None else None)
+    features, thresholds, defaults, gains, covers = [], [], [], [], []
+    for depth in range(self.max_depth):
+        first = 2 ** depth - 1
+        n_nodes = 2 ** depth
+        rel = node - first
+        impl = (self._hist_impl_sparse(n_nodes)
+                if layout is not None else "xla")
+        if impl == "pallas":
+            hist = self._level_histogram_sparse(
+                layout, rel, gh_row, gh_e,
+                None if mesh else rel[layout.rid], n_nodes)
+        else:
+            if gh_k is None:
+                gh_k = gh_row[rid] * emw
+            keys = (rel[rid] * F + fi) * B + ebin
+            hist = jax.ops.segment_sum(
+                gh_k, keys, num_segments=n_nodes * F * B
+            ).reshape(n_nodes, F, B, 2)
+        gh_node = segment_sum(
+            gh_row, rel, num_segments=n_nodes,
+            force="pallas" if impl == "pallas" and not mesh else None)
+        (split_f, split_b, split_d, split_g,
+         lo, hi, active) = self._level_splits_from_hist(
+            hist, gh_node, depth, col_mask, col_key, lo, hi, active)
+        features.append(split_f)
+        thresholds.append(split_b)
+        defaults.append(split_d)
+        gains.append(split_g)
+        covers.append(gh_node[:, 1])
+        go_right = self._route_sparse(fi, ebin, emask, rid, split_f[rel],
+                                      split_b[rel], split_d[rel], rows)
+        node = 2 * node + 1 + go_right.astype(jnp.int32)
+    n_leaves = 2 ** self.max_depth
+    leaf_rel = node - (n_leaves - 1)
+    leaf_force = ("pallas" if layout is not None and not mesh
+                  and self._hist_impl_sparse(n_leaves) == "pallas"
+                  else None)
+    gh_leaf = segment_sum(gh_row, leaf_rel, num_segments=n_leaves,
+                          force=leaf_force)
+    leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
+    if mono:
+        leaf_w = jnp.clip(leaf_w, lo, hi)
+    leaf = self.learning_rate * leaf_w
+    return (jnp.concatenate(features), jnp.concatenate(thresholds),
+            jnp.concatenate(defaults), jnp.concatenate(gains),
+            jnp.concatenate(covers), leaf, leaf_rel)
+
+
+class EagerGBDT(GBDT):
+    """The model with the reference builder in the jitted one's place.  The
+    reference wants the unsorted entries, which `fit_batch` drops when the
+    layout can stand for them: it gets them back from the layout."""
+
+    def _build_tree_sparse(self, entries, layout, grad, hess, col_mask,
+                           col_key):
+        if entries is None:
+            live = np.asarray(layout.gkey) >= 0
+            gkey = np.asarray(layout.gkey)[live]
+            entries = (jnp.asarray(np.asarray(layout.rid)[live]),
+                       jnp.asarray(gkey // layout.nb),
+                       jnp.asarray(gkey % layout.nb),
+                       jnp.ones(gkey.shape, bool))
+        return build_tree_sparse_eager(self, entries, layout, grad, hess,
+                                       col_mask, col_key)
+
+
+BASE = dict(num_features=4, num_trees=3, max_depth=3, num_bins=8,
+            learning_rate=0.5, missing_aware=True)
+CASES = {
+    "plain": {},
+    "stochastic": dict(subsample=0.8, colsample_bytree=0.75,
+                       colsample_bylevel=0.75),
+    "monotone": dict(monotone_constraints=[1, 0, -1, 0]),
+    "interaction": dict(interaction_constraints=[[0, 1], [2, 3]]),
+    "squared": dict(objective="squared"),
+    "gamma_weighted": dict(gamma=0.05, scale_pos_weight=2.0,
+                           min_child_weight=0.5),
+}
+
+
+@pytest.mark.parametrize("histogram", ["xla", "pallas"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_jitted_sparse_tree_equals_the_eager_one(case, histogram):
+    """Bit for bit: same splits, gains, covers and leaves, on the fixture
+    of test_gbdt.py's sparse identity tests and over the training controls
+    its sparse tests use."""
+    rng = np.random.default_rng(40)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=200, feats=4)
+    kw = dict(BASE, histogram=histogram, **CASES[case])
+    got = GBDT(**kw).fit_batch(batch, binner)
+    want = EagerGBDT(**kw).fit_batch(batch, binner)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]), err_msg=k)
+    assert np.any(np.asarray(got["threshold"]) < BASE["num_bins"])
+
+
+def test_jitted_sparse_tree_softmax_equals_the_eager_one():
+    rng = np.random.default_rng(44)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=200, feats=4)
+    import dataclasses
+    label = jnp.asarray(rng.integers(0, 3, 200).astype(np.float32))
+    batch = dataclasses.replace(batch, label=label)
+    kw = dict(BASE, num_trees=2, objective="softmax", num_class=3,
+              histogram="pallas")
+    got = GBDT(**kw).fit_batch(batch, binner)
+    want = EagerGBDT(**kw).fit_batch(batch, binner)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]),
+                                      np.asarray(want[k]), err_msg=k)
+
+
+def test_jitted_sparse_tree_under_a_mesh_equals_the_eager_one():
+    """The mesh route keeps its unsorted entries, its XLA node totals and
+    the shard_map'd kernel inside the one program."""
+    from jax.sharding import Mesh
+    rng = np.random.default_rng(43)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=256, feats=4)
+    mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
+    kw = dict(BASE, num_trees=2, histogram="pallas",
+              histogram_mesh=(mesh, "data"))
+    got = GBDT(**kw).fit_batch(batch, binner)
+    want = EagerGBDT(**kw).fit_batch(batch, binner)
+    # one program lets GSPMD add the shards' row sums in another order than
+    # op by op: the same splits, the sums to rounding
+    for k in want:
+        if k in ("split_gain", "split_cover", "leaf"):
+            np.testing.assert_allclose(np.asarray(got[k]),
+                                       np.asarray(want[k]), rtol=1e-5,
+                                       atol=1e-6, err_msg=k)
+        else:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(want[k]), err_msg=k)
+
+
+def test_fit_batch_keeps_one_copy_of_the_entries_when_the_kernel_runs():
+    """Every level on the kernel, one device: the tree program is handed the
+    layout and no other entry array; a level on XLA keeps them."""
+    rng = np.random.default_rng(40)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=200, feats=4)
+    seen = []
+
+    class Spy(GBDT):
+        def _build_tree_sparse(self, entries, layout, *a):
+            seen.append((entries is None, layout is None))
+            return GBDT._build_tree_sparse(self, entries, layout, *a)
+
+    Spy(**dict(BASE, num_trees=1, histogram="pallas")).fit_batch(batch,
+                                                                 binner)
+    Spy(**dict(BASE, num_trees=1, histogram="xla")).fit_batch(batch, binner)
+    assert seen == [(True, False), (False, True)]
+
+
+def test_a_row_that_holds_a_feature_twice_is_routed_as_on_xla():
+    """`_route_layout` finds one entry of a (row, feature) pair where
+    `_route_sparse` takes the largest bin over all of them: a batch whose
+    layout does not say ``rows_ascend`` keeps its entries and the latter,
+    so the kernel's forest splits as XLA's does."""
+    import dataclasses
+    rng = np.random.default_rng(40)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=200, feats=4)
+    ptr, index = np.asarray(batch.row_ptr), np.asarray(batch.index).copy()
+    value = np.asarray(batch.value).copy()
+    twice = [r for r in range(200) if ptr[r + 1] - ptr[r] >= 2][:20]
+    for r in twice:     # the row's second entry repeats its first feature
+        index[ptr[r] + 1] = index[ptr[r]]
+        value[ptr[r] + 1] = value[ptr[r]] + 1.0
+    batch = dataclasses.replace(batch, index=jnp.asarray(index),
+                                value=jnp.asarray(value))
+    seen = []
+
+    class Spy(GBDT):
+        def _build_tree_sparse(self, entries, layout, *a):
+            seen.append((entries is None, layout.rows_ascend))
+            return GBDT._build_tree_sparse(self, entries, layout, *a)
+
+    kw = dict(BASE, num_trees=2)
+    got = Spy(**dict(kw, histogram="pallas")).fit_batch(batch, binner)
+    want = GBDT(**dict(kw, histogram="xla")).fit_batch(batch, binner)
+    assert seen == [(False, False)] * 2
+    for k in ("feature", "threshold", "default_right"):
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=k)
+
+
+# ---- the layout ------------------------------------------------------------
+
+
+def host_layout(rid, fi, eb, em, num_features, num_bins, num_shards=1,
+                rows=None):
+    """`sparse_hist_layout` as it stood before PR 27 (numpy, stable argsort
+    by feature a shard), kept as the reference for the device sort; the
+    padded sizes follow PR 27's rounding (`_round_up_some`: two draws of one
+    data set compile once)."""
+    nb = 1 << max(num_bins - 1, 1).bit_length()
+    num_kt = -(-num_features * nb // _KEY_TILE)
+    rid, fi, eb = (np.asarray(a).astype(np.int64) for a in (rid, fi, eb))
+    em = np.asarray(em).astype(bool)
+    local = rows // num_shards if num_shards > 1 else 0
+    built = []
+    for s in range(num_shards):
+        sel = em & ((rid // local == s) if num_shards > 1 else True)
+        r, f, e = rid[sel] - s * local, fi[sel], eb[sel]
+        order = np.argsort(f, kind="stable")
+        starts = np.zeros(num_features + 1, np.int64)
+        np.cumsum(np.bincount(f, minlength=num_features), out=starts[1:])
+        tstart = np.zeros(num_kt, np.int32)
+        tcount = np.zeros(num_kt, np.int32)
+        for kt in range(num_kt):
+            flo = min((kt * _KEY_TILE) // nb, num_features)
+            fhi = min(-(-((kt + 1) * _KEY_TILE) // nb), num_features)
+            a, b = int(starts[flo]), int(starts[fhi])
+            if b > a:
+                tstart[kt] = a // _NNZ_TILE
+                tcount[kt] = -(-b // _NNZ_TILE) - tstart[kt]
+        built.append((r[order], f[order] * nb + e[order], tstart, tcount))
+    nnz_pad = _round_up_some(max(len(b[0]) for b in built), _NNZ_TILE, 64)
+    gkey = np.full(num_shards * nnz_pad, -1, np.int32)
+    rid_p = np.zeros(num_shards * nnz_pad, np.int32)
+    for s, (r, g, _, _) in enumerate(built):
+        gkey[s * nnz_pad:s * nnz_pad + len(g)] = g
+        rid_p[s * nnz_pad:s * nnz_pad + len(r)] = r
+    tstart = np.concatenate([b[2] for b in built])
+    tcount = np.concatenate([b[3] for b in built])
+    return dict(gkey=gkey, rid=rid_p, tstart=tstart, tcount=tcount,
+                nnz_pad=nnz_pad, nnz_live=sum(len(b[0]) for b in built),
+                max_tiles=_round_up_some(int(tcount.max()), 1, 16), nb=nb,
+                num_kt=num_kt)
+
+
+
+def random_entries(rng, rows, num_features, num_bins, nnz, present=None):
+    rid = np.sort(rng.integers(0, rows, nnz)).astype(np.int32)
+    fi = rng.choice(present if present is not None
+                    else np.arange(num_features), nnz).astype(np.int32)
+    eb = rng.integers(1, num_bins, nnz).astype(np.int32)
+    em = rng.random(nnz) < 0.9
+    return rid, fi, eb, em
+
+
+LAYOUTS = {
+    # name: (rows, F, B, nnz, features present, shards)
+    "small": (96, 5, 16, 700, None, 1),
+    "empty_features": (64, 9, 16, 900, [1, 4, 8], 1),
+    # 256 bins: a feature's keys are half a key tile; 512-wide strides
+    # (num_bins 300 -> nb 512) give every feature a whole tile
+    "feature_owns_a_key_tile": (128, 3, 300, 5000, None, 1),
+    "several_blocks_a_tile": (256, 2, 256, 6000, None, 1),
+    "no_live_entry": (16, 3, 8, 40, None, 1),
+    "sharded": (8 * 32, 3, 8, 1800, None, 8),
+    "sharded_with_an_empty_shard": (8 * 32, 4, 16, 900, None, 8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_device_layout_equals_the_host_one(name):
+    rows, F, B, nnz, present, shards = LAYOUTS[name]
+    rng = np.random.default_rng(sorted(LAYOUTS).index(name))
+    rid, fi, eb, em = random_entries(rng, rows, F, B, nnz, present)
+    if name == "no_live_entry":
+        em[:] = False
+    if name == "sharded_with_an_empty_shard":
+        em &= rid // 32 != 5
+    got = sparse_hist_layout(jnp.asarray(rid), jnp.asarray(fi),
+                             jnp.asarray(eb), jnp.asarray(em), F, B,
+                             num_shards=shards, rows=rows)
+    want = host_layout(rid, fi, eb, em, F, B, shards, rows)
+    for k, v in want.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, k)), v,
+                                      err_msg=k)
+
+
+def test_device_layout_refuses_entries_out_of_range():
+    rid = jnp.zeros(4, jnp.int32)
+    ok = jnp.ones(4, bool)
+    with pytest.raises(ValueError, match="out of range"):
+        sparse_hist_layout(rid, jnp.array([0, 1, 5, 2]), jnp.ones(4), ok,
+                           5, 8)
+    with pytest.raises(ValueError, match="out of range"):
+        sparse_hist_layout(rid, jnp.array([0, 1, 2, 3]),
+                           jnp.array([1, 8, 1, 1]), ok, 5, 8)
+    # a dead entry may hold anything
+    sparse_hist_layout(rid, jnp.array([0, 1, 9, 3]), jnp.ones(4),
+                       jnp.array([True, True, False, True]), 5, 8)
+
+
+# ---- entry binning ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("features,bins,n", [(5, 8, 1000), (40, 256, 20000),
+                                             (3, 3, 7), (968, 256, 50000)])
+def test_binning_by_sort_equals_binning_by_bisection(features, bins, n):
+    """`transform_entries`' two forms (chosen by the number of entries) give
+    one code an entry: ties with a cut, values below and above every cut,
+    signed zeros, NaN, features the sample never saw."""
+    from dmlc_core_tpu.models import QuantileBinner
+    from dmlc_core_tpu.models.gbdt import _bin_by_bisection, _bin_by_sort
+    rng = np.random.default_rng(n)
+    index = rng.integers(0, features, n)
+    value = np.round(rng.standard_normal(n), 1).astype(np.float32)
+    value[rng.random(n) < 0.01] = np.nan
+    value[rng.random(n) < 0.01] = 0.0
+    value[rng.random(n) < 0.005] = -0.0
+    seen = ~np.isnan(value) & (index != features - 1)
+    binner = QuantileBinner(num_bins=bins, missing_aware=True).fit_sparse(
+        index[seen][: n // 2], value[seen][: n // 2], features)
+    by_sort = np.asarray(_bin_by_sort(binner.cuts, jnp.asarray(index),
+                                      jnp.asarray(value)))
+    by_bisection = np.asarray(_bin_by_bisection(
+        binner.cuts, jnp.asarray(index), jnp.asarray(value)))
+    np.testing.assert_array_equal(by_sort, by_bisection)
+    assert by_sort.min() == (0 if np.isnan(value).any() else 1)
+    assert by_sort.max() <= bins - 1
+    np.testing.assert_array_equal(
+        np.asarray(binner.transform_entries(jnp.asarray(index),
+                                            jnp.asarray(value))), by_sort)

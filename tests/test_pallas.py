@@ -352,7 +352,7 @@ def test_sparse_layout_feature_sort_determinism():
     rid, fi, eb, em, rel, gh = _sparse_case(rng, rows, F, B, n_nodes, 700)
     la = sparse_hist_layout(rid, fi, eb, em, F, B)
     lb = sparse_hist_layout(rid, fi, eb, em, F, B)
-    for f in ("gkey", "rid", "w", "tstart", "tcount"):
+    for f in ("gkey", "rid", "tstart", "tcount"):
         np.testing.assert_array_equal(np.asarray(getattr(la, f)),
                                       np.asarray(getattr(lb, f)), err_msg=f)
     ha = histogram_gh_sparse(rid, fi, eb, em, rel, gh, n_nodes, F, B,
@@ -383,20 +383,20 @@ def test_histogram_gh_sparse_shardmap_psum_matches_global():
                                 num_shards=8, rows=rows)
     mt = layout.max_tiles
 
-    def local(gk, rid_l, w_l, ts, tc, rel_l, gh_l):
+    def local(gk, rid_l, ts, tc, rel_l, gh_l):
         rel_e = rel_l[rid_l]
-        gh_e = gh_l[rid_l] * w_l[:, None]
+        gh_e = gh_l[rid_l].T
         h = histogram_gh_sparse_kernel(gk, rel_e, gh_e, ts, tc,
                                        n_nodes, F, B, mt)
         return jax.lax.psum(h, "data")
 
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
     sharded = jax.jit(shard_map_compat(
-        local, mesh, in_specs=(P("data"),) * 7, out_specs=P(),
+        local, mesh, in_specs=(P("data"),) * 6, out_specs=P(),
         check_replication=False))
     rs = NamedSharding(mesh, P("data"))
     got = sharded(*(jax.device_put(a, rs) for a in
-                    (layout.gkey, layout.rid, layout.w,
+                    (layout.gkey, layout.rid,
                      layout.tstart, layout.tcount, rel, gh)))
     want = histogram_gh_sparse(rid, fi, eb, em, rel, gh, n_nodes, F, B)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=4e-6)
