@@ -183,86 +183,97 @@ def _segment_sum_bwd(num_segments, interpret, res, g):
 _segment_sum_pallas_diff.defvjp(_segment_sum_fwd, _segment_sum_bwd)
 
 
-_KEY_TILE = 512    # (feature, bin) key lanes per out tile
+_KEY_TILE = 512    # (feature, bin) key lanes per out tile (sparse kernel)
 
 # Most nodes per level the two histogram kernels are routed to by
 # ``GBDT(histogram="auto")``.  Their compare work does not depend on
-# n_nodes; what grows with it is the MXU M axis and the VMEM tiles — the A
-# tile [rows-or-entries per step, 2*n_pad], its mask and one-hot
-# temporaries, and the double-buffered out tile [2*n_pad, KEY_TILE] — so
-# each cap is the largest power of two at which Mosaic fits the kernel into
-# its 16 MiB of scoped VMEM on a v5e (PR 21, libtpu 0.0.34).  Above it the
-# compiler refuses: the dense kernel at 1024 nodes asks for 25.81M, the
-# sparse kernel (1024-entry steps against the dense kernel's 512-row ones)
-# for 21.24M at 512 — "RESOURCE_EXHAUSTED: Ran out of memory in memory
-# space vmem ... exceeded scoped vmem limit".  chip_smoke.py compiles and
-# checks both kernels at exactly these values, so a cap that stops
-# compiling fails there and not in somebody's depth-10 fit.
+# n_nodes; what grows with it is the MXU M axis and the VMEM tiles (the A
+# tile, its mask and one-hot temporaries, the out tile).  The sparse
+# kernel's cap is the largest power of two at which Mosaic fits it into its
+# 16 MiB of scoped VMEM on a v5e (PR 21, libtpu 0.0.34): at 512 nodes it
+# asks for 21.24M, "RESOURCE_EXHAUSTED: Ran out of memory in memory space
+# vmem ... exceeded scoped vmem limit".  The dense kernel's cap is the most
+# a chip run has held it to: it compiles for a described v5e at 1024 nodes
+# too (its step is sized by ``_hist_plan``, not by the node count alone),
+# and nothing has run it there.  chip_smoke.py compiles and checks both
+# kernels at exactly these values, so a cap that stops compiling fails
+# there and not in somebody's depth-10 fit.
 HIST_NODE_LIMIT = 512
 SPARSE_HIST_NODE_LIMIT = 256
 
+# The dense kernel's own tile sizes (the other kernels read none of them);
+# how a call chooses among them: ``_hist_plan``, at the end of this file.
+_HIST_ROW_TILE = 1024      # rows a grid step
+_HIST_MIN_STRIDE = 64      # least key lanes a feature: <= 4 features a tile
+_HIST_STACK_NODES = 32     # node columns up to which ONE dot holds 3 parts
+_HIST_STEP_BYTES = 6 << 20     # a step's out block and A^T may take this ...
+_HIST_STEP_TILES = 32          # ... and a step unrolls at most this many tiles
 
-def _hist_kernel(nb: int, fpt: int, q: int, n_pad: int,
-                 bins_ref, rel_ref, gh_ref, out_ref):
-    """One (key-tile, row-tile) step of the histogram-as-matmul:
 
-        out[(lane, node), (feature, bin)] += A^T B
-        A[row, (lane, node)] = gh[lane, row] * [rel[row] == node]
-        B[row, (feature, bin)] = [bins[feature, row] == bin]
+def _hist_kernel(plan, bins_ref, rel_ref, gh_ref, out_ref):
+    """One (key-group, row-tile) step of the histogram-as-matmul:
 
-    The M axis is (2 lanes x n_pad nodes) — wide enough to feed the MXU
-    (the naive per-feature formulation had M=2, so every matmul paid for
-    128 rows and used 2).  B's one-hot build is the only compare work:
-    O(rows * F * num_bins) instead of O(rows * F * num_bins * n_nodes).
-    Everything stays 2-D (squeezing indexing lowers to a Mosaic-rejected
-    gather) and feature rows are read via dynamic *ref* loads
-    (lax.dynamic_slice on a loaded array is unimplemented in Mosaic)."""
-    kt = pl.program_id(0)
-    rt = pl.program_id(1)
+        out[(part, lane, node), (feature, bin)] += A^T B
+        A^T[(part, lane, node), row] = gh[part, lane, row] * [rel[row] == node]
+        B^T[(feature, bin), row]     = [bins[feature, row] == bin]
 
-    @pl.when(rt == 0)
+    Rows stay on the lane axis, as ``bins``, ``rel`` and ``gh`` arrive, so
+    no operand is relaid: A^T and B^T are sublane broadcasts compared with
+    an iota, and the contraction is over the last axis of both.  Both are
+    bfloat16.  B^T is 0/1; ``gh_ref`` holds (grad, hess) as three parts
+    that are each exactly a bfloat16 and sum to the float32
+    (``_split_bf16x3``); so every product is exact and the MXU's float32
+    accumulation gives what ``Precision.HIGHEST`` gave, in one pass,
+    without the passes that multiplied by the zero low parts of B.  The M
+    axis is (3 parts x 2 lanes x n_pad nodes).  A^T is built once a step
+    and serves each of the step's ``tiles`` key tiles of ``w`` lanes
+    (``fpt`` whole features each, or a ``w``-lane slice of one feature
+    when ``q`` > 1); B's one-hot is the only work that grows with the
+    keys, O(rows * F * num_bins) compares whatever n_nodes.  Everything
+    stays 2-D and feature rows are read via dynamic *ref* loads (squeezing
+    indexing and lax.dynamic_slice on a loaded array do not lower in
+    Mosaic)."""
+    w, nb, fpt, q, n_pad = plan.w, plan.nb, plan.fpt, plan.q, plan.n_pad
+    first_tile = pl.program_id(0) * plan.tiles
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # A: [ROW, 2*n_pad] node-masked (grad, hess).  Padding rows carry
-    # rel == n_pad (matches no node column) AND gh == 0, so they are inert.
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (_ROW_TILE, n_pad), 1)
-    rel_col = jnp.broadcast_to(rel_ref[...].reshape(_ROW_TILE, 1),
-                               (_ROW_TILE, n_pad))
-    mask = (rel_col == node_ids).astype(jnp.float32)
-    g_col = jnp.broadcast_to(gh_ref[0:1, :].reshape(_ROW_TILE, 1),
-                             (_ROW_TILE, n_pad))
-    h_col = jnp.broadcast_to(gh_ref[1:2, :].reshape(_ROW_TILE, 1),
-                             (_ROW_TILE, n_pad))
-    a = jnp.concatenate([mask * g_col, mask * h_col], axis=1)
-    # B: [ROW, KEY_TILE] one-hot of this tile's (feature, bin) keys
-    loc = jax.lax.broadcasted_iota(jnp.int32, (_ROW_TILE, _KEY_TILE), 1)
-    b = jnp.zeros((_ROW_TILE, _KEY_TILE), jnp.float32)
-    # bins_ref holds an 8-feature block (see in_specs); the rows this tile
-    # needs are at dynamic offsets *within* the block, hence the pl.ds ref
-    # loads (lax.dynamic_slice on a loaded array is unimplemented, and an
-    # (fpt, ROW) block would break the mult-of-8-or-full tiling rule).
-    if q == 1:
-        # nb <= KEY_TILE: tile kt covers fpt whole features; fpt divides 8,
-        # so all of them live in this 8-feature block
-        base = (kt * fpt) % 8
-        for fl in range(fpt):
-            bf = bins_ref[pl.ds(base + fl, 1), :]       # [1, ROW]
-            bcol = jnp.broadcast_to(bf.reshape(_ROW_TILE, 1),
-                                    (_ROW_TILE, _KEY_TILE))
-            b += (loc == bcol + fl * nb).astype(jnp.float32)
-    else:
-        # nb == q * KEY_TILE: tile kt is slice (kt % q) of feature kt // q
-        bf = bins_ref[pl.ds((kt // q) % 8, 1), :]
-        bcol = jnp.broadcast_to(bf.reshape(_ROW_TILE, 1),
-                                (_ROW_TILE, _KEY_TILE))
-        b += (loc == bcol - (kt % q) * _KEY_TILE).astype(jnp.float32)
-    # contract over rows; HIGHEST keeps f32 exactness on the MXU (DEFAULT
-    # rounds gh through bf16: measured 3.5e-2 abs error on N(0,1) grads)
-    out_ref[...] += jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)
+    rt = rel_ref.shape[1]
+    # Padding rows carry rel == n_pad (matches no node column) AND gh == 0:
+    # whatever bins the ragged last block holds there is inert.
+    node_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, rt), 0)
+    mask = jnp.broadcast_to(rel_ref[...], (n_pad, rt)) == node_ids
+    a_t = jnp.concatenate(
+        [jnp.where(mask, jnp.broadcast_to(gh_ref[i:i + 1, :], (n_pad, rt)),
+                   0.0) for i in range(6)], axis=0).astype(jnp.bfloat16)
+    lanes = min(nb, w)
+    loc = jax.lax.broadcasted_iota(jnp.int32, (lanes, rt), 0)
+
+    def one_hot(feature, offset):
+        # bins_ref is every feature or an 8-feature block (see in_specs);
+        # a padding feature past the last reads some row: its keys are cut
+        row = bins_ref[pl.ds(feature % plan.fb, 1), :]          # [1, rt]
+        hit = loc == jnp.broadcast_to(row, (lanes, rt)) - offset
+        return jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)
+
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    m = 2 * n_pad
+    for j in range(plan.tiles):
+        kt = first_tile + j
+        if q == 1:      # tile kt: features kt * fpt + fl, one after another
+            b_t = jnp.concatenate(
+                [one_hot(kt * fpt + fl, 0) for fl in range(fpt)], axis=0)
+        else:           # tile kt: slice kt % q of feature kt // q
+            b_t = one_hot(kt // q, (kt % q) * w)
+        if plan.parts == 3:
+            acc = dot(a_t, b_t)
+        else:
+            acc = sum(dot(a_t[p * m:(p + 1) * m], b_t) for p in range(3))
+        out_ref[:, j * w:(j + 1) * w] += acc
 
 
 @functools.partial(jax.jit,
@@ -273,56 +284,35 @@ def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
     """bins_t: [F, rows] int32; rel: [rows] int32 node ids; gh: [rows, 2].
     Returns [n_nodes, F, num_bins, 2]."""
     F, rows = bins_t.shape
-    # Keys tile in KEY_TILE lanes, so bins are laid out on a power-of-2
-    # stride >= num_bins: either several whole features per tile (fpt) or
-    # several tiles per feature (q).  Bin codes < num_bins never touch the
-    # padded lanes; they are sliced off below.
-    nb = 1 << max(num_bins - 1, 1).bit_length()   # next pow2 >= num_bins
-    # floor the stride so fpt <= 8: the per-tile feature loop is unrolled,
-    # and tiny num_bins would otherwise unroll KEY_TILE/nb (up to 256)
-    # compare bodies — measured to crash the TPU compiler outright
-    nb = max(nb, _KEY_TILE // 8)
-    if nb <= _KEY_TILE:
-        fpt, q = _KEY_TILE // nb, 1
-    else:
-        fpt, q = 1, nb // _KEY_TILE
-    rows_pad = pl.cdiv(max(rows, 1), _ROW_TILE) * _ROW_TILE
-    k_pad = pl.cdiv(F * nb, _KEY_TILE) * _KEY_TILE
-    f_pad = k_pad // nb
-    # bins stream in 8-feature blocks (the smallest legal sublane tile), so
-    # each grid step fetches 8 rows of bins instead of all f_pad — the HBM
-    # traffic and VMEM block stay O(1) in F.  The kernel indexes inside the
-    # block with pl.ds; fpt | 8 guarantees a tile's features never straddle
-    # a block boundary.
-    f_pad8 = pl.cdiv(f_pad, 8) * 8
-    n_pad = pl.cdiv(n_nodes, 8) * 8
-    m_pad = 2 * n_pad
+    p = _hist_plan(F, num_bins, n_nodes)
+    k_pad = p.num_kt * p.w
+    m_pad = 2 * p.parts * p.n_pad
+    rows_pad = pl.cdiv(max(rows, 1), _HIST_ROW_TILE) * _HIST_ROW_TILE
     with jax.named_scope("ops.hist_layout"):
-        bins_p = jnp.zeros((f_pad8, rows_pad), jnp.int32
-                           ).at[:F, :rows].set(bins_t)
-        rel_p = jnp.full((1, rows_pad), n_pad, jnp.int32
+        rel_p = jnp.full((1, rows_pad), p.n_pad, jnp.int32
                          ).at[0, :rows].set(rel)
-        gh_p = jnp.zeros((2, rows_pad), jnp.float32).at[:, :rows].set(
-            gh.astype(jnp.float32).T)
-    if q == 1:
-        bins_index = lambda kt, rt: ((kt * fpt) // 8, rt)   # noqa: E731
-    else:
-        bins_index = lambda kt, rt: ((kt // q) // 8, rt)    # noqa: E731
+        # (part, lane) rows: g_hi, h_hi, g_mid, h_mid, g_lo, h_lo, 0, 0
+        gh_p = jnp.zeros((8, rows_pad), jnp.float32).at[:6, :rows].set(
+            jnp.concatenate(_split_bf16x3(gh.astype(jnp.float32).T)))
+    def bins_index(g, rt):
+        # the block that holds step g's features: its first tile's first
+        return ((g * p.tiles * p.fpt // p.q) // p.fb, rt)
+
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, nb, fpt, q, n_pad),
-        grid=(k_pad // _KEY_TILE, rows_pad // _ROW_TILE),
+        functools.partial(_hist_kernel, p),
+        grid=(p.num_kt // p.tiles, rows_pad // _HIST_ROW_TILE),
         in_specs=[
-            pl.BlockSpec((8, _ROW_TILE), bins_index),
-            pl.BlockSpec((1, _ROW_TILE), lambda kt, rt: (0, rt)),
-            pl.BlockSpec((2, _ROW_TILE), lambda kt, rt: (0, rt)),
+            pl.BlockSpec((p.fb, _HIST_ROW_TILE), bins_index),
+            pl.BlockSpec((1, _HIST_ROW_TILE), lambda g, rt: (0, rt)),
+            pl.BlockSpec((8, _HIST_ROW_TILE), lambda g, rt: (0, rt)),
         ],
-        out_specs=pl.BlockSpec((m_pad, _KEY_TILE), lambda kt, rt: (0, kt)),
+        out_specs=pl.BlockSpec((m_pad, p.tiles * p.w), lambda g, rt: (0, g)),
         out_shape=jax.ShapeDtypeStruct((m_pad, k_pad), jnp.float32),
         interpret=interpret,
         name=DENSE_HIST_KERNEL,
-    )(bins_p, rel_p, gh_p)
+    )(bins_t, rel_p, gh_p)
     with jax.named_scope("ops.hist_layout"):
-        return (out.reshape(2, n_pad, f_pad, nb)
+        return (out.reshape(p.parts, 2, p.n_pad, k_pad // p.nb, p.nb).sum(0)
                 [:, :n_nodes, :F, :num_bins]
                 .transpose(1, 2, 3, 0))                 # [n, F, B, 2]
 
@@ -342,18 +332,28 @@ def histogram_gh(bins: jax.Array, rel: jax.Array, gh: jax.Array,
     of HBM traffic (Higgs-11M x 28 features: ~3.7 GB per level); it is
     the right trade on CPU.
 
-    "pallas" -> the histogram-as-matmul kernel above: per (key-tile,
-    row-tile) step it builds A = node-masked (grad, hess) [ROW, 2*nodes]
-    and B = bin one-hot [ROW, KEY_TILE] and contracts over rows on the
-    MXU at f32 (HIGHEST) precision — scatter-free, nothing materialized
-    at [rows, F] granularity, compare work O(rows*F*bins) independent of
-    n_nodes, and an M axis wide enough to use the systolic array.
-    Against the XLA path on a v5e (rows=65,536, F=28, 256 bins, PR 21's
+    "pallas" -> the histogram-as-matmul kernel above: per row tile it
+    builds A^T = node-masked (grad, hess) [3*2*nodes, ROW] and, key tile by
+    key tile, B^T = bin one-hot [KEYS, ROW], both bfloat16, and contracts
+    over rows on the MXU in ONE pass with float32 accumulation —
+    scatter-free, nothing materialized at [rows, F] granularity, compare
+    work O(rows*F*bins) independent of n_nodes.  Float32 exactness comes
+    from the operands, not from ``Precision.HIGHEST``: (grad, hess) go in
+    as three bfloat16 parts that sum to the float32 bit for bit
+    (``_split_bf16x3``: every finite float32 of magnitude >= 9.9e-32, up to
+    the largest; below that a value loses at most 1.2e-38 where the chip
+    flushes denormals), B is 0/1, and the parts' partial histograms are
+    added in float32 (on the M axis of one dot up to 32 node columns, three
+    dots above).  A non-finite grad or hess makes every bucket of its
+    row's node non-finite (inf * 0 on the MXU), as it always did.  The
+    tiling is chosen by the static shapes alone (``_hist_plan``).
+    Against the XLA path on a v5e (rows=65,536, F=28, 256 bins,
     chip_smoke.py): max error <= 7e-7 of the largest bucket at 1, 32 and
     512 nodes (accumulation order only), so the backends stay drop-in
-    interchangeable.  Its speed against XLA scatter has no measurement on
-    the current installation.  Interpret mode off-TPU is a correctness
-    tool, not an execution path.
+    interchangeable.  Its speed is in PERF.md, from the benchmark's
+    ledger; against XLA scatter it has no measurement on the current
+    installation.  Interpret mode off-TPU is a correctness tool, not an
+    execution path.
     """
     check_force(force, "histogram backend")
     if force == "pallas":
@@ -773,3 +773,84 @@ def segment_sum(contrib: jax.Array, row_id: jax.Array, num_segments: int,
                                        pallas_interpret())
         return out.astype(contrib.dtype)
     return jax.ops.segment_sum(contrib, row_id, num_segments=num_segments)
+
+
+# ---- dense histogram: tiling plan and the exact bfloat16 split --------------
+
+
+@dataclasses.dataclass(frozen=True)
+class _HistPlan:
+    """How one ``_histogram_gh_pallas`` call is tiled; static, from shapes."""
+    nb: int       # key lanes a feature: the power of 2 >= num_bins (>= 64)
+    w: int        # key lanes a tile
+    fpt: int      # whole features a tile (when q == 1)
+    q: int        # tiles a feature (when fpt == 1)
+    num_kt: int   # key tiles in all
+    n_pad: int    # node columns, padded to 8
+    parts: int    # bfloat16 parts that are rows of one dot: 3, else 1
+    tiles: int    # key tiles a grid step: all of them, else a power of 2
+    fb: int       # feature rows in a block of bins
+
+
+def _hist_plan(num_features: int, num_bins: int, n_nodes: int) -> _HistPlan:
+    """One algorithm; its tiling is chosen by the static shapes alone.
+
+    Keys tile in ``w`` lanes, so bins are laid out on a power-of-2 stride
+    ``nb`` >= num_bins: either several whole features per tile (``fpt``) or
+    several tiles per feature (``q``).  Bin codes < num_bins never touch the
+    padded lanes, which the caller slices off.  The stride has a floor so
+    that a tile's unrolled feature loop stays short (tiny num_bins once
+    unrolled 256 compare bodies and crashed the TPU compiler outright).  A
+    tile is one feature wide where it can be: on a v5e, at 10.5M rows x 28
+    features x 256 bins and one node, 256-lane tiles took 52.7 ms a level,
+    512-lane ones 58.0, 128-lane slices 205 (my chip runs, PR 28).
+
+    A grid step takes as many key tiles as its out block ``[M, tiles * w]``
+    and A^T ``[3 * 2 * n_pad, rows a step]`` leave it (``_HIST_STEP_BYTES``,
+    ``_HIST_STEP_TILES``): A^T is built once a step and the bins are read
+    once a step.  While that is every key tile, the grid is (1, row tiles)
+    and the bins of all F features are in the block.  Past that, key tiles
+    return to the grid, outermost, in groups of at most 8 features' tiles,
+    a power of two, because bins then stream in 8-feature blocks (the
+    smallest legal sublane tile): a group's features never straddle a
+    block, and the kernel indexes inside it with pl.ds.  ``num_kt`` is
+    rounded up to whole groups; the padding tiles' keys are sliced off."""
+    nb = max(1 << max(num_bins - 1, 1).bit_length(), _HIST_MIN_STRIDE)
+    w = min(max(nb, 256), 512)
+    fpt, q = (w // nb, 1) if nb <= w else (1, nb // w)
+    num_kt = pl.cdiv(num_features * nb, w)
+    n_pad = pl.cdiv(n_nodes, 8) * 8
+    parts = 3 if n_pad <= _HIST_STACK_NODES else 1
+
+    def fits(tiles):
+        return tiles <= _HIST_STEP_TILES and (
+            2 * parts * n_pad * tiles * w * 4
+            + 6 * n_pad * _HIST_ROW_TILE * 2) <= _HIST_STEP_BYTES
+
+    if fits(num_kt):
+        tiles, fb = num_kt, num_features
+    else:
+        tiles, fb = 8 * q // fpt, min(num_features, 8)
+        while tiles > 1 and not fits(tiles):
+            tiles //= 2
+        num_kt = pl.cdiv(num_kt, tiles) * tiles
+    return _HistPlan(nb=nb, w=w, fpt=fpt, q=q, num_kt=num_kt, n_pad=n_pad,
+                     parts=parts, tiles=tiles, fb=fb)
+
+
+def _split_bf16x3(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """float32 ``x`` as three float32 arrays that are each exactly a
+    bfloat16 and sum to ``x`` bit for bit: the top 16 bits of ``x``, of what
+    is left, and the rest (8 significant bits each, 24 in all).  By masking,
+    not by rounding: a cast to bfloat16 and back is what XLA may elide as
+    excess precision, and rounding the largest float32 up would overflow.
+    Exact for zeros and for every finite magnitude from 2**-103 (9.9e-32)
+    up; below that the last part is a denormal float32, which the chip
+    flushes to zero (an error of at most 2**-126 a value)."""
+    def top16(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+    hi = top16(x)
+    mid = top16(x - hi)
+    return hi, mid, x - hi - mid

@@ -64,9 +64,28 @@ def op_names(compiled) -> set:
     return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
 
 
-@pytest.mark.parametrize("n_nodes", [16, 32, 64])
+def kernel_dots(fn, *shapes) -> list:
+    """``(operand dtypes, precision, result dtype)`` of every contraction
+    in the Pallas kernels of ``fn``, read from their jaxprs."""
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                yield ([str(v.aval.dtype) for v in eqn.invars],
+                       eqn.params["precision"],
+                       str(eqn.outvars[0].aval.dtype))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, inside
+                                or eqn.primitive.name == "pallas_call")
+    return list(walk(jax.make_jaxpr(fn)(*shapes).jaxpr, False))
+
+
+# 1 to 32 nodes: every key tile in one step, the three parts on one dot;
+# 64: the parts on three dots; 128: groups of 8 features' tiles on the grid;
+# 512 = HIST_NODE_LIMIT: one tile a step
+@pytest.mark.parametrize("n_nodes", [1, 16, 32, 64, 128, 512])
 def test_dense_histogram_kernel_keeps_the_name_the_benchmark_matches(
         one_chip, quiet_cache, n_nodes):
+    assert n_nodes <= pallas_segment.HIST_NODE_LIMIT
     pattern = json.loads((LAYER_METRICS / "hist_ms_per_round.json")
                          .read_text())["args"]["pattern"]
     roofline = json.loads((LAYER_METRICS / "hist_roofline.json")
@@ -77,16 +96,22 @@ def test_dense_histogram_kernel_keeps_the_name_the_benchmark_matches(
         return pallas_segment._histogram_gh_pallas(
             bins.T, rel, gh, n_nodes, BINS, False)
 
-    compiled = jax.jit(level).lower(
-        on(one_chip, (ROWS, FEATURES), jnp.int32),
-        on(one_chip, (ROWS,), jnp.int32),
-        on(one_chip, (ROWS, 2), jnp.float32)).compile()
+    shapes = (on(one_chip, (ROWS, FEATURES), jnp.int32),
+              on(one_chip, (ROWS,), jnp.int32),
+              on(one_chip, (ROWS, 2), jnp.float32))
+    compiled = jax.jit(level).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     kernels = [n for n in instructions(compiled) if re.search(pattern, n)]
     assert len(kernels) == 1, (pattern, kernels)
     assert kernels[0].startswith("%" + pallas_segment.DENSE_HIST_KERNEL)
     assert any("ops.hist_layout" in n for n in op_names(compiled))
+    # one bfloat16 pass with float32 accumulation: no float32 operand and
+    # no HIGHEST left in the kernel
+    dots = kernel_dots(level, *shapes)
+    assert dots and all(
+        operands == ["bfloat16", "bfloat16"] and precision is None
+        and result == "float32" for operands, precision, result in dots), dots
 
 
 def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(

@@ -88,18 +88,18 @@ def test_histogram_gh_matches_xla():
 
 def test_histogram_gh_wide_and_narrow_bins_match_xla():
     """The kernel's key-tiling branches beyond the GBDT-default shapes:
-    num_bins > KEY_TILE=512 routes a feature across several key tiles
+    num_bins > 512 routes a feature across several 512-lane key tiles
     (the q>1 branch — kt//q feature select, kt%q in-feature slice), and
-    tiny num_bins engages the fpt<=8 unroll clamp (effective stride
-    KEY_TILE/8 with most lanes padded).  Neither is reachable from
-    GBDT/QuantileBinner (bins <= 256), so they are pinned here on the
-    op's public surface."""
+    tiny num_bins engages the stride's floor (64 lanes a feature, four
+    features a 256-lane tile, most lanes padded).  Neither is reachable
+    from GBDT/QuantileBinner (bins <= 256), so they are pinned here on
+    the op's public surface."""
     rng = np.random.default_rng(11)
     for rows, F, B, n_nodes in [
             (300, 3, 1024, 4),    # q=2: feature spans two key tiles
             (120, 2, 2048, 2),    # q=4
             (100, 5, 600, 3),     # non-pow2 > 512 -> nb=1024, q=2
-            (90, 4, 2, 2),        # fpt clamp: nb floors at 64
+            (90, 4, 2, 2),        # stride floor: nb = 64, fpt = 4
             (150, 9, 3, 5),       # non-pow2 tiny bins through the clamp
     ]:
         bins = jnp.asarray(rng.integers(0, B, (rows, F)).astype(np.int32))
@@ -418,3 +418,161 @@ def test_segment_sum_empty_shard_dtype_matches_contrib():
                                        jnp.zeros((0,), jnp.int32),
                                        4, interpret=True)
         assert internal.dtype == dtype and internal.shape == (4, 2)
+
+
+# ---- dense histogram kernel against float64 ---------------------------------
+
+
+def _hist_f64(bins, rel, gh, n_nodes, num_bins):
+    """The histogram summed in float64, bucket by bucket."""
+    rows, F = bins.shape
+    out = np.zeros((n_nodes, F, num_bins, 2))
+    for f in range(F):
+        np.add.at(out, (rel, f, bins[:, f]), gh.astype(np.float64))
+    return out
+
+
+def _hist_case(rows, F, B, n_nodes, seed):
+    """Gradients spread over six decades, so that the low bits of the large
+    ones and the whole of the small ones both have to arrive."""
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, B, (rows, F)).astype(np.int32)
+    rel = rng.integers(0, n_nodes, rows).astype(np.int32)
+    gh = (rng.standard_normal((rows, 2))
+          * 10.0 ** rng.integers(-3, 3, (rows, 2))).astype(np.float32)
+    return bins, rel, gh
+
+
+def _worst(got, want):
+    """Largest error as a share of the largest bucket."""
+    return np.abs(np.asarray(got, np.float64) - want).max() / np.abs(want).max()
+
+
+# the kernel's worst over these cases is 1.4e-7; one bfloat16 pass reads
+# 1.7e-3 to 2.4e-3 and two of the three parts 0.9e-5 to 2e-5 (the control)
+HIST_F64_LIMIT = 5e-7
+
+# rows never a multiple of the 1,024-row tile; F of 1, odd, not a multiple
+# of 8, past 32 tiles; strides below the 256-lane tile (fpt 4, 2), at it,
+# above 512 (q 2, 4); node columns on one dot (<= 32) and on three
+HIST_F64_CASES = [
+    # (n_nodes, F, num_bins, rows)
+    (1, 1, 16, 700), (1, 28, 256, 2100), (2, 5, 64, 1500), (2, 130, 16, 1100),
+    (8, 28, 256, 1030), (8, 5, 1024, 1100), (8, 130, 256, 300),
+    (32, 5, 256, 2050), (32, 28, 64, 1300), (32, 1, 1024, 999),
+    (64, 28, 256, 1200), (64, 5, 16, 700), (128, 28, 256, 1100),
+    (128, 5, 1024, 300), (512, 5, 16, 2050), (512, 28, 256, 1200),
+    (512, 3, 2048, 130), (40, 3, 300, 999), (24, 7, 2, 300),
+]
+
+
+@pytest.mark.parametrize("n_nodes,F,num_bins,rows", HIST_F64_CASES)
+def test_dense_histogram_kernel_is_float32_exact_against_float64(
+        n_nodes, F, num_bins, rows):
+    bins, rel, gh = _hist_case(rows, F, num_bins, n_nodes,
+                               seed=n_nodes * 1000 + F)
+    want = _hist_f64(bins, rel, gh, n_nodes, num_bins)
+    got = histogram_gh(jnp.asarray(bins), jnp.asarray(rel), jnp.asarray(gh),
+                       n_nodes, num_bins, force="pallas")
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert _worst(got, want) < HIST_F64_LIMIT
+
+
+@pytest.mark.parametrize("step_bytes,tiles", [(6 << 20, 28), (1 << 20, 8),
+                                              (1 << 18, 2), (0, 1)])
+def test_dense_histogram_kernel_tilings_agree(monkeypatch, step_bytes, tiles):
+    """Every tiling the plan can choose — all key tiles a step, the tiles
+    of 8 or of 2 features, one tile — gives the same sums but for the order
+    of additions.  The room a step may take is squeezed here, in the place
+    of shapes large enough to squeeze it."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    monkeypatch.setattr(ps, "_HIST_STEP_BYTES", step_bytes)
+    assert ps._hist_plan(28, 256, 8).tiles == tiles
+    for n_nodes, F, num_bins, rows in [(8, 28, 256, 1100), (2, 11, 64, 1500),
+                                       (64, 5, 1024, 700)]:
+        bins, rel, gh = _hist_case(rows, F, num_bins, n_nodes, seed=F)
+        want = _hist_f64(bins, rel, gh, n_nodes, num_bins)
+        # not through the jit's cache: the plan is read while tracing
+        got = ps._histogram_gh_pallas.__wrapped__(
+            jnp.asarray(bins.T), jnp.asarray(rel), jnp.asarray(gh),
+            n_nodes, num_bins, True)
+        assert _worst(got, want) < HIST_F64_LIMIT, (n_nodes, F, num_bins)
+
+
+def test_one_bfloat16_pass_or_two_parts_would_fail_the_float64_limit():
+    """The control: the same histogram from (grad, hess) rounded to one
+    bfloat16, and from two of the three parts, summed in float64 — what a
+    kernel that dropped a pass would give at best — is outside the limit
+    the kernel is held to."""
+    from dmlc_core_tpu.ops.pallas_segment import _split_bf16x3
+    worst_one, worst_two = 0.0, 0.0
+    for n_nodes, F, num_bins, rows in HIST_F64_CASES[:6]:
+        bins, rel, gh = _hist_case(rows, F, num_bins, n_nodes,
+                                   seed=n_nodes * 1000 + F)
+        want = _hist_f64(bins, rel, gh, n_nodes, num_bins)
+        hi, mid, _ = (np.asarray(p) for p in _split_bf16x3(jnp.asarray(gh)))
+        one = np.asarray(jnp.asarray(gh).astype(jnp.bfloat16)
+                         .astype(jnp.float32))
+        worst_one = max(worst_one, _worst(
+            _hist_f64(bins, rel, one, n_nodes, num_bins), want))
+        worst_two = max(worst_two, _worst(
+            _hist_f64(bins, rel, hi + mid, n_nodes, num_bins), want))
+    assert worst_one > 100 * HIST_F64_LIMIT, worst_one
+    assert worst_two > 10 * HIST_F64_LIMIT, worst_two
+
+
+def _f32(bits):
+    return np.asarray(bits, np.uint32).view(np.float32)
+
+
+SPLIT_CASES = {
+    "normals of every magnitude": _f32(
+        (np.arange(24, 255, dtype=np.uint32)[:, None] << 23)
+        | np.random.default_rng(3).integers(0, 1 << 23, (231, 64),
+                                            dtype=np.uint32)).ravel(),
+    "negative": -_f32((np.arange(24, 255, dtype=np.uint32) << 23) | 0x5A5A5A),
+    "every low bit set": _f32((np.arange(24, 255, dtype=np.uint32) << 23)
+                              | 0x7FFFFF),
+    "zeros": np.array([0.0, -0.0], np.float32),
+    "largest float32, past bfloat16's rounding overflow": np.array(
+        [np.finfo(np.float32).max, -np.finfo(np.float32).max,
+         3.3961775e38, 3.3895314e38], np.float32),
+    "powers of two": _f32(np.arange(24, 255, dtype=np.uint32) << 23),
+}
+
+
+@pytest.mark.parametrize("name", list(SPLIT_CASES))
+def test_split_bf16x3_is_exact(name):
+    """hi + mid + lo == x bit for bit, each part a bfloat16 as it stands."""
+    from dmlc_core_tpu.ops.pallas_segment import _split_bf16x3
+    x = SPLIT_CASES[name]
+    parts = [np.asarray(p) for p in _split_bf16x3(jnp.asarray(x))]
+    for p in parts:
+        assert np.isfinite(p).all()
+        back = np.asarray(jnp.asarray(p).astype(jnp.bfloat16)
+                          .astype(jnp.float32))
+        assert np.array_equal(back.view(np.uint32), p.view(np.uint32))
+    total = (parts[0].astype(np.float64) + parts[1].astype(np.float64)
+             + parts[2].astype(np.float64))
+    assert np.array_equal(total, x.astype(np.float64))
+    # and in float32, in the order the kernel's accumulator meets them
+    assert np.array_equal((parts[0] + parts[1]) + parts[2], x)
+
+
+def test_split_bf16x3_below_the_exact_range_and_non_finite():
+    """Below 2**-103 the last part is a denormal float32: with denormals
+    kept (this CPU) the parts still sum to x, and a chip that flushes them
+    loses at most 2**-126 a value.  Denormal inputs keep that bound.  A
+    non-finite input stays non-finite in its first part."""
+    from dmlc_core_tpu.ops.pallas_segment import _split_bf16x3
+    tiny = _f32((np.arange(1, 24, dtype=np.uint32) << 23) | 0x7FFFFF)
+    denormal = _f32(np.array([1, 0x7FFFFF, 0x400001], np.uint32))
+    for x in (tiny, denormal):
+        parts = [np.asarray(p, np.float64)
+                 for p in _split_bf16x3(jnp.asarray(x))]
+        flushed = [np.where(np.abs(p) < 2.0 ** -126, 0.0, p) for p in parts]
+        assert np.abs(sum(flushed) - np.where(np.abs(x) < 2.0 ** -126, 0.0, x)
+                      ).max() <= 2.0 ** -126
+    hi, _, _ = _split_bf16x3(jnp.asarray([np.inf, -np.inf, np.nan],
+                                         jnp.float32))
+    assert not np.isfinite(np.asarray(hi)).any()
