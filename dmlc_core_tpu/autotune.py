@@ -40,12 +40,11 @@ Env toggles (all read at attach time):
   was not constructed with an explicit ``autotune=`` argument.
 - ``DMLCTPU_AUTOTUNE_WINDOW=N`` — decide every N batches mid-epoch
   (0, the default, decides at epoch boundaries only).
-- ``DMLCTPU_AUTOTUNE_MAX_WORKERS`` / ``DMLCTPU_AUTOTUNE_MAX_BUFFER_MB`` /
-  ``DMLCTPU_AUTOTUNE_MAX_PREFETCH`` / ``DMLCTPU_AUTOTUNE_MAX_CHUNK_MB`` —
-  knob ceilings (defaults: max(4, cpu_count), 256, 8, 16; a chunk ceiling
-  of 0 freezes the chunk knob).
-- ``DMLCTPU_AUTOTUNE_MARGIN`` — fractional regression that triggers a
-  revert (default 0.05).
+
+The knob ceilings and the revert margin are :class:`AutoTuner` keyword
+arguments (defaults: max(4, cpu_count) workers, 256 MB of buffer, prefetch
+depth 8, 16 MB chunks, margin 0.05; a chunk ceiling of 0 freezes the chunk
+knob).
 """
 
 from __future__ import annotations
@@ -75,9 +74,10 @@ _MIN_WINDOW_WALL_S = 0.02
 _CHUNK_FLOOR = 1 << 20  # first chunk_bytes step (grow-only at the split)
 _CHUNK_CEIL = 16 << 20
 
+_DECISION_LOG_LEN = 256
+
 _LOCK = threading.Lock()
-_DECISIONS: Deque[dict] = collections.deque(
-    maxlen=int(os.environ.get("DMLCTPU_AUTOTUNE_LOG", "256") or "256"))
+_DECISIONS: Deque[dict] = collections.deque(maxlen=_DECISION_LOG_LEN)
 _TUNERS: "weakref.WeakSet[AutoTuner]" = weakref.WeakSet()
 
 
@@ -90,13 +90,6 @@ def armed() -> bool:
 def _env_int(name: str, default: int) -> int:
     try:
         return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
     except ValueError:
         return default
 
@@ -119,7 +112,7 @@ def maybe_attach(target) -> Optional["AutoTuner"]:
 
 def decision_log() -> list:
     """The process-wide structured decision log (newest last, bounded by
-    DMLCTPU_AUTOTUNE_LOG entries, shared by every tuner)."""
+    ``_DECISION_LOG_LEN`` entries, shared by every tuner)."""
     with _LOCK:
         return list(_DECISIONS)
 
@@ -157,29 +150,21 @@ class AutoTuner:
 
     def __init__(self, target, window_batches: Optional[int] = None,
                  max_workers: Optional[int] = None,
-                 max_buffer_mb: Optional[int] = None,
-                 max_prefetch: Optional[int] = None,
-                 max_chunk_mb: Optional[int] = None,
-                 margin: Optional[float] = None):
+                 max_buffer_mb: int = 256,
+                 max_prefetch: int = 8,
+                 max_chunk_mb: int = _CHUNK_CEIL >> 20,
+                 margin: float = 0.05):
         self._target = weakref.ref(target)
         self.window_batches = (window_batches if window_batches is not None
                                else _env_int("DMLCTPU_AUTOTUNE_WINDOW", 0))
+        # None: as many workers as the host has cores, and never under 4
         self.max_workers = (max_workers if max_workers is not None
-                            else _env_int("DMLCTPU_AUTOTUNE_MAX_WORKERS",
-                                          max(4, os.cpu_count() or 1)))
-        self.max_buffer_mb = (max_buffer_mb if max_buffer_mb is not None
-                              else _env_int("DMLCTPU_AUTOTUNE_MAX_BUFFER_MB",
-                                            256))
-        self.max_prefetch = (max_prefetch if max_prefetch is not None
-                             else _env_int("DMLCTPU_AUTOTUNE_MAX_PREFETCH", 8))
-        # 0 freezes the chunk knob entirely (the bench's armed-but-converged
-        # overhead gate uses that to leave the controller nothing to step)
-        self.max_chunk_bytes = (
-            max_chunk_mb if max_chunk_mb is not None
-            else _env_int("DMLCTPU_AUTOTUNE_MAX_CHUNK_MB",
-                          _CHUNK_CEIL >> 20)) << 20
-        self.margin = (margin if margin is not None
-                       else _env_float("DMLCTPU_AUTOTUNE_MARGIN", 0.05))
+                            else max(4, os.cpu_count() or 1))
+        self.max_buffer_mb = max_buffer_mb
+        self.max_prefetch = max_prefetch
+        # 0 freezes the chunk knob entirely: the controller has nothing to step
+        self.max_chunk_bytes = max_chunk_mb << 20
+        self.margin = margin
         self.epochs = 0
         self.windows = 0
         self.steps = 0

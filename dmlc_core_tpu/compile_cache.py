@@ -3,7 +3,7 @@
 A GBDT fit compiles one program per tree level and serving one per request
 bucket; without a persistent cache every process start pays all of them
 again.  ``configure()`` is called by each entry point that compiles
-(``chip_smoke.py``, the examples, ``bench.py``'s device child, the scoring
+(``chip_smoke.py``, the examples, ``benchmark/run.py``, the scoring
 server's ``main``) before its first jit — never at package import, so a
 library user's own cache settings are left alone.
 """

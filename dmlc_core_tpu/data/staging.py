@@ -1401,7 +1401,7 @@ class DeviceStagingIter:
         #   emit_wait_s blocked handing off (device queue full = the
         #               CONSUMER/device is the limiter, not this pipeline)
         # Cheap enough to keep always on (a few clock reads per multi-MB
-        # batch); bench.py folds it into the staging phase so a slow run
+        # batch); ``counters`` serves it, so a slow epoch
         # pins its own bottleneck instead of inviting guesses.
         prof = {"native_s": 0.0, "host_wait_s": 0.0, "stage_s": 0.0,
                 "emit_wait_s": 0.0, "batches": 0}

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import os
 import time
 from typing import Optional, Tuple
 
@@ -648,12 +647,6 @@ class GBDT:
             raise ValueError("scale_pos_weight applies to the logistic "
                              "objective (weight rows directly otherwise)")
         self.scale_pos_weight = scale_pos_weight
-        if histogram == "auto":
-            # bench/ops escape hatch: force a histogram backend fleet-wide
-            # without touching model code.  An explicit constructor
-            # argument always wins over the environment.
-            histogram = (os.environ.get("DMLCTPU_GBDT_HISTOGRAM", "").strip()
-                         or "auto")
         if histogram not in ("auto", "xla", "pallas"):
             raise ValueError("histogram must be 'auto', 'xla' or 'pallas'")
         self.histogram = histogram

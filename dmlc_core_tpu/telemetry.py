@@ -560,8 +560,8 @@ class Window:
 @contextlib.contextmanager
 def window() -> Iterator[Window]:
     """Snapshot-pair context manager: one :class:`Window` measuring the
-    body.  Replaces the hand-rolled before/after snapshot plumbing in
-    bench.py, the watchdog tests, and the autotuner::
+    body.  Replaces hand-rolled before/after snapshot plumbing, as the
+    telemetry tests and (through :class:`Window`) the autotuner use it::
 
         with telemetry.window() as w:
             run_epoch()

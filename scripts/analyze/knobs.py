@@ -28,7 +28,7 @@ REGISTRY_SECTION = "Env knob registry"
 
 SCAN_DIRS = ["cpp", "dmlc_core_tpu", "tests", "scripts", "doc", "examples"]
 SCAN_SUFFIXES = (".h", ".cc", ".py", ".sh", ".md")
-SCAN_EXTRA = ["bench.py", "CMakeLists.txt", "Makefile"]
+SCAN_EXTRA = ["CMakeLists.txt", "Makefile"]
 
 FAULT_POINT_REG_RE = re.compile(r'DMLCTPU_FAULT_POINT\(\s*\w+\s*,\s*"([^"]+)"')
 FAULT_SPEC_USE_RE = re.compile(

@@ -29,7 +29,7 @@ fi
 
 # Build under the same lock _native.py's on-demand build takes: two
 # concurrent `cmake -B` configures of one tree corrupt each other's
-# CMakeFiles/ and both fail (seen: gate racing bench.py's device child).
+# CMakeFiles/ and both fail (seen: this gate racing a Python import).
 mkdir -p build
 exec 9>build/.dmlctpu_build_lock
 flock 9

@@ -7,6 +7,7 @@ Policy tests drive :meth:`AutoTuner.decide` directly with hand-built
 machine speed or whether native telemetry is compiled in.
 """
 import json
+import os
 import urllib.request
 
 import numpy as np
@@ -105,6 +106,16 @@ def test_chunk_ceiling_zero_freezes_the_knob():
     rec = t.decide(make_window(mb=100, stage="shard"))
     assert rec["action"] == "hold"                     # nothing left to step
     assert tgt.knobs["chunk_bytes"] == 0
+
+
+def test_default_bounds_are_the_documented_constants():
+    """A tuner armed by DMLCTPU_AUTOTUNE=1 alone (``maybe_attach`` passes no
+    bound) runs with the ceilings and margin doc/autotune.md states."""
+    t = autotune.AutoTuner(FakeTarget())
+    assert t.max_workers == max(4, os.cpu_count() or 1)
+    assert (t.max_buffer_mb, t.max_prefetch, t.max_chunk_bytes, t.margin) \
+        == (256, 8, 16 << 20, 0.05)
+    assert autotune._DECISIONS.maxlen == 256
 
 
 def test_bottleneck_move_clears_the_block():

@@ -1313,19 +1313,6 @@ def test_sparse_sharded_fit_batch_pallas_matches_xla():
     _assert_forests_identical(p_xla, p_mesh)
 
 
-def test_gbdt_histogram_env_knob(monkeypatch):
-    """DMLCTPU_GBDT_HISTOGRAM overrides histogram='auto' only — an
-    explicit constructor argument always wins (bench/ops escape hatch)."""
-    monkeypatch.setenv("DMLCTPU_GBDT_HISTOGRAM", "pallas")
-    assert GBDT(num_features=3).histogram == "pallas"
-    assert GBDT(num_features=3, histogram="xla").histogram == "xla"
-    monkeypatch.setenv("DMLCTPU_GBDT_HISTOGRAM", "bogus")
-    with pytest.raises(ValueError, match="histogram"):
-        GBDT(num_features=3)
-    monkeypatch.delenv("DMLCTPU_GBDT_HISTOGRAM")
-    assert GBDT(num_features=3).histogram == "auto"
-
-
 def route_level_by_gather(self, bins_t, rel, split_f, split_b, split_d):
     """The level's routing as it stood before PR 26, kept as the reference:
     one gather per row for its node's feature, bin threshold and default
