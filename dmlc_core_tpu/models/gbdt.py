@@ -798,15 +798,12 @@ class GBDT:
     def _sparse_fit_layout(self, row_id, findex, ebin, emask, rows: int,
                            streamed: bool = False):
         """The once-per-fit feature-sorted entry layout, or None when no
-        level of this fit can resolve to the sparse Pallas kernel (the
-        scatter path needs no layout).  ``findex`` is static across every
-        level and tree, so one device sort (``ops.sparse_hist_layout``)
-        amortizes over ``num_trees * max_depth`` level passes; what it
-        costs is the span ``gbdt.entry_sort`` and the counter
-        ``gbdt.entry_sort_us`` (to the sort's end on the device: the host
-        reads the run starts back).  Sharded over the ``histogram_mesh``
-        axis when declared (streamed fits keep the kernel single-device:
-        their batch slicing is row-offset based and never mesh-sharded)."""
+        level of this fit can resolve to the sparse Pallas kernel.  One
+        device sort serves every tree's levels (``ops.sparse_hist_layout``;
+        span ``gbdt.entry_sort``, counter ``gbdt.entry_sort_us``); the entry
+        sub-tiles a kernel level runs and the grid steps it launches go to
+        ``gbdt.sparse_hist_blocks`` and ``gbdt.sparse_hist_grid_steps``.
+        Sharded over ``histogram_mesh``'s axis, never in a streamed fit."""
         if not self._sparse_layout_enabled(streamed):
             return None
         num_shards = (1 if self.mesh_plan is None
@@ -817,6 +814,9 @@ class GBDT:
                 row_id, findex, ebin, emask, self.num_features,
                 self.num_bins, num_shards=num_shards, rows=rows))
         counter_add("gbdt.entry_sort_us", int((time.monotonic() - t0) * 1e6))
+        counter_add("gbdt.sparse_hist_blocks",
+                    int(np.asarray(layout.tcount).sum()))
+        counter_add("gbdt.sparse_hist_grid_steps", layout.grid_steps)
         return layout
 
     def _level_histogram_sparse(self, layout, rel: jax.Array,

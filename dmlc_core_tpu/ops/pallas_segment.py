@@ -32,9 +32,9 @@ be the only sparse backend).  The fix is that ``findex`` never changes:
 :func:`sparse_hist_layout` sorts the entries by feature ONCE per staged
 batch (a device sort, amortized over ``num_trees x max_depth`` level passes)
 and records, per key tile, the contiguous block span of entries whose
-keys can land in that tile.  The kernel grid is then
-``(key tiles, max blocks per tile)`` with the span table scalar-
-prefetched: each grid step DMAs only its own feature block's entries, so
+keys can land in that tile.  The kernel grid is then ``(key tiles, blocks
+of 32 entry sub-tiles in the fullest tile's span)`` with the span table
+scalar-prefetched: each grid step DMAs only its own features' entries, so
 compare work is O(nnz * KEY_TILE / NNZ_TILE) per entry tile — no
 ``n_nodes`` factor (nodes ride the MXU M axis like ``_hist_kernel``) and
 no full-F factor (a tile only ever sees its own features' entries).
@@ -188,12 +188,12 @@ _KEY_TILE = 512    # (feature, bin) key lanes per out tile (sparse kernel)
 # Most nodes per level the two histogram kernels are routed to by
 # ``GBDT(histogram="auto")``.  Their compare work does not depend on
 # n_nodes; what grows with it is the MXU M axis and the VMEM tiles (the A
-# tile, its mask and one-hot temporaries, the out tile).  The sparse
-# kernel's cap is the largest power of two at which Mosaic fits it into its
-# 16 MiB of scoped VMEM on a v5e (PR 21, libtpu 0.0.34): at 512 nodes it
-# asks for 21.24M, "RESOURCE_EXHAUSTED: Ran out of memory in memory space
-# vmem ... exceeded scoped vmem limit".  The dense kernel's cap is the most
-# a chip run has held it to: it compiles for a described v5e at 1024 nodes
+# tile, its mask and one-hot temporaries, the out tile).  Each cap is the
+# most a chip run has held its kernel to.  PR 21 set the sparse one where
+# the float32 step of that time stopped fitting the 16 MiB of scoped VMEM on
+# a v5e; since PR 30 its bfloat16 step compiles for a described v5e at 512
+# nodes too and runs out of VMEM at 1024 (libtpu 0.0.34).  The dense kernel
+# compiles for a described v5e at 1024 nodes
 # too (its step is sized by ``_hist_plan``, not by the node count alone),
 # and nothing has run it there.  chip_smoke.py compiles and checks both
 # kernels at exactly these values, so a cap that stops compiling fails
@@ -453,6 +453,12 @@ class SparseHistLayout:
     fstart: jax.Array
     nnz_live: jax.Array
 
+    @property
+    def grid_steps(self) -> int:
+        """Grid steps that one level's kernel calls launch, over all shards
+        (``tcount.sum()`` is the entry sub-tiles they run)."""
+        return self.num_shards * self.num_kt * _sparse_steps(self.max_tiles)
+
 
 def _round_up_some(n: int, granule: int, parts: int) -> int:
     """``n`` rounded up to a multiple of ``granule`` and of about one
@@ -575,52 +581,94 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
         tcount=jnp.asarray(tcount.reshape(-1), jnp.int32))
 
 
-def _sparse_hist_kernel(n_pad: int, tstart_ref, tcount_ref,
+# Entry sub-tiles of _NNZ_TILE that one grid step of the sparse kernel loops
+# over.  Every key tile owns the steps of the fullest one, and a step that
+# runs nothing still costs 0.3 us on a v5e: at the Bosch cell's layout
+# (2.18e8 entries, 484 key tiles, the fullest span 2,304 sub-tiles, the
+# mean 440) a level of up to 8 nodes took 373 / 189 / 150 / 131 / 125 ms at
+# 1 / 4 / 8 / 16 / 32 sub-tiles a step (my chip runs, PR 30).
+_SPARSE_STEP_TILES = 32
+
+
+def _sparse_steps(max_tiles: int) -> int:
+    """Grid steps a key tile: the most ``_SPARSE_STEP_TILES``-blocks that a
+    span of ``max_tiles`` entry sub-tiles touches, wherever it starts."""
+    return (max_tiles + _SPARSE_STEP_TILES - 2) // _SPARSE_STEP_TILES + 1
+
+
+def _sparse_hist_kernel(n_pad: int, parts: int, tstart_ref, tcount_ref,
                         gkey_ref, rel_ref, gh_ref, out_ref):
-    """One (key-tile, entry-block) step of the sparse histogram:
+    """One (key tile, entry block) step of the sparse histogram; the block
+    is ``_SPARSE_STEP_TILES`` sub-tiles of ``_NNZ_TILE`` entries, and for
+    each sub-tile of the key tile's span
 
-        out[(lane, node), key] += A^T B
-        A[entry, (lane, node)] = gh[lane, entry] * [rel[entry] == node]
-        B[entry, key]          = [gkey[entry] - kt*KEY_TILE == key]
+        out[(part, lane, node), key] += A^T B
+        A^T[(part, lane, node), entry] = gh[part, lane, entry]
+                                         * [rel[entry] == node]
+        B^T[key, entry]                = [gkey[entry] - kt*KEY_TILE == key]
 
-    The scalar-prefetched span table makes the entry-block index map
-    data-dependent: step (kt, et) reads block ``tstart[kt] + et`` and the
-    body only runs while ``et < tcount[kt]`` — entries sorted by feature
-    mean each key tile touches just its own features' blocks.  Entries of
-    a neighboring feature sharing a boundary block self-mask: their gkey
-    falls outside this tile's [0, KEY_TILE) local range, so B's one-hot
-    row is all zero.  Same 2-D-shapes / HIGHEST-precision discipline as
-    ``_hist_kernel``."""
+    as ``_hist_kernel`` does it, with entries where its rows are: entries
+    stay on the lane axis, as ``gkey``, ``rel`` and ``gh`` arrive, so no
+    operand is relaid; A^T and B^T are sublane broadcasts compared with an
+    iota, both bfloat16, and one ``dot_general`` contracts the last axis of
+    both into float32.  B^T is 0/1 and (grad, hess) go in as the three
+    parts of ``_split_bf16x3``, made here from the float32 block (an
+    operand of parts with ``nnz`` lanes would be gigabytes), so every
+    product is exact and the float32 accumulation gives what
+    ``Precision.HIGHEST`` gave in six passes: the parts are rows of one dot
+    (``parts`` 3: M = 3 x 2 x n_pad) up to ``_HIST_STACK_NODES`` node
+    columns and three dots (``parts`` 1: M = 2 x n_pad) past it.
+
+    The scalar-prefetched span table (``tstart``, ``tcount``, in sub-tiles)
+    makes the block index data-dependent: step (kt, et) reads block
+    ``tstart[kt] // _SPARSE_STEP_TILES + et`` and runs the sub-tiles of it
+    that lie in ``[tstart[kt], tstart[kt] + tcount[kt])`` — entries sorted
+    by feature mean that a key tile touches its own features' blocks only,
+    and a block past the span costs one empty grid step.  Entries of a
+    neighbouring feature in a boundary sub-tile, and the padding lanes
+    (``gkey == -1``), mask themselves: their key is outside this tile's
+    [0, KEY_TILE), so their column of B^T is zero.  What a block holds
+    past the array's end is never read: no span reaches there."""
     kt = pl.program_id(0)
     et = pl.program_id(1)
+    first = (tstart_ref[kt] // _SPARSE_STEP_TILES + et) * _SPARSE_STEP_TILES
+    stop = tstart_ref[kt] + tcount_ref[kt]
 
     @pl.when(et == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(et < tcount_ref[kt])
-    def _accum():
-        # A: [NNZ_TILE, 2*n_pad] node-masked (grad, hess) lanes.  Padding
-        # entries carry gkey == -1: their row of B is all zero.
-        node_ids = jax.lax.broadcasted_iota(jnp.int32, (_NNZ_TILE, n_pad), 1)
-        rel_col = jnp.broadcast_to(rel_ref[...].reshape(_NNZ_TILE, 1),
-                                   (_NNZ_TILE, n_pad))
-        mask = (rel_col == node_ids).astype(jnp.float32)
-        g_col = jnp.broadcast_to(gh_ref[0:1, :].reshape(_NNZ_TILE, 1),
-                                 (_NNZ_TILE, n_pad))
-        h_col = jnp.broadcast_to(gh_ref[1:2, :].reshape(_NNZ_TILE, 1),
-                                 (_NNZ_TILE, n_pad))
-        a = jnp.concatenate([mask * g_col, mask * h_col], axis=1)
-        # B: [NNZ_TILE, KEY_TILE] one-hot of each entry's own static key
-        loc = jax.lax.broadcasted_iota(jnp.int32, (_NNZ_TILE, _KEY_TILE), 1)
-        key_col = jnp.broadcast_to(
-            (gkey_ref[...] - kt * _KEY_TILE).reshape(_NNZ_TILE, 1),
-            (_NNZ_TILE, _KEY_TILE))
-        b = (key_col == loc).astype(jnp.float32)
-        out_ref[...] += jax.lax.dot_general(
-            a, b, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
+    node_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, _NNZ_TILE), 0)
+    loc = (jax.lax.broadcasted_iota(jnp.int32, (_KEY_TILE, _NNZ_TILE), 0)
+           + kt * _KEY_TILE)
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    m = 2 * n_pad
+
+    def sub_tile(j, carry):
+        lanes = pl.ds(pl.multiple_of(j * _NNZ_TILE, _NNZ_TILE), _NNZ_TILE)
+        mask = jnp.broadcast_to(rel_ref[:, lanes],
+                                (n_pad, _NNZ_TILE)) == node_ids
+        a_t = jnp.concatenate(
+            [jnp.where(mask, jnp.broadcast_to(part[i:i + 1, :],
+                                              (n_pad, _NNZ_TILE)), 0.0)
+             for part in _split_bf16x3(gh_ref[:, lanes]) for i in range(2)],
+            axis=0).astype(jnp.bfloat16)
+        hit = loc == jnp.broadcast_to(gkey_ref[:, lanes],
+                                      (_KEY_TILE, _NNZ_TILE))
+        b_t = jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)
+        if parts == 3:
+            out_ref[...] += dot(a_t, b_t)
+        else:
+            out_ref[...] += sum(dot(a_t[p * m:(p + 1) * m], b_t)
+                                for p in range(3))
+        return carry
+
+    # the span's sub-tiles in this block; none in a step past the span
+    jax.lax.fori_loop(jnp.maximum(tstart_ref[kt] - first, 0),
+                      jnp.clip(stop - first, 0, _SPARSE_STEP_TILES),
+                      sub_tile, None)
 
 
 @functools.partial(jax.jit,
@@ -635,47 +683,50 @@ def _histogram_gh_sparse_pallas(gkey: jax.Array, rel_e: jax.Array,
     multiple of _NNZ_TILE); gh_e: [2, nnz_pad] f32, (grad, hess) already
     gathered onto the entries, lanes first as the kernel reads them (an
     ``[nnz_pad, 2]`` array would have to be transposed here at every
-    level); tstart/tcount: [num_kt] int32 block spans.  Returns
+    level); tstart/tcount: [num_kt] int32 spans in sub-tiles of _NNZ_TILE
+    entries, none longer than ``max_tiles``.  Returns
     [n_nodes, F, num_bins, 2] f32."""
     nnz_pad = gkey.shape[0]
     nb, num_kt = _sparse_geometry(num_features, num_bins)
     k_pad = num_kt * _KEY_TILE
     f_pad = k_pad // nb
     n_pad = pl.cdiv(n_nodes, 8) * 8
-    m_pad = 2 * n_pad
-    nblocks = nnz_pad // _NNZ_TILE
+    parts = 3 if n_pad <= _HIST_STACK_NODES else 1
+    m_pad = 2 * parts * n_pad
+    block = _SPARSE_STEP_TILES * _NNZ_TILE
     with jax.named_scope("ops.hist_layout"):
         gkey2 = gkey.reshape(1, nnz_pad)
         rel2 = rel_e.astype(jnp.int32).reshape(1, nnz_pad)
         gh2 = gh_e.astype(jnp.float32)
 
-    # block index of entry inputs at step (kt, et): clamped so skipped
-    # steps (et >= tcount[kt]) re-address an in-range block — a repeated
-    # index means no re-fetch, keeping HBM traffic proportional to the
-    # executed tiles only
+    # block index of entry inputs at step (kt, et): steps past the span
+    # re-address its last block — a repeated index means no re-fetch, so
+    # HBM traffic is that of the blocks that hold a span
     def eidx(kt, et, ts, tc):
-        return (0, jnp.minimum(ts[kt] + et, nblocks - 1))
+        last = jnp.maximum(ts[kt] + tc[kt] - 1, ts[kt])
+        return (0, jnp.minimum(ts[kt] // _SPARSE_STEP_TILES + et,
+                               last // _SPARSE_STEP_TILES))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(num_kt, max_tiles),
+        grid=(num_kt, _sparse_steps(max_tiles)),
         in_specs=[
-            pl.BlockSpec((1, _NNZ_TILE), eidx),
-            pl.BlockSpec((1, _NNZ_TILE), eidx),
-            pl.BlockSpec((2, _NNZ_TILE), eidx),
+            pl.BlockSpec((1, block), eidx),
+            pl.BlockSpec((1, block), eidx),
+            pl.BlockSpec((2, block), eidx),
         ],
         out_specs=pl.BlockSpec((m_pad, _KEY_TILE),
                                lambda kt, et, ts, tc: (0, kt)),
     )
     out = pl.pallas_call(
-        functools.partial(_sparse_hist_kernel, n_pad),
+        functools.partial(_sparse_hist_kernel, n_pad, parts),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, k_pad), jnp.float32),
         interpret=interpret,
         name=SPARSE_HIST_KERNEL,
     )(tstart, tcount, gkey2, rel2, gh2)
     with jax.named_scope("ops.hist_layout"):
-        return (out.reshape(2, n_pad, f_pad, nb)
+        return (out.reshape(parts, 2, n_pad, f_pad, nb).sum(0)
                 [:, :n_nodes, :num_features, :num_bins]
                 .transpose(1, 2, 3, 0))         # [n, F, B, 2]
 
@@ -714,14 +765,20 @@ def histogram_gh_sparse(row_id, findex, ebin, emask, rel, gh,
     "pallas" -> the sparse histogram-as-matmul kernel: entries sorted by
     feature once (``layout``; built here when not supplied — pass a
     prebuilt one to amortize the sort over a whole fit), then per
-    (key-tile, entry-block) grid step A = node-masked per-entry (grad,
-    hess) [NNZ_TILE, 2*nodes] contracts against B = key one-hot
-    [NNZ_TILE, KEY_TILE] on the MXU at f32/HIGHEST.  The scalar-
-    prefetched span table means a key tile only reads its own features'
-    entry blocks: compare work O(nnz * KEY_TILE) total, independent of
-    ``n_nodes`` and of F, vs the dense kernel's O(rows * F * bins).  Max
-    abs err vs the scatter path <= 4e-6 (accumulation order only), so
-    the backends stay drop-in interchangeable.
+    (key tile, entry sub-tile) A^T = node-masked per-entry (grad, hess)
+    [3*2*nodes, NNZ_TILE] contracts against B^T = key one-hot
+    [KEY_TILE, NNZ_TILE] over the entries, both bfloat16, on the MXU in
+    ONE pass with float32 accumulation.  Float32 exactness comes from the
+    operands, as in ``histogram_gh``: (grad, hess) go in as the three
+    bfloat16 parts of ``_split_bf16x3``, B^T is 0/1, and the parts'
+    partial histograms are added in float32 (on the M axis of one dot up
+    to 32 node columns, three dots above).  The scalar-prefetched span
+    table means a key tile only reads its own features' entry blocks:
+    compare work O(nnz * KEY_TILE) total, independent of ``n_nodes`` and
+    of F, vs the dense kernel's O(rows * F * bins).  Against the XLA path
+    on a v5e (65,536 rows x 28 features, 256 bins, chip_smoke.py, PR 30):
+    max error <= 6e-7 of the largest bucket at 1, 32 and 256 nodes
+    (accumulation order), so the backends stay drop-in interchangeable.
     """
     check_force(force, "histogram backend")
     if force == "pallas":

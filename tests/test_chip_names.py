@@ -1,5 +1,5 @@
 """What the benchmark finds in a device trace by name is still there after
-the TPU's compiler: the dense histogram kernel's instruction, and the scopes
+the TPU's compiler: the two histogram kernels' instructions, and the scopes
 of the tree program.  Compiled here for a described v5e, not run (one file,
 one topology fixture: only one process may hold the TPU's library)."""
 import json
@@ -112,6 +112,47 @@ def test_dense_histogram_kernel_keeps_the_name_the_benchmark_matches(
     assert dots and all(
         operands == ["bfloat16", "bfloat16"] and precision is None
         and result == "float32" for operands, precision, result in dots), dots
+
+
+# the Bosch cell's layout (benchmark/configs/bosch-gbdt.json: 968 features x
+# 256 bins; 2.18e8 entry lanes, the fullest key tile's span 2,304 sub-tiles).
+# 1 to 32 nodes: the three parts on one dot; 64: on three; 256 =
+# SPARSE_HIST_NODE_LIMIT, within the scoped VMEM
+@pytest.mark.parametrize("n_nodes", [1, 32, 64, 256])
+def test_sparse_histogram_kernel_keeps_the_name_the_benchmark_matches(
+        one_chip, quiet_cache, n_nodes):
+    assert n_nodes <= pallas_segment.SPARSE_HIST_NODE_LIMIT
+    pattern = json.loads((LAYER_METRICS / "sparse_hist_ms_per_round.json")
+                         .read_text())["args"]["pattern"]
+    roofline = json.loads((LAYER_METRICS / "sparse_hist_roofline.json")
+                          .read_text())["args"]["pattern"]
+    assert pattern == roofline
+    nnz, features, max_tiles = 218103808, 968, 2304
+    num_kt = features * BINS // pallas_segment._KEY_TILE
+
+    def level(gkey, rel_e, gh_e, tstart, tcount):
+        return pallas_segment._histogram_gh_sparse_pallas(
+            gkey, rel_e, gh_e, tstart, tcount, n_nodes, features, BINS,
+            max_tiles, False)
+
+    shapes = (on(one_chip, (nnz,), jnp.int32), on(one_chip, (nnz,), jnp.int32),
+              on(one_chip, (2, nnz), jnp.float32),
+              on(one_chip, (num_kt,), jnp.int32),
+              on(one_chip, (num_kt,), jnp.int32))
+    compiled = jax.jit(level).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    kernels = [n for n in instructions(compiled) if re.search(pattern, n)]
+    assert len(kernels) == 1, (pattern, kernels)
+    assert kernels[0].startswith("%" + pallas_segment.SPARSE_HIST_KERNEL)
+    # no array of nnz lanes is made for the kernel (the parts are split
+    # inside it, the entries go in as they came): the scratch is the
+    # histogram's, under one int32 an entry
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * nnz
+    dots = kernel_dots(level, *shapes)
+    assert len(dots) == (1 if n_nodes <= 32 else 3)
+    assert all(operands == ["bfloat16", "bfloat16"] and precision is None
+               and result == "float32"
+               for operands, precision, result in dots), dots
 
 
 def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(

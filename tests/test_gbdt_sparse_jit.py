@@ -189,6 +189,47 @@ def test_fit_batch_keeps_one_copy_of_the_entries_when_the_kernel_runs():
     assert seen == [(True, False), (False, True)]
 
 
+@pytest.mark.parametrize("mesh", [False, True])
+def test_a_fit_counts_what_a_kernel_level_of_its_layout_runs_and_launches(
+        mesh):
+    """Once a fit, whatever its trees and levels: the entry sub-tiles in
+    the key tiles' spans to ``gbdt.sparse_hist_blocks``, the grid steps
+    that a level's kernel calls launch (every shard's) to
+    ``gbdt.sparse_hist_grid_steps``; nothing from a fit without a layout."""
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    rng = np.random.default_rng(43)
+    batch, binner, *_ = _sparse_identity_fixture(rng, rows=256, feats=4)
+    kw = dict(BASE, num_trees=2, histogram="pallas")
+    if mesh:
+        from jax.sharding import Mesh
+        kw["histogram_mesh"] = (Mesh(np.asarray(jax.devices()[:8]),
+                                     ("data",)), "data")
+    model, layouts = GBDT(**kw), []
+    build = ps.sparse_hist_layout
+
+    def spy(*a, **k):
+        layouts.append(build(*a, **k))
+        return layouts[-1]
+
+    names = ("gbdt.sparse_hist_blocks", "gbdt.sparse_hist_grid_steps")
+    before = [telemetry.counter_get(n) for n in names]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr("dmlc_core_tpu.models.gbdt.sparse_hist_layout", spy)
+        model.fit_batch(batch, binner)
+        GBDT(**dict(BASE, num_trees=1, histogram="xla")).fit_batch(batch,
+                                                                   binner)
+    got = [telemetry.counter_get(n) - b for n, b in zip(names, before)]
+    (layout,) = layouts
+    shards = 8 if mesh else 1
+    assert layout.num_shards == shards
+    steps = ps._sparse_steps(layout.max_tiles)
+    assert got == [int(np.asarray(layout.tcount).sum()),
+                   shards * layout.num_kt * steps]
+    # 4 features of 8 bins: one key tile a shard, its span one sub-tile
+    assert got == [shards, shards] and steps == 1
+
+
 def test_a_row_that_holds_a_feature_twice_is_routed_as_on_xla():
     """`_route_layout` finds one entry of a (row, feature) pair where
     `_route_sparse` takes the largest bin over all of them: a batch whose

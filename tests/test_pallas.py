@@ -521,6 +521,86 @@ def test_one_bfloat16_pass_or_two_parts_would_fail_the_float64_limit():
     assert worst_two > 10 * HIST_F64_LIMIT, worst_two
 
 
+# ---- sparse histogram kernel against float64 --------------------------------
+
+
+def _sparse_f64_case(n_nodes, F, num_bins, rows, nnz, empty, seed):
+    """COO entries with `_hist_case`'s gradients (six decades); the
+    features in ``empty`` hold no entry."""
+    rng = np.random.default_rng(seed)
+    rid = rng.integers(0, rows, nnz).astype(np.int32)
+    fi = rng.choice(np.setdiff1d(np.arange(F), empty), nnz).astype(np.int32)
+    eb = rng.integers(1, num_bins, nnz).astype(np.int32)
+    rel = rng.integers(0, n_nodes, rows).astype(np.int32)
+    gh = (rng.standard_normal((rows, 2))
+          * 10.0 ** rng.integers(-3, 3, (rows, 2))).astype(np.float32)
+    want = np.zeros((n_nodes, F, num_bins, 2))
+    np.add.at(want, (rel[rid], fi, eb), gh[rid].astype(np.float64))
+    return rid, fi, eb, rel, gh, want
+
+
+# one key tile of 512 lanes (F * stride <= 512) and many; strides 2, 16, 256
+# and 512 (300 bins); node columns on one dot (<= 32, 5 padded to 8) and on
+# three (40 to the cap of 256); a feature with no entries, first, inside and
+# last; key tiles whose span is shorter than one sub-tile of 1,024 entries,
+# spans of 14 and 21 sub-tiles, and one of 59, longer than a grid step's 32
+SPARSE_F64_CASES = [
+    # (n_nodes, F, num_bins, rows, nnz, features with no entry)
+    (1, 3, 16, 200, 900, ()), (1, 2, 256, 3000, 60000, ()),
+    (5, 7, 16, 300, 2500, (3,)), (8, 5, 256, 300, 2500, (0,)),
+    (8, 40, 2, 300, 3000, ()), (32, 6, 256, 900, 41000, (5,)),
+    (32, 3, 300, 400, 3000, ()), (40, 4, 300, 400, 3000, (1,)),
+    (40, 2, 16, 700, 20500, ()), (64, 3, 2, 300, 1500, ()),
+    (64, 9, 256, 500, 9000, (2, 3)), (128, 5, 16, 400, 3000, ()),
+    (128, 4, 256, 600, 5000, ()), (256, 4, 256, 600, 5000, ()),
+    (256, 2, 16, 300, 1100, ()),
+]
+
+
+@pytest.mark.parametrize("n_nodes,F,num_bins,rows,nnz,empty",
+                         SPARSE_F64_CASES)
+def test_sparse_histogram_kernel_is_float32_exact_against_float64(
+        n_nodes, F, num_bins, rows, nnz, empty):
+    from dmlc_core_tpu.ops.pallas_segment import histogram_gh_sparse
+    rid, fi, eb, rel, gh, want = _sparse_f64_case(
+        n_nodes, F, num_bins, rows, nnz, empty, seed=n_nodes * 1000 + F)
+    got = histogram_gh_sparse(
+        jnp.asarray(rid), jnp.asarray(fi), jnp.asarray(eb),
+        jnp.ones(nnz, bool), jnp.asarray(rel), jnp.asarray(gh), n_nodes, F,
+        num_bins, force="pallas")
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert _worst(got, want) < HIST_F64_LIMIT
+    assert not np.asarray(got)[:, list(empty)].any()
+    assert not np.asarray(got)[:, :, 0].any()       # bin 0: the absent cells
+
+
+@pytest.mark.parametrize("sub_tiles", [1, 4, 64])
+def test_sparse_histogram_kernel_sub_tiles_a_step_agree(monkeypatch,
+                                                        sub_tiles):
+    """One sub-tile a grid step (the grid of the layout's own span table),
+    4, and more than any span here holds: the same sub-tiles are added in
+    the same order, so the sums are the same to the bit."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    assert ps._SPARSE_STEP_TILES == 32
+    for n_nodes, F, num_bins, rows, nnz, empty in [
+            SPARSE_F64_CASES[1], SPARSE_F64_CASES[5], SPARSE_F64_CASES[10]]:
+        rid, fi, eb, rel, gh, want = _sparse_f64_case(
+            n_nodes, F, num_bins, rows, nnz, empty, seed=F)
+        layout = ps.sparse_hist_layout(rid, fi, eb, np.ones(nnz, bool), F,
+                                       num_bins)
+
+        def level():    # not through the jit's cache: read while tracing
+            return np.asarray(ps._histogram_gh_sparse_pallas.__wrapped__(
+                layout.gkey, jnp.asarray(rel)[layout.rid],
+                jnp.asarray(gh)[layout.rid].T, layout.tstart, layout.tcount,
+                n_nodes, F, num_bins, layout.max_tiles, True))
+        at_32 = level()
+        assert _worst(at_32, want) < HIST_F64_LIMIT
+        monkeypatch.setattr(ps, "_SPARSE_STEP_TILES", sub_tiles)
+        np.testing.assert_array_equal(level(), at_32)
+        monkeypatch.undo()
+
+
 def _f32(bits):
     return np.asarray(bits, np.uint32).view(np.float32)
 
