@@ -601,7 +601,7 @@ def phase_mesh(ctx: dict) -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from dmlc_core_tpu import DeviceStagingIter
+    from dmlc_core_tpu import DeviceStagingIter, telemetry
     from dmlc_core_tpu.models import GBDT
     from dmlc_core_tpu.parallel import MeshPlan
     size, binner = ctx["size"], ctx["binner"]
@@ -661,13 +661,30 @@ def phase_mesh(ctx: dict) -> dict:
     fits = {}
     level_bytes = [2 ** d * FEATURES * size["bins"] * 8
                    for d in range(size["depth"])]
-    for collective in ("auto", "flat"):
-        p = MeshPlan.build(collective=collective)
+    # "auto" (the ring from 256 KiB on), and the plan of the benchmark's
+    # four-chip cell, built from that cell's own parameters the way its
+    # generator builds it (benchmark/traffic/mesh_fit.py: make_plan), so that
+    # this leg and the cell cannot drift apart
+    cell = json.loads((HERE / "benchmark" / "workloads"
+                       / "airline-gbdt.fit-mesh4.json").read_text())["params"]
+    plans = {"auto": MeshPlan.build(collective="auto"),
+             cell["collective"]: MeshPlan.build(
+                 devices=jax.devices()[:n], collective=cell["collective"],
+                 overlap_chunks=int(cell["overlap_chunks"]))}
+    for collective, p in plans.items():
         m = GBDT(histogram=routed, histogram_mesh=p, **ctx["config"])
         require(set(m.level_backends()) == {"pallas"},
                 f"mesh fit resolved levels to {m.level_backends()}")
         t0 = time.monotonic()
+        before = telemetry.snapshot()
         forest = jax.block_until_ready(m.fit(bins, y))
+        counted = telemetry.counters_delta(before, telemetry.snapshot())
+        want = (size["trees"] * size["depth"],
+                size["trees"] * sum(level_bytes))
+        got = (counted.get("mesh.allreduce_calls", 0),
+               counted.get("mesh.collective_bytes", 0))
+        require(got == want, f"mesh fit ({collective}) counted {got} "
+                f"reductions and bytes, want {want}")
         loss = float(m.loss(forest, bins, y))
         require(abs(loss - ctx["dense_loss"]) <= 1e-3,
                 f"mesh fit ({collective}) loss {loss:.6f} vs one-device "

@@ -1039,7 +1039,7 @@ class GBDT:
                 base = jnp.sum(label * w) / sum_w
         params["base"] = base.astype(jnp.float32)
 
-        margin = jnp.full(label.shape, params["base"])
+        margin = jnp.full(label.shape, params["base"], device=self._on(label))
         root_key = jax.random.PRNGKey(self.seed)
         have_eval = eval_margin is not None
         ev_m = (jnp.full(eval_label.shape, params["base"]) if have_eval
@@ -1049,7 +1049,7 @@ class GBDT:
         grad_hess = grad_hess or self._grad_hess
         eval_loss_fn = eval_loss_fn or self._objective_loss
         for t_idx in range(self.num_trees):
-            with telemetry.span("gbdt.tree"):
+            with self._tree_span(build_tree):
                 # these run op by op, each its own program: a profile finds
                 # them as the programs that are not jit(_build_tree); the
                 # scope names them once the round is one program
@@ -1270,7 +1270,7 @@ class GBDT:
         root_key = jax.random.PRNGKey(self.seed)
         feats, thrs, dirs, sgains, scovers, leaves = [], [], [], [], [], []
         for r in range(self.num_trees):
-            with telemetry.span("gbdt.tree"):   # one round: K trees
+            with self._tree_span(build_tree, K):   # one round: K trees
                 with jax.named_scope("gbdt.boost"):
                     p = jax.nn.softmax(margin, axis=1)
                 if have_eval:
@@ -1753,8 +1753,8 @@ class GBDT:
         ``objective='rank:pairwise'`` (contiguous groups; stage with
         ``with_qid=True``); its eval_set form is the 4-tuple
         ``(eval_bins, eval_label, eval_weight_or_None, eval_qid)``.
-        Returns the forest pytree.
         """
+        bins, label, weight = self._shard_inputs(bins, label, weight)
         label = label.astype(jnp.float32)
         w = (jnp.ones_like(label) if weight is None
              else weight.astype(jnp.float32))
@@ -2376,3 +2376,60 @@ class GBDT:
              if self.objective == "softmax"
              else self.margins(params, bins))
         return self._objective_loss(m, label, weight)
+
+    # ---- what a mesh plan adds to a fit -------------------------------------
+    # Defined below every other method, and called from lines that were
+    # there (``fit`` gives up a docstring line for its call): a program's
+    # source lines and its callers' are part of its compile-cache key
+    # (compile_cache.py), so nothing above may move, and no frame may come
+    # between ``fit`` and a tree program.  So kept, the tree programs of a
+    # fit without a plan are the same bytes as before these were added.
+
+    def _shard_inputs(self, bins, label, weight):
+        """Under a mesh plan, ``fit``'s row arrays laid out over the plan's
+        axes (span ``gbdt.shard_inputs``): ``shard_map``'s even-rows rule
+        checked here, by name, and each array placed with
+        ``plan.data_sharding()`` — one that already lies so is handed back
+        as it is, a host array or one on a single chip is divided once a
+        fit instead of once a tree.  A legacy ``(mesh, axis)`` spec keeps
+        its uneven rows for GSPMD and is left alone then."""
+        plan = self.mesh_plan
+        if plan is None:
+            return bins, label, weight
+        with telemetry.span("gbdt.shard_inputs"):
+            rows, shards = int(bins.shape[0]), plan.num_shards
+            if rows % shards:
+                if plan.prefer_gspmd:
+                    return bins, label, weight
+                raise ValueError(
+                    f"{rows} rows do not divide over the mesh plan's "
+                    f"{shards} shards: the kernel runs under shard_map, "
+                    "which wants as many rows on every shard")
+            sharding = plan.data_sharding()
+            return tuple(a if a is None else jax.device_put(a, sharding)
+                         for a in (bins, label, weight))
+
+    def _on(self, label):
+        """Where ``_boost`` makes its margins: under a mesh plan beside a
+        label that lies over the plan's axes (``jnp.full(label.shape, ...)``
+        made them whole on the first chip, 460 MB at 115M rows, and every
+        round's first op resharded them); else where ``jnp.full`` puts
+        them, as ever."""
+        plan = self.mesh_plan
+        if (plan is None or not getattr(label, "committed", False)
+                or label.shape[0] % plan.num_shards):
+            return None
+        return label.sharding
+
+    def _tree_span(self, build_tree, trees: int = 1):
+        """The span ``gbdt.tree`` around one boosting round's ``trees`` calls
+        of ``build_tree``; under a mesh plan it also counts the reductions
+        those calls run (``MeshPlan.counting``), under the name of the fit
+        that made ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one
+        tree program each).  A context manager and not a wrapper: nothing
+        of it is on the stack while a tree program is traced."""
+        span = telemetry.span("gbdt.tree")
+        if self.mesh_plan is None:
+            return span
+        kind = getattr(build_tree, "__qualname__", "").split(".<locals>")[0]
+        return self.mesh_plan.counting(kind, trees, around=span)

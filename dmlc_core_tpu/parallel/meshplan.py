@@ -110,11 +110,11 @@ class MeshPlan:
         self.overlap_chunks = (
             max(1, int(overlap_chunks)) if overlap_chunks is not None
             else _env_overlap_chunks())
-        # legacy (mesh, axis)-tuple adapters set this: XLA-backend
-        # consumers keep relying on GSPMD auto-partitioning (which
-        # tolerates uneven row counts) instead of the explicit
-        # shard_map route, exactly as the tuple behaved pre-plan
+        # legacy (mesh, axis)-tuple adapters set this: XLA consumers keep
+        # GSPMD auto-partitioning (uneven rows) instead of shard_map
         self.prefer_gspmd = bool(prefer_gspmd)
+        # what allreduce has been traced with; what counting() saw of it
+        self._traced, self._census = [0, 0], {}
         platforms = {d.platform for d in np.asarray(mesh.devices).ravel()}
         self.fabric = "ici" if platforms == {"tpu"} else "host"
 
@@ -234,19 +234,19 @@ class MeshPlan:
         """All-reduce over the plan axes; call inside traced code.
 
         Strategy defaults to :meth:`strategy_for` on the payload size
-        (static under trace).  Publishes a trace-time census of the
-        bytes each compiled reduction moves per execution.
+        (static under trace).  Runs when a program is TRACED, once a
+        compile: :meth:`counting` counts what it notes once a call.
         """
         if op not in _OPS:
             raise ValueError(
                 f"unknown allreduce op '{op}' (have {sorted(_OPS)})")
         nbytes = int(x.size) * x.dtype.itemsize
         strat = strategy or self.strategy_for(nbytes)
-        try:
-            from .. import telemetry
-            telemetry.counter_add("mesh.collective_bytes", nbytes)
-        except Exception:
-            pass
+        # noted, not counted: this line runs once a compile, however often
+        # the compiled reduction runs (counting(), at the end of the class;
+        # no line above it may move: a program's lines are in its cache key)
+        self._traced[0] += 1
+        self._traced[1] += nbytes
         # every plan-routed reduction under one scope, flat or hierarchical:
         # collective time is read off a device trace by this name
         with jax.named_scope("mesh.allreduce"):
@@ -317,6 +317,56 @@ class MeshPlan:
         return res
 
 
+    def counting(self, program: str, executions: int = 1, around=None):
+        """Context manager around ``executions`` calls of ONE jitted program
+        whose trace calls :meth:`allreduce`: adds what they reduce to the
+        counters ``mesh.allreduce_calls`` and ``mesh.collective_bytes`` (the
+        payload a reduction is handed, as every chip holds it; not what a
+        route moves over the wires).
+
+        Only the caller of a compiled program knows how often it runs.
+        What one execution reduces is noted when a call inside the block
+        traces the program, under the caller's name for it, ``program``,
+        and counted for these calls and for every later block of that
+        name, which finds the program compiled.  A context manager and not
+        a wrapper, so that no frame of it stands between the caller and the
+        program while that is traced (source lines and callers are part of
+        a program's compile-cache key).  ``around``: a context manager to
+        hold open around the block (a span)."""
+        return _Counting(self, program, executions, around)
+
+
+class _Counting:
+    """:meth:`MeshPlan.counting`'s block."""
+
+    def __init__(self, plan, program, executions, around):
+        self.plan, self.program = plan, program
+        self.executions, self.around = executions, around
+
+    def __enter__(self):
+        if self.around is not None:
+            self.around.__enter__()
+        self.mark = tuple(self.plan._traced)
+
+    def __exit__(self, *exc):
+        plan = self.plan
+        calls, nbytes = (plan._traced[0] - self.mark[0],
+                         plan._traced[1] - self.mark[1])
+        if calls:
+            plan._census[self.program] = (calls, nbytes)
+        calls, nbytes = plan._census.get(self.program, (0, 0))
+        try:
+            from .. import telemetry
+            telemetry.counter_add("mesh.allreduce_calls",
+                                  calls * self.executions)
+            telemetry.counter_add("mesh.collective_bytes",
+                                  nbytes * self.executions)
+        except Exception:
+            pass
+        if self.around is not None:
+            return self.around.__exit__(*exc)
+
+
 def plan_allreduce_bench(plan: MeshPlan, strategy: str = "auto",
                          mib_per_device: float = 8.0, iters: int = 10,
                          warmup: int = 2) -> dict:
@@ -340,12 +390,14 @@ def plan_allreduce_bench(plan: MeshPlan, strategy: str = "auto",
         np.random.default_rng(0).standard_normal((nfloats,),
                                                  dtype=np.float32),
         plan.data_sharding())
-    for _ in range(max(1, warmup)):
-        step(x).block_until_ready()
+    with plan.counting("plan_allreduce_bench", max(1, warmup)):
+        for _ in range(max(1, warmup)):
+            step(x).block_until_ready()
     watch = Stopwatch()
-    for _ in range(iters):
-        out = step(x)
-    out.block_until_ready()
+    with plan.counting("plan_allreduce_bench", iters):
+        for _ in range(iters):
+            out = step(x)
+        out.block_until_ready()
     secs = watch.elapsed() / iters
     nbytes = nfloats * 4 // n  # per-device payload, NCCL-tests convention
     algo = nbytes / secs / 1e9

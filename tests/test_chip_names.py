@@ -179,3 +179,53 @@ def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(
     assert len(kernels) == model.max_depth
     kernel_ops = [n for n in names if n.endswith("/pallas_call")]
     assert kernel_ops and all("/gbdt.hist/" in n for n in kernel_ops)
+
+
+# the Airline cell's tree program (benchmark/configs/airline-gbdt.json: 13
+# features x 256 bins, depth 8) for the four chips of a described v5e 2x2,
+# rows sharded, at a row count a chip that is no whole number of row tiles
+def test_sharded_tree_program_reduces_once_a_level_and_gathers_nothing(
+        topo, quiet_cache, monkeypatch):
+    """What a four-chip trace is read by: eight kernels under ``shard_map``
+    with the dense kernel's name, each level's histogram reduced once under
+    the scope ``mesh.allreduce``, the leaf sums' psum the compiler inserts,
+    and no all-gather, all-to-all or permute of a row array anywhere."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from dmlc_core_tpu.parallel import MeshPlan
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+    plan = MeshPlan(Mesh(np.asarray(topo.devices[:4]), ("data",)), ("data",),
+                    collective="flat", overlap_chunks=1)
+    features, depth, rows_chip = 13, 8, 100_000
+    rows = 4 * rows_chip
+    model = GBDT(num_features=features, num_trees=2, max_depth=depth,
+                 num_bins=BINS, learning_rate=0.1, min_child_weight=1.0,
+                 histogram="pallas", histogram_mesh=plan)
+    by_rows, whole = plan.data_sharding(), plan.replicated_sharding()
+    compiled = model._build_tree.lower(
+        model, on(by_rows, (rows, features), jnp.uint8),
+        on(by_rows, (rows,), jnp.float32), on(by_rows, (rows,), jnp.float32),
+        on(whole, (features,), jnp.bool_), on(whole, (2,), jnp.uint32)
+    ).compile()
+    text = compiled.as_text()
+    pattern = json.loads((LAYER_METRICS / "mesh_hist_ms_per_round.json")
+                         .read_text())["args"]["pattern"]
+    kernels = [n for n in instructions(compiled) if re.search(pattern, n)]
+    assert len(kernels) == depth and text.count("tpu_custom_call") == depth
+    reduces = re.findall(r"= (\S+) all-reduce(?:-start)?\(.*?op_name=\"([^\"]*)\"",
+                         text)
+    levels = [shape for shape, name in reduces if "mesh.allreduce" in name]
+    assert sorted(int(s.split("[")[1].split(",")[0]) for s in levels) == [
+        2 ** d for d in range(depth)]
+    others = [name for _, name in reduces if "mesh.allreduce" not in name]
+    assert len(others) == 1 and "gbdt.leaf" in others[0], others
+    for op in ("all-gather", "all-to-all", "collective-permute",
+               "reduce-scatter"):
+        assert not re.search(rf"= .*\b{op}(-start)?\(", text), op
+    scope = json.loads((LAYER_METRICS / "allreduce_ms_per_round.json")
+                       .read_text())["args"]["scope"]
+    assert any(re.search(scope, n) for n in op_names(compiled))
+    # a chip holds its own rows and nothing of another's: the arguments are
+    # a quarter of the rows each
+    assert compiled.memory_analysis().argument_size_in_bytes < rows_chip * 32
