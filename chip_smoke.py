@@ -659,7 +659,9 @@ def phase_mesh(ctx: dict) -> dict:
     bins = jax.device_put(ctx["dense_bins"], plan.data_sharding())
     y = jax.device_put(label, plan.data_sharding())
     fits = {}
-    level_bytes = [2 ** d * FEATURES * size["bins"] * 8
+    # what a level reduces: its built node histograms, the root and then one
+    # child of every parent (the siblings are derived after the reduction)
+    level_bytes = [max(2 ** (d - 1), 1) * FEATURES * size["bins"] * 8
                    for d in range(size["depth"])]
     # "auto" (the ring from 256 KiB on), and the plan of the benchmark's
     # four-chip cell, built from that cell's own parameters the way its

@@ -24,10 +24,10 @@ XLA —
   prediction is ``max_depth`` vectorized gathers — XLA-friendly and
   checkpointable as a plain pytree via dmlc_core_tpu.checkpoint.
 
-Sibling-histogram subtraction (build the smaller child, subtract from the
-parent) is deliberately not used: it halves FLOPs on serial CPUs but makes
-the level pass stateful; on TPU the full-level segment-sum is a single
-bandwidth-bound fused op and the simpler schedule wins.
+Below the root a level builds ONE child of every parent, the one with the
+smaller hessian mass, and derives its sibling as parent - built (XGBoost
+hist's subtraction): the kernels' time grows with the node columns on the
+MXU's M axis, so half the columns is a level's time of the level above.
 """
 from __future__ import annotations
 
@@ -711,17 +711,17 @@ class GBDT:
         return [impl(2 ** d) for d in range(self.max_depth)]
 
     def _level_histogram(self, bins_i: jax.Array, rel: jax.Array,
-                         gh: jax.Array, n_nodes: int) -> jax.Array:
-        """Per-level [nodes, F, bins, 2] histogram with backend routing.
-
+                         gh: jax.Array, n_nodes: int, impl: str) -> jax.Array:
+        """[n_nodes, F, bins, 2] histogram of the rows whose ``rel`` is in
+        [0, n_nodes) — a level's built columns; ``_NO_SLOT`` adds nothing
+        on either backend — through ``impl``, the level's `_hist_impl`.
         Plain ``histogram_gh`` call normally (GSPMD partitions the XLA
         path and inserts the psum on sharded fits).  With a mesh plan
         set and the level resolving to the Pallas backend — or the plan
         asking for overlap (``overlap_chunks > 1``) — the kernel runs
         per-device on local row shards under ``jax.shard_map`` and the
         shards combine with the plan's allreduce (flat psum or
-        hierarchical by payload; pattern proven by
-        tests/test_pallas.py::test_histogram_gh_shardmap_psum_matches_global).
+        hierarchical; `test_histogram_gh_shardmap_psum_matches_global`).
 
         Overlap: with K = overlap_chunks > 1 the feature axis splits
         into K chunks and the reduce of chunk k is issued before the
@@ -736,9 +736,9 @@ class GBDT:
         """
         from jax.sharding import PartitionSpec as P
 
-        impl = self._hist_impl(n_nodes)
-        B = self.num_bins
-        plan = self.mesh_plan
+        # the plan's allreduce below carries the built columns only: their
+        # siblings are derived after it (`_with_siblings`)
+        B, plan = self.num_bins, self.mesh_plan
         K = 1 if plan is None else min(plan.overlap_chunks,
                                        self.num_features)
         # explicit shard_map route: always for the pallas kernel (no
@@ -821,8 +821,8 @@ class GBDT:
 
     def _level_histogram_sparse(self, layout, rel: jax.Array,
                                 gh_row: jax.Array, gh_e, rel_e, n_nodes: int):
-        """Sparse per-level [nodes, F, bins, 2] via the Pallas kernel.
-
+        """Sparse [n_nodes, F, bins, 2] via the Pallas kernel, of the rows
+        with ``rel`` in [0, n_nodes): a level's built columns, no ``_NO_SLOT``.
         Single-device: the entry gathers against the feature-sorted layout
         (``gh_e`` once a tree, ``rel_e`` once a level, both by the caller
         under its scope ``gbdt.entry_gather``) feed one kernel call.  With
@@ -1318,6 +1318,13 @@ class GBDT:
                     ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                                jax.Array, jax.Array, jax.Array]:
         """One tree from per-row (grad, hess); levels unrolled under jit.
+        The root's histogram is built whole; a level below it builds one
+        child of every parent (`_smaller_child`) from the rows that stand in
+        it (`_child_slot`; the bit travels in `_route_level`'s word, no
+        gather a row) and derives the other (`_with_siblings`), so the
+        backend sums ``2 ** (depth - 1)`` node columns where the level has
+        ``2 ** depth``; under a mesh plan the built columns are reduced
+        before the subtraction.
 
         bins: u8 [rows, features]; grad/hess: f32 [rows] (weight-scaled,
         padding rows carry 0 mass).  Returns (feature, threshold,
@@ -1338,25 +1345,26 @@ class GBDT:
         hi = jnp.full(1, jnp.inf)
         active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
                   if self._interaction_groups is not None else None)
-        features = []
-        thresholds = []
-        defaults = []
-        gains = []
-        covers = []
+        features, thresholds, defaults, gains, covers = [], [], [], [], []
+        # a row's column among the level's built histograms: at the root its
+        # node, below it `_child_slot`; the level above, and which child of
+        hist, slot, right_built = None, node, None  # each parent was built
         for depth in range(self.max_depth):
             first = 2 ** depth - 1          # heap id of the level's first node
             n_nodes = 2 ** depth
             # fused histogram build: ONE reduction over rows x features
             # carrying (grad, hess) lanes together — the key array (the
-            # bandwidth bottleneck) is read once, not once per statistic.
-            # Backend per level via _hist_impl: the Pallas one-hot-
-            # contraction kernel on TPU while the level is shallow
-            # (scatter-free; see ops.histogram_gh for the layout and the
-            # HBM-footprint contrast), XLA scatter-add otherwise.
+            # bandwidth bottleneck) is read once, not once per statistic —
+            # of the built columns only (`_built_columns`), their siblings
+            # by subtraction.  Backend per level via _hist_impl: the Pallas
+            # kernel on TPU while the level is shallow (scatter-free; see
+            # ops.histogram_gh), XLA scatter-add otherwise.
             with jax.named_scope("gbdt.hist"):
                 rel = node - first          # [rows] in [0, n_nodes)
                 gh = jnp.stack([grad, hess], axis=-1)  # [rows, 2]
-                hist = self._level_histogram(bins_i, rel, gh, n_nodes)
+                hist = _with_siblings(hist, self._level_histogram(
+                    bins_i, slot, gh, _built_columns(depth),
+                    self._hist_impl(n_nodes)), right_built)
             with jax.named_scope("gbdt.split"):
                 hist_g = hist[..., 0]
                 hist_h = hist[..., 1]
@@ -1399,18 +1407,22 @@ class GBDT:
                                                 wl, wr, lo, hi)
                 if active is not None:
                     active = self._next_active(active, split_f, split_b)
+                covers.append(h_tot[:, 0, 0])   # node hessian mass (any f)
+                right_built = _smaller_child([hl_ for _, hl_ in dirs],
+                                             covers[-1], split_f, split_b,
+                                             split_d)
             features.append(split_f)
             thresholds.append(split_b)
             defaults.append(split_d)
             gains.append(split_g)
-            covers.append(h_tot[:, 0, 0])   # node hessian mass (any f)
             # route rows: children of heap node n are 2n+1 (left), 2n+2
             with jax.named_scope("gbdt.route"):
                 # the same expression as ops.histogram_gh's kernel layout:
                 # XLA keeps one transpose of the bins for the whole tree
-                go_right = self._route_level(bins_i.T, rel, split_f, split_b,
-                                             split_d)
+                go_right, built_bit = self._route_level(
+                    bins_i.T, rel, split_f, split_b, split_d, right_built)
                 node = 2 * node + 1 + go_right.astype(jnp.int32)
+                slot = _child_slot(rel, go_right, built_bit)
 
         # leaf weights: -G/(H + lambda) per leaf, shrunken (clamped into the
         # node's propagated bounds first under monotone constraints)
@@ -1431,15 +1443,19 @@ class GBDT:
 
     def _route_level(self, bins_t: jax.Array, rel: jax.Array,
                      split_f: jax.Array, split_b: jax.Array,
-                     split_d: jax.Array) -> jax.Array:
-        """Which rows of a level go to their node's right child: bool [rows].
+                     split_d: jax.Array, right_built: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array]:
+        """Which rows of a level go to their node's right child, and whether
+        their node's right child is the one the next level builds
+        (``right_built[rel]``, for `_child_slot`): two bool [rows].
 
         bins_t: i32 [F, rows], feature-major; rel: [rows] in [0, n_nodes);
-        split_f / split_b / split_d: the level's [n_nodes] split tables.
+        split_f / split_b / split_d / right_built: the level's [n_nodes]
+        split tables.
         Dense compare-and-select with rows on the lanes, integers only: a
         per-row gather (``bins[arange(rows), split_f[rel]]``) took 22 ns a
         row a level on a v5e whatever the table's size, a quarter of a
-        round at 10.5M rows (PERF.md, PR 26).  The node's three entries
+        round at 10.5M rows (PERF.md, PR 26).  The node's four entries
         travel as one word, so the pass over the nodes selects once (past
         ``_ROUTE_SELECT_NODES`` nodes one gather of that word is cheaper);
         the row's bin is then the one term of a sum over the features that
@@ -1449,10 +1465,12 @@ class GBDT:
         n_nodes = split_f.shape[0]
         # split_b reaches num_bins, the null split's sentinel
         bbits = self.num_bins.bit_length()
-        if F.bit_length() + bbits + 1 > 31:
+        if F.bit_length() + bbits + 2 > 31:
             raise ValueError(f"{F} features do not pack beside "
                              f"{self.num_bins} bins into an int32 word")
-        word = (split_f << (bbits + 1)) | (split_d << bbits) | split_b
+        word = ((split_f << (bbits + 2))
+                | (right_built.astype(jnp.int32) << (bbits + 1))
+                | (split_d << bbits) | split_b)
         if n_nodes > _ROUTE_SELECT_NODES:
             word = word[rel]
         elif n_nodes > 1:
@@ -1460,13 +1478,13 @@ class GBDT:
             word = jnp.sum(jnp.where(rel[None, :] == ids, word[:, None], 0),
                            axis=0)                          # [rows]
         feat = jnp.arange(F, dtype=jnp.int32)[:, None]
-        row_bin = jnp.sum(jnp.where((word >> (bbits + 1))[None, :] == feat,
+        row_bin = jnp.sum(jnp.where((word >> (bbits + 2))[None, :] == feat,
                                     bins_t, 0), axis=0)
         go_right = row_bin > (word & ((1 << bbits) - 1))
         if self.missing_aware:
             go_right = jnp.where(row_bin == 0, ((word >> bbits) & 1) == 1,
                                  go_right)
-        return go_right
+        return go_right, ((word >> (bbits + 1)) & 1) == 1
 
     @functools.partial(jax.jit, static_argnums=0)
     def _tree_margins(self, feature: jax.Array, threshold: jax.Array,
@@ -1497,8 +1515,9 @@ class GBDT:
         propagation.  Shared verbatim by the resident sparse tree builder
         and the out-of-core streamed builder, so the two produce identical
         forests from identical data — only how the histogram was
-        accumulated differs.  Returns
-        ``(split_f, split_b, split_d, split_g, lo, hi, active)``."""
+        accumulated differs.  Returns ``(split_f, split_b, split_d,
+        split_g, lo, hi, active, right_built)``, the last `_smaller_child`'s
+        word on which child of each node the next level builds."""
         with jax.named_scope("gbdt.split"):
             lam = self.lambda_
             mono = self.monotone_constraints is not None
@@ -1534,7 +1553,10 @@ class GBDT:
                                             wl, wr, lo, hi)
             if active is not None:
                 active = self._next_active(active, split_f, split_b)
-        return split_f, split_b, split_d, split_g, lo, hi, active
+            right_built = _smaller_child([hl_ for _, hl_ in dirs],
+                                         gh_node[:, 1], split_f, split_b,
+                                         split_d)
+        return split_f, split_b, split_d, split_g, lo, hi, active, right_built
 
     @functools.partial(jax.jit, static_argnums=0)
     def _build_tree_sparse(self, entries, layout, grad: jax.Array,
@@ -1550,6 +1572,11 @@ class GBDT:
         dual-direction gain machinery is shared with the dense
         missing-aware path.  Requires ``missing_aware=True`` bins from
         ``transform_entries`` (all codes >= 1; bin 0 stays empty).
+        As in `_build_tree`, a level below the root accumulates one child
+        of every parent — an entry is keyed by its row's `_child_slot`, and
+        ``_NO_SLOT`` drops it on either backend — and derives the sibling
+        (`_with_siblings`); the node totals are still every node's.  The
+        root's slots are all 0 and are not gathered (`_entry_slots`).
 
         Histogram accumulation routes through the ``histogram=`` backend
         knob per level (`_hist_impl_sparse`): XLA keeps the flattened-key
@@ -1596,34 +1623,38 @@ class GBDT:
         active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
                   if self._interaction_groups is not None else None)
         features, thresholds, defaults, gains, covers = [], [], [], [], []
+        # as in `_build_tree`: a row's column among the built histograms,
+        # the level above, and which child of each of its nodes was built
+        hist, slot, right_built = None, node, None
         for depth, impl in enumerate(impls):
             first = 2 ** depth - 1
             n_nodes = 2 ** depth
+            cols = _built_columns(depth)
             rel = node - first
-            rel_e = None
             if impl == "pallas":
-                if not mesh:
-                    with jax.named_scope("gbdt.entry_gather"):
-                        rel_e = rel[layout.rid]
+                slot_e = (None if mesh
+                          else _entry_slots(layout.rid, slot, depth))
                 with jax.named_scope("gbdt.hist"):
-                    hist = self._level_histogram_sparse(
-                        layout, rel, gh_row, gh_e, rel_e, n_nodes)
+                    built = self._level_histogram_sparse(
+                        layout, slot, gh_row, gh_e, slot_e, cols)
             else:
                 with jax.named_scope("gbdt.hist"):
                     if gh_k is None:    # padding lanes carry 0 mass
                         gh_k = (gh_row[rid]
                                 * emask.astype(jnp.float32)[:, None])
-                    keys = (rel[rid] * F + fi) * B + ebin
-                    hist = jax.ops.segment_sum(
-                        gh_k, keys, num_segments=n_nodes * F * B
-                    ).reshape(n_nodes, F, B, 2)             # bin 0 is empty
+                    keys = (slot[rid] * F + fi) * B + ebin
+                    built = jax.ops.segment_sum(
+                        gh_k, keys, num_segments=cols * F * B
+                    ).reshape(cols, F, B, 2)                # bin 0 is empty
+            with jax.named_scope("gbdt.hist"):
+                hist = _with_siblings(hist, built, right_built)
             with jax.named_scope("gbdt.node_totals"):
                 gh_node = segment_sum(
                     gh_row, rel, num_segments=n_nodes,
                     force="pallas" if impl == "pallas" and not mesh
                     else None)
-            (split_f, split_b, split_d, split_g,
-             lo, hi, active) = self._level_splits_from_hist(
+            (split_f, split_b, split_d, split_g, lo, hi, active,
+             right_built) = self._level_splits_from_hist(
                 hist, gh_node, depth, col_mask, col_key, lo, hi, active)
             features.append(split_f)
             thresholds.append(split_b)
@@ -1639,6 +1670,7 @@ class GBDT:
                                                   split_f[rel], split_b[rel],
                                                   split_d[rel], rows)
                 node = 2 * node + 1 + go_right.astype(jnp.int32)
+                slot = _child_slot(rel, go_right, right_built[rel])
 
         n_leaves = 2 ** self.max_depth
         with jax.named_scope("gbdt.leaf"):
@@ -2005,21 +2037,26 @@ class GBDT:
         def batch_entries(b):
             return self._entry_bins(b, binner)
 
+        def route_batch(node_b, prev, first_prev, rid, fi, ebin, emask):
+            # a batch's rows through `prev`'s splits: their nodes, and their
+            # columns among the histograms the next level builds
+            pf, pb, pd, pr = prev
+            rel_p = node_b - first_prev
+            go_right = self._route_sparse(fi, ebin, emask, rid, pf[rel_p],
+                                          pb[rel_p], pd[rel_p],
+                                          node_b.shape[0])
+            return (2 * node_b + 1 + go_right.astype(jnp.int32),
+                    _child_slot(rel_p, go_right, pr[rel_p]))
+
         def route_pass(node, prev, first_prev):
             # one streamed pass routing every row through `prev`'s splits
             # (per-batch entry bins recomputed, per the residency contract)
-            pf, pb, pd = prev
             routed = []
             for off, b in stream():
                 nb = int(b.label.shape[0])
-                rid, fi, ebin, emask = batch_entries(b)
-                node_b = node[off:off + nb]
-                rel_p = node_b - first_prev
-                go_right = self._route_sparse(fi, ebin, emask, rid,
-                                              pf[rel_p], pb[rel_p],
-                                              pd[rel_p], nb)
-                routed.append(2 * node_b + 1 + go_right.astype(jnp.int32))
-            return jnp.concatenate(routed)
+                routed.append(route_batch(node[off:off + nb], prev,
+                                          first_prev, *batch_entries(b)))
+            return tuple(jnp.concatenate(a) for a in zip(*routed))
 
         def build_tree(grad, hess, col_mask, ck):
             gh_row = jnp.stack([grad, hess], axis=-1)      # [rows, 2]
@@ -2032,10 +2069,14 @@ class GBDT:
             active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
                       if self._interaction_groups is not None else None)
             features, thresholds, defaults, gains, covers = [], [], [], [], []
-            prev = None  # previous level's (split_f, split_b, split_d)
+            # previous level's (split_f, split_b, split_d, right_built) while
+            # its rows are still to be routed; `hist4` its histograms, and
+            # `slot` (as in `_build_tree`) every row's built column
+            prev, hist4, slot, right_built = None, None, node, None
             for depth in range(self.max_depth):
                 first = 2 ** depth - 1
                 n_nodes = 2 ** depth
+                cols = _built_columns(depth)
                 impl = (self._hist_impl_sparse(n_nodes)
                         if layout is not None else "xla")
                 if impl == "pallas":
@@ -2044,47 +2085,46 @@ class GBDT:
                     # routing into its accumulation pass), then ONE kernel
                     # call over the resident sorted layout
                     if prev is not None:
-                        node = route_pass(node, prev, 2 ** (depth - 1) - 1)
+                        node, slot = route_pass(node, prev,
+                                                2 ** (depth - 1) - 1)
                         prev = None
-                    rel = node - first
                     counter_add("gbdt.hist_sparse_pallas", 1)
-                    hist4 = self._level_histogram_sparse(
-                        layout, rel, gh_row, gh_e, rel[layout.rid], n_nodes)
-                    gh_node = segment_sum(gh_row, rel,
+                    built = self._level_histogram_sparse(
+                        layout, slot, gh_row, gh_e,
+                        _entry_slots(layout.rid, slot, depth), cols)
+                    gh_node = segment_sum(gh_row, node - first,
                                           num_segments=n_nodes,
                                           force="pallas")
                 else:
-                    hist = jnp.zeros((n_nodes * F * B, 2), jnp.float32)
+                    hist = jnp.zeros((cols * F * B, 2), jnp.float32)
                     routed = []
                     for off, b in stream():
                         nb = int(b.label.shape[0])
                         rid, fi, ebin, emask = batch_entries(b)
-                        node_b = node[off:off + nb]
+                        slot_b = slot[off:off + nb]
                         if prev is not None:
                             # route through the previous level's splits in
                             # the same pass that accumulates this level's
-                            # histogram
-                            pf, pb, pd = prev
-                            rel_p = node_b - (2 ** (depth - 1) - 1)
-                            go_right = self._route_sparse(
-                                fi, ebin, emask, rid, pf[rel_p], pb[rel_p],
-                                pd[rel_p], nb)
-                            node_b = (2 * node_b + 1
-                                      + go_right.astype(jnp.int32))
-                            routed.append(node_b)
-                        rel = node_b - first
+                            # histogram, which wants the routed rows' slots
+                            # and not the ones they came with
+                            node_b, slot_b = route_batch(
+                                node[off:off + nb], prev,
+                                2 ** (depth - 1) - 1, rid, fi, ebin, emask)
+                            routed.append((node_b, slot_b))
                         gh_k = (gh_row[off:off + nb][rid]
                                 * emask.astype(jnp.float32)[:, None])
-                        keys = (rel[rid] * F + fi) * B + ebin
+                        keys = (slot_b[rid] * F + fi) * B + ebin
                         hist = hist + jax.ops.segment_sum(
-                            gh_k, keys, num_segments=n_nodes * F * B)
+                            gh_k, keys, num_segments=cols * F * B)
                     if prev is not None:
-                        node = jnp.concatenate(routed)
-                    hist4 = hist.reshape(n_nodes, F, B, 2)
+                        node, slot = (jnp.concatenate(a)
+                                      for a in zip(*routed))
+                    built = hist.reshape(cols, F, B, 2)
                     gh_node = jax.ops.segment_sum(gh_row, node - first,
                                                   num_segments=n_nodes)
-                (split_f, split_b, split_d, split_g,
-                 lo, hi, active) = self._level_splits_from_hist(
+                hist4 = _with_siblings(hist4, built, right_built)
+                (split_f, split_b, split_d, split_g, lo, hi, active,
+                 right_built) = self._level_splits_from_hist(
                     hist4, gh_node, depth,
                     col_mask, col_key=ck, lo=lo, hi=hi, active=active)
                 features.append(split_f)
@@ -2092,10 +2132,10 @@ class GBDT:
                 defaults.append(split_d)
                 gains.append(split_g)
                 covers.append(gh_node[:, 1])
-                prev = (split_f, split_b, split_d)
+                prev = (split_f, split_b, split_d, right_built)
 
             # final pass: route through the deepest splits to the leaves
-            node = route_pass(node, prev, 2 ** (self.max_depth - 1) - 1)
+            node, _ = route_pass(node, prev, 2 ** (self.max_depth - 1) - 1)
 
             n_leaves = 2 ** self.max_depth
             leaf_rel = node - (n_leaves - 1)
@@ -2423,13 +2463,96 @@ class GBDT:
 
     def _tree_span(self, build_tree, trees: int = 1):
         """The span ``gbdt.tree`` around one boosting round's ``trees`` calls
-        of ``build_tree``; under a mesh plan it also counts the reductions
+        of ``build_tree``, which also counts the node histograms those trees
+        build and derive (``gbdt.hist_nodes_built``, ``gbdt.hist_nodes_derived``:
+        `_built_columns`); under a mesh plan it also counts the reductions
         those calls run (``MeshPlan.counting``), under the name of the fit
         that made ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one
         tree program each).  A context manager and not a wrapper: nothing
         of it is on the stack while a tree program is traced."""
         span = telemetry.span("gbdt.tree")
+        built = trees * sum(map(_built_columns, range(self.max_depth)))
+        counter_add("gbdt.hist_nodes_built", built)
+        counter_add("gbdt.hist_nodes_derived",
+                    trees * (2 ** self.max_depth - 1) - built)
         if self.mesh_plan is None:
             return span
         kind = getattr(build_tree, "__qualname__", "").split(".<locals>")[0]
         return self.mesh_plan.counting(kind, trees, around=span)
+
+
+# ---- sibling subtraction: what a level builds, and what it derives ----------
+# A level's histograms are determined by its parent's and ONE child's of each
+# parent: hist(sibling) = hist(parent) - hist(built), bucket by bucket
+# (XGBoost hist's subtraction).  Both kernels' time grows with the node
+# columns on the MXU's M axis, so every tree builder (`_build_tree`,
+# `_build_tree_sparse`, `fit_streamed`) asks its backend for half a level's
+# columns through these functions, and for the root whole.  Defined below
+# the class for the reason given above `_shard_inputs`.
+
+# The column of a row that stands in no built node.  Every histogram backend
+# drops it: the kernels compare ids with their node columns, all >= 0, and
+# `jax.ops.segment_sum` drops a negative key (tests/test_gbdt_siblings.py).
+_NO_SLOT = -1
+
+
+def _built_columns(depth: int) -> int:
+    """Node histograms the level at ``depth`` asks its backend for: the root,
+    then one child of each of the ``2 ** (depth - 1)`` parents."""
+    return 2 ** max(depth - 1, 0)
+
+
+def _smaller_child(hl_dirs, h_tot, split_f, split_b, split_d) -> jax.Array:
+    """Which child of each node the next level builds: bool [nodes], True
+    for the right one.  The child with the smaller hessian mass at the
+    node's chosen split, so that the derived sibling, which inherits the
+    parent's absolute rounding error, is the larger: its relative error is
+    at most twice the parent's plus the built child's (deriving a child of
+    a thousandth of the mass would multiply it by a thousand).  A null
+    split sends every row left, so its empty right child is built.
+
+    hl_dirs: the left hessian mass ``[nodes, F, B]`` of every cut, one array
+    a default direction; h_tot: [nodes]; split_*: the chosen [nodes]
+    tables, nulls as `_pick_splits` encodes them."""
+    n_nodes, _, B = hl_dirs[0].shape
+    at = (split_f * B + jnp.minimum(split_b, B - 1))[:, None]
+    hl = [jnp.take_along_axis(a.reshape(n_nodes, -1), at, 1)[:, 0]
+          for a in hl_dirs]
+    left = hl[0] if len(hl) == 1 else jnp.where(split_d == 1, hl[1], hl[0])
+    left = jnp.where(split_b >= B, h_tot, left)
+    return h_tot - left < left
+
+
+def _child_slot(rel: jax.Array, go_right: jax.Array,
+                right_built: jax.Array) -> jax.Array:
+    """A routed row's column among the histograms the next level builds: its
+    parent's index ``rel`` if it went to the child that is built
+    (``right_built``, its parent's word of `_smaller_child`), else
+    ``_NO_SLOT``."""
+    return jnp.where(go_right == right_built, rel, _NO_SLOT)
+
+
+def _entry_slots(rid: jax.Array, slot: jax.Array, depth: int) -> jax.Array:
+    """The rows' ``slot`` on the sorted entries' lanes (``rid``: each lane's
+    row), for the sparse kernel.  At the root every slot is 0 and nothing
+    is gathered: the gather cost 1.9 s a tree at 2.18e8 entries on a v5e,
+    8.6 ns an element (PERF.md, PR 27)."""
+    with jax.named_scope("gbdt.entry_gather"):
+        if depth == 0:
+            return jnp.zeros(rid.shape, jnp.int32)
+        return slot[rid]
+
+
+def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
+    """A level's ``[2 * parents, F, B, 2]`` histograms from the level
+    above's ``parent`` ``[parents, F, B, 2]`` and the ``built`` child of
+    each (``right_built``: which), the other derived as parent - built in
+    float32, interleaved in heap order.  The root has no parent and is built
+    whole."""
+    if parent is None:
+        return built
+    derived = parent - built
+    right = right_built[:, None, None, None]
+    pair = jnp.stack([jnp.where(right, derived, built),
+                      jnp.where(right, built, derived)], axis=1)
+    return pair.reshape((-1,) + built.shape[1:])

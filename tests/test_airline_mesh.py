@@ -221,7 +221,10 @@ def test_mesh_counters_grow_alike_on_every_fit(traffic):
             "mesh.collective_bytes", 0)
 
     first, second, third = fit_delta(), fit_delta(), fit_delta()
-    level_bytes = sum(2 ** d for d in range(DEPTH)) * 13 * BINS * 2 * 4
+    # a level reduces its built node histograms only: the root, then one
+    # child of every parent, 1 + sum of 2^(d-1) a tree
+    built = 1 + sum(2 ** (d - 1) for d in range(1, DEPTH))
+    level_bytes = built * 13 * BINS * 2 * 4
     assert first == (DEPTH * TREES, level_bytes * TREES)
     assert second == first and third == first
 

@@ -187,9 +187,11 @@ def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(
 def test_sharded_tree_program_reduces_once_a_level_and_gathers_nothing(
         topo, quiet_cache, monkeypatch):
     """What a four-chip trace is read by: eight kernels under ``shard_map``
-    with the dense kernel's name, each level's histogram reduced once under
-    the scope ``mesh.allreduce``, the leaf sums' psum the compiler inserts,
-    and no all-gather, all-to-all or permute of a row array anywhere."""
+    with the dense kernel's name, each level's built histograms (the root,
+    then one child of every parent) reduced once under the scope
+    ``mesh.allreduce`` before their siblings are derived, the leaf sums'
+    psum the compiler inserts, and no all-gather, all-to-all or permute of a
+    row array anywhere."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -216,8 +218,12 @@ def test_sharded_tree_program_reduces_once_a_level_and_gathers_nothing(
     reduces = re.findall(r"= (\S+) all-reduce(?:-start)?\(.*?op_name=\"([^\"]*)\"",
                          text)
     levels = [shape for shape, name in reduces if "mesh.allreduce" in name]
-    assert sorted(int(s.split("[")[1].split(",")[0]) for s in levels) == [
-        2 ** d for d in range(depth)]
+    # the built columns only: the root, then one child of every parent
+    built = [1] + [2 ** (d - 1) for d in range(1, depth)]
+    assert sorted(int(s.split("[")[1].split(",")[0]) for s in levels) == built
+    out_rows = sorted(int(m) for m in re.findall(
+        r"%_histogram_gh_pallas[\w.\-]* = f32\[(\d+),", text))
+    assert out_rows == sorted((6 if c <= 32 else 2) * max(8, c) for c in built)
     others = [name for _, name in reduces if "mesh.allreduce" not in name]
     assert len(others) == 1 and "gbdt.leaf" in others[0], others
     for op in ("all-gather", "all-to-all", "collective-permute",

@@ -172,6 +172,90 @@ def test_route_gathers_nothing_a_row():
     assert sum(int(np.prod(shape)) >= rows for shape in old) >= kw["max_depth"]
 
 
+def device_ops(traced, primitive: str) -> list:
+    """``(scope path, operands' avals, results' avals)`` of every equation
+    of ``primitive`` in a traced program, in program order, nested calls
+    (the kernels' jitted wrappers, split finding) walked through."""
+    def walk(jaxpr, path):
+        for eqn in jaxpr.eqns:
+            here = f"{path}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == primitive:
+                yield (here, [v.aval for v in eqn.invars],
+                       [v.aval for v in eqn.outvars])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, here)
+    return list(walk(traced.jaxpr.jaxpr, ""))
+
+
+def tree_program(features: int, max_depth: int, sparse: bool, rows: int = 192):
+    """The traced tree program of a cell's shape (its features, bins and
+    depth) over a few rows; the sparse one as `fit_batch` hands it over when
+    every level runs the kernel.  Returns it with its entry lanes."""
+    model = GBDT(num_features=features, num_trees=1, max_depth=max_depth,
+                 num_bins=256, missing_aware=True, histogram="pallas")
+    tail = (jnp.zeros(rows), jnp.zeros(rows), jnp.ones(features, bool),
+            jax.random.PRNGKey(0))
+    if not sparse:
+        return model._build_tree.trace(
+            model, jnp.zeros((rows, features), jnp.uint8), *tail), 0
+    from dmlc_core_tpu.ops.pallas_segment import sparse_hist_layout
+    rid = jnp.repeat(jnp.arange(rows, dtype=jnp.int32), 3)
+    layout = sparse_hist_layout(rid, (7 * rid + jnp.arange(rid.shape[0]))
+                                % features, 1 + rid % 255,
+                                jnp.ones(rid.shape, bool), features, 256)
+    return (model._build_tree_sparse.trace(model, None, layout, *tail),
+            layout.rid.shape[0])
+
+
+# the three GBDT cells' shapes (benchmark/configs/*-gbdt.json)
+CELL_TREES = {"higgs": (28, 6, False), "airline": (13, 8, False),
+              "bosch": (968, 8, True)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TREES))
+def test_a_level_asks_its_kernel_for_one_child_of_each_parent(cell):
+    """Both kernels' M axis is 6 x node columns (padded to 8): below the
+    root a level hands them half its nodes, so the level at depth d runs the
+    kernel the level above ran before PR 32."""
+    features, max_depth, sparse = CELL_TREES[cell]
+    traced, _ = tree_program(features, max_depth, sparse)
+    calls = [(path, outs) for path, _ins, outs
+             in device_ops(traced, "pallas_call") if "gbdt.hist" in path]
+    assert len(calls) == max_depth
+    for depth, (path, outs) in enumerate(calls):
+        assert len(outs) == 1
+        cols = 1 if depth == 0 else 2 ** (depth - 1)
+        assert 2 * cols <= outs[0].shape[0] <= 6 * max(8, cols), (path, depth)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_TREES))
+def test_no_level_gathers_a_row_for_its_histogram(cell):
+    """What a row needs to know of the built child reaches it in the pass
+    that routes it (the dense tree's packed word) or by the entry gathers
+    that were there (the sparse tree): under ``gbdt.hist`` nothing is
+    gathered by row or by entry, nor under the dense ``gbdt.route``; and the
+    sparse tree gathers its node ids onto the entries once a level below
+    the root — the root's are zeros — beside the (grad, hess) pair's once."""
+    features, max_depth, sparse = CELL_TREES[cell]
+    rows = 192
+    traced, lanes = tree_program(features, max_depth, sparse, rows)
+    gathers = device_ops(traced, "gather")
+    for path, ins, _outs in gathers:
+        taken = int(np.prod(ins[1].shape[:-1]))
+        if "gbdt.hist" in path or (not sparse and "gbdt.route" in path):
+            assert taken < rows, (path, ins)
+    by_entry = [ins[0] for path, ins, _outs in gathers
+                if "gbdt.entry_gather" in path
+                and int(np.prod(ins[1].shape[:-1])) == lanes]
+    if not sparse:
+        assert by_entry == []
+        return
+    ids = [a for a in by_entry if a.dtype == jnp.int32]
+    assert len(ids) == max_depth - 1 and all(a.shape == (rows,) for a in ids)
+    pairs = [a for a in by_entry if a.dtype == jnp.float32]
+    assert len(pairs) == 1 and pairs[0].shape == (rows, 2)
+
+
 @pytest.mark.parametrize("scope", [s for s in named_scopes()
                                    if s.startswith(("ffm.", "sgd.loss"))])
 def test_backward_ops_keep_the_forward_scope(programs, scope):

@@ -1313,16 +1313,17 @@ def test_sparse_sharded_fit_batch_pallas_matches_xla():
     _assert_forests_identical(p_xla, p_mesh)
 
 
-def route_level_by_gather(self, bins_t, rel, split_f, split_b, split_d):
+def route_level_by_gather(self, bins_t, rel, split_f, split_b, split_d,
+                          right_built):
     """The level's routing as it stood before PR 26, kept as the reference:
-    one gather per row for its node's feature, bin threshold and default
-    direction, and one for the row's bin on that feature."""
+    one gather per row for its node's feature, bin threshold, default
+    direction and built child, and one for the row's bin on that feature."""
     rows = bins_t.shape[1]
     row_bin = bins_t.T[jnp.arange(rows), split_f[rel]]
     go_right = row_bin > split_b[rel]
     if self.missing_aware:
         go_right = jnp.where(row_bin == 0, split_d[rel] == 1, go_right)
-    return go_right
+    return go_right, right_built[rel]
 
 
 def gather_routed(**kw) -> GBDT:
@@ -1468,10 +1469,11 @@ def test_route_past_the_select_limit_gathers_the_word(monkeypatch):
 
 
 def test_route_word_refuses_what_does_not_pack():
-    """Feature id, default direction and bin threshold share one int32."""
+    """Feature id, built child, default direction and bin threshold share
+    one int32."""
     model = GBDT(num_features=3, num_bins=256)
     with pytest.raises(ValueError, match="int32 word"):
         model._route_level(
-            jax.ShapeDtypeStruct((1 << 21, 8), jnp.int32), None,
+            jax.ShapeDtypeStruct((1 << 20, 8), jnp.int32), None,
             jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32),
-            jnp.zeros(1, jnp.int32))
+            jnp.zeros(1, jnp.int32), jnp.zeros(1, bool))

@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_core_tpu.models import GBDT
+from dmlc_core_tpu.models.gbdt import (_built_columns, _child_slot,
+                                       _entry_slots, _with_siblings)
 from dmlc_core_tpu.ops.pallas_segment import (_KEY_TILE, _NNZ_TILE,
                                               _round_up_some, segment_sum,
                                               sparse_hist_layout)
@@ -20,7 +22,9 @@ def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
     """`GBDT._build_tree_sparse` as it stood before PR 27, kept as the
     reference: every level's gathers, scatters and kernel call dispatched
     op by op (the layout's weight lane ``w`` went with the host sort, so the
-    per-tree entry gather is no longer multiplied by it)."""
+    per-tree entry gather is no longer multiplied by it; since PR 32 a level
+    builds one child of each parent, through the functions the jitted
+    builder calls)."""
     F, B = self.num_features, self.num_bins
     rows = grad.shape[0]
     mono = self.monotone_constraints is not None
@@ -37,28 +41,32 @@ def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
     active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
               if self._interaction_groups is not None else None)
     features, thresholds, defaults, gains, covers = [], [], [], [], []
+    hist, slot, right_built = None, node, None
     for depth in range(self.max_depth):
         first = 2 ** depth - 1
         n_nodes = 2 ** depth
+        cols = _built_columns(depth)
         rel = node - first
         impl = (self._hist_impl_sparse(n_nodes)
                 if layout is not None else "xla")
         if impl == "pallas":
-            hist = self._level_histogram_sparse(
-                layout, rel, gh_row, gh_e,
-                None if mesh else rel[layout.rid], n_nodes)
+            built = self._level_histogram_sparse(
+                layout, slot, gh_row, gh_e,
+                None if mesh else _entry_slots(layout.rid, slot, depth),
+                cols)
         else:
             if gh_k is None:
                 gh_k = gh_row[rid] * emw
-            keys = (rel[rid] * F + fi) * B + ebin
-            hist = jax.ops.segment_sum(
-                gh_k, keys, num_segments=n_nodes * F * B
-            ).reshape(n_nodes, F, B, 2)
+            keys = (slot[rid] * F + fi) * B + ebin
+            built = jax.ops.segment_sum(
+                gh_k, keys, num_segments=cols * F * B
+            ).reshape(cols, F, B, 2)
+        hist = _with_siblings(hist, built, right_built)
         gh_node = segment_sum(
             gh_row, rel, num_segments=n_nodes,
             force="pallas" if impl == "pallas" and not mesh else None)
-        (split_f, split_b, split_d, split_g,
-         lo, hi, active) = self._level_splits_from_hist(
+        (split_f, split_b, split_d, split_g, lo, hi, active,
+         right_built) = self._level_splits_from_hist(
             hist, gh_node, depth, col_mask, col_key, lo, hi, active)
         features.append(split_f)
         thresholds.append(split_b)
@@ -68,6 +76,7 @@ def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
         go_right = self._route_sparse(fi, ebin, emask, rid, split_f[rel],
                                       split_b[rel], split_d[rel], rows)
         node = 2 * node + 1 + go_right.astype(jnp.int32)
+        slot = _child_slot(rel, go_right, right_built[rel])
     n_leaves = 2 ** self.max_depth
     leaf_rel = node - (n_leaves - 1)
     leaf_force = ("pallas" if layout is not None and not mesh
