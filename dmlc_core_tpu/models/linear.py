@@ -13,19 +13,24 @@ import jax.numpy as jnp
 
 from ..data.staging import PaddedBatch
 from ..ops.pallas_segment import check_force
-from ..ops.sparse import csr_matvec
-from .common import SGDModelMixin
+from ..ops.sparse import csr_matvec, csr_row_sums
+from .common import TouchedRowsMixin
 
 
-class SparseLinearModel(SGDModelMixin):
+class SparseLinearModel(TouchedRowsMixin):
     """Logistic regression / linear regression over sparse batches.
 
     objective: "logistic" (labels in {0,1} or {-1,1}) or "squared".
+    optimizer: None (plain minibatch SGD over the whole table) or
+        ``common.FTRL(alpha, beta, l1, l2)``: per-coordinate FTRL-Proximal
+        over the rows a batch names (``TouchedRowsMixin``); ``init`` then
+        returns the state ``(z, n)`` beside ``w`` and ``b``.
     """
 
     def __init__(self, num_features: int, objective: str = "logistic",
                  l2: float = 0.0, learning_rate: float = 0.1,
-                 sdot_backend: str | None = None, mesh_plan=None):
+                 sdot_backend: str | None = None, mesh_plan=None,
+                 optimizer=None):
         if objective not in ("logistic", "squared"):
             raise ValueError(f"unknown objective '{objective}'")
         check_force(sdot_backend, "sdot_backend")
@@ -43,11 +48,13 @@ class SparseLinearModel(SGDModelMixin):
         # jitted train_step's gradient reduction becomes the psum over
         # the plan axes
         self._set_mesh_plan(mesh_plan)
+        self._set_optimizer(optimizer)
 
     def init(self, seed: int = 0) -> dict:
         del seed  # linear model: zero init is canonical
-        return {"w": jnp.zeros(self.num_features, jnp.float32),
-                "b": jnp.zeros((), jnp.float32)}
+        return self.init_optimizer(
+            {"w": jnp.zeros(self.num_features, jnp.float32),
+             "b": jnp.zeros((), jnp.float32)})
 
     # ---- pure functions (jit-friendly) --------------------------------------
     def margins(self, params: dict, batch: PaddedBatch) -> jax.Array:
@@ -74,3 +81,11 @@ class SparseLinearModel(SGDModelMixin):
         if self.objective == "logistic":
             out["accuracy"] = correct / max(total_w, 1.0)
         return out
+
+    def margins_of_rows(self, rows: dict, dense: dict,
+                        batch: PaddedBatch) -> jax.Array:
+        """Per-row scores from the weights gathered an entry,
+        ``rows["w"] = w[batch.index]`` (the touched-rows step)."""
+        with jax.named_scope("linear.margins"):
+            return csr_row_sums(rows["w"] * batch.value, batch.row_ids(),
+                                batch.row_ptr) + dense["b"]

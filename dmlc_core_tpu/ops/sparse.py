@@ -88,3 +88,89 @@ def csr_to_dense_missing(index: jax.Array, value: jax.Array,
     acc = jnp.zeros((num_rows, num_features, 2), jnp.float32
                     ).at[row_id, index].add(lanes, mode="drop")
     return jnp.where(acc[..., 1] > 0, acc[..., 0], jnp.nan)
+
+
+# ---- reduction onto the distinct keys of a batch ----------------------------
+
+
+def run_sums(keys: jax.Array, *columns: jax.Array) -> tuple:
+    """Inclusive running sum of each of ``columns`` inside every run of
+    equal ``keys`` (runs contiguous, as after a sort): the last lane of a run
+    holds the run's sum.  ``ceil(log2(n))`` passes of one shifted add — no
+    scatter, and a pairwise order of summation."""
+    n, d = keys.shape[0], 1
+    while d < n:
+        same = jnp.concatenate([jnp.zeros(d, bool), keys[d:] == keys[:-d]])
+        columns = tuple(
+            c + jnp.where(same, jnp.concatenate(
+                [jnp.zeros(d, c.dtype), c[:-d]]), 0) for c in columns)
+        d *= 2
+    return columns
+
+
+def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
+                  bound: int) -> tuple:
+    """Per-entry ``columns`` summed onto the distinct ``index`` values of
+    the ``live`` entries.  Sorts are this chip's cheap primitive (0.8 ms for
+    655,360 lanes of key and payload on a v5e, where a gather or a scatter
+    of as many elements takes 10), so both halves are one: a sort by key
+    that carries the columns, :func:`run_sums`, and a second sort that
+    brings the lanes where a run ends to the front.  Returns ``(keys, sums,
+    count)`` on the entry lanes: the first ``count`` lanes hold the distinct
+    keys, ascending, and their sums; every later lane holds a distinct id
+    ``>= bound``, ascending too, so that a gather with ``mode="fill"`` or a
+    scatter with ``mode="drop"`` may be told ``unique_indices`` and
+    ``indices_are_sorted`` and passes those lanes over.  Needs
+    ``bound + len(index) <= 2**31``."""
+    n = index.shape[0]
+    key = jnp.where(live, index, bound)
+    sk, *sc = jax.lax.sort((key, *columns), num_keys=1, is_stable=False)
+    sums = run_sums(sk, *sc)
+    ends = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)]) & (
+        sk < bound)
+    spare = bound + jnp.arange(n, dtype=sk.dtype)
+    keys, *sums = jax.lax.sort((jnp.where(ends, sk, spare), *sums),
+                               num_keys=1, is_stable=False)
+    return keys, tuple(sums), jnp.sum(ends, dtype=jnp.int32)
+
+
+# ---- sums over the rows of a CSR batch, without a scatter an entry ----------
+
+
+def csr_row_spread(per_row: jax.Array, row_id: jax.Array,
+                   row_ptr: jax.Array) -> jax.Array:
+    """``per_row[row_id]`` an entry lane (the transpose of
+    :func:`csr_row_sums`): each row's value set on its first lane — one
+    scatter a ROW — and carried along the row's run by :func:`run_sums`,
+    which adds zeros to it and so changes no bit."""
+    lanes = row_id.shape[0]
+    first = jnp.where(row_ptr[1:] > row_ptr[:-1], row_ptr[:-1], lanes)
+    marks = jnp.zeros(lanes, per_row.dtype).at[first].set(
+        per_row, mode="drop", unique_indices=True)
+    return run_sums(row_id, marks)[0]
+
+
+@jax.custom_vjp
+def csr_row_sums(contrib: jax.Array, row_id: jax.Array,
+                 row_ptr: jax.Array) -> jax.Array:
+    """``out[r] = sum of contrib over row r's lanes`` for a CSR batch whose
+    lanes lie in row order (``row_id`` from ``PaddedBatch.row_ids()``):
+    :func:`run_sums` along the rows and one gather a ROW at its last lane.
+    What ``segment_sum(contrib, row_id, rows)`` gives, where that is a
+    scatter-add an entry forward and a gather an entry backward (5.8 and 5.7
+    ms for 655,360 entries of 16,384 rows on a v5e, against 0.3 and 0.5)."""
+    (running,) = run_sums(row_id, contrib)
+    held = row_ptr[1:] > row_ptr[:-1]
+    return jnp.where(held, running[jnp.maximum(row_ptr[1:] - 1, 0)], 0.0)
+
+
+def _csr_row_sums_fwd(contrib, row_id, row_ptr):
+    return csr_row_sums(contrib, row_id, row_ptr), (row_id, row_ptr)
+
+
+def _csr_row_sums_bwd(res, ct):
+    row_id, row_ptr = res
+    return csr_row_spread(ct, row_id, row_ptr), None, None
+
+
+csr_row_sums.defvjp(_csr_row_sums_fwd, _csr_row_sums_bwd)

@@ -235,3 +235,46 @@ def test_sharded_tree_program_reduces_once_a_level_and_gathers_nothing(
     # a chip holds its own rows and nothing of another's: the arguments are
     # a quarter of the rows each
     assert compiled.memory_analysis().argument_size_in_bytes < rows_chip * 32
+
+
+def test_touched_rows_step_updates_its_tables_in_place(one_chip, quiet_cache):
+    """The FTRL step at the ``criteo-tb-ftrl`` cell's shapes (2^29 buckets,
+    16,384 x 39 entries padded to 655,360 lanes), through the TPU's
+    compiler: all three 2 GiB tables alias their outputs, the temporaries
+    are megabytes (no ``f32[536870912]`` beside the donated tables), the five
+    scopes the cell's metrics read are in the compiled program, and the only
+    instructions that make a table are the three scatters of each candidate
+    visit (a loop of one trip or none, its carry in place)."""
+    from dmlc_core_tpu.data.staging import PaddedBatch
+    from dmlc_core_tpu.models.common import FTRL, TOUCHED_ROWS_VISITS
+    from dmlc_core_tpu.models.linear import SparseLinearModel
+    features, rows, lanes = 2 ** 29, 16384, 655360
+    model = SparseLinearModel(features, optimizer=FTRL())
+    params = jax.tree.map(lambda a: on(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init))
+    batch = PaddedBatch(
+        label=on(one_chip, (rows,), jnp.float32),
+        weight=on(one_chip, (rows,), jnp.float32),
+        row_ptr=on(one_chip, (rows + 1,), jnp.int32),
+        index=on(one_chip, (lanes,), jnp.int32),
+        value=on(one_chip, (lanes,), jnp.float32),
+        num_rows=on(one_chip, (), jnp.int32))
+    compiled = model._touched_rows_step.lower(model, params, batch).compile()
+    memory = compiled.memory_analysis()
+    tables = 3 * 4 * features
+    assert memory.alias_size_in_bytes >= tables
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.argument_size_in_bytes < tables + (16 << 20)
+    names = op_names(compiled)
+    for scope in ("sgd.unique", "sgd.gather_rows", "linear.margins",
+                  "sgd.ftrl", "sgd.scatter_rows"):
+        part = re.compile(r"[/(]" + re.escape(scope) + r"[/)]")
+        assert any(part.search(n) for n in names), scope
+    made = re.findall(r"^\s*(?:ROOT )?%([a-z\-]+)[\w.\-]* = f32\[536870912\]",
+                      compiled.as_text(), re.M)
+    # the tables themselves, handed on; the only ops that make one are each
+    # visit's three scatters (each a fusion of its own in the loop's body)
+    assert set(made) <= {"scatter", "fusion", "get-tuple-element", "param",
+                         "params"}, sorted(set(made))
+    visits = len([c for c in TOUCHED_ROWS_VISITS if c < lanes]) + 1
+    assert made.count("scatter") == made.count("fusion") == 3 * visits

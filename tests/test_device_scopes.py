@@ -72,6 +72,10 @@ def programs() -> dict:
         num_rows=jnp.asarray(np.int32(rows)),
         field=jnp.asarray(np.tile(np.arange(fields, dtype=np.int32), rows)))
     step = ffm._train_step.lower(ffm, ffm.init(0), batch)
+    from dmlc_core_tpu.models.common import FTRL
+    from dmlc_core_tpu.models.linear import SparseLinearModel
+    linear = SparseLinearModel(features, optimizer=FTRL())
+    touched = linear._touched_rows_step.lower(linear, linear.init(), batch)
 
     plan = MeshPlan.build()
     reduce = jax.jit(plan.shard_map(
@@ -80,9 +84,13 @@ def programs() -> dict:
             jnp.zeros(plan.num_shards * 4))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
             "sparse_tree": paths_of(sparse_tree),
-            "step": paths_of(step), "reduce": paths_of(reduce)}
+            "step": paths_of(step), "touched": paths_of(touched),
+            "reduce": paths_of(reduce)}
 
 
+# scopes of the touched-rows step (`TouchedRowsMixin`), not of `_train_step`
+TOUCHED_ROWS = {"sgd.unique", "sgd.gather_rows", "linear.margins", "sgd.ftrl",
+                "sgd.scatter_rows"}
 # scopes that only one of the two tree programs opens
 DENSE_ONLY = {"gbdt.cast"}
 SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
@@ -96,9 +104,12 @@ def carries(paths: set, scope: str, under: str = "") -> bool:
 @pytest.mark.parametrize("scope", named_scopes())
 def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
     where = {"gbdt": "fit", "ops": "fit", "batch": "step", "ffm": "step",
-             "sgd": "step", "mesh": "reduce"}[scope.split(".")[0]]
+             "sgd": "step", "mesh": "reduce",
+             "linear": "touched"}[scope.split(".")[0]]
     if scope in SPARSE_ONLY:
         where = "sparse_tree"
+    if scope in TOUCHED_ROWS:
+        where = "touched"
     assert carries(programs[where], scope), (
         f"no op of the {where} program carries the scope {scope}")
     if scope.startswith("gbdt.") and scope != "gbdt.boost":
@@ -270,7 +281,7 @@ def test_backward_ops_keep_the_forward_scope(programs, scope):
 def test_no_metric_names_an_unknown_scope_prefix():
     assert named_scopes(), "the benchmark names no scope at all"
     assert {s.split(".")[0] for s in named_scopes()} <= {
-        "gbdt", "ops", "batch", "ffm", "sgd", "mesh"}
+        "gbdt", "ops", "batch", "ffm", "sgd", "mesh", "linear"}
 
 
 def test_span_lands_in_the_profiler_trace_and_in_the_native_ring(tmp_path):
@@ -369,3 +380,19 @@ def test_a_scope_is_part_of_the_compile_cache_key(cache_in):
     run_scoped_or_not(False)
     run_scoped_or_not(True)
     assert cache_in() - base == 3
+
+
+def test_touched_rows_step_nests_its_scopes_and_keeps_the_shared_ones(
+        programs):
+    """The FTRL step is one program, `jit(_touched_rows_step)`: the model's
+    margins inside `sgd.loss` forward and backward, `batch.row_ids` as the
+    FFM step has it, and no dense parameter pass (`sgd.update`)."""
+    paths = programs["touched"]
+    for scope in sorted(TOUCHED_ROWS):
+        assert carries(paths, scope, under="jit(_touched_rows_step)"), scope
+    assert carries(paths, "linear.margins", under="sgd.loss")
+    assert carries(paths, "linear.margins", under="transpose(")
+    assert carries(paths, "batch.row_ids")
+    assert not carries(paths, "sgd.update")
+    assert not any(TOUCHED_ROWS & set(re.split(r"[/()]", p))
+                   for p in programs["step"])
