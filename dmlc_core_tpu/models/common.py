@@ -228,7 +228,8 @@ class TouchedRowsMixin(SGDModelMixin):
             raise ValueError("with an optimizer the penalty is the "
                              "optimizer's (FTRL(l2=...)), not the model's")
         self.optimizer = optimizer
-        # distinct keys of the steps in flight, one device scalar a step
+        # (distinct keys, tiles written) of the steps in flight: two device
+        # scalars a step
         self._touched = collections.deque()
 
     def init_optimizer(self, params: dict) -> dict:
@@ -244,22 +245,26 @@ class TouchedRowsMixin(SGDModelMixin):
         if self.optimizer is None:
             return super().train_step(params, batch)
         with telemetry.span("sgd.step"):
-            new_params, loss, touched = self._touched_rows_step(params, batch)
-        self._touched.append(touched)
+            new_params, loss, counts = self._touched_rows_step(params, batch)
+        self._touched.append(counts)
         self.flush_step_counters(wait=False)
         return new_params, loss
 
     def flush_step_counters(self, wait: bool = True) -> None:
-        """Add the finished steps to the counters ``sgd.steps`` and
-        ``sgd.touched_rows``.  ``wait=False`` (every ``train_step``) takes
-        only the steps the device has finished and never waits for it."""
-        while self._touched and (wait or self._touched[0].is_ready()):
-            telemetry.counter_add("sgd.touched_rows",
-                                  int(self._touched.popleft()))
+        """Add the finished steps to the counters ``sgd.steps``,
+        ``sgd.touched_rows`` and ``sgd.scatter_tiles`` (tiles the in-place
+        kernel wrote; 0 where XLA's scatter ran).  ``wait=False`` (every
+        ``train_step``) takes only the steps the device has finished and
+        never waits for it."""
+        while self._touched and (wait or self._touched[0][0].is_ready()):
+            touched, tiles = self._touched.popleft()
+            telemetry.counter_add("sgd.touched_rows", int(touched))
+            telemetry.counter_add("sgd.scatter_tiles", int(tiles))
             telemetry.counter_add("sgd.steps", 1)
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
     def _touched_rows_step(self, params: dict, batch) -> tuple:
+        from ..ops.pallas_rows import scatter_rows
         from ..ops.sparse import padded_row_mean, reduce_by_key
         opt, names = self.optimizer, self.row_tables
         state = params["ftrl"]
@@ -294,8 +299,10 @@ class TouchedRowsMixin(SGDModelMixin):
         sizes = [c for c in TOUCHED_ROWS_VISITS if c < entries] + [entries]
         sorted_distinct = dict(unique_indices=True, indices_are_sorted=True)
         tables = {k: (params[k], state["z"][k], state["n"][k]) for k in names}
+        tiles = jnp.zeros((), jnp.int32)
         for fewer, lanes in zip([0] + sizes, sizes):
-            def visit(_, tables, lanes=lanes):
+            def visit(_, carry, lanes=lanes):
+                tables, tiles = carry
                 out = {}
                 for name, column in zip(names, sums):
                     with jax.named_scope("sgd.gather_rows"):
@@ -304,18 +311,19 @@ class TouchedRowsMixin(SGDModelMixin):
                             for t in tables[name][1:])
                     with jax.named_scope("sgd.ftrl"):
                         updated = opt.apply(z, n, column[:lanes])
+                    # in place either way: the tiles the keys name where the
+                    # table is long against ``lanes``, XLA's scatters elsewhere
                     with jax.named_scope("sgd.scatter_rows"):
-                        out[name] = tuple(
-                            t.at[keys[:lanes]].set(rows_, mode="drop",
-                                                   **sorted_distinct)
-                            for t, rows_ in zip(tables[name], updated))
-                return out
+                        out[name], wrote = scatter_rows(
+                            tables[name], keys[:lanes], updated, touched)
+                    tiles = tiles + wrote
+                return out, tiles
 
             mine = (touched > fewer) & (touched <= lanes)
-            tables = jax.lax.fori_loop(0, mine.astype(jnp.int32), visit,
-                                       tables)
+            tables, tiles = jax.lax.fori_loop(0, mine.astype(jnp.int32), visit,
+                                              (tables, tiles))
         with jax.named_scope("sgd.ftrl"):
             tables.update({k: opt.apply(state["z"][k], state["n"][k],
                                         g_dense[k]) for k in dense})
         w, z, n = ({k: t[i] for k, t in tables.items()} for i in range(3))
-        return dict(w, ftrl={"z": z, "n": n}), loss, touched
+        return dict(w, ftrl={"z": z, "n": n}), loss, (touched, tiles)

@@ -237,18 +237,28 @@ def test_sharded_tree_program_reduces_once_a_level_and_gathers_nothing(
     assert compiled.memory_analysis().argument_size_in_bytes < rows_chip * 32
 
 
-def test_touched_rows_step_updates_its_tables_in_place(one_chip, quiet_cache):
+@pytest.mark.parametrize("features,kernel_visits", [
+    (2 ** 29, [1 << 16, 1 << 17, 1 << 18, 1 << 19, 655360]),
+    (2 ** 27, [1 << 16, 1 << 17])])
+def test_touched_rows_step_updates_its_tables_in_place(
+        one_chip, quiet_cache, monkeypatch, features, kernel_visits):
     """The FTRL step at the ``criteo-tb-ftrl`` cell's shapes (2^29 buckets,
-    16,384 x 39 entries padded to 655,360 lanes), through the TPU's
-    compiler: all three 2 GiB tables alias their outputs, the temporaries
-    are megabytes (no ``f32[536870912]`` beside the donated tables), the five
-    scopes the cell's metrics read are in the compiled program, and the only
-    instructions that make a table are the three scatters of each candidate
-    visit (a loop of one trip or none, its carry in place)."""
+    16,384 x 39 entries padded to 655,360 lanes) and over a quarter of its
+    table, through the TPU's compiler: all three tables alias their outputs,
+    the temporaries are megabytes (no table-sized array beside the donated
+    tables), the five scopes the cell's metrics read are in the compiled
+    program.  The visits under the crossover (every one at 2^29 buckets; 2^16
+    and 2^17 keys at 2^27) are the in-place kernel, under
+    ``sgd.scatter_rows`` and by the name a trace shows, the tables handed
+    to it and back by bitcasts; the visits past it keep XLA's three scatters
+    (each a loop of one trip or none, its carry in place)."""
     from dmlc_core_tpu.data.staging import PaddedBatch
     from dmlc_core_tpu.models.common import FTRL, TOUCHED_ROWS_VISITS
     from dmlc_core_tpu.models.linear import SparseLinearModel
-    features, rows, lanes = 2 ** 29, 16384, 655360
+    from dmlc_core_tpu.ops import pallas_rows
+    # the code asks the default backend, which is the CPU here
+    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
+    rows, lanes = 16384, 655360
     model = SparseLinearModel(features, optimizer=FTRL())
     params = jax.tree.map(lambda a: on(one_chip, a.shape, a.dtype),
                           jax.eval_shape(model.init))
@@ -270,11 +280,32 @@ def test_touched_rows_step_updates_its_tables_in_place(one_chip, quiet_cache):
                   "sgd.ftrl", "sgd.scatter_rows"):
         part = re.compile(r"[/(]" + re.escape(scope) + r"[/)]")
         assert any(part.search(n) for n in names), scope
-    made = re.findall(r"^\s*(?:ROOT )?%([a-z\-]+)[\w.\-]* = f32\[536870912\]",
-                      compiled.as_text(), re.M)
-    # the tables themselves, handed on; the only ops that make one are each
-    # visit's three scatters (each a fusion of its own in the loop's body)
+    text = compiled.as_text()
+    visits = [c for c in TOUCHED_ROWS_VISITS if c < lanes] + [lanes]
+    kernel = [c for c in visits
+              if pallas_rows.engages(features, c, jnp.float32)]
+    assert kernel == kernel_visits
+    calls = re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = .* custom-call\(.*"
+                       r'op_name="([^"]*)"', text, re.M)
+    calls = [(name, op) for name, op in calls
+             if pallas_rows.SCATTER_ROWS_KERNEL in name]
+    assert len(calls) == len(kernel), calls
+    for name, op in calls:
+        assert name.startswith("%" + pallas_rows.SCATTER_ROWS_KERNEL)
+        assert "/sgd.scatter_rows/" in op, op
+    made = re.findall(r"^\s*(?:ROOT )?%([a-z\-]+)[\w.\-]* = "
+                      rf"f32\[{features}\]", text, re.M)
+    # the tables themselves, handed on; the only ops that make one are the
+    # three scatters of each visit past the crossover (each a fusion of its
+    # own in the loop's body) and the bitcasts that bring a table back from
+    # the kernel's view of it, ``[F / 1024, 8, 128]``
     assert set(made) <= {"scatter", "fusion", "get-tuple-element", "param",
-                         "params"}, sorted(set(made))
-    visits = len([c for c in TOUCHED_ROWS_VISITS if c < lanes]) + 1
-    assert made.count("scatter") == made.count("fusion") == 3 * visits
+                         "params", "bitcast"}, sorted(set(made))
+    xla = len(visits) - len(kernel)
+    assert made.count("scatter") == made.count("fusion") == 3 * xla
+    assert made.count("bitcast") == 3 * len(kernel)
+    viewed = re.findall(r"^\s*(?:ROOT )?%([a-z\-]+)[\w.\-]* = "
+                        rf"f32\[{features // 1024},8,128\]", text, re.M)
+    # (``%pallas_call.n``: the elements of the kernel's tuple)
+    assert set(viewed) <= {"bitcast", "pallas"}, sorted(set(viewed))
+    assert viewed.count("bitcast") == 3 * len(kernel)

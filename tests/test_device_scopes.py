@@ -396,3 +396,38 @@ def test_touched_rows_step_nests_its_scopes_and_keeps_the_shared_ones(
     assert not carries(paths, "sgd.update")
     assert not any(TOUCHED_ROWS & set(re.split(r"[/()]", p))
                    for p in programs["step"])
+
+
+def test_touched_rows_step_runs_the_rows_kernel_under_scatter_rows(
+        monkeypatch):
+    """Where the table is long against a visit's lanes (forced here, at a
+    table of two tiles, and lowered for the TPU) the step's scatters are ONE
+    kernel a visit, ``ops/pallas_rows.py``'s, under ``sgd.scatter_rows`` and
+    by its own name, which is what `ftrl_scatter_ms_per_step` goes on
+    reading; no XLA scatter is left under the scope, and the other four
+    scopes are where they were."""
+    from dmlc_core_tpu.models.common import FTRL
+    from dmlc_core_tpu.models.linear import SparseLinearModel
+    from dmlc_core_tpu.ops import pallas_rows
+    monkeypatch.setattr(pallas_rows, "engages", lambda *_: True)
+    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
+    rows, per_row = 64, 16
+    batch = PaddedBatch(
+        label=jnp.zeros(rows), weight=jnp.ones(rows),
+        row_ptr=jnp.arange(rows + 1, dtype=jnp.int32) * per_row,
+        index=jnp.zeros(rows * per_row, jnp.int32),
+        value=jnp.ones(rows * per_row), num_rows=jnp.asarray(np.int32(rows)))
+    linear = SparseLinearModel(2 * pallas_rows.TILE, optimizer=FTRL())
+    lowered = linear._touched_rows_step.trace(
+        linear, linear.init(), batch).lower(lowering_platforms=("tpu",))
+    paths = paths_of(lowered)
+    for scope in sorted(TOUCHED_ROWS):
+        assert carries(paths, scope, under="jit(_touched_rows_step)"), scope
+    under = [p for p in paths if "/sgd.scatter_rows/" in p]
+    assert any(pallas_rows.SCATTER_ROWS_KERNEL in p and "pallas_call" in p
+               for p in under), sorted(under)
+    assert not any("scatter" in p.rsplit("/", 1)[-1] for p in under), (
+        sorted(under))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f'kernel_name = "{pallas_rows.SCATTER_ROWS_KERNEL}"' in text
