@@ -452,6 +452,10 @@ int DmlcTpuTelemetryTraceDumpJson(const char** out);
  * time.monotonic_ns()//1000) into the active trace. */
 int DmlcTpuTelemetryRecordSpan(const char* name, int64_t ts_us,
                                int64_t dur_us);
+/* the same, under the lineage id of the batch the span handled (>= 0): it
+ * goes out as args.lineage whether or not a trace context is set. */
+int DmlcTpuTelemetryRecordSpanLineage(const char* name, int64_t ts_us,
+                                      int64_t dur_us, int64_t lineage);
 /* set/adjust/read the named process-wide gauge (created on first use) —
  * how the Python staging loop publishes H2D queue depth for the flight
  * recorder. */

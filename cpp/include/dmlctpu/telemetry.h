@@ -177,11 +177,31 @@ bool TraceActive();
 /*! \brief Chrome trace-event JSON of everything buffered since TraceStart. */
 std::string TraceDumpJson();
 /*! \brief record one complete span.  `name` must be a string literal (the
- *  pointer is stored); use RecordSpanOwned for dynamic names. */
-void RecordSpan(const char* name, int64_t ts_us, int64_t dur_us);
+ *  pointer is stored); use RecordSpanOwned for dynamic names.  `lineage`
+ *  (>= 0) is the id of the batch or chunk the span handled and goes out as
+ *  args.lineage whether or not a trace context is set; left at -1 the span
+ *  takes the recording thread's ScopedLineage, else the ambient context's. */
+void RecordSpan(const char* name, int64_t ts_us, int64_t dur_us,
+                int64_t lineage = -1);
 /*! \brief record one complete span with an owned (copied) name — the C API /
  *  Python path. */
-void RecordSpanOwned(const std::string& name, int64_t ts_us, int64_t dur_us);
+void RecordSpanOwned(const std::string& name, int64_t ts_us, int64_t dur_us,
+                     int64_t lineage = -1);
+/*! \brief the lineage that spans recorded on THIS thread carry while the
+ *  guard lives, for code that holds the chunk around callees that do not
+ *  (the sharded worker around its inner parser's parse.chunk).  Thread-local:
+ *  unlike the process-wide context slot it cannot be raced by another
+ *  worker.  Guards nest; a plain store and load while tracing is off. */
+class ScopedLineage {
+ public:
+  explicit ScopedLineage(int64_t lineage);
+  ~ScopedLineage();
+  ScopedLineage(const ScopedLineage&) = delete;
+  ScopedLineage& operator=(const ScopedLineage&) = delete;
+
+ private:
+  int64_t prev_;
+};
 
 /*! \brief RAII span: records [ctor, dtor) when tracing is active.  The check
  *  at construction is one relaxed atomic load, so leaving these in hot
@@ -195,14 +215,18 @@ class ScopedSpan {
     }
   }
   ~ScopedSpan() {
-    if (name_ != nullptr) RecordSpan(name_, t0_, NowUs() - t0_);
+    if (name_ != nullptr) RecordSpan(name_, t0_, NowUs() - t0_, lineage_);
   }
+  /*! \brief the batch or chunk this span turned out to handle (known only
+   *  once the body has run, e.g. a batch's first row's chunk). */
+  void set_lineage(int64_t lineage) { lineage_ = lineage; }
   ScopedSpan(const ScopedSpan&) = delete;
   ScopedSpan& operator=(const ScopedSpan&) = delete;
 
  private:
   const char* name_ = nullptr;
   int64_t t0_ = 0;
+  int64_t lineage_ = -1;
 };
 
 /*! \brief accumulate elapsed wall time into a counter (microseconds).
@@ -318,12 +342,21 @@ inline void TraceStart() {}
 inline void TraceStop() {}
 inline bool TraceActive() { return false; }
 inline std::string TraceDumpJson() { return "{\"traceEvents\":[]}"; }
-inline void RecordSpan(const char*, int64_t, int64_t) {}
-inline void RecordSpanOwned(const std::string&, int64_t, int64_t) {}
+inline void RecordSpan(const char*, int64_t, int64_t, int64_t = -1) {}
+inline void RecordSpanOwned(const std::string&, int64_t, int64_t,
+                            int64_t = -1) {}
+
+class ScopedLineage {
+ public:
+  explicit ScopedLineage(int64_t) {}
+  ScopedLineage(const ScopedLineage&) = delete;
+  ScopedLineage& operator=(const ScopedLineage&) = delete;
+};
 
 class ScopedSpan {
  public:
   explicit ScopedSpan(const char*) {}
+  void set_lineage(int64_t) {}
   ScopedSpan(const ScopedSpan&) = delete;
   ScopedSpan& operator=(const ScopedSpan&) = delete;
 };

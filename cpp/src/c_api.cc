@@ -206,9 +206,14 @@ int DmlcTpuTelemetryTraceDumpJson(const char** out) {
 
 int DmlcTpuTelemetryRecordSpan(const char* name, int64_t ts_us,
                                int64_t dur_us) {
+  return DmlcTpuTelemetryRecordSpanLineage(name, ts_us, dur_us, -1);
+}
+
+int DmlcTpuTelemetryRecordSpanLineage(const char* name, int64_t ts_us,
+                                      int64_t dur_us, int64_t lineage) {
   return Guard([&] {
     if (dmlctpu::telemetry::TraceActive()) {
-      dmlctpu::telemetry::RecordSpanOwned(name, ts_us, dur_us);
+      dmlctpu::telemetry::RecordSpanOwned(name, ts_us, dur_us, lineage);
     }
     return 0;
   });

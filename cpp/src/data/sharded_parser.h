@@ -394,9 +394,19 @@ class ShardedParser : public Parser<IndexType, DType> {
         static_cast<int64_t>(buffered_bytes_));
   }
 
+  /*! \brief lineage id: which source bytes produced a chunk — a pure
+   *  function of the partition, so identical across re-parses and
+   *  completely independent of whether tracing is armed */
+  int64_t ChunkLineage(unsigned j, size_t chunk) const {
+    return static_cast<int64_t>(
+        (static_cast<uint64_t>(part_ * virtual_parts_ + j) << 32) |
+        (static_cast<uint64_t>(chunk) & 0xffffffffu));
+  }
+
   void ParseOnePart(unsigned j, size_t skip_chunks = 0,
                     size_t chunk_bytes = 0) {
     telemetry::ScopedSpan span("shard.part");
+    span.set_lineage(ChunkLineage(j, 0));  // the part, by its first chunk
     telemetry::ScopedAccum part_timer(telemetry::stage::ShardPartUs());
     telemetry::stage::ShardParts().Add(1);
     // nthread=1: worker threads ARE the parse parallelism; parseahead=0
@@ -428,9 +438,14 @@ class ShardedParser : public Parser<IndexType, DType> {
             free_pool_.pop_back();
           }
         }
+        // the inner parser's parse.chunk / parse.block spans are this
+        // chunk's: they take its lineage from the thread, not from a slot
+        // that the other workers write
+        telemetry::ScopedLineage of_chunk(ChunkLineage(j, chunk_idx));
         if (!impl->CallParseNext(&blocks)) break;
       } else {
         // fallback for parser types that hide their impl: copy block views
+        telemetry::ScopedLineage of_chunk(ChunkLineage(j, chunk_idx));
         if (!parser->Next()) break;
         blocks.emplace_back();
         blocks.back().Push(parser->Value());
@@ -476,13 +491,8 @@ class ShardedParser : public Parser<IndexType, DType> {
           });
         }
         if (stop_ || error_) return;
-        // lineage id: which source bytes produced this chunk — a pure
-        // function of the partition, so identical across re-parses and
-        // completely independent of whether tracing is armed
-        const int64_t lineage = static_cast<int64_t>(
-            (static_cast<uint64_t>(part_ * virtual_parts_ + j) << 32) |
-            (static_cast<uint64_t>(this_chunk) & 0xffffffffu));
-        parts_[j].q.push_back(QueuedChunk{std::move(blocks), cost, lineage});
+        parts_[j].q.push_back(QueuedChunk{std::move(blocks), cost,
+                                          ChunkLineage(j, this_chunk)});
         buffered_bytes_ += cost;
         telemetry::stage::ShardBufferedBytes().Set(
             static_cast<int64_t>(buffered_bytes_));

@@ -304,6 +304,7 @@ class StagedBatcherT {
     last_nnz_ = nnz;
     Finalize(slot, rows, nnz);
     slot->arena->lineage = lineage;
+    span.set_lineage(lineage);
     if constexpr (telemetry::Enabled()) {
       namespace ts = telemetry::stage;
       const int64_t total = telemetry::NowUs() - pack_t0;

@@ -60,6 +60,8 @@ struct TraceEvent {
 std::atomic<uint64_t> g_ctx_trace_id{0};
 std::atomic<uint64_t> g_ctx_parent{0};
 std::atomic<int64_t> g_ctx_lineage{-1};
+// ScopedLineage's slot: the lineage of the chunk this thread is working on.
+thread_local int64_t t_lineage = -1;
 
 // Per-thread buffer.  The shared_ptr in the global list keeps it alive past
 // thread exit so TraceDumpJson can still read events from finished workers.
@@ -117,10 +119,14 @@ ThreadTraceBuf& LocalBuf() {
 void PushEvent(TraceEvent&& ev) {
   ThreadTraceBuf& b = LocalBuf();
   ev.tid = b.tid;
+  // the span's own lineage wins, then the thread's, then the context's
+  if (ev.lineage < 0) ev.lineage = t_lineage;
   ev.trace_id = g_ctx_trace_id.load(std::memory_order_relaxed);
   if (ev.trace_id != 0) {
     ev.parent = g_ctx_parent.load(std::memory_order_relaxed);
-    ev.lineage = g_ctx_lineage.load(std::memory_order_relaxed);
+    if (ev.lineage < 0) {
+      ev.lineage = g_ctx_lineage.load(std::memory_order_relaxed);
+    }
   }
   std::lock_guard<std::mutex> lk(b.mu);
   const size_t cap = TraceRingCap();
@@ -334,12 +340,20 @@ void TraceStart() {
 
 void TraceStop() { g_trace_active.store(false, std::memory_order_release); }
 
-void RecordSpan(const char* name, int64_t ts_us, int64_t dur_us) {
-  PushEvent(TraceEvent{name, std::string(), 0, ts_us, dur_us});
+ScopedLineage::ScopedLineage(int64_t lineage) : prev_(t_lineage) {
+  t_lineage = lineage;
 }
 
-void RecordSpanOwned(const std::string& name, int64_t ts_us, int64_t dur_us) {
-  PushEvent(TraceEvent{nullptr, name, 0, ts_us, dur_us});
+ScopedLineage::~ScopedLineage() { t_lineage = prev_; }
+
+void RecordSpan(const char* name, int64_t ts_us, int64_t dur_us,
+                int64_t lineage) {
+  PushEvent(TraceEvent{name, std::string(), 0, ts_us, dur_us, 0, 0, lineage});
+}
+
+void RecordSpanOwned(const std::string& name, int64_t ts_us, int64_t dur_us,
+                     int64_t lineage) {
+  PushEvent(TraceEvent{nullptr, name, 0, ts_us, dur_us, 0, 0, lineage});
 }
 
 std::string TraceDumpJson() {
@@ -369,6 +383,8 @@ std::string TraceDumpJson() {
         out += ",\"args\":{\"trace_id\":\"" + HexId(ev.trace_id) +
                "\",\"parent\":\"" + HexId(ev.parent) +
                "\",\"lineage\":" + std::to_string(ev.lineage) + "}";
+      } else if (ev.lineage >= 0) {
+        out += ",\"args\":{\"lineage\":" + std::to_string(ev.lineage) + "}";
       }
       out += "}";
     }

@@ -7,6 +7,7 @@
 #include <atomic>
 #include <chrono>
 #include <cmath>
+#include <cstdlib>
 #include <cstring>
 #include <fstream>
 #include <limits>
@@ -259,6 +260,60 @@ TESTCASE(trace_json_wellformed_multithreaded) {
   telemetry::TraceStart();
   telemetry::TraceStop();
   EXPECT_EQV(ParseTrace(telemetry::TraceDumpJson()).size(), 0u);
+}
+
+// one event of a dump, as text: from its name to the next event's
+static std::string EventText(const std::string& dump, const std::string& name) {
+  const size_t at = dump.find("{\"name\":\"" + name + "\"");
+  if (at == std::string::npos) return std::string();
+  const size_t next = dump.find("{\"name\":", at + 1);
+  return dump.substr(at, next == std::string::npos ? next : next - at);
+}
+
+TESTCASE(span_lineage_needs_no_trace_context) {
+  telemetry::SetTraceContext(0, 0, -1);
+  telemetry::TraceStart();
+  {
+    telemetry::ScopedSpan own("test.own_lineage");
+    own.set_lineage(42);  // known once the body has run
+  }
+  {
+    // the thread's: what a worker sets around a callee that records spans
+    telemetry::ScopedLineage of_chunk((int64_t{3} << 32) | 5);
+    { telemetry::ScopedSpan inner("test.thread_lineage"); }
+    {
+      telemetry::ScopedLineage nested(7);
+      telemetry::RecordSpan("test.nested_lineage", 1, 1);
+    }
+    telemetry::RecordSpan("test.back_to_outer", 1, 1);
+    telemetry::RecordSpan("test.explicit_wins", 1, 1, 9);
+  }
+  telemetry::RecordSpan("test.no_lineage", 1, 1);
+  std::thread([] {  // another thread does not see this thread's guard
+    telemetry::RecordSpan("test.other_thread", 1, 1);
+  }).join();
+  EXPECT_EQV(DmlcTpuTelemetryRecordSpanLineage("test.c_api_lineage", 5, 6, 11),
+             0);
+  telemetry::TraceStop();
+  const std::string js = telemetry::TraceDumpJson();
+  WalkJson(js.c_str());
+  if (!telemetry::Enabled()) return;
+  auto lineage_of = [&](const char* name) {
+    const std::string ev = EventText(js, name);
+    EXPECT_TRUE(!ev.empty());
+    const size_t at = ev.find("\"args\":{\"lineage\":");
+    if (at == std::string::npos) return int64_t{-1};
+    EXPECT_TRUE(ev.find("trace_id") == std::string::npos);
+    return static_cast<int64_t>(std::atoll(ev.c_str() + at + 18));
+  };
+  EXPECT_EQV(lineage_of("test.own_lineage"), int64_t{42});
+  EXPECT_EQV(lineage_of("test.thread_lineage"), (int64_t{3} << 32) | 5);
+  EXPECT_EQV(lineage_of("test.nested_lineage"), int64_t{7});
+  EXPECT_EQV(lineage_of("test.back_to_outer"), (int64_t{3} << 32) | 5);
+  EXPECT_EQV(lineage_of("test.explicit_wins"), int64_t{9});
+  EXPECT_EQV(lineage_of("test.no_lineage"), int64_t{-1});
+  EXPECT_EQV(lineage_of("test.other_thread"), int64_t{-1});
+  EXPECT_EQV(lineage_of("test.c_api_lineage"), int64_t{11});
 }
 
 TESTCASE(spans_not_recorded_while_inactive) {

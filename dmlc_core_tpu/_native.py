@@ -231,6 +231,8 @@ _LIB.DmlcTpuTelemetryTraceStop.argtypes = []
 _LIB.DmlcTpuTelemetryTraceDumpJson.argtypes = [ctypes.POINTER(ctypes.c_char_p)]
 _LIB.DmlcTpuTelemetryRecordSpan.argtypes = [
     ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64]
+_LIB.DmlcTpuTelemetryRecordSpanLineage.argtypes = [
+    ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryGaugeSet.argtypes = [ctypes.c_char_p, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryGaugeAdd.argtypes = [ctypes.c_char_p, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryGaugeGet.argtypes = [

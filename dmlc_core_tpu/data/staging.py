@@ -97,6 +97,9 @@ def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None,
     ``feed.wait`` and the counter ``h2d.consumer_wait_us`` — what the
     training loop waited for the whole pipeline (host queues are internal
     hand-offs, already counted as ``h2d.wait_us`` by their own consumer).
+    Each of its items rides with the time its ``put`` returned, so that the
+    consumer can record the span ``feed.handoff`` — put returned to get
+    returned, the latency of the queue itself (``_feed_get``, below).
     """
     q: queue.Queue = queue.Queue(maxsize=max(prefetch, 1))
     sentinel = object()
@@ -104,9 +107,13 @@ def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None,
     error: list = []
 
     def emit(item) -> bool:
+        if device_feed:
+            item = _Handoff(item)
         while not stop.is_set():
             try:
                 q.put(item, timeout=0.1)
+                if device_feed:
+                    item.put_us = telemetry.now_us()
                 if depth_gauge is not None:
                     telemetry.gauge_set(depth_gauge, q.qsize())
                 return True
@@ -139,14 +146,7 @@ def _staged_iter(produce, prefetch: int, depth_gauge: Optional[str] = None,
     reached_end = False
     try:
         while True:
-            if device_feed:
-                t0 = time.monotonic()
-                with telemetry.span("feed.wait"):
-                    item = q.get()
-                telemetry.counter_add("h2d.consumer_wait_us",
-                                      int((time.monotonic() - t0) * 1e6))
-            else:
-                item = q.get()
+            item = _feed_get(q) if device_feed else q.get()
             if depth_gauge is not None:
                 telemetry.gauge_set(depth_gauge, q.qsize())
             if item is sentinel:
@@ -481,6 +481,44 @@ def pad_batch_to_bucket(batch, row_bucket: Optional[int] = None,
             if batch.field is not None:
                 kw["field"] = jnp.pad(batch.field, (0, pn))
     return _dc_replace(batch, **kw)
+
+
+class _Handoff:
+    """A device batch on its way through the feed's last queue: the stager's
+    ``emit`` stamps ``put_us`` once its ``put`` has returned (0 until then:
+    a consumer that was waiting can have the batch before the stager runs
+    again, and its hand-off then took no time worth a span)."""
+    __slots__ = ("item", "put_us")
+
+    def __init__(self, item):
+        self.item = item
+        self.put_us = 0
+
+
+def _feed_get(q: queue.Queue):
+    """The consumer's ``get`` on the queue of device batches, with what the
+    ring and the registry are told of it: ``feed.wait`` (both sinks; counter
+    ``h2d.consumer_wait_us`` over the same stretch) under the lineage of the
+    batch it got, and ``feed.handoff`` from the stager's ``put`` returning to
+    this ``get`` returning.  Returns the batch, or the end-of-stream item.
+    (The caller's rebind of its ``item`` drops the previous batch: releasing
+    device arrays whose step is still queued took 0.8 ms on a v5e, PERF.md
+    PR 37.  It falls after this span and counter, not inside them.)"""
+    t0 = time.monotonic()
+    with telemetry.span("feed.wait") as wait:
+        got = q.get()
+        got_us = telemetry.now_us()
+        batch = isinstance(got, _Handoff)
+        if batch:
+            wait.lineage = telemetry.lineage(got.item)
+    telemetry.counter_add("h2d.consumer_wait_us",
+                          int((time.monotonic() - t0) * 1e6))
+    if not batch:
+        return got
+    put_us = got.put_us or got_us
+    telemetry.record_span("feed.handoff", put_us, got_us - put_us,
+                          wait.lineage)
+    return got.item
 
 
 class _StagedBatchC(ctypes.Structure):
@@ -964,6 +1002,13 @@ class RecordStagingIter:
                                 device_feed=True)
 
 
+# (key of ``DeviceStagingIter.counters``, registry counter, its units to the key's)
+_H2D_COUNTERS = (("host_wait_s", "h2d.wait_us", 1e6),
+                 ("stage_s", "h2d.busy_us", 1e6),
+                 ("emit_wait_s", "h2d.emit_wait_us", 1e6),
+                 ("batches", "h2d.batches", 1))
+
+
 class DeviceStagingIter:
     """Iterate PaddedBatches staged into device memory, one batch ahead.
 
@@ -1055,7 +1100,10 @@ class DeviceStagingIter:
         self._with_qid = with_qid
         self._max_index = -1
         self.batches_staged = 0
-        self.profile = None  # per-epoch stage breakdown; set by __iter__
+        # the registry's h2d counters when the epoch began, and this
+        # iterator's own time in the native Next: what ``counters`` reads
+        self._epoch_base = None
+        self._native_us = 0
         # throughput self-reporting cadence in batches (0 = off); parity with
         # the reference loaders' MB/sec logs (basic_row_iter.h:70-81)
         self._log_every = log_every
@@ -1097,9 +1145,21 @@ class DeviceStagingIter:
 
     @property
     def counters(self) -> dict:
-        """Per-stage pipeline counters for the current/last epoch: the
-        ``profile`` breakdown plus pipeline configuration and totals."""
-        c = dict(self.profile or {})
+        """Per-stage pipeline counters for the current/last epoch, seconds,
+        plus pipeline configuration and totals.  The stager's three times
+        and its batches are the process-wide registry's ``h2d.wait_us`` /
+        ``busy_us`` / ``emit_wait_us`` / ``batches`` since the epoch began
+        (one set of readings: another feed running in the process at the
+        same time shows in them too); ``native_s`` is this iterator's own
+        time blocked in the native ``NextOwned`` (the span ``pack.next``).
+        No breakdown for a multi-host epoch."""
+        c = {}
+        if self._epoch_base is not None:
+            c = {key: (telemetry.counter_get(name) - base) / scale
+                 for (key, name, scale), base
+                 in zip(_H2D_COUNTERS, self._epoch_base)}
+            c["batches"] = int(c["batches"])
+            c["native_s"] = self._native_us / 1e6
         c.update(num_workers=self._num_workers, reorder=self._reorder,
                  prefetch_depth=self._prefetch, bytes_read=self.bytes_read,
                  batches_staged=self.batches_staged)
@@ -1155,7 +1215,7 @@ class DeviceStagingIter:
         # one span per staged batch, in the dmlctpu telemetry trace (shared
         # steady-clock epoch with the native parse/pack spans) and, as
         # ``dmlctpu.h2d.stage_batch``, in a running jax profiler trace
-        with telemetry.span("h2d.stage_batch"):
+        with telemetry.span("h2d.stage_batch", telemetry.lineage(w)):
             return self._stage_inner(w)
 
     def _stage_inner(self, w: dict) -> PaddedBatch:
@@ -1166,16 +1226,19 @@ class DeviceStagingIter:
                    w["value"], num_rows)
                   + ((w["field"],) if with_field else ())
                   + ((w["qid"],) if with_qid else ()))
-        if self._sharding is None:
-            # one batched dispatch for the whole pytree
-            staged = jax.device_put(leaves)
-        else:
+        shardings = None
+        if self._sharding is not None:
             repl = self._replicated_sharding()
             shardings = ((self._sharding, self._sharding, repl,
                           self._sharding, self._sharding, repl)
                          + ((self._sharding,) if with_field else ())
                          + ((self._sharding,) if with_qid else ()))
-            staged = jax.device_put(leaves, shardings)
+        # one batched dispatch for the whole pytree; the call alone is a
+        # span of the ring, so that wrap and dispatch separate
+        t0 = telemetry.now_us()
+        staged = jax.device_put(leaves, shardings)
+        telemetry.record_span("h2d.device_put", t0, telemetry.now_us() - t0,
+                              telemetry.lineage(w))
 
         batch = PaddedBatch(
             label=staged[0], weight=staged[1], row_ptr=staged[2],
@@ -1386,38 +1449,47 @@ class DeviceStagingIter:
 
         if self._sharding is not None and jax.process_count() > 1:
             # no producer breakdown on this path; clear any prior epoch's
-            # so a stale single-host profile is never misattributed
-            self.profile = None
+            # so a stale single-host one is never misattributed
+            self._epoch_base = None
             yield from self._iter_multihost()
             return
 
-        # per-epoch pipeline breakdown (seconds, cumulative):
-        #   native_s    blocking in the C++ parse+pack (NextOwned), on the
-        #               pack-driver thread; with num_workers > 1 this is
-        #               mostly reorder-queue waiting, not parse CPU
-        #   host_wait_s the stager thread starved for host batches (the
-        #               parse side is the limiter)
-        #   stage_s     wrap + device_put dispatch (async; not transfer)
-        #   emit_wait_s blocked handing off (device queue full = the
-        #               CONSUMER/device is the limiter, not this pipeline)
+        # The hand-offs of a batch, each timed once: the reading goes to the
+        # registry (``counters`` and ``stall_attribution`` read it there) and,
+        # while a trace runs, to the ring as a span under the batch's lineage:
+        #   pack.next      blocking in the C++ parse+pack (NextOwned), on the
+        #                  pack-driver thread; with num_workers > 1 this is
+        #                  mostly reorder-queue waiting, not parse CPU
+        #   h2d.host_wait  the stager thread starved for host batches (the
+        #                  parse side is the limiter): h2d.wait_us
+        #   h2d.stage_batch  wrap + device_put dispatch (async; not
+        #                  transfer): h2d.busy_us
+        #   h2d.emit_wait  blocked handing off (device queue full = the
+        #                  CONSUMER/device is the limiter, not this pipeline):
+        #                  h2d.emit_wait_us
         # Cheap enough to keep always on (a few clock reads per multi-MB
-        # batch); ``counters`` serves it, so a slow epoch
-        # pins its own bottleneck instead of inviting guesses.
-        prof = {"native_s": 0.0, "host_wait_s": 0.0, "stage_s": 0.0,
-                "emit_wait_s": 0.0, "batches": 0}
-        self.profile = prof
+        # batch), so a slow epoch pins its own bottleneck instead of
+        # inviting guesses.  These go to the ring only: an annotation of a
+        # feed thread in the profiler's file would name device gaps that the
+        # consumer's spans name.
+        self._epoch_base = [telemetry.counter_get(name)
+                            for _, name, _ in _H2D_COUNTERS]
+        self._native_us = 0
 
         def produce_host(emit):
             with self._lock:
                 check(self._lib.DmlcTpuStagedBatcherBeforeFirst(self._handle))
                 c = _StagedBatchOwnedC()
                 while True:
-                    t0 = time.monotonic()
+                    t0 = telemetry.now_us()
                     rc = check(self._lib.DmlcTpuStagedBatcherNextOwned(
                         self._handle, ctypes.byref(c)))
-                    prof["native_s"] += time.monotonic() - t0
+                    t1 = telemetry.now_us()
+                    self._native_us += t1 - t0
                     if rc != 1:
                         return
+                    telemetry.record_span("pack.next", t0, t1 - t0,
+                                          int(c.lineage))
                     if not emit(self._wrap_owned(c)):
                         return
 
@@ -1432,26 +1504,26 @@ class DeviceStagingIter:
             try:
                 it = iter(host_iter)
                 while True:
-                    t0 = time.monotonic()
+                    t0 = telemetry.now_us()
                     w = next(it, None)
-                    t1 = time.monotonic()
-                    prof["host_wait_s"] += t1 - t0
+                    t1 = telemetry.now_us()
+                    telemetry.counter_add("h2d.wait_us", t1 - t0)
                     if w is None:
                         return
                     batch = self._stage(w)
-                    t2 = time.monotonic()
-                    prof["stage_s"] += t2 - t1
+                    t2 = telemetry.now_us()
                     ok = emit(batch)
-                    t3 = time.monotonic()
-                    prof["emit_wait_s"] += t3 - t2
-                    prof["batches"] += 1
+                    t3 = telemetry.now_us()
+                    lineage = telemetry.lineage(batch)
+                    telemetry.record_span("h2d.host_wait", t0, t1 - t0,
+                                          lineage)
+                    telemetry.record_span("h2d.emit_wait", t2, t3 - t2,
+                                          lineage)
                     # publish H2D feed occupancy into the process-wide
                     # telemetry registry (same us units as the native
                     # stages, so stall_attribution sees the whole pipeline)
-                    telemetry.counter_add("h2d.wait_us", int((t1 - t0) * 1e6))
-                    telemetry.counter_add("h2d.busy_us", int((t2 - t1) * 1e6))
-                    telemetry.counter_add("h2d.emit_wait_us",
-                                          int((t3 - t2) * 1e6))
+                    telemetry.counter_add("h2d.busy_us", t2 - t1)
+                    telemetry.counter_add("h2d.emit_wait_us", t3 - t2)
                     telemetry.counter_add("h2d.batches", 1)
                     if not ok:
                         return

@@ -34,7 +34,7 @@ __all__ = [
     "counters_delta", "snapshot_restarted", "merge_snapshots",
     "histogram_quantile", "trace_start", "trace_stop", "trace_dump_json",
     "trace_dump", "record_span", "span", "now_us", "trace_armed",
-    "new_trace_id",
+    "clock_fit", "to_profiler_ns", "new_trace_id",
     "set_trace_context", "get_trace_context", "clear_trace_context",
     "trace_context_wire", "adopt_trace_context", "lineage", "json_validate",
     "stall_attribution",
@@ -202,13 +202,23 @@ def trace_armed() -> bool:
 
 def trace_start() -> None:
     """Start buffering spans (clears spans from any previous trace)."""
-    global _trace_armed
-    _native.check(_native.lib().DmlcTpuTelemetryTraceStart())
+    global _trace_armed, _ring_ours
+    _ring_start()
     _trace_armed = True
+    _ring_ours = False      # the caller's trace: no session's end stops it
+
+
+def _ring_start() -> None:
+    global _ring_on
+    _native.check(_native.lib().DmlcTpuTelemetryTraceStart())
+    _ring_on = True
+    _sync_marks.clear()
 
 
 def trace_stop() -> None:
+    global _ring_on, _ring_ours
     _native.check(_native.lib().DmlcTpuTelemetryTraceStop())
+    _ring_on = _ring_ours = False
 
 
 def trace_dump_json() -> str:
@@ -220,17 +230,111 @@ def trace_dump_json() -> str:
 
 
 def trace_dump() -> dict:
-    return json.loads(trace_dump_json())
+    """The ring's Chrome trace, parsed; ``otherData.clock_sync`` lists the
+    steady-clock reading of every sync mark written since the ring started
+    (see :func:`to_profiler_ns`)."""
+    doc = json.loads(trace_dump_json())
+    doc.setdefault("otherData", {})["clock_sync"] = list(_sync_marks)
+    return doc
 
 
-def record_span(name: str, ts_us: int, dur_us: int) -> None:
+def record_span(name: str, ts_us: int, dur_us: int, lineage: int = -1) -> None:
     """Record one complete span into the active trace.  Timestamps are
     steady-clock microseconds — ``time.monotonic_ns() // 1000`` on Linux
     shares an epoch with the native spans, so Python and C++ spans line up
-    on one timeline."""
-    _native.check(
-        _native.lib().DmlcTpuTelemetryRecordSpan(name.encode(), int(ts_us),
-                                                 int(dur_us)))
+    on one timeline.  ``lineage`` (see :func:`lineage`) is the batch the
+    span handled and goes out as ``args.lineage``."""
+    _native.check(_native.lib().DmlcTpuTelemetryRecordSpanLineage(
+        name.encode(), int(ts_us), int(dur_us), int(lineage)))
+
+
+# ---- the ring beside a jax.profiler session -----------------------------------
+#
+# While a ``jax.profiler`` session is live the ring records too, so that one
+# traced run holds the host's side twice over: the few spans that may name a
+# device gap as annotations in the profiler's file, and every stage and
+# hand-off of the feed, native ones included, in the ring.  Sync marks tie the
+# ring's steady clock to the profiler's.
+
+_JAX_PROFILER = "jax._src.profiler"     # where jax keeps its live session
+_SYNC_PREFIX = "dmlctpu.clock_sync."
+_SYNC_EVERY_US = 1_000_000
+_sync_marks: List[int] = []     # steady-clock us of every mark of this trace
+_ring_on = False                # the ring is recording
+_ring_seen = None               # the profiler session last seen, or None
+_ring_ours = False              # the ring was started for it: stop it with it
+_ring_lock = threading.Lock()
+
+
+def _follow_profiler(profiler) -> None:
+    """Start the ring the first time a span sees a live profiler session
+    (unless the caller's own trace is recording: that one stays), stop it
+    when the session is gone, and write a sync mark at the start and whenever
+    the last is older than a second.  An attribute read while none runs."""
+    global _ring_seen, _ring_ours
+    state = getattr(sys.modules.get(_JAX_PROFILER), "_profile_state", None)
+    session = getattr(state, "profile_session", None)
+    if session is _ring_seen:
+        if session is not None:
+            last = _sync_marks[-1:]     # a caller's trace_start may clear it
+            if not last or now_us() - last[0] > _SYNC_EVERY_US:
+                _sync_mark(profiler)
+        return
+    with _ring_lock:
+        if session is _ring_seen:
+            return
+        if _ring_ours:
+            trace_stop()
+        if session is not None:
+            if not _ring_on:
+                _ring_start()
+                _ring_ours = True
+            _sync_mark(profiler)
+        _ring_seen = session
+
+
+def _sync_mark(profiler) -> None:
+    """One reading of the steady clock, written into the profiler's file as
+    the NAME of an annotation of no length: the profiler stamps its own clock
+    on the annotation, so each mark is one point of the map between the two.
+    (Only a host event's name, start and end reach the benchmark's readers.)"""
+    reading = now_us()
+    with profiler.TraceAnnotation(_SYNC_PREFIX + str(reading)):
+        pass
+    _sync_marks.append(reading)
+
+
+def clock_fit(marks) -> Tuple[float, float, float]:
+    """``(offset_ns, drift, err_us)`` of the straight line through sync
+    marks ``[(steady_us, profiler_ns), ...]`` (two or more, by least
+    squares): ``profiler_ns = offset_ns + (1 + drift) * 1000 * steady_us``.
+    ``err_us`` bounds the map's error at the marks: the largest distance of
+    a mark from the line plus the one microsecond a reading is cut to."""
+    marks = sorted(marks)
+    if len(marks) < 2:
+        raise ValueError("clock_fit needs two sync marks or more")
+    x0, y0 = marks[0]
+    xs = [1000.0 * (x - x0) for x, _ in marks]      # ns since the first mark
+    ys = [float(y - y0) for _, y in marks]
+    n = len(marks)
+    mx, my = sum(xs) / n, sum(ys) / n
+    if xs[-1] < 1e8:
+        slope = 1.0     # under 0.1 s apart: rounding, not drift, would set it
+    else:
+        slope = (sum((x - mx) * (y - my) for x, y in zip(xs, ys))
+                 / sum((x - mx) ** 2 for x in xs))
+    at0 = my - slope * mx                   # the line at the first mark
+    err_ns = max(abs(at0 + slope * x - y) for x, y in zip(xs, ys))
+    return (y0 + at0 - slope * 1000.0 * x0, slope - 1.0,
+            err_ns / 1000.0 + 1.0)
+
+
+def to_profiler_ns(ts_us: float, marks) -> float:
+    """A steady-clock time of the ring (microseconds) on the profiler's clock
+    (nanoseconds), through the sync marks ``[(steady_us, profiler_ns), ...]``
+    read off the profiler's file: the one map between the two timelines."""
+    offset_ns, drift, _ = clock_fit(marks)
+    return offset_ns + (1.0 + drift) * 1000.0 * ts_us
 
 
 def _profiler_annotation(name: str):
@@ -240,26 +344,38 @@ def _profiler_annotation(name: str):
     profiler = getattr(sys.modules.get("jax"), "profiler", None)
     if profiler is None:
         return None
+    _follow_profiler(profiler)
     return profiler.TraceAnnotation("dmlctpu." + name)
 
 
+class _Span:
+    """What :func:`span` yields: set ``lineage`` inside the body when the
+    batch in hand is known only there."""
+    __slots__ = ("lineage",)
+
+    def __init__(self, lineage: int):
+        self.lineage = lineage
+
+
 @contextlib.contextmanager
-def span(name: str) -> Iterator[None]:
+def span(name: str, lineage: int = -1) -> Iterator[_Span]:
     """One span, two sinks.  The body is recorded into the native ring
     (steady clock; ``trace_dump()``, Perfetto) when tracing is on, and — in
     a process where jax is already imported — also as the profiler
     annotation ``dmlctpu.<name>``, which a running ``jax.profiler`` trace
-    puts on the device trace's clock (a flag test while none runs)."""
+    puts on the device trace's clock (a flag test while none runs).  The
+    ring's copy carries ``lineage``, the batch the span handled."""
     note = _profiler_annotation(name)
+    this = _Span(lineage)
     t0 = now_us()
     try:
         if note is None:
-            yield
+            yield this
         else:
             with note:
-                yield
+                yield this
     finally:
-        record_span(name, t0, now_us() - t0)
+        record_span(name, t0, now_us() - t0, this.lineage)
 
 
 # ---- trace context (job-wide causality) -------------------------------------

@@ -502,3 +502,66 @@ def test_lineage_minted_untraced_and_tracing_bit_identity(libsvm_file):
         telemetry.trace_stop()
     assert got_bits == ref_bits, "tracing changed staged bytes"
     assert got_lin == ref_lin, "tracing changed lineage ids"
+
+
+FEED_SPANS = ("pack.next", "h2d.host_wait", "h2d.stage_batch",
+              "h2d.device_put", "h2d.emit_wait", "feed.handoff", "feed.wait")
+
+
+def test_every_feed_span_carries_its_batch_lineage(tmp_path):
+    """A two-batch file through the sharded pool with the ring on: each
+    hand-off of each batch is one span under the batch's lineage id, and the
+    native stages behind it carry the chunk's (from the chunk in hand, no
+    trace context set)."""
+    from dmlc_core_tpu import telemetry
+    if not telemetry.enabled():
+        pytest.skip("tracing is compiled out")
+    path = tmp_path / "two.libsvm"
+    path.write_text("".join(
+        f"{i % 2} {i % 50}:1 {50 + i % 7}:0.5\n" for i in range(512)))
+    it = dt.DeviceStagingIter(str(path), batch_size=256, nnz_bucket=512,
+                              num_workers=2)
+    telemetry.trace_start()
+    try:
+        lineages = [telemetry.lineage(b) for b in it]
+    finally:
+        telemetry.trace_stop()
+    assert len(lineages) == 2 and len(set(lineages)) == 2
+    assert all(x >= 0 for x in lineages)
+    assert telemetry.get_trace_context()[0] == 0
+    spans = {}
+    for e in telemetry.trace_dump()["traceEvents"]:
+        spans.setdefault(e["name"], []).append(
+            e.get("args", {}).get("lineage", -1))
+    for name in FEED_SPANS:
+        got = [x for x in spans[name] if x >= 0]
+        assert sorted(got) == sorted(lineages), (name, spans[name])
+    # (the batcher packs ahead from its making on and again after the
+    # epoch's rewind: a batch can be packed twice)
+    assert set(spans["pack.batch"]) - {-1} == set(lineages)
+    # the stream's end is a wait and a pack too, for no batch
+    assert spans["feed.wait"].count(-1) == 1
+    for lineage in lineages:
+        assert lineage in spans["parse.chunk"], spans["parse.chunk"]
+        assert (lineage >> 32) << 32 in spans["shard.part"]
+    assert -1 not in spans["parse.chunk"] + spans["shard.part"]
+
+
+def test_counters_are_the_registrys_readings(libsvm_file):
+    """``counters`` serves the stager's breakdown from the registry's own
+    h2d counters since the epoch began, not from a second set of clocks."""
+    from dmlc_core_tpu import telemetry
+    if not telemetry.enabled():
+        pytest.skip("counters read 0 with telemetry compiled out")
+    it = dt.DeviceStagingIter(libsvm_file, batch_size=128, nnz_bucket=512)
+    assert "batches" not in it.counters         # no epoch yet
+    names = ("h2d.wait_us", "h2d.busy_us", "h2d.emit_wait_us", "h2d.batches")
+    for _epoch in range(2):
+        before = [telemetry.counter_get(n) for n in names]
+        assert sum(int(b.num_rows) for b in it) == 1000
+        moved = [telemetry.counter_get(n) - b for n, b in zip(names, before)]
+        c = it.counters
+        assert c["batches"] == moved[3] == 8
+        assert [c["host_wait_s"], c["stage_s"], c["emit_wait_s"]] == [
+            m / 1e6 for m in moved[:3]]
+        assert c["native_s"] >= 0.0
