@@ -1321,10 +1321,10 @@ class GBDT:
         The root's histogram is built whole; a level below it builds one
         child of every parent (`_smaller_child`) from the rows that stand in
         it (`_child_slot`; the bit travels in `_route_level`'s word, no
-        gather a row) and derives the other (`_with_siblings`), so the
-        backend sums ``2 ** (depth - 1)`` node columns where the level has
-        ``2 ** depth``; under a mesh plan the built columns are reduced
-        before the subtraction.
+        gather a row) and derives the other (`_with_siblings`): the backend
+        sums ``2 ** (depth - 1)`` node columns of the level's ``2 ** depth``,
+        reduced under a mesh plan before the subtraction.  A leaf's (G, H)
+        are the sums its parent's split was chosen by (`_split_child_sums`).
 
         bins: u8 [rows, features]; grad/hess: f32 [rows] (weight-scaled,
         padding rows carry 0 mass).  Returns (feature, threshold,
@@ -1425,18 +1425,18 @@ class GBDT:
                 slot = _child_slot(rel, go_right, built_bit)
 
         # leaf weights: -G/(H + lambda) per leaf, shrunken (clamped into the
-        # node's propagated bounds first under monotone constraints)
+        # node's propagated bounds first under monotone constraints).  Leaves
+        # 2k, 2k + 1 are the children of the last level's node k: their
+        # (G, H) are the sums its split was chosen by, no pass over the rows
+        # (a null split's right leaf holds none: exactly (0, 0), weight 0)
         n_leaves = 2 ** self.max_depth
         with jax.named_scope("gbdt.leaf"):
-            leaf_rel = node - (n_leaves - 1)
-            gh_leaf = jax.ops.segment_sum(jnp.stack([grad, hess], axis=-1),
-                                          leaf_rel, num_segments=n_leaves)
+            leaf_rel = node - (n_leaves - 1)  # each row's leaf, for `_boost`
+            gh_leaf = _split_child_sums(dirs, split_f, split_b, split_d)
             leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
             if mono:
                 leaf_w = jnp.clip(leaf_w, lo, hi)
             leaf = self.learning_rate * leaf_w
-        # leaf_rel doubles as each row's final leaf assignment, so fit()
-        # can update margins without re-routing every row through the tree
         return (jnp.concatenate(features), jnp.concatenate(thresholds),
                 jnp.concatenate(defaults), jnp.concatenate(gains),
                 jnp.concatenate(covers), leaf, leaf_rel)
@@ -2556,3 +2556,37 @@ def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
     pair = jnp.stack([jnp.where(right, derived, built),
                       jnp.where(right, built, derived)], axis=1)
     return pair.reshape((-1,) + built.shape[1:])
+
+
+def _split_child_sums(dirs, split_f, split_b, split_d) -> jax.Array:
+    """(G, H) of both children of every node at its chosen split, float32
+    ``[2 * nodes, 2]`` in heap order (left, right): read off the cumulative
+    histograms the split search already holds, so a dense tree's leaves cost
+    no pass over the rows (the scatter-add of a ``[rows, 2]`` pair was 6.8 ns
+    and 512 B of scratch a row on a v5e: PERF.md, PR 38) and, under a mesh
+    plan, they come from the reduced histograms: global with no collective.
+    The left child's sums are ``dirs[split_d]`` at ``(split_f, split_b)``,
+    missing mass on its side included; the right child's the node's totals
+    in that feature's histogram (its last cumulative sum) less the left's,
+    the one subtraction the gain was computed with (XGBoost's hist takes a
+    leaf's weight from the same statistics).  A null split's cut lies past
+    the last bin and defaults left: the read is clamped to the last bin,
+    where the cumulative sum IS the total, so the left child is the node and
+    the right child exactly (0, 0), weight 0.
+
+    dirs: ``(gl, hl)`` ``[nodes, F, B]`` a default direction, left-to-right
+    cumulative sums, ``dirs[0]`` with the missing bin on the left; split_*:
+    the chosen [nodes] tables, nulls as `_pick_splits` encodes them."""
+    n_nodes, _, B = dirs[0][0].shape
+    last = (split_f * B + B - 1)[:, None]
+    at = jnp.minimum(last, (split_f * B + split_b)[:, None])
+
+    def pick(a, i):
+        return jnp.take_along_axis(a.reshape(n_nodes, -1), i, 1)[:, 0]
+
+    left = [jnp.stack([pick(gl, at), pick(hl, at)], axis=-1)
+            for gl, hl in dirs]
+    left = (left[0] if len(left) == 1
+            else jnp.where((split_d == 1)[:, None], left[1], left[0]))
+    total = jnp.stack([pick(a, last) for a in dirs[0]], axis=-1)
+    return jnp.stack([left, total - left], axis=1).reshape(-1, 2)
