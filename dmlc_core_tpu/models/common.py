@@ -196,6 +196,76 @@ class FTRL:
         return self.weights(z_new, n_new), z_new, n_new
 
 
+@dataclasses.dataclass(frozen=True)
+class AdaGrad:
+    """Per-coordinate AdaGrad as DiFacto's servers keep an embedding row
+    (Li et al., WSDM 2016, Algorithm 3; dmlc/wormhole ``learn/difacto``
+    ``sgd_server_handle.h``): ``g += l2 v``, ``n += g^2``,
+    ``v -= alpha g / (beta + sqrt(n))``.  A coordinate keeps ``n``."""
+    alpha: float = 0.01
+    beta: float = 1.0
+    l2: float = 0.0
+
+    def __post_init__(self):
+        if self.alpha <= 0 or min(self.beta, self.l2) < 0:
+            raise ValueError(f"AdaGrad needs alpha > 0 and beta, l2 >= 0, "
+                             f"got {self}")
+
+    def apply(self, v: jax.Array, n: jax.Array, g: jax.Array) -> tuple:
+        """One update of coordinates holding ``(v, n)`` with gradient ``g``:
+        the new ``(v, n)``."""
+        g = g + self.l2 * v
+        n_new = n + g * g
+        return v - self.alpha * g / (self.beta + jnp.sqrt(n_new)), n_new
+
+
+#: where each rule's state lives in the parameters, its slots in the order
+#: ``apply`` takes and returns them after the weight
+_RULE_STATE = {FTRL: ("ftrl", ("z", "n")), AdaGrad: ("adagrad", ("n",))}
+#: what rides beside the parameters: the rules' state (training's alone: a
+#: snapshot leaves it out) and the count table (which scoring's gate reads)
+RULE_STATE_KEYS = tuple(held for held, _slots in _RULE_STATE.values())
+STATE_KEYS = RULE_STATE_KEYS + ("count",)
+
+
+def _with_state(params: dict, rule, name: str) -> tuple:
+    """Parameter ``name`` and its state under ``rule``: ``(weight,
+    *state)``, the order ``apply`` returns them in."""
+    held, slots = _RULE_STATE[type(rule)]
+    return (params[name],) + tuple(params[held][slot][name] for slot in slots)
+
+
+def _read_by(rule, table: tuple) -> tuple:
+    """Which of a parameter's ``(weight, *state)`` the rule reads: FTRL's
+    weight is the closed form of its state and is never read."""
+    return table[1:] if isinstance(rule, FTRL) else table
+
+
+def _apply(rule, held: tuple, g: jax.Array) -> tuple:
+    """``rule.apply`` under the rule's own scope: the new ``(weight,
+    *state)``."""
+    if isinstance(rule, AdaGrad):
+        with jax.named_scope("sgd.adagrad"):
+            return rule.apply(*held, g)
+    with jax.named_scope("sgd.ftrl"):
+        return rule.apply(*held, g)
+
+
+def visit_distinct(touched, entries: int, carry, body):
+    """ONE visit of the distinct keys, over the shortest run of lanes of
+    ``TOUCHED_ROWS_VISITS`` (and the entry lanes after them) that holds all
+    ``touched`` of them: ``body(lanes, trip, carry)`` in a loop of one trip
+    or none a candidate (a ``cond`` would copy the tables into its branches;
+    a loop's carry stays in place); ``trip`` is the loop's own index, 0."""
+    sizes = [c for c in TOUCHED_ROWS_VISITS if c < entries] + [entries]
+    for fewer, lanes in zip([0] + sizes, sizes):
+        mine = (touched > fewer) & (touched <= lanes)
+        carry = jax.lax.fori_loop(
+            0, mine.astype(jnp.int32),
+            lambda trip, c, lanes=lanes: body(lanes, trip, c), carry)
+    return carry
+
+
 class TouchedRowsMixin(SGDModelMixin):
     """``train_step`` for a model that names an ``optimizer``: distinct keys
     of the batch, their rows gathered, the loss differentiated with respect
@@ -207,37 +277,74 @@ class TouchedRowsMixin(SGDModelMixin):
     step above runs.
 
     A model provides ``row_tables`` (the parameters that ``batch.index``
-    addresses, one float a key today: a table of rows ``[F, ...]`` would
-    carry a column a trailing element through the same sorts; every other
-    parameter is one more coordinate that every row holds) and
-    ``margins_of_rows(rows, dense, batch)``, its margins from the rows
-    gathered an entry (``rows[k]`` is ``params[k][batch.index]``).
+    addresses, ``[F]`` or ``[F, K]``; every other parameter is one more
+    coordinate that every row holds) and ``margins_of_rows(rows, dense,
+    batch)``, its margins from the rows gathered an entry (``rows[k]`` is
+    ``params[k][batch.index]``).  ``optimizer`` is one ``FTRL`` for a model
+    of one table, or a rule a table: an ``FTRL`` for the first (the other
+    parameters share it) and an ``AdaGrad`` for each of the rest.
 
-    ``params["ftrl"]`` holds the state, ``{"z": {...}, "n": {...}}`` shaped
-    like the parameters; ``params[k]`` itself stays the weight, so
-    ``predict``, checkpoints and the scoring server see what they saw.
+    ``gated_tables`` and ``count_threshold`` are DiFacto's gate: a gated
+    table's row exists only for a key seen more than ``count_threshold``
+    times and while l1 has not zeroed the key's weight in the first table.
+    ``params["count"]`` then holds a key's occurrences, int32, and
+    ``margins_of_rows`` takes a fourth argument, the entries whose gate is
+    open.
+
+    The rules' state rides beside the parameters (``params["ftrl"] = {"z":
+    {...}, "n": {...}}``, ``params["adagrad"] = {"n": {...}}``, each shaped
+    like the parameters under that rule); ``params[k]`` itself stays the
+    weight, so ``predict``, checkpoints and the scoring server see what they
+    saw.
     """
 
     optimizer = None
     row_tables = ("w",)
+    gated_tables = ()
+    count_threshold = None
 
     def _set_optimizer(self, optimizer) -> None:
-        if optimizer is not None and not isinstance(optimizer, FTRL):
+        names = self.row_tables
+        fits = optimizer is None or (
+            len(names) == 1 if isinstance(optimizer, FTRL) else
+            isinstance(optimizer, dict) and set(optimizer) == set(names)
+            and isinstance(optimizer[names[0]], FTRL)
+            and all(isinstance(optimizer[k], AdaGrad) for k in names[1:]))
+        if not fits:
             raise ValueError(f"unknown optimizer {optimizer!r}")
         if optimizer is not None and self.l2 > 0.0:
             raise ValueError("with an optimizer the penalty is the "
                              "optimizer's (FTRL(l2=...)), not the model's")
         self.optimizer = optimizer
-        # (distinct keys, tiles written) of the steps in flight: two device
-        # scalars a step
+        # the counts of the steps in flight: device scalars
         self._touched = collections.deque()
 
+    def rule_of(self, name: str):
+        """The rule of table ``name``; of every other parameter, the rule
+        of the first table."""
+        if isinstance(self.optimizer, dict):
+            return self.optimizer.get(name, self.optimizer[self.row_tables[0]])
+        return self.optimizer
+
     def init_optimizer(self, params: dict) -> dict:
-        """``params`` with the optimizer's zero state beside them."""
+        """``params`` with each rule's zero state and, under a gate, the
+        count table beside them (no optimizer: as given)."""
         if self.optimizer is None:
             return params
-        zeros = functools.partial(jax.tree.map, jnp.zeros_like)
-        return dict(params, ftrl={"z": zeros(params), "n": zeros(params)})
+        out = dict(params)
+        for name, p in params.items():
+            held, slots = _RULE_STATE[type(self.rule_of(name))]
+            for slot in slots:
+                out.setdefault(held, {}).setdefault(slot, {})[name] = (
+                    jnp.zeros_like(p))
+        if self.count_threshold is not None:
+            out["count"] = jnp.zeros(self.num_features, jnp.int32)
+        return out
+
+    def active(self, count: jax.Array, weight: jax.Array) -> jax.Array:
+        """The gate from a key's count and its first table's weight, each
+        gathered wherever the caller needs the gate (an entry, a key)."""
+        return (count > self.count_threshold) & (weight != 0)
 
     def train_step(self, params: dict, batch) -> Tuple[dict, jax.Array]:
         """One step; returns (new_params, loss), the weighted mean loss of
@@ -252,34 +359,79 @@ class TouchedRowsMixin(SGDModelMixin):
 
     def flush_step_counters(self, wait: bool = True) -> None:
         """Add the finished steps to the counters ``sgd.steps``,
-        ``sgd.touched_rows`` and ``sgd.scatter_tiles`` (tiles the in-place
-        kernel wrote; 0 where XLA's scatter ran).  ``wait=False`` (every
-        ``train_step``) takes only the steps the device has finished and
-        never waits for it."""
+        ``sgd.touched_rows``, ``sgd.scatter_tiles`` (tiles the in-place
+        kernel wrote; 0 where XLA's scatter ran) and, under a gate,
+        ``sgd.active_rows`` (distinct keys whose gate was open) and
+        ``sgd.activated_rows`` (those whose count crossed the threshold in
+        the step).  ``wait=False`` (every ``train_step``) takes only the
+        steps the device has finished and never waits for it."""
         while self._touched and (wait or self._touched[0][0].is_ready()):
-            touched, tiles = self._touched.popleft()
+            touched, tiles, *gate = self._touched.popleft()
             telemetry.counter_add("sgd.touched_rows", int(touched))
             telemetry.counter_add("sgd.scatter_tiles", int(tiles))
             telemetry.counter_add("sgd.steps", 1)
+            if gate:
+                telemetry.counter_add("sgd.active_rows", int(gate[0]))
+                telemetry.counter_add("sgd.activated_rows", int(gate[1]))
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
     def _touched_rows_step(self, params: dict, batch) -> tuple:
+        """Under a gate the step runs in DiFacto's order: a key's occurrences
+        are added to ``params["count"]`` first; the gate is read off the
+        counts and weights as the step then finds them; a gated table's row
+        enters a margin and is updated only where the gate is open, and is
+        written back bit for bit where it is shut."""
         from ..ops.pallas_rows import scatter_rows
         from ..ops.sparse import padded_row_mean, reduce_by_key
-        opt, names = self.optimizer, self.row_tables
-        state = params["ftrl"]
-        dense = {k: v for k, v in params.items()
-                 if k not in names and k != "ftrl"}
-        with jax.named_scope("sgd.gather_rows"):
-            rows = {k: params[k][batch.index] for k in names}
+        names, first = self.row_tables, self.row_tables[0]
+        rules = {k: self.rule_of(k) for k in params if k not in STATE_KEYS}
+        gate = self.count_threshold is not None
+        dense = [k for k in rules if k not in names]
+        index, live = batch.index, batch.value != 0
+        entries = index.shape[0]
+        sorted_distinct = dict(unique_indices=True, indices_are_sorted=True)
+        tiles = zero = jnp.zeros((), jnp.int32)
+        gated = ()
 
+        if gate:
+            with jax.named_scope("sgd.unique"):
+                keys, (seen,), touched = reduce_by_key(
+                    index, live, (live.astype(jnp.int32),), self.num_features)
+
+            def count_visit(lanes, _trip, carry):
+                count, tiles, _ = carry
+                with jax.named_scope("sgd.gather_rows"):
+                    old = count.at[keys[:lanes]].get(
+                        mode="fill", fill_value=0, **sorted_distinct)
+                with jax.named_scope("sgd.count"):
+                    new = old + seen[:lanes]
+                    crossed = jnp.sum(
+                        (jnp.arange(lanes) < touched)
+                        & (old <= self.count_threshold)
+                        & (new > self.count_threshold), dtype=jnp.int32)
+                with jax.named_scope("sgd.scatter_rows"):
+                    (count,), wrote = scatter_rows(
+                        (count,), keys[:lanes], (new,), touched)
+                return count, tiles + wrote, crossed
+
+            count, tiles, crossed = visit_distinct(
+                touched, entries, (params["count"], tiles, zero), count_visit)
+
+        with jax.named_scope("sgd.gather_rows"):
+            rows = {k: params[k][index] for k in names}
+            if gate:
+                seen_an_entry = count[index]
+        if gate:
+            with jax.named_scope("sgd.count"):
+                gated = (live & self.active(seen_an_entry, rows[first]),)
         # the loss differentiated with respect to the gathered rows: the
         # gradient of the SUM over the minibatch, d(loss_r)/d(margin_r)
         # written out (``logistic_nll``'s own derivative is off by a half
         # where a margin is exactly 0, as every margin of a first step is)
         with jax.named_scope("sgd.loss"):
             m, pull = jax.vjp(
-                lambda r, d: self.margins_of_rows(r, d, batch), rows, dense)
+                lambda r, d: self.margins_of_rows(r, d, batch, *gated),
+                rows, {k: params[k] for k in dense})
             if self.objective == "logistic":
                 per_row = logistic_nll(m, batch.label)
                 slope = jax.nn.sigmoid(m) - jnp.where(batch.label > 0.5, 1., 0.)
@@ -287,43 +439,65 @@ class TouchedRowsMixin(SGDModelMixin):
                 per_row, slope = 0.5 * (m - batch.label) ** 2, m - batch.label
             loss = padded_row_mean(per_row, batch.weight)
             g_rows, g_dense = pull(slope * batch.weight)
+        # tables of one float a key ride the sorts beside the key (and the
+        # gate with them: a key's sum is over 0 where it is open); rows of K
+        # floats are summed in key order and gathered a distinct key
+        flat = [k for k in names if params[k].ndim == 1]
+        wide = [k for k in names if params[k].ndim > 1]
         with jax.named_scope("sgd.unique"):
-            entries = batch.index.shape[0]
-            keys, sums, touched = reduce_by_key(
-                batch.index, batch.value != 0,
-                tuple(g_rows[k] for k in names), self.num_features)
-        # the distinct keys lie first and ascending: ONE visit of the tables,
-        # over the shortest run of lanes that holds them all.  Each candidate
-        # is a loop of one trip or none (a ``cond`` would copy the tables
-        # into its branches; a loop's carry stays in place)
-        sizes = [c for c in TOUCHED_ROWS_VISITS if c < entries] + [entries]
-        sorted_distinct = dict(unique_indices=True, indices_are_sorted=True)
-        tables = {k: (params[k], state["z"][k], state["n"][k]) for k in names}
-        tiles = jnp.zeros((), jnp.int32)
-        for fewer, lanes in zip([0] + sizes, sizes):
-            def visit(_, carry, lanes=lanes):
-                tables, tiles = carry
-                out = {}
-                for name, column in zip(names, sums):
-                    with jax.named_scope("sgd.gather_rows"):
-                        z, n = (t.at[keys[:lanes]].get(
-                            mode="fill", fill_value=0.0, **sorted_distinct)
-                            for t in tables[name][1:])
-                    with jax.named_scope("sgd.ftrl"):
-                        updated = opt.apply(z, n, column[:lanes])
-                    # in place either way: the tiles the keys name where the
-                    # table is long against ``lanes``, XLA's scatters elsewhere
-                    with jax.named_scope("sgd.scatter_rows"):
-                        out[name], wrote = scatter_rows(
-                            tables[name], keys[:lanes], updated, touched)
-                    tiles = tiles + wrote
-                return out, tiles
+            keys, sums, touched, *wide_sums = reduce_by_key(
+                index, live, tuple(g_rows[k] for k in flat) + tuple(
+                    on.astype(jnp.float32) for on in gated),
+                self.num_features, tuple(g_rows[k] for k in wide))
 
-            mine = (touched > fewer) & (touched <= lanes)
-            tables, tiles = jax.lax.fori_loop(0, mine.astype(jnp.int32), visit,
-                                              (tables, tiles))
-        with jax.named_scope("sgd.ftrl"):
-            tables.update({k: opt.apply(state["z"][k], state["n"][k],
-                                        g_dense[k]) for k in dense})
-        w, z, n = ({k: t[i] for k, t in tables.items()} for i in range(3))
-        return dict(w, ftrl={"z": z, "n": n}), loss, (touched, tiles)
+        def visit(lanes, trip, carry):
+            tables, tiles, opened = carry
+            at, out = keys[:lanes], {}
+            if gate:
+                with jax.named_scope("sgd.count"):
+                    open_ = (sums[-1][:lanes] > 0) & (
+                        jnp.arange(lanes) < touched)
+                    opened = jnp.sum(open_, dtype=jnp.int32)
+            for name in names:
+                with jax.named_scope("sgd.gather_rows"):
+                    held = tuple(t.at[at].get(mode="fill", fill_value=0.0,
+                                              **sorted_distinct)
+                                 for t in _read_by(rules[name], tables[name]))
+                    if name in flat:
+                        g = sums[flat.index(name)][:lanes]
+                    else:
+                        # ``trip`` is 0: with it this gather, which reads
+                        # nothing the loop carries, stays in its loop;
+                        # without it XLA hoists it out of every candidate's
+                        # loop and runs them all every step (46 ms on a v5e)
+                        running, ends = wide_sums[0]
+                        g = running[wide.index(name)][ends[:lanes] + trip]
+                updated = _apply(rules[name], held, g)
+                if name in self.gated_tables:
+                    with jax.named_scope("sgd.count"):
+                        updated = tuple(
+                            jnp.where(open_.reshape((-1,) + (1,) * (
+                                new.ndim - 1)), new, old)
+                            for new, old in zip(updated, held))
+                # in place either way: the tiles the keys name where the
+                # table is long against ``lanes``, XLA's scatters elsewhere
+                with jax.named_scope("sgd.scatter_rows"):
+                    out[name], wrote = scatter_rows(
+                        tables[name], at, updated, touched)
+                tiles = tiles + wrote
+            return out, tiles, opened
+
+        tables = {k: _with_state(params, rules[k], k) for k in names}
+        tables, tiles, opened = visit_distinct(
+            touched, entries, (tables, tiles, zero), visit)
+        tables.update({k: _apply(rules[k], _read_by(rules[k], _with_state(
+            params, rules[k], k)), g_dense[k]) for k in dense})
+        out = {k: t[0] for k, t in tables.items()}
+        for k, t in tables.items():
+            held, slots = _RULE_STATE[type(rules[k])]
+            for slot, value in zip(slots, t[1:]):
+                out.setdefault(held, {}).setdefault(slot, {})[k] = value
+        if gate:
+            out["count"] = count
+            return out, loss, (touched, tiles, opened, crossed)
+        return out, loss, (touched, tiles)

@@ -43,14 +43,24 @@ _WAIT = 64
 CROSSOVER_FLOATS_PER_LANE = 640
 
 
-def engages(length: int, lanes: int, dtype) -> bool:
-    """Whether a visit of ``lanes`` keys to float tables of ``length`` takes
-    the kernel: read off static shapes and the backend (compiled kernels
-    run on a TPU alone), nothing else."""
-    return (not pallas_interpret() and dtype == jnp.float32
+def _kernel_holds(dtype, width: int) -> bool:
+    """32-bit elements; rows of one element, or of whole sublane groups."""
+    return jnp.dtype(dtype).itemsize == 4 and (width == 1 or width % 8 == 0)
+
+
+def _visit_shape(table, keys) -> tuple:
+    """``engages``' arguments for a visit of ``keys`` to ``table``."""
+    return (table.shape[0], keys.shape[0], table.dtype, *table.shape[1:])
+
+
+def engages(length: int, lanes: int, dtype, width: int = 1) -> bool:
+    """Whether a visit of ``lanes`` keys to tables of ``length`` rows of
+    ``width`` floats takes the kernel: read off static shapes and the backend
+    (compiled kernels run on a TPU alone), nothing else."""
+    return (not pallas_interpret() and _kernel_holds(dtype, width)
             and length % TILE == 0
             and lanes % CHUNK == 0
-            and length >= lanes * CROSSOVER_FLOATS_PER_LANE)
+            and length * width >= lanes * CROSSOVER_FLOATS_PER_LANE)
 
 
 def _kernel(count_ref, keys_ref, *refs, n_tables: int):
@@ -148,15 +158,15 @@ def _kernel(count_ref, keys_ref, *refs, n_tables: int):
 
 def scatter_rows_inplace(tables, keys, rows, count) -> tuple:
     """``t.at[keys].set(r, mode="drop")`` for each of ``tables`` and its
-    ``rows``, writing only the tiles the keys name.
-
-    ``tables``: 1-D float32 tables of one length ``F``, ``F % 1024 == 0``,
-    each aliased to its output (donate them, or XLA copies them first);
-    ``keys``: ``s32[lanes]``, ``lanes % 1024 == 0``, the ``count`` distinct
-    keys first and ascending, whatever follows them (ids ``>= F``) never
-    read; ``rows``: one ``f32[lanes]`` a table.  Returns ``(tables, tiles)``:
-    ``tiles`` the tiles written, a distinct tile counted once a table.
-    """
+    ``rows``, writing only the tiles the keys name.  ``tables``: 32-bit
+    tables ``[F]`` (or ``[F, K]``: ``_scatter_wide_inplace``, below) of one
+    shape, ``F % 1024 == 0``, each aliased to its output (donate them, or
+    XLA copies them first); ``keys``: ``s32[lanes]``, ``lanes % 1024 == 0``,
+    the ``count`` distinct keys first and ascending, whatever follows them
+    (ids ``>= F``) never read; ``rows``: one ``[lanes]`` (``[lanes, K]``) a
+    table.  Returns ``(tables, tiles)``: ``tiles`` the 4 KB tiles written."""
+    if tables[0].ndim == 2:
+        return _scatter_wide_inplace(tables, keys, rows, count)
     n, length, lanes = len(tables), tables[0].shape[0], keys.shape[0]
     chunks = lanes // CHUNK
     tiled = [t.reshape(length // TILE, 8, 128) for t in tables]
@@ -181,7 +191,7 @@ def scatter_rows_inplace(tables, keys, rows, count) -> tuple:
             in_specs=[smem_chunk] * (1 + n) + [anywhere] * n,
             out_specs=[anywhere] * n
             + [pl.BlockSpec(memory_space=pltpu.SMEM)],
-            scratch_shapes=[pltpu.VMEM((CHUNK, 8, 128), jnp.float32)] * n + [
+            scratch_shapes=[pltpu.VMEM((CHUNK, 8, 128), rows[0].dtype)] * n + [
                 pltpu.SMEM((1,), jnp.int32), pltpu.SemaphoreType.DMA((n,))]),
         # table k is operand 2 + n + k, the count and the keys before the rows
         input_output_aliases={2 + n + k: k for k in range(n)},
@@ -198,8 +208,153 @@ def scatter_rows(tables, keys, rows, count) -> tuple:
     """Rows set at sorted distinct ``keys`` into ``tables``: the kernel
     where :func:`engages` says so, XLA's scatter elsewhere.  Returns
     ``(tables, tiles)``, ``tiles`` 0 on XLA's path."""
-    if engages(tables[0].shape[0], keys.shape[0], tables[0].dtype):
+    if engages(*_visit_shape(tables[0], keys)):
         return scatter_rows_inplace(tables, keys, rows, count)
     return tuple(t.at[keys].set(r, mode="drop", unique_indices=True,
                                 indices_are_sorted=True)
                  for t, r in zip(tables, rows)), jnp.zeros((), jnp.int32)
+
+
+# ---- rows of K floats -------------------------------------------------------
+# XLA lays a ``f32[F, K]`` table of small K out with F on the lanes
+# (``{0,1:T(8,128)}``: no padding of K to 128), so its transpose ``[K, F]`` is
+# the same bytes and a key's K floats sit in ONE lane of K / 8 tiles: a block
+# of 128 neighbouring keys is ``[K, 128]``, what the kernel below copies in,
+# patches a lane of and copies back.  (A flat row-major view would cost a
+# relayout of the whole table, 34 GB for ``f32[2^26, 16]``.)
+_BLOCK_SHIFT = 7
+BLOCK = 1 << _BLOCK_SHIFT
+
+
+def _kernel_wide(count_ref, keys_ref, *refs, n_tables: int):
+    """:func:`_kernel` for tables ``[K, F]``: a block of 128 keys where that
+    has a tile of 1,024, a key's row rolled along the lanes to the key's own
+    and selected into its block."""
+    rows_refs = refs[:n_tables]                     # [K, CHUNK] in VMEM
+    tables = refs[2 * n_tables:3 * n_tables]        # [K, F], the outputs
+    tiles_ref = refs[3 * n_tables]
+    bufs = refs[3 * n_tables + 1:4 * n_tables + 1]  # [CHUNK, K, 128]
+    last_block, sems = refs[4 * n_tables + 1:]
+    step = pl.program_id(0)
+    count = count_ref[0]
+    width = bufs[0].shape[1]
+
+    @pl.when(step == 0)
+    def _():
+        tiles_ref[0] = 0
+        last_block[0] = -1
+
+    def block_of(k, block):
+        return tables[k].at[:, pl.ds(pl.multiple_of(block * BLOCK, BLOCK),
+                                     BLOCK)]
+
+    def copy_in(k, block, slot):
+        return pltpu.make_async_copy(block_of(k, block), bufs[k].at[slot],
+                                     sems.at[k])
+
+    def copy_out(k, block, slot):
+        return pltpu.make_async_copy(bufs[k].at[slot], block_of(k, block),
+                                     sems.at[k])
+
+    def drain(slots):
+        """As :func:`_kernel`'s: a wait takes its destination's bytes off
+        the semaphore, so ``_WAIT`` blocks at a time, then one by one."""
+        def wait(many):
+            def body(_, carry):
+                for k in range(n_tables):
+                    held = bufs[k].at[pl.ds(0, many)]
+                    pltpu.make_async_copy(held, held, sems.at[k]).wait()
+                return carry
+            return body
+        jax.lax.fori_loop(0, slots // _WAIT, wait(_WAIT), 0)
+        jax.lax.fori_loop(0, slots % _WAIT, wait(1), 0)
+
+    @pl.when(step * CHUNK < count)
+    def _():
+        here = jnp.minimum(count - step * CHUNK, CHUNK)
+
+        def fetch(j, carry):
+            slots, prev = carry
+            block = keys_ref[j] >> _BLOCK_SHIFT
+            new = block != prev
+
+            @pl.when(new)
+            def _():
+                for k in range(n_tables):
+                    copy_in(k, block, slots).start()
+            return slots + new.astype(jnp.int32), block
+
+        first = keys_ref[0] >> _BLOCK_SHIFT
+        slots, last = jax.lax.fori_loop(0, here, fetch,
+                                        (jnp.int32(0), jnp.int32(-1)))
+        drain(slots)
+
+        lane = jax.lax.broadcasted_iota(jnp.int32, (width, BLOCK), 1)
+
+        def patch(j, carry):
+            slot, prev = carry
+            key = keys_ref[j]
+            block = key >> _BLOCK_SHIFT
+            slot = slot + (block != prev).astype(jnp.int32)
+            at = key & (BLOCK - 1)
+            named = lane == at
+            # the 128 rows that hold row j, turned so that it lies on lane
+            # ``at``; the select takes that lane alone
+            group = pl.multiple_of((j >> _BLOCK_SHIFT) * BLOCK, BLOCK)
+            turn = (at - j) & (BLOCK - 1)
+            for k in range(n_tables):
+                row = pltpu.roll(rows_refs[k][:, pl.ds(group, BLOCK)], turn, 1)
+                bufs[k][slot] = jnp.where(named, row, bufs[k][slot])
+            after = keys_ref[jnp.minimum(j + 1, CHUNK - 1)] >> _BLOCK_SHIFT
+
+            @pl.when((after != block) | (j == here - 1))
+            def _():
+                for k in range(n_tables):
+                    copy_out(k, block, slot).start()
+            return slot, block
+
+        jax.lax.fori_loop(0, here, patch, (jnp.int32(-1), jnp.int32(-1)))
+        drain(slots)
+        straddles = (first == last_block[0]).astype(jnp.int32)
+        tiles_ref[0] += n_tables * -(-width // 8) * (slots - straddles)
+        last_block[0] = last
+
+
+def _scatter_wide_inplace(tables, keys, rows, count) -> tuple:
+    """:func:`scatter_rows_inplace` for tables ``[F, K]`` and rows ``[lanes,
+    K]``, ``K % 8 == 0`` on a chip (any K interpreted): the blocks of 128
+    keys the keys name, ``K / 8`` tiles each, read, patched and written."""
+    n, lanes = len(tables), keys.shape[0]
+    length, width = tables[0].shape
+    across = [t.T for t in tables]
+    count = jnp.minimum(count.astype(jnp.int32), jnp.sum(
+        (keys >= 0) & (keys < length), dtype=jnp.int32))
+
+    def chunk_of(i, count_ref):
+        return jnp.minimum(i, jnp.maximum(count_ref[0] - 1, 0) // CHUNK)
+
+    smem_chunk = pl.BlockSpec((CHUNK,), lambda i, c: (chunk_of(i, c),),
+                              memory_space=pltpu.SMEM)
+    rows_chunk = pl.BlockSpec((width, CHUNK), lambda i, c: (0, chunk_of(i, c)))
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    *out, tiles = pl.pallas_call(
+        functools.partial(_kernel_wide, n_tables=n),
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in across]
+        + [jax.ShapeDtypeStruct((1,), jnp.int32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lanes // CHUNK,),
+            in_specs=[smem_chunk] + [rows_chunk] * n + [anywhere] * n,
+            out_specs=[anywhere] * n
+            + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((CHUNK, width, BLOCK), rows[0].dtype)
+                            ] * n + [pltpu.SMEM((1,), jnp.int32),
+                                     pltpu.SemaphoreType.DMA((n,))]),
+        input_output_aliases={2 + n + k: k for k in range(n)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True,
+            vmem_limit_bytes=n * CHUNK * width * BLOCK * 4 + (8 << 20)),
+        interpret=pltpu.InterpretParams() if pallas_interpret() else False,
+        name=SCATTER_ROWS_KERNEL,
+    )(count.reshape(1), keys, *(r.T for r in rows), *across)
+    return tuple(t.T for t in out), tiles[0]

@@ -93,8 +93,14 @@ def csr_to_dense_missing(index: jax.Array, value: jax.Array,
 # ---- reduction onto the distinct keys of a batch ----------------------------
 
 
+def _over(mask: jax.Array, like: jax.Array) -> jax.Array:
+    """A mask a lane, broadcast over ``like``'s trailing dimensions."""
+    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
+
+
 def run_sums(keys: jax.Array, *columns: jax.Array) -> tuple:
-    """Inclusive running sum of each of ``columns`` inside every run of
+    """Inclusive running sum of each of ``columns`` (``[lanes]`` or ``[lanes,
+    K]``: every trailing element along its own run) inside every run of
     equal ``keys`` (runs contiguous, as after a sort): the last lane of a run
     holds the run's sum.  ``ceil(log2(n))`` passes of one shifted add — no
     scatter, and a pairwise order of summation."""
@@ -102,14 +108,15 @@ def run_sums(keys: jax.Array, *columns: jax.Array) -> tuple:
     while d < n:
         same = jnp.concatenate([jnp.zeros(d, bool), keys[d:] == keys[:-d]])
         columns = tuple(
-            c + jnp.where(same, jnp.concatenate(
-                [jnp.zeros(d, c.dtype), c[:-d]]), 0) for c in columns)
+            c + jnp.where(_over(same, c), jnp.concatenate(
+                [jnp.zeros((d,) + c.shape[1:], c.dtype), c[:-d]]), 0)
+            for c in columns)
         d *= 2
     return columns
 
 
 def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
-                  bound: int) -> tuple:
+                  bound: int, rows: tuple = ()) -> tuple:
     """Per-entry ``columns`` summed onto the distinct ``index`` values of
     the ``live`` entries.  Sorts are this chip's cheap primitive (0.8 ms for
     655,360 lanes of key and payload on a v5e, where a gather or a scatter
@@ -121,17 +128,34 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
     ``>= bound``, ascending too, so that a gather with ``mode="fill"`` or a
     scatter with ``mode="drop"`` may be told ``unique_indices`` and
     ``indices_are_sorted`` and passes those lanes over.  Needs
-    ``bound + len(index) <= 2**31``."""
+    ``bound + len(index) <= 2**31``.
+
+    ``rows``: payloads of K floats an entry (``[lanes, K]``).  A sort that
+    carried K columns more would pay for each, so with them the first sort
+    also carries the lane it took an entry from, each of ``rows`` is brought
+    into key order by ONE gather of rows and summed along its runs, and the
+    second sort carries the lane where each run ends: a fourth element
+    ``(running, ends)`` is returned, and the rows' sums at the first
+    ``lanes`` distinct keys are ``r[ends[:lanes]]`` for each ``r`` of
+    ``running`` — a gather of as many rows as a visit takes, not of every
+    entry."""
     n = index.shape[0]
     key = jnp.where(live, index, bound)
-    sk, *sc = jax.lax.sort((key, *columns), num_keys=1, is_stable=False)
+    lane = (jnp.arange(n, dtype=jnp.int32),) if rows else ()
+    sk, *sc = jax.lax.sort((key, *lane, *columns), num_keys=1, is_stable=False)
+    order, sc = sc[:len(lane)], sc[len(lane):]
     sums = run_sums(sk, *sc)
     ends = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)]) & (
         sk < bound)
     spare = bound + jnp.arange(n, dtype=sk.dtype)
-    keys, *sums = jax.lax.sort((jnp.where(ends, sk, spare), *sums),
+    keys, *sums = jax.lax.sort((jnp.where(ends, sk, spare), *lane, *sums),
                                num_keys=1, is_stable=False)
-    return keys, tuple(sums), jnp.sum(ends, dtype=jnp.int32)
+    at, sums = sums[:len(lane)], tuple(sums[len(lane):])
+    count = jnp.sum(ends, dtype=jnp.int32)
+    if not rows:
+        return keys, sums, count
+    running = run_sums(sk, *(r[order[0]] for r in rows))
+    return keys, sums, count, (running, at[0])
 
 
 # ---- sums over the rows of a CSR batch, without a scatter an entry ----------
@@ -140,28 +164,31 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
 def csr_row_spread(per_row: jax.Array, row_id: jax.Array,
                    row_ptr: jax.Array) -> jax.Array:
     """``per_row[row_id]`` an entry lane (the transpose of
-    :func:`csr_row_sums`): each row's value set on its first lane — one
-    scatter a ROW — and carried along the row's run by :func:`run_sums`,
-    which adds zeros to it and so changes no bit."""
+    :func:`csr_row_sums`; ``per_row`` of ``[rows]`` or ``[rows, K]``): each
+    row's value set on its first lane — one scatter a ROW — and carried
+    along the row's run by :func:`run_sums`, which adds zeros to it and so
+    changes no bit."""
     lanes = row_id.shape[0]
     first = jnp.where(row_ptr[1:] > row_ptr[:-1], row_ptr[:-1], lanes)
-    marks = jnp.zeros(lanes, per_row.dtype).at[first].set(
-        per_row, mode="drop", unique_indices=True)
+    marks = jnp.zeros((lanes,) + per_row.shape[1:], per_row.dtype).at[
+        first].set(per_row, mode="drop", unique_indices=True)
     return run_sums(row_id, marks)[0]
 
 
 @jax.custom_vjp
 def csr_row_sums(contrib: jax.Array, row_id: jax.Array,
                  row_ptr: jax.Array) -> jax.Array:
-    """``out[r] = sum of contrib over row r's lanes`` for a CSR batch whose
-    lanes lie in row order (``row_id`` from ``PaddedBatch.row_ids()``):
-    :func:`run_sums` along the rows and one gather a ROW at its last lane.
+    """``out[r] = sum of contrib over row r's lanes`` (``contrib`` of
+    ``[lanes]`` or ``[lanes, K]``) for a CSR batch whose lanes lie in row
+    order (``row_id`` from ``PaddedBatch.row_ids()``): :func:`run_sums`
+    along the rows and one gather a ROW at its last lane.
     What ``segment_sum(contrib, row_id, rows)`` gives, where that is a
     scatter-add an entry forward and a gather an entry backward (5.8 and 5.7
     ms for 655,360 entries of 16,384 rows on a v5e, against 0.3 and 0.5)."""
     (running,) = run_sums(row_id, contrib)
     held = row_ptr[1:] > row_ptr[:-1]
-    return jnp.where(held, running[jnp.maximum(row_ptr[1:] - 1, 0)], 0.0)
+    return jnp.where(_over(held, running),
+                     running[jnp.maximum(row_ptr[1:] - 1, 0)], 0.0)
 
 
 def _csr_row_sums_fwd(contrib, row_id, row_ptr):

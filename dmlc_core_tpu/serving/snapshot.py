@@ -56,8 +56,10 @@ def pack_snapshot(family: str, config: dict, params: dict,
     """Serialize (family, constructor config, flat params dict[, binner])
     into one snapshot payload.  ``config`` must be the keyword arguments
     that rebuild the model object (JSON-serializable); ``params`` a flat
-    dict of arrays/scalars (every family's ``init()`` shape)."""
+    dict of arrays/scalars (every family's ``init()`` shape; an optimizer's
+    nested state is dropped, a gated model's flat ``count`` table rides)."""
     _family_cls(family)  # validate early, before any bytes move
+    from ..models.common import RULE_STATE_KEYS
     manifest = []
     blobs = []
     for key in sorted(params):
@@ -65,6 +67,8 @@ def pack_snapshot(family: str, config: dict, params: dict,
         if v is None:
             manifest.append({"key": key, "kind": "none"})
             continue
+        if isinstance(v, dict) and key in RULE_STATE_KEYS:
+            continue    # training's state: scoring reads weights (and counts)
         if isinstance(v, dict):
             raise ValueError(f"params['{key}'] is nested; snapshots carry "
                              "flat param dicts only")

@@ -43,8 +43,9 @@ def paths_of(lowered) -> set:
 def programs() -> dict:
     """Scope paths of the three device programs, lowered for the CPU at
     tiny sizes: a whole GBDT fit (depth 2, the Pallas route interpreted, so
-    that the kernel wrapper's layout ops are there), one FFM ``_train_step``
-    and a plan-routed reduction."""
+    that the kernel wrapper's layout ops are there), one FFM ``_train_step``,
+    the touched-rows step of a linear model and of a gated factorization
+    machine, and a plan-routed reduction."""
     model = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=16,
                  missing_aware=True, histogram="pallas")
     bins = jnp.zeros((64, 4), jnp.uint8)
@@ -76,6 +77,11 @@ def programs() -> dict:
     from dmlc_core_tpu.models.linear import SparseLinearModel
     linear = SparseLinearModel(features, optimizer=FTRL())
     touched = linear._touched_rows_step.lower(linear, linear.init(), batch)
+    from dmlc_core_tpu.models.common import AdaGrad
+    from dmlc_core_tpu.models.fm import FactorizationMachine
+    fm = FactorizationMachine(features, 4, threshold=1, optimizer={
+        "w": FTRL(), "v": AdaGrad()})
+    tables = fm._touched_rows_step.lower(fm, fm.init(), batch)
 
     plan = MeshPlan.build()
     reduce = jax.jit(plan.shard_map(
@@ -85,12 +91,16 @@ def programs() -> dict:
     return {"fit": paths_of(fit), "tree": paths_of(tree),
             "sparse_tree": paths_of(sparse_tree),
             "step": paths_of(step), "touched": paths_of(touched),
+            "tables": paths_of(tables),
             "reduce": paths_of(reduce)}
 
 
 # scopes of the touched-rows step (`TouchedRowsMixin`), not of `_train_step`
 TOUCHED_ROWS = {"sgd.unique", "sgd.gather_rows", "linear.margins", "sgd.ftrl",
                 "sgd.scatter_rows"}
+# scopes the same step opens for row-shaped tables under a count gate alone
+# (the factorization machine's, which carries every scope of TOUCHED_ROWS too)
+TOUCHED_TABLES = {"fm.margins", "sgd.adagrad", "sgd.count"}
 # scopes that only one of the two tree programs opens
 DENSE_ONLY = {"gbdt.cast"}
 SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
@@ -104,12 +114,16 @@ def carries(paths: set, scope: str, under: str = "") -> bool:
 @pytest.mark.parametrize("scope", named_scopes())
 def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
     where = {"gbdt": "fit", "ops": "fit", "batch": "step", "ffm": "step",
-             "sgd": "step", "mesh": "reduce",
-             "linear": "touched"}[scope.split(".")[0]]
+             "sgd": "step", "mesh": "reduce", "linear": "touched",
+             "fm": "tables"}[scope.split(".")[0]]
     if scope in SPARSE_ONLY:
         where = "sparse_tree"
     if scope in TOUCHED_ROWS:
         where = "touched"
+        assert carries(programs["tables"], scope,
+                       under="jit(_touched_rows_step)")
+    if scope in TOUCHED_TABLES:
+        where = "tables"
     assert carries(programs[where], scope), (
         f"no op of the {where} program carries the scope {scope}")
     if scope.startswith("gbdt.") and scope != "gbdt.boost":
@@ -281,7 +295,7 @@ def test_backward_ops_keep_the_forward_scope(programs, scope):
 def test_no_metric_names_an_unknown_scope_prefix():
     assert named_scopes(), "the benchmark names no scope at all"
     assert {s.split(".")[0] for s in named_scopes()} <= {
-        "gbdt", "ops", "batch", "ffm", "sgd", "mesh", "linear"}
+        "gbdt", "ops", "batch", "ffm", "sgd", "mesh", "linear", "fm"}
 
 
 def test_span_lands_in_the_profiler_trace_and_in_the_native_ring(tmp_path):
