@@ -268,19 +268,21 @@ def visit_distinct(touched, entries: int, carry, body):
 
 class TouchedRowsMixin(SGDModelMixin):
     """``train_step`` for a model that names an ``optimizer``: distinct keys
-    of the batch, their rows gathered, the loss differentiated with respect
-    to the *gathered* rows, one optimizer update a distinct key, rows
-    scattered back in place into the donated tables.  No table-sized
-    temporary and no table-sized gradient; a coordinate no live entry names
-    is neither gathered nor set (the scatters' pass over their operand is
-    XLA's, see ``TOUCHED_ROWS_VISITS``).  Without an optimizer the plain SGD
-    step above runs.
+    of the batch, their rows gathered once a key and carried to the entries
+    by sorts, the loss differentiated with respect to the rows an *entry*
+    holds, one optimizer update a distinct key, rows scattered back in place
+    into the donated tables.  No table-sized temporary and no table-sized
+    gradient; a coordinate no live entry names is neither gathered nor set
+    (the scatters' pass over their operand is XLA's, see
+    ``TOUCHED_ROWS_VISITS``).  Without an optimizer the plain SGD step above
+    runs.
 
     A model provides ``row_tables`` (the parameters that ``batch.index``
     addresses, ``[F]`` or ``[F, K]``; every other parameter is one more
     coordinate that every row holds) and ``margins_of_rows(rows, dense,
-    batch)``, its margins from the rows gathered an entry (``rows[k]`` is
-    ``params[k][batch.index]``).  ``optimizer`` is one ``FTRL`` for a model
+    batch)``, its margins from the rows an entry (``rows[k]`` is
+    ``params[k][batch.index]`` on every live lane, 0 on a dead one).
+    ``optimizer`` is one ``FTRL`` for a model
     of one table, or a rule a table: an ``FTRL`` for the first (the other
     parameters share it) and an ``AdaGrad`` for each of the rest.
 
@@ -360,15 +362,18 @@ class TouchedRowsMixin(SGDModelMixin):
     def flush_step_counters(self, wait: bool = True) -> None:
         """Add the finished steps to the counters ``sgd.steps``,
         ``sgd.touched_rows``, ``sgd.scatter_tiles`` (tiles the in-place
-        kernel wrote; 0 where XLA's scatter ran) and, under a gate,
-        ``sgd.active_rows`` (distinct keys whose gate was open) and
-        ``sgd.activated_rows`` (those whose count crossed the threshold in
-        the step).  ``wait=False`` (every ``train_step``) takes only the
-        steps the device has finished and never waits for it."""
+        kernel wrote; 0 where XLA's scatter ran), ``sgd.spread_entries``
+        (live entries whose rows reached them from the distinct keys
+        through ``spread_by_key``) and, under a gate, ``sgd.active_rows``
+        (distinct keys whose gate was open) and ``sgd.activated_rows``
+        (those whose count crossed the threshold in the step).
+        ``wait=False`` (every ``train_step``) takes only the steps the
+        device has finished and never waits for it."""
         while self._touched and (wait or self._touched[0][0].is_ready()):
-            touched, tiles, *gate = self._touched.popleft()
+            touched, tiles, spread, *gate = self._touched.popleft()
             telemetry.counter_add("sgd.touched_rows", int(touched))
             telemetry.counter_add("sgd.scatter_tiles", int(tiles))
+            telemetry.counter_add("sgd.spread_entries", int(spread))
             telemetry.counter_add("sgd.steps", 1)
             if gate:
                 telemetry.counter_add("sgd.active_rows", int(gate[0]))
@@ -376,55 +381,117 @@ class TouchedRowsMixin(SGDModelMixin):
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
     def _touched_rows_step(self, params: dict, batch) -> tuple:
-        """Under a gate the step runs in DiFacto's order: a key's occurrences
+        """Every table is read ONCE a step and once a distinct key: a first
+        visit of the distinct keys gathers each table's row and its state,
+        ``spread_by_key`` carries what the margins take an entry to the
+        entries (bit for bit what ``params[k][index]`` holds there), and the
+        second visit applies the rules to what the first read and scatters.
+
+        Under a gate the step runs in DiFacto's order: a key's occurrences
         are added to ``params["count"]`` first; the gate is read off the
-        counts and weights as the step then finds them; a gated table's row
-        enters a margin and is updated only where the gate is open, and is
-        written back bit for bit where it is shut."""
+        counts and weights as the step then finds them, a distinct key; a
+        gated table's row enters a margin and is updated only where the gate
+        is open, and is written back bit for bit where it is shut."""
         from ..ops.pallas_rows import scatter_rows
-        from ..ops.sparse import padded_row_mean, reduce_by_key
+        from ..ops.sparse import (padded_row_mean, reduce_by_key,
+                                  spread_by_key)
         names, first = self.row_tables, self.row_tables[0]
         rules = {k: self.rule_of(k) for k in params if k not in STATE_KEYS}
         gate = self.count_threshold is not None
         dense = [k for k in rules if k not in names]
+        # tables of one float a key ride the sorts beside the key; rows of K
+        # floats are gathered by rank and summed in key order
+        flat = [k for k in names if params[k].ndim == 1]
+        wide = [k for k in names if params[k].ndim > 1]
         index, live = batch.index, batch.value != 0
         entries = index.shape[0]
+        lane = jnp.arange(entries, dtype=jnp.int32)
         sorted_distinct = dict(unique_indices=True, indices_are_sorted=True)
         tiles = zero = jnp.zeros((), jnp.int32)
         gated = ()
 
-        if gate:
-            with jax.named_scope("sgd.unique"):
-                keys, (seen,), touched = reduce_by_key(
-                    index, live, (live.astype(jnp.int32),), self.num_features)
+        def padded(x):
+            """A visit's lanes with zeros up to the entry lanes after them:
+            one shape whichever candidate ran."""
+            return jnp.concatenate([x, jnp.zeros(
+                (entries - x.shape[0],) + x.shape[1:], x.dtype)])
 
+        def lanes_of(x, lanes, trip):
+            """The first ``lanes`` of what another visit ``padded``.
+            ``trip`` is 0: with it what a visit computes from arrays its
+            loop does not carry stays in its loop; without it XLA hoists
+            that out of every candidate's loop and runs all five every step
+            (the embedding gradients' gathers so: 46 ms on a v5e)."""
+            return jax.lax.dynamic_slice_in_dim(x, trip, lanes)
+
+        # the distinct keys, how the entries lie on them and, under a gate,
+        # each key's occurrences
+        with jax.named_scope("sgd.unique"):
+            keys, seen, touched, runs = reduce_by_key(
+                index, live, (live.astype(jnp.int32),) if gate else (),
+                self.num_features, runs=True)
+
+        if gate:
             def count_visit(lanes, _trip, carry):
-                count, tiles, _ = carry
+                count, tiles, _, _ = carry
                 with jax.named_scope("sgd.gather_rows"):
                     old = count.at[keys[:lanes]].get(
                         mode="fill", fill_value=0, **sorted_distinct)
                 with jax.named_scope("sgd.count"):
-                    new = old + seen[:lanes]
+                    new = old + seen[0][:lanes]
                     crossed = jnp.sum(
-                        (jnp.arange(lanes) < touched)
+                        (lane[:lanes] < touched)
                         & (old <= self.count_threshold)
                         & (new > self.count_threshold), dtype=jnp.int32)
                 with jax.named_scope("sgd.scatter_rows"):
                     (count,), wrote = scatter_rows(
                         (count,), keys[:lanes], (new,), touched)
-                return count, tiles + wrote, crossed
+                return count, tiles + wrote, crossed, padded(new)
 
-            count, tiles, crossed = visit_distinct(
-                touched, entries, (params["count"], tiles, zero), count_visit)
+            count, tiles, crossed, new_count = visit_distinct(
+                touched, entries, (params["count"], tiles, zero,
+                                   jnp.zeros(entries, jnp.int32)), count_visit)
 
-        with jax.named_scope("sgd.gather_rows"):
-            rows = {k: params[k][index] for k in names}
-            if gate:
-                seen_an_entry = count[index]
+        tables = {k: _with_state(params, rules[k], k) for k in names}
+
+        def read(lanes, trip, _held):
+            at = keys[:lanes] + trip
+            with jax.named_scope("sgd.gather_rows"):
+                return jax.tree.map(lambda t: padded(t.at[at].get(
+                    mode="fill", fill_value=0, **sorted_distinct)), tables)
+
+        # ``w`` is gathered, not taken as the closed form of ``(z, n)``: a
+        # table restored without its state holds a weight that is not
+        held = visit_distinct(touched, entries, jax.tree.map(
+            lambda t: jnp.zeros((entries,) + t.shape[1:], t.dtype), tables),
+            read)
         if gate:
             with jax.named_scope("sgd.count"):
-                gated = (live & self.active(seen_an_entry, rows[first]),)
-        # the loss differentiated with respect to the gathered rows: the
+                open_ = (lane < touched) & self.active(
+                    new_count, held[first][0])
+                opened = jnp.sum(open_, dtype=jnp.int32)
+        with jax.named_scope("sgd.unique"):
+            spread = spread_by_key(
+                runs, tuple(held[k][0] for k in flat)
+                + ((open_.astype(jnp.int32),) if gate else ())
+                + ((lane,) if wide else ()))
+        rows = dict(zip(flat, spread))
+        if wide:
+            # an entry takes its key's row by the key's rank out of the
+            # distinct keys' rows (a dead entry's lane reads past them and
+            # takes 0).  Out of a copy made HERE: what a loop hands on
+            # lives in HBM, and this gather out of HBM costs what the
+            # table's did (13.9 ms on a v5e); a fresh array XLA keeps in
+            # fast memory, as it does the gradient rows (3 ms)
+            with jax.named_scope("sgd.gather_rows"):
+                rank = jnp.where(live, spread[-1], entries)
+                rows.update({k: jnp.where(
+                    (lane < touched)[:, None], held[k][0], 0).at[rank].get(
+                        mode="fill", fill_value=0) for k in wide})
+        if gate:
+            with jax.named_scope("sgd.count"):
+                gated = (live & (spread[len(flat)] > 0),)
+        # the loss differentiated with respect to the rows an entry: the
         # gradient of the SUM over the minibatch, d(loss_r)/d(margin_r)
         # written out (``logistic_nll``'s own derivative is off by a half
         # where a margin is exactly 0, as every margin of a first step is)
@@ -439,65 +506,53 @@ class TouchedRowsMixin(SGDModelMixin):
                 per_row, slope = 0.5 * (m - batch.label) ** 2, m - batch.label
             loss = padded_row_mean(per_row, batch.weight)
             g_rows, g_dense = pull(slope * batch.weight)
-        # tables of one float a key ride the sorts beside the key (and the
-        # gate with them: a key's sum is over 0 where it is open); rows of K
-        # floats are summed in key order and gathered a distinct key
-        flat = [k for k in names if params[k].ndim == 1]
-        wide = [k for k in names if params[k].ndim > 1]
         with jax.named_scope("sgd.unique"):
-            keys, sums, touched, *wide_sums = reduce_by_key(
-                index, live, tuple(g_rows[k] for k in flat) + tuple(
-                    on.astype(jnp.float32) for on in gated),
+            _keys, sums, _touched, *wide_sums = reduce_by_key(
+                index, live, tuple(g_rows[k] for k in flat),
                 self.num_features, tuple(g_rows[k] for k in wide))
 
         def visit(lanes, trip, carry):
-            tables, tiles, opened = carry
+            tables, tiles = carry
             at, out = keys[:lanes], {}
-            if gate:
-                with jax.named_scope("sgd.count"):
-                    open_ = (sums[-1][:lanes] > 0) & (
-                        jnp.arange(lanes) < touched)
-                    opened = jnp.sum(open_, dtype=jnp.int32)
             for name in names:
                 with jax.named_scope("sgd.gather_rows"):
-                    held = tuple(t.at[at].get(mode="fill", fill_value=0.0,
-                                              **sorted_distinct)
-                                 for t in _read_by(rules[name], tables[name]))
+                    was = tuple(lanes_of(h, lanes, trip) for h in held[name])
                     if name in flat:
                         g = sums[flat.index(name)][:lanes]
                     else:
-                        # ``trip`` is 0: with it this gather, which reads
-                        # nothing the loop carries, stays in its loop;
-                        # without it XLA hoists it out of every candidate's
-                        # loop and runs them all every step (46 ms on a v5e)
                         running, ends = wide_sums[0]
                         g = running[wide.index(name)][ends[:lanes] + trip]
-                updated = _apply(rules[name], held, g)
+                # the rule takes arrays that are made, whichever candidate
+                # runs: fused into what brings them, its products and sums
+                # would contract differently from one candidate to the next
+                was, g = jax.lax.optimization_barrier((was, g))
+                updated = _apply(rules[name], _read_by(rules[name], was), g)
                 if name in self.gated_tables:
                     with jax.named_scope("sgd.count"):
+                        on = lanes_of(open_, lanes, trip)
                         updated = tuple(
-                            jnp.where(open_.reshape((-1,) + (1,) * (
+                            jnp.where(on.reshape((-1,) + (1,) * (
                                 new.ndim - 1)), new, old)
-                            for new, old in zip(updated, held))
+                            for new, old in zip(updated, was))
                 # in place either way: the tiles the keys name where the
                 # table is long against ``lanes``, XLA's scatters elsewhere
                 with jax.named_scope("sgd.scatter_rows"):
                     out[name], wrote = scatter_rows(
                         tables[name], at, updated, touched)
                 tiles = tiles + wrote
-            return out, tiles, opened
+            return out, tiles
 
-        tables = {k: _with_state(params, rules[k], k) for k in names}
-        tables, tiles, opened = visit_distinct(
-            touched, entries, (tables, tiles, zero), visit)
+        tables, tiles = visit_distinct(
+            touched, entries, (tables, tiles), visit)
         tables.update({k: _apply(rules[k], _read_by(rules[k], _with_state(
             params, rules[k], k)), g_dense[k]) for k in dense})
         out = {k: t[0] for k, t in tables.items()}
         for k, t in tables.items():
-            held, slots = _RULE_STATE[type(rules[k])]
+            under, slots = _RULE_STATE[type(rules[k])]
             for slot, value in zip(slots, t[1:]):
-                out.setdefault(held, {}).setdefault(slot, {})[k] = value
+                out.setdefault(under, {}).setdefault(slot, {})[k] = value
+        counts = (touched, tiles, jnp.sum(live, dtype=jnp.int32))
         if gate:
             out["count"] = count
-            return out, loss, (touched, tiles, opened, crossed)
-        return out, loss, (touched, tiles)
+            counts += (opened, crossed)
+        return out, loss, counts

@@ -91,6 +91,9 @@ def csr_to_dense_missing(index: jax.Array, value: jax.Array,
 
 
 # ---- reduction onto the distinct keys of a batch ----------------------------
+# (the import stays below the CSR products: a program's compile-cache key
+# holds its source lines, and theirs stay where they were)
+from typing import NamedTuple  # noqa: E402
 
 
 def _over(mask: jax.Array, like: jax.Array) -> jax.Array:
@@ -115,8 +118,40 @@ def run_sums(keys: jax.Array, *columns: jax.Array) -> tuple:
     return columns
 
 
+def run_fill(keys: jax.Array, *columns: jax.Array) -> tuple:
+    """The value on the LAST lane of every run of equal ``keys`` (runs
+    contiguous) set on all the run's lanes: :func:`run_sums`'s passes the
+    other way round, by select and not by adding zeros, so every bit
+    (``-0.0``'s sign, an int32) arrives as it was.  After the pass at
+    distance ``d`` a lane holds lane ``min(i + 2d - 1, its run's end)``."""
+    n, d = keys.shape[0], 1
+    while d < n:
+        same = jnp.concatenate([keys[:-d] == keys[d:], jnp.zeros(d, bool)])
+        columns = tuple(
+            jnp.where(same, jnp.concatenate([c[d:], jnp.zeros(d, c.dtype)]), c)
+            for c in columns)
+        d *= 2
+    return columns
+
+
+class KeyRuns(NamedTuple):
+    """How a batch's entries lie on its distinct keys, as the two sorts of
+    :func:`reduce_by_key` found it; what :func:`spread_by_key` takes back
+    the other way.  All on the entry lanes."""
+    #: the compact lanes: :func:`reduce_by_key`'s ``keys`` and ``count``
+    keys: jax.Array
+    count: jax.Array
+    #: the live entries' keys ascending, ``bound`` on the dead ones after
+    sorted_keys: jax.Array
+    #: the entry lane that each sorted lane came from
+    order: jax.Array
+    #: the sorted lane where compact lane ``j``'s run ends; after the
+    #: distinct keys, the sorted lanes that end no run (a permutation)
+    ends: jax.Array
+
+
 def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
-                  bound: int, rows: tuple = ()) -> tuple:
+                  bound: int, rows: tuple = (), runs: bool = False) -> tuple:
     """Per-entry ``columns`` summed onto the distinct ``index`` values of
     the ``live`` entries.  Sorts are this chip's cheap primitive (0.8 ms for
     655,360 lanes of key and payload on a v5e, where a gather or a scatter
@@ -138,10 +173,13 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
     ``(running, ends)`` is returned, and the rows' sums at the first
     ``lanes`` distinct keys are ``r[ends[:lanes]]`` for each ``r`` of
     ``running`` — a gather of as many rows as a visit takes, not of every
-    entry."""
+    entry.
+
+    ``runs``: the sorts carry the lanes as they do for ``rows`` and a last
+    element, the :class:`KeyRuns`, is returned for :func:`spread_by_key`."""
     n = index.shape[0]
     key = jnp.where(live, index, bound)
-    lane = (jnp.arange(n, dtype=jnp.int32),) if rows else ()
+    lane = (jnp.arange(n, dtype=jnp.int32),) if rows or runs else ()
     sk, *sc = jax.lax.sort((key, *lane, *columns), num_keys=1, is_stable=False)
     order, sc = sc[:len(lane)], sc[len(lane):]
     sums = run_sums(sk, *sc)
@@ -152,10 +190,36 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
                                num_keys=1, is_stable=False)
     at, sums = sums[:len(lane)], tuple(sums[len(lane):])
     count = jnp.sum(ends, dtype=jnp.int32)
-    if not rows:
-        return keys, sums, count
-    running = run_sums(sk, *(r[order[0]] for r in rows))
-    return keys, sums, count, (running, at[0])
+    out = (keys, sums, count)
+    if rows:
+        out += ((run_sums(sk, *(r[order[0]] for r in rows)), at[0]),)
+    if runs:
+        out += (KeyRuns(keys, count, sk, order[0], at[0]),)
+    return out
+
+
+def spread_by_key(runs: KeyRuns, columns: tuple) -> tuple:
+    """The transpose of :func:`reduce_by_key`: ``columns`` hold one value a
+    DISTINCT key on the compact lanes (``c[j]`` belongs to ``runs.keys[j]``;
+    ``[m]`` for any ``m`` that holds the distinct keys, any 32-bit dtype),
+    and every live entry gets its own key's value, bit for bit what a gather
+    of the column at the entry's rank would give; a dead entry gets 0.  No
+    gather an entry: a sort that sets each compact lane on the lane where
+    its run ends, :func:`run_fill` along the runs, and a sort by the lane
+    each entry came from.  A key's rank rides as a column too
+    (``jnp.arange``), and rows of K floats then take ONE gather by it out
+    of their compact array (a sort would pay for each of the K columns)."""
+    n = runs.order.shape[0]
+    held = jnp.arange(n, dtype=jnp.int32) < runs.count
+    columns = tuple(
+        jnp.where(held, jnp.concatenate(
+            [c, jnp.zeros(n - c.shape[0], c.dtype)]), 0) for c in columns)
+    _, *at_ends = jax.lax.sort((runs.ends, *columns), num_keys=1,
+                               is_stable=False)
+    filled = run_fill(runs.sorted_keys, *at_ends)
+    _, *spread = jax.lax.sort((runs.order, *filled), num_keys=1,
+                              is_stable=False)
+    return tuple(spread)
 
 
 # ---- sums over the rows of a CSR batch, without a scatter an entry ----------

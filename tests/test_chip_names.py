@@ -316,3 +316,70 @@ def test_touched_rows_step_updates_its_tables_in_place(
     # (``%pallas_call.n``: the elements of the kernel's tuple)
     assert set(viewed) <= {"bitcast", "pallas"}, sorted(set(viewed))
     assert viewed.count("bitcast") == 3 * len(kernel)
+    # a table is read a DISTINCT key, inside its candidate's loop of one
+    # trip or none: ``(w, z, n)`` at each candidate's lanes.  No gather
+    # outside the loops has a table for its operand: the entries' weights
+    # come from the distinct keys' through the sorts (``w[index]`` at the
+    # 655,360 entry lanes was 8.7 ms of an 18.6 ms step)
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])",
+                               text, re.M))
+    gathers = re.findall(
+        r"= f32\[(\d+)\]\S* gather\((%[\w.\-]+), .*op_name=\"([^\"]*)\"", text)
+    from_table = [(int(n), op) for n, operand, op in gathers
+                  if shape_of[operand] == f"f32[{features}]"]
+    assert sorted(n for n, _op in from_table) == sorted(visits * 3)
+    for _n, op in from_table:
+        assert "/while/body/sgd.gather_rows/" in op, op
+
+
+def test_gated_step_reads_its_tables_a_distinct_key(one_chip, quiet_cache,
+                                                    monkeypatch):
+    """The DiFacto step at the ``criteo-tb-difacto`` cell's shapes (2^26
+    buckets x ``(w, z, n)``, a count, rows of 16 floats and their AdaGrad
+    sums; 655,360 entry lanes), through the TPU's compiler: every gather out
+    of a table sits in a candidate's loop of one trip or none (none reads a
+    table an entry, as three did for 33 ms of a 76 ms step); the one gather
+    an entry lane, a key's row by its rank, reads a fresh array that the
+    compiler keeps in fast memory (``S(1)``: out of what a loop hands on, in
+    HBM, it cost the 13.9 ms the table's did); the temporaries stay under a
+    thirtieth of the tables."""
+    from dmlc_core_tpu.data.staging import PaddedBatch
+    from dmlc_core_tpu.models.common import FTRL, AdaGrad
+    from dmlc_core_tpu.models.fm import FactorizationMachine
+    from dmlc_core_tpu.ops import pallas_rows
+    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
+    rows, lanes, features, width = 16384, 655360, 2 ** 26, 16
+    model = FactorizationMachine(
+        features, width, optimizer={"w": FTRL(l1=4.0), "v": AdaGrad()},
+        threshold=16)
+    params = jax.tree.map(lambda a: on(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init, 0))
+    batch = PaddedBatch(
+        label=on(one_chip, (rows,), jnp.float32),
+        weight=on(one_chip, (rows,), jnp.float32),
+        row_ptr=on(one_chip, (rows + 1,), jnp.int32),
+        index=on(one_chip, (lanes,), jnp.int32),
+        value=on(one_chip, (lanes,), jnp.float32),
+        num_rows=on(one_chip, (), jnp.int32))
+    compiled = model._touched_rows_step.lower(model, params, batch).compile()
+    memory = compiled.memory_analysis()
+    tables = (4 + 2 * width) * 4 * features
+    assert memory.alias_size_in_bytes >= tables
+    assert memory.temp_size_in_bytes < 320 << 20
+    text = compiled.as_text()
+    made = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\]\S*)", text, re.M))
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\((%[\w.\-]+), "
+                         r".*op_name=\"([^\"]*)\"", text)
+    table_shapes = {f"f32[{features}]", f"s32[{features}]",
+                    f"f32[{features},{width}]"}
+    from_table = [op for _result, operand, op in gathers
+                  if made[operand].split("{")[0] in table_shapes]
+    # the count; (w, z, n) and (v, N): six reads a candidate, five of them
+    assert len(from_table) == 6 * 5
+    for op in from_table:
+        assert "/while/body/sgd.gather_rows/" in op, op
+    by_rank = [made[operand] for result, operand, op in gathers
+               if op == "jit(_touched_rows_step)/sgd.gather_rows/gather"]
+    assert len(by_rank) == 1 and by_rank[0].startswith(
+        f"f32[{lanes},{width}]") and "S(1)" in by_rank[0], by_rank

@@ -543,3 +543,102 @@ def test_a_step_taken_twice_is_a_count_mismatch(tmp_path):
     got = {c["name"]: c["value"] for c in generator.check(state, reference)}
     assert got["live_count_mismatch"] > 0 and got["count_mismatch"] == 0
     generator.teardown(state)
+
+
+# (f) the spread ---------------------------------------------------------------
+
+
+def plain_spread(features: int, calls: list):
+    """``spread_by_key`` as the plain gather it replaces: a table rebuilt
+    from the compact columns, read at every entry's key."""
+    def spread(runs, columns):
+        calls.append(len(columns))
+        n = runs.order.shape[0]
+        key = jnp.zeros(n, jnp.int32).at[runs.order].set(runs.sorted_keys)
+        return tuple(jnp.where(key < features, jnp.zeros(
+            features + 1, c.dtype).at[runs.keys[:c.shape[0]]].set(
+                c, mode="drop")[key], 0) for c in columns)
+    return spread
+
+
+@pytest.mark.parametrize("lanes", common.TOUCHED_ROWS_VISITS + (655360,))
+def test_rows_of_16_floats_reach_their_entries_by_the_rank_the_spread_carries(
+        lanes):
+    """At every candidate of ``TOUCHED_ROWS_VISITS``: a key's rank among
+    the distinct keys rides the spread as one more column, and an entry's
+    row, gathered by it out of the candidate's compact ``[lanes, 16]``
+    rows, is ``table[index]`` bit for bit on every live lane."""
+    from dmlc_core_tpu.ops.sparse import spread_by_key
+    entries, features, width = 655360, 1 << 20, 16
+    rng = np.random.default_rng(lanes)
+    distinct = min(lanes - 1, entries * 5 // 6)
+    ids = rng.choice(features, size=distinct, replace=False)
+    pick = rng.integers(0, distinct // 3, entries)
+    pick[:distinct] = np.arange(distinct)
+    live = rng.random(entries) < 0.9
+    live[:distinct] = True
+    order = rng.permutation(entries)
+    index, live = ids[pick][order].astype(np.int32), live[order]
+
+    @jax.jit
+    def both(index, live, seed):
+        table = jax.random.normal(seed, (features, width), jnp.float32)
+        keys, _, count, runs = reduce_by_key(index, live, (), features,
+                                             runs=True)
+        compact = table.at[keys[:lanes]].get(
+            mode="fill", fill_value=0, unique_indices=True,
+            indices_are_sorted=True)
+        (rank,) = spread_by_key(runs, (jnp.arange(entries, dtype=jnp.int32),))
+        got = compact.at[jnp.where(live, rank, entries)].get(
+            mode="fill", fill_value=0)
+        return count, rank, got, table[index]
+    count, rank, got, want = both(index, live, jax.random.PRNGKey(lanes))
+    assert int(count) == distinct
+    assert np.array_equal(np.asarray(rank)[live], np.searchsorted(
+        np.unique(index[live]), index[live]))
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(got[live].view(np.uint32),
+                          want[live].view(np.uint32))
+    assert not got[~live].any() and (~live).sum() > 1000
+
+
+def test_three_steps_with_the_spread_patched_to_the_plain_gather(monkeypatch):
+    """``w``, the gate and the rank ride the spread; with a gather an entry
+    in its place the same program leaves the same tables bit for bit, the
+    counts and the shut rows among them."""
+    from dmlc_core_tpu.ops import sparse
+    batches = drawn(10)[:3]
+    m, through, losses = follow(batches, model.__wrapped__())
+    calls = []
+    monkeypatch.setattr(sparse, "spread_by_key",
+                        plain_spread(FEATURES, calls))
+    _m, plain, again = follow(batches, model.__wrapped__())
+    assert calls and set(calls) == {3} and losses == again
+    assert np.any(np.asarray(through["adagrad"]["n"]["v"]) != 0)
+    for a, b in zip(jax.tree.leaves(through), jax.tree.leaves(plain)):
+        assert np.array_equal(np.asarray(a).view(np.uint32),
+                              np.asarray(b).view(np.uint32))
+
+
+def test_a_restored_w_without_its_state_enters_the_first_steps_margins():
+    """A scorer's snapshot holds ``w``, ``v``, ``b`` and the counts, not the
+    rules' state: training on from it, the first step's margins (and its
+    gate, ``w != 0``) read the restored table a distinct key."""
+    m = model.__wrapped__()
+    batch = drawn(4)[0]
+    params = m.init(3)
+    rng = np.random.default_rng(5)
+    params["w"] = jnp.asarray(rng.normal(size=FEATURES), jnp.float32)
+    params["count"] = jnp.full(FEATURES, SIZES["threshold"], jnp.int32)
+    after = dict(params, count=params["count"] + jnp.asarray(np.bincount(
+        batch["index"][batch["value"] != 0], minlength=FEATURES), jnp.int32))
+    want = float(m.loss(after, padded(batch)))
+    before = telemetry.snapshot()
+    _m, got, (loss,) = follow([batch], m, params)
+    m.flush_step_counters()
+    delta = telemetry.counters_delta(before, telemetry.snapshot())
+    assert loss == pytest.approx(want, rel=1e-6)
+    assert abs(loss - np.log(2)) > 0.05
+    # every touched key's gate is open: its count passed, its weight is set
+    assert delta["sgd.active_rows"] == delta["sgd.touched_rows"] > 10
+    assert delta["sgd.spread_entries"] == int(np.sum(batch["value"] != 0))

@@ -420,3 +420,135 @@ def test_csr_row_sums_is_segment_sum_forward_and_backward(seed):
     grad = jax.grad(lambda c: jnp.sum(csr_row_sums(c, row_id, row_ptr) * ct))(
         contrib)
     assert np.array_equal(np.asarray(grad)[:real], spread[:real])
+
+
+# ---- the spread: a distinct key's values carried to its entries -------------
+
+
+def lanes_that_name(rng, distinct: int, lanes: int, features: int):
+    """``lanes`` entry lanes whose live ones name exactly ``distinct`` keys
+    of ``features``: most keys on several entries, some on one, dead lanes
+    in between (their index names a key that is live elsewhere)."""
+    ids = rng.choice(features, size=distinct, replace=False)
+    live = rng.random(lanes) < 0.9
+    live[:distinct] = True
+    pick = rng.integers(0, max(distinct // 2, 1), lanes)
+    pick[:distinct] = np.arange(distinct)
+    order = rng.permutation(lanes)
+    return ids[pick][order].astype(np.int32), live[order]
+
+
+def plain_spread(features: int, calls: list):
+    """``spread_by_key`` as the plain gather it replaces: a table rebuilt
+    from the compact columns, read at every entry's key."""
+    def spread(runs, columns):
+        calls.append(len(columns))
+        n = runs.order.shape[0]
+        key = jnp.zeros(n, jnp.int32).at[runs.order].set(runs.sorted_keys)
+        return tuple(jnp.where(key < features, jnp.zeros(
+            features + 1, c.dtype).at[runs.keys[:c.shape[0]]].set(
+                c, mode="drop")[key], 0) for c in columns)
+    return spread
+
+
+def candidates():
+    from dmlc_core_tpu.models.common import TOUCHED_ROWS_VISITS
+    return TOUCHED_ROWS_VISITS + (655360,)
+
+
+@pytest.mark.parametrize("lanes", candidates())
+def test_spread_by_key_is_the_gather_of_the_table_bit_for_bit(lanes):
+    """At every candidate of ``TOUCHED_ROWS_VISITS`` (and the entry lanes
+    after them), with as many distinct keys as only that candidate holds:
+    a float32 table (``-0.0`` among its values) and an int32 one, read at
+    the candidate's lanes of distinct keys and spread, are ``table[index]``
+    on every live lane and 0 on every dead one."""
+    from dmlc_core_tpu.ops.sparse import spread_by_key
+    entries, features = 655360, 1 << 20
+    rng = np.random.default_rng(lanes)
+    distinct = min(lanes - 3, entries * 5 // 6)
+    index, live = lanes_that_name(rng, distinct, entries, features)
+    table = rng.normal(size=features).astype(np.float32)
+    table[rng.random(features) < 0.2] = -0.0
+    counts = rng.integers(-5, 1 << 30, features).astype(np.int32)
+
+    @jax.jit
+    def both(index, live, table, counts):
+        keys, _, count, runs = reduce_by_key(index, live, (), features,
+                                             runs=True)
+        read = dict(mode="fill", fill_value=0, unique_indices=True,
+                    indices_are_sorted=True)
+        return count, spread_by_key(runs, (
+            table.at[keys[:lanes]].get(**read),
+            counts.at[keys[:lanes]].get(**read))), (table[index],
+                                                    counts[index])
+    count, got, want = both(index, live, table, counts)
+    assert int(count) == distinct == len(np.unique(index[live]))
+    on_one = np.bincount(index[live], minlength=features)
+    assert (on_one == 1).sum() > 100 and on_one.max() > 3
+    assert np.signbit(np.asarray(want[0])[live]
+                      [np.asarray(want[0])[live] == 0]).any()
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == (entries,)
+        assert np.array_equal(g[live].view(np.uint32),
+                              w[live].view(np.uint32))
+        assert not g[~live].any() and (~live).sum() > 1000
+
+
+def test_run_fill_carries_a_runs_last_lane_by_select():
+    from dmlc_core_tpu.ops.sparse import run_fill
+    keys = jnp.asarray([3, 3, 3, 3, 3, 7, 9, 9, 9, 9, 9, 9, 9, 12, 12])
+    x = jnp.asarray([1, 2, 3, 4, -0.0, 5, 6, 6, 6, 6, 6, 6, 7, 8, np.inf],
+                    jnp.float32)
+    i = jnp.arange(15, dtype=jnp.int32)
+    fx, fi = run_fill(keys, x, i)
+    assert np.array_equal(np.asarray(fi), [4] * 5 + [5] + [12] * 7 + [14] * 2)
+    assert np.array_equal(np.asarray(fx).view(np.uint32),
+                          np.asarray(x)[np.asarray(fi)].view(np.uint32))
+
+
+def test_three_steps_with_the_spread_patched_to_the_plain_gather(monkeypatch):
+    """The rest of the step is the same program, so the tables after three
+    steps are the same bit for bit whether the entries' rows came through
+    the sorts or through a gather an entry."""
+    from dmlc_core_tpu.ops import sparse
+    batches = drawn(12, steps=3)
+    _m, through, losses = follow(batches)
+    calls = []
+    monkeypatch.setattr(sparse, "spread_by_key",
+                        plain_spread(FEATURES, calls))
+    _m, plain, again = follow(batches, model())
+    assert calls and set(calls) == {1}      # a trace a shape: ``w`` rides
+    assert losses == again and losses[0] != losses[2]
+    for a, b in zip(jax.tree.leaves(through), jax.tree.leaves(plain)):
+        assert np.array_equal(np.asarray(a).view(np.uint32),
+                              np.asarray(b).view(np.uint32))
+
+
+def test_a_restored_w_without_its_state_enters_the_first_steps_margins():
+    """A snapshot leaves the state out: the restored ``w`` is not the
+    closed form of its zero ``(z, n)``, and the step's margins read the
+    TABLE, a distinct key."""
+    m = model()
+    batch = drawn(21, steps=1)[0]
+    params = m.init()
+    rng = np.random.default_rng(2)
+    w = rng.normal(size=FEATURES).astype(np.float32)
+    params["w"] = jnp.asarray(w)
+    want = float(m.loss(params, padded(batch)))
+    _m, _p, (loss,) = follow([batch], m, params)
+    assert loss == pytest.approx(want, rel=1e-6)
+    assert abs(loss - np.log(2)) > 0.05      # what a zero table would read
+
+
+def test_spread_entries_counts_the_live_entries():
+    batches = drawn(14, steps=3)
+    model().flush_step_counters()       # another test's steps in flight
+    before = telemetry.snapshot()
+    m, _p, _l = follow(batches)
+    m.flush_step_counters()
+    delta = telemetry.counters_delta(before, telemetry.snapshot())
+    live = sum(int(np.sum(b["value"] != 0)) for b in batches)
+    assert delta["sgd.spread_entries"] == live > 100
+    assert delta["sgd.steps"] == 3
