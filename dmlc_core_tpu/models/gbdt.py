@@ -568,7 +568,7 @@ class GBDT:
                  base_score=None,
                  scale_pos_weight: float = 1.0,
                  histogram: str = "auto",
-                 histogram_mesh=None):
+                 histogram_mesh=None, grow_policy="depthwise", max_leaves=0):
         if objective not in ("logistic", "squared", "softmax",
                              "rank:pairwise"):
             raise ValueError(f"unknown objective '{objective}'")
@@ -2590,3 +2590,30 @@ def _split_child_sums(dirs, split_f, split_b, split_d) -> jax.Array:
             else jnp.where((split_d == 1)[:, None], left[1], left[0]))
     total = jnp.stack([pick(a, last) for a in dirs[0]], axis=-1)
     return jnp.stack([left, total - left], axis=1).reshape(-1, 2)
+
+
+# ---- growth policy ----------------------------------------------------------
+# ``GBDT(..., grow_policy="lossguide", max_leaves=L)`` constructs
+# `gbdt_leafwise.LeafwiseGBDT`, the best-first builder and its pointer forest
+# (XGBoost's names; "depthwise" is this file's level-by-level builder and the
+# default).  ``__init__`` takes the two names and does nothing with them: they
+# are checked here, where the class is chosen.  Below everything else for the
+# reason given above `_shard_inputs`.
+
+
+def _new_by_grow_policy(cls, *args, grow_policy: str = "depthwise",
+                        max_leaves: int = 0, **kwargs):
+    if grow_policy not in ("depthwise", "lossguide"):
+        raise ValueError("grow_policy must be 'depthwise' or 'lossguide'")
+    if grow_policy == "depthwise" and max_leaves:
+        raise ValueError("max_leaves belongs to grow_policy='lossguide': a "
+                         "depth-wise tree holds 2 ** max_depth leaves")
+    if cls is GBDT and grow_policy == "lossguide":
+        from .gbdt_leafwise import LeafwiseGBDT
+        cls = LeafwiseGBDT
+    return object.__new__(cls)
+
+
+GBDT.grow_policy = "depthwise"
+GBDT.max_leaves = 0
+GBDT.__new__ = staticmethod(_new_by_grow_policy)

@@ -383,3 +383,49 @@ def test_gated_step_reads_its_tables_a_distinct_key(one_chip, quiet_cache,
                if op == "jit(_touched_rows_step)/sgd.gather_rows/gather"]
     assert len(by_rank) == 1 and by_rank[0].startswith(
         f"f32[{lanes},{width}]") and "S(1)" in by_rank[0], by_rank
+
+
+# the Epsilon cell's tree program (benchmark/configs/epsilon-lgbm.json: 2,000
+# features x 255 bins, 255 leaves best-first) at a fifth of its rows: past a
+# chunk, so every static size of a segment's work and the loop of whole chunks
+# are in it
+def test_leafwise_tree_program_compiles_at_the_cells_width(
+        one_chip, quiet_cache, monkeypatch):
+    """The leaf-wise builder's program through the TPU's compiler at 2,000
+    features: the dense kernel under its name, at one node column, in every
+    branch of the ladder and under the scope the cell's metrics read; the
+    other scopes there too; and the pool of leaf histograms (1.04 GB) is
+    never copied — its parent's read is fenced before the children's writes
+    (the first version copied it at every expansion)."""
+    from dmlc_core_tpu.models import gbdt_leafwise
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+    rows, features, leaves = 80_000, 2000, 255
+    model = GBDT(num_features=features, num_trees=2, num_bins=255,
+                 learning_rate=0.1, lambda_=0.0, min_child_weight=100.0,
+                 grow_policy="lossguide", max_leaves=leaves, max_depth=0,
+                 histogram="pallas")
+    compiled = model._grow_tree.lower(
+        model, on(one_chip, (rows, features // 4), jnp.int32),
+        on(one_chip, (rows,), jnp.float32), on(one_chip, (rows,), jnp.float32),
+        on(one_chip, (features,), jnp.bool_)).compile()
+    names = op_names(compiled)
+    for scope in ("gbdt.leafwise.hist", "gbdt.leafwise.partition",
+                  "gbdt.leafwise.split", "gbdt.leafwise.pick",
+                  "gbdt.leafwise.subtract", "ops.hist_layout"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    kernels = [n for n in instructions(compiled)
+               if n.startswith("%" + pallas_segment.DENSE_HIST_KERNEL)]
+    sizes = gbdt_leafwise._segment_sizes(rows, gbdt_leafwise._SEGMENT_CHUNK)
+    assert sizes == [1024, 2048, 4096, 8192, 16384, 32768, 65536]
+    # an expansion's ladder, each size once, and its loop of whole chunks;
+    # the root's rows are static: its one whole chunk and the rest's size
+    assert len(kernels) == len(sizes) + 3
+    kernel_ops = [n for n in names if n.endswith("/pallas_call")]
+    assert kernel_ops and all("/gbdt.leafwise.hist/" in n for n in kernel_ops)
+    text = compiled.as_text()
+    pool = rf"f32\[{leaves},{features},255,2\]"
+    assert re.search(pool, text)
+    assert not re.search(pool + r"\S* copy\(", text)
+    # the pool 1.04 GB; a chunk's rows gathered, unpacked and relaid for the
+    # kernel, 0.52 GB a copy; the kernel's output 0.1: 3.3 GB in all
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

@@ -62,6 +62,13 @@ def programs() -> dict:
         model, None, layout, jnp.zeros(64), jnp.zeros(64), jnp.ones(4, bool),
         jax.random.PRNGKey(0))
 
+    # the leaf-wise builder's tree program (`models/gbdt_leafwise.py`)
+    best = GBDT(num_features=4, num_trees=1, num_bins=16, histogram="pallas",
+                grow_policy="lossguide", max_leaves=4)
+    leafwise = best._grow_tree.lower(
+        best, jnp.zeros((64, 1), jnp.int32), jnp.zeros(64), jnp.zeros(64),
+        jnp.ones(4, bool))
+
     rows, fields, features = 8, 3, 32
     ffm = FieldAwareFactorizationMachine(num_features=features,
                                          num_fields=fields)
@@ -90,6 +97,7 @@ def programs() -> dict:
             jnp.zeros(plan.num_shards * 4))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
             "sparse_tree": paths_of(sparse_tree),
+            "leafwise": paths_of(leafwise),
             "step": paths_of(step), "touched": paths_of(touched),
             "tables": paths_of(tables),
             "reduce": paths_of(reduce)}
@@ -104,6 +112,9 @@ TOUCHED_TABLES = {"fm.margins", "sgd.adagrad", "sgd.count"}
 # scopes that only one of the two tree programs opens
 DENSE_ONLY = {"gbdt.cast"}
 SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
+# scopes of the leaf-wise builder's program, `jit(_grow_tree)`
+LEAFWISE = {"gbdt.leafwise.hist", "gbdt.leafwise.partition",
+            "gbdt.leafwise.split", "gbdt.leafwise.pick"}
 
 
 def carries(paths: set, scope: str, under: str = "") -> bool:
@@ -116,6 +127,10 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
     where = {"gbdt": "fit", "ops": "fit", "batch": "step", "ffm": "step",
              "sgd": "step", "mesh": "reduce", "linear": "touched",
              "fm": "tables"}[scope.split(".")[0]]
+    if scope in LEAFWISE:
+        assert carries(programs["leafwise"], scope, under="jit(_grow_tree)")
+        assert not carries(programs["tree"], scope)
+        return
     if scope in SPARSE_ONLY:
         where = "sparse_tree"
     if scope in TOUCHED_ROWS:
