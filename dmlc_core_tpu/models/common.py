@@ -157,6 +157,7 @@ class SGDModelMixin:
 # lines, and the plain SGD step keeps the lines it had (PERF.md section 6).
 import collections  # noqa: E402
 import dataclasses  # noqa: E402
+import math  # noqa: E402
 
 #: lanes of sorted distinct keys the step's visit of its tables may take,
 #: and the batch's own lanes after these.  The step's cost does not follow
@@ -165,6 +166,12 @@ import dataclasses  # noqa: E402
 #: are visited ONCE, and what follows their number is the lanes the gathers
 #: and scatters carry (PERF.md section 5 has the step's time by distinct keys)
 TOUCHED_ROWS_VISITS = (1 << 16, 1 << 17, 1 << 18, 1 << 19)
+#: distinct keys ``_wide_rows_step`` reads, updates and writes at a time (a
+#: loop of as many trips as the batch's keys need, one at Criteo's 50,800),
+#: and the lanes of the windows it sums a row's gradients along inside a
+#: key's run before the windows themselves (``ops.sparse.window_totals``)
+WIDE_ROWS_CHUNK = 1 << 16
+RUN_WINDOW = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -219,12 +226,28 @@ class AdaGrad:
         return v - self.alpha * g / (self.beta + jnp.sqrt(n_new)), n_new
 
 
+@dataclasses.dataclass(frozen=True)
+class SGD:
+    """Plain minibatch SGD, ``p -= learning_rate * g``, with ``g`` the
+    gradient of the weighted MEAN loss (``SGDModelMixin._train_step``'s; the
+    other rules take the minibatch's sum).  A coordinate keeps nothing, and
+    one no live entry names has ``g = 0``: the rule is the dense step without
+    a penalty, on the rows a batch names."""
+    learning_rate: float = 0.05
+
+    def apply(self, p: jax.Array, g: jax.Array) -> tuple:
+        """One update of coordinates ``p`` with gradient ``g``: the new
+        ``(p,)``."""
+        return (p - self.learning_rate * g,)
+
+
 #: where each rule's state lives in the parameters, its slots in the order
 #: ``apply`` takes and returns them after the weight
-_RULE_STATE = {FTRL: ("ftrl", ("z", "n")), AdaGrad: ("adagrad", ("n",))}
+_RULE_STATE = {FTRL: ("ftrl", ("z", "n")), AdaGrad: ("adagrad", ("n",)),
+               SGD: ("sgd", ())}
 #: what rides beside the parameters: the rules' state (training's alone: a
 #: snapshot leaves it out) and the count table (which scoring's gate reads)
-RULE_STATE_KEYS = tuple(held for held, _slots in _RULE_STATE.values())
+RULE_STATE_KEYS = tuple(held for held, slots in _RULE_STATE.values() if slots)
 STATE_KEYS = RULE_STATE_KEYS + ("count",)
 
 
@@ -233,6 +256,17 @@ def _with_state(params: dict, rule, name: str) -> tuple:
     *state)``, the order ``apply`` returns them in."""
     held, slots = _RULE_STATE[type(rule)]
     return (params[name],) + tuple(params[held][slot][name] for slot in slots)
+
+
+def _params_of(tables: dict, rules: dict) -> dict:
+    """The parameters from each one's ``(weight, *state)``: the weights at
+    the top, each rule's state where ``_RULE_STATE`` keeps it."""
+    out = {k: t[0] for k, t in tables.items()}
+    for k, t in tables.items():
+        under, slots = _RULE_STATE[type(rules[k])]
+        for slot, value in zip(slots, t[1:]):
+            out.setdefault(under, {}).setdefault(slot, {})[k] = value
+    return out
 
 
 def _read_by(rule, table: tuple) -> tuple:
@@ -246,6 +280,9 @@ def _apply(rule, held: tuple, g: jax.Array) -> tuple:
     *state)``."""
     if isinstance(rule, AdaGrad):
         with jax.named_scope("sgd.adagrad"):
+            return rule.apply(*held, g)
+    if isinstance(rule, SGD):
+        with jax.named_scope("sgd.update"):
             return rule.apply(*held, g)
     with jax.named_scope("sgd.ftrl"):
         return rule.apply(*held, g)
@@ -284,7 +321,13 @@ class TouchedRowsMixin(SGDModelMixin):
     ``params[k][batch.index]`` on every live lane, 0 on a dead one).
     ``optimizer`` is one ``FTRL`` for a model
     of one table, or a rule a table: an ``FTRL`` for the first (the other
-    parameters share it) and an ``AdaGrad`` for each of the rest.
+    parameters share it) and an ``AdaGrad`` for each of the rest; or one
+    ``SGD`` for every parameter, which keeps no state: the parameters stay
+    as ``init`` made them.
+
+    A table whose rows have a shape of their own (``[F, A, K]``: the
+    field-aware machine's) takes the same walk in ``_wide_rows_step``,
+    which is laid out for rows that no fast memory holds an entry.
 
     ``gated_tables`` and ``count_threshold`` are DiFacto's gate: a gated
     table's row exists only for a key seen more than ``count_threshold``
@@ -307,7 +350,7 @@ class TouchedRowsMixin(SGDModelMixin):
 
     def _set_optimizer(self, optimizer) -> None:
         names = self.row_tables
-        fits = optimizer is None or (
+        fits = optimizer is None or isinstance(optimizer, SGD) or (
             len(names) == 1 if isinstance(optimizer, FTRL) else
             isinstance(optimizer, dict) and set(optimizer) == set(names)
             and isinstance(optimizer[names[0]], FTRL)
@@ -343,6 +386,45 @@ class TouchedRowsMixin(SGDModelMixin):
             out["count"] = jnp.zeros(self.num_features, jnp.int32)
         return out
 
+    def lay_entries(self, batch):
+        """The batch as ``_wide_rows_step`` walks it: anything that holds
+        ``index``, ``value`` (0 on a dead lane), ``label`` and ``weight``;
+        it is what ``margins_of_rows`` is handed there.  As staged, unless a
+        model's margins want the entries in another order than the rows'."""
+        return batch
+
+    def _loss_and_slope(self, m: jax.Array, batch) -> tuple:
+        """``(loss, cotangent)`` from the margins: the weighted mean loss
+        of the batch, and what the rows' gradients are pulled from —
+        ``d(loss_r)/d(margin_r)`` written out (``logistic_nll``'s own
+        derivative is off by a half where a margin is exactly 0, as every
+        margin of a first step is) times the rows' weights: the gradient of
+        the SUM over the minibatch, which is FTRL's and DiFacto's
+        convention; under ``SGD`` of the weighted MEAN, as
+        ``_train_step``'s autodiff has it."""
+        from ..ops.sparse import padded_row_mean
+        mean = isinstance(self.optimizer, SGD)
+        if self.objective == "logistic":
+            per_row = logistic_nll(m, batch.label)
+            if mean:
+                # sigmoid(m) as a quotient of two vectors: a v5e takes
+                # ``1 / x`` (``jax.nn.sigmoid`` is one) by an estimate that
+                # reads 1e-6 high, and a gradient summed from such slopes
+                # comes out 2e-6 long on every leaf, where the dense step's
+                # and the plain reference's lie 1e-7 apart
+                e = jnp.exp(-jnp.abs(m))
+                slope = (jnp.where(m >= 0, 1.0, e) / (1.0 + e)
+                         - jnp.where(batch.label > 0.5, 1., 0.))
+            else:
+                slope = jax.nn.sigmoid(m) - jnp.where(batch.label > 0.5, 1., 0.)
+        else:
+            per_row, slope = 0.5 * (m - batch.label) ** 2, m - batch.label
+        loss = padded_row_mean(per_row, batch.weight)
+        if mean:
+            return loss, slope * (batch.weight * (
+                1.0 / jnp.maximum(jnp.sum(batch.weight), 1.0)))
+        return loss, slope * batch.weight
+
     def active(self, count: jax.Array, weight: jax.Array) -> jax.Array:
         """The gate from a key's count and its first table's weight, each
         gathered wherever the caller needs the gate (an entry, a key)."""
@@ -353,8 +435,11 @@ class TouchedRowsMixin(SGDModelMixin):
         the batch before its update.  ``params`` is donated."""
         if self.optimizer is None:
             return super().train_step(params, batch)
+        wide = any(params[k].ndim > 2 for k in self.row_tables)
         with telemetry.span("sgd.step"):
-            new_params, loss, counts = self._touched_rows_step(params, batch)
+            new_params, loss, counts = (
+                self._wide_rows_step if wide else self._touched_rows_step)(
+                    params, batch)
         self._touched.append(counts)
         self.flush_step_counters(wait=False)
         return new_params, loss
@@ -393,8 +478,7 @@ class TouchedRowsMixin(SGDModelMixin):
         gated table's row enters a margin and is updated only where the gate
         is open, and is written back bit for bit where it is shut."""
         from ..ops.pallas_rows import scatter_rows
-        from ..ops.sparse import (padded_row_mean, reduce_by_key,
-                                  spread_by_key)
+        from ..ops.sparse import reduce_by_key, spread_by_key
         names, first = self.row_tables, self.row_tables[0]
         rules = {k: self.rule_of(k) for k in params if k not in STATE_KEYS}
         gate = self.count_threshold is not None
@@ -491,21 +575,13 @@ class TouchedRowsMixin(SGDModelMixin):
         if gate:
             with jax.named_scope("sgd.count"):
                 gated = (live & (spread[len(flat)] > 0),)
-        # the loss differentiated with respect to the rows an entry: the
-        # gradient of the SUM over the minibatch, d(loss_r)/d(margin_r)
-        # written out (``logistic_nll``'s own derivative is off by a half
-        # where a margin is exactly 0, as every margin of a first step is)
+        # the loss differentiated with respect to the rows an entry
         with jax.named_scope("sgd.loss"):
             m, pull = jax.vjp(
                 lambda r, d: self.margins_of_rows(r, d, batch, *gated),
                 rows, {k: params[k] for k in dense})
-            if self.objective == "logistic":
-                per_row = logistic_nll(m, batch.label)
-                slope = jax.nn.sigmoid(m) - jnp.where(batch.label > 0.5, 1., 0.)
-            else:
-                per_row, slope = 0.5 * (m - batch.label) ** 2, m - batch.label
-            loss = padded_row_mean(per_row, batch.weight)
-            g_rows, g_dense = pull(slope * batch.weight)
+            loss, slope = self._loss_and_slope(m, batch)
+            g_rows, g_dense = pull(slope)
         with jax.named_scope("sgd.unique"):
             _keys, sums, _touched, *wide_sums = reduce_by_key(
                 index, live, tuple(g_rows[k] for k in flat),
@@ -546,13 +622,165 @@ class TouchedRowsMixin(SGDModelMixin):
             touched, entries, (tables, tiles), visit)
         tables.update({k: _apply(rules[k], _read_by(rules[k], _with_state(
             params, rules[k], k)), g_dense[k]) for k in dense})
-        out = {k: t[0] for k, t in tables.items()}
-        for k, t in tables.items():
-            under, slots = _RULE_STATE[type(rules[k])]
-            for slot, value in zip(slots, t[1:]):
-                out.setdefault(under, {}).setdefault(slot, {})[k] = value
+        out = _params_of(tables, rules)
         counts = (touched, tiles, jnp.sum(live, dtype=jnp.int32))
         if gate:
             out["count"] = count
             counts += (opened, crossed)
         return out, loss, counts
+
+    @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    def _wide_rows_step(self, params: dict, batch) -> tuple:
+        """``_touched_rows_step``'s walk for tables whose rows have a shape
+        of their own (``[F, A, K]``; a row is its ``A * K`` floats of a 2-D
+        array wherever it is off its table) and are so wide that nothing an
+        entry holds of them stays in fast memory (156 floats at Criteo's
+        field-aware shapes: 409 MB an array).  The same steps — the distinct
+        keys, every table read once a key, rows carried to the entries by
+        rank, the loss differentiated with respect to the rows an entry, the
+        gradients summed along the keys' runs, one rule a key, rows scattered
+        in place — laid out for such rows and for a program that is fetched
+        in a second (a sort of the entry lanes is 2-3 MB of it):
+
+        - five sorts of the entry lanes, not eleven: the model's own order
+          (``lay_entries``), the keys with their lanes, the distinct keys
+          with the lanes their runs start on, each entry's rank brought back
+          (the rank a running count of run starts), and the windows' lanes.
+          Nothing is gathered one float an entry lane (5 ms each on a v5e,
+          where a sort of the lanes takes 0.8);
+        - every table's row lies side by side in ONE array, ``w``'s float
+          beside the 156 of ``v``: one gather by rank takes it to the
+          entries, one by the keys' sort brings its gradient back;
+        - the keys are visited ``WIDE_ROWS_CHUNK`` at a time in ONE loop body
+          of as many trips as they need (a trip reads the chunk's rows, hands
+          them to its entries out of its own lanes, which fast memory holds,
+          and keeps them for the update), where ``visit_distinct`` compiles a
+          body a candidate size;
+        - the gradient rows are summed at two levels
+          (``ops.sparse.window_totals``): four passes over every entry lane,
+          then the windows of a chunk's runs.
+
+        No gate: ``count_threshold`` is for ``_touched_rows_step``."""
+        from ..ops.pallas_rows import scatter_rows
+        from ..ops.sparse import run_sums, run_windows, window_totals
+        if self.count_threshold is not None:
+            raise ValueError("a count gate runs on _touched_rows_step")
+        batch = self.lay_entries(batch)
+        names = self.row_tables
+        rules = {k: self.rule_of(k) for k in params if k not in STATE_KEYS}
+        dense = [k for k in rules if k not in names]
+        index, live = batch.index, batch.value != 0
+        entries, bound = index.shape[0], self.num_features
+        chunk = min(WIDE_ROWS_CHUNK, entries)
+        padded = -(-entries // chunk) * chunk
+        windows = min(entries, chunk + entries // RUN_WINDOW)
+        lane = jnp.arange(entries, dtype=jnp.int32)
+        sorted_distinct = dict(mode="fill", unique_indices=True,
+                               indices_are_sorted=True)
+        tables = {k: _with_state(params, rules[k], k) for k in names}
+
+        def row_shape(t):
+            return (math.prod(t.shape[1:]),) * (t.ndim > 1)
+
+        with jax.named_scope("sgd.unique"):
+            sk, order = jax.lax.sort((jnp.where(live, index, bound), lane),
+                                     num_keys=1, is_stable=False)
+            alive = sk < bound
+            start = alive & jnp.concatenate(
+                [jnp.ones(1, bool), sk[1:] != sk[:-1]])
+            touched = jnp.sum(start, dtype=jnp.int32)
+            spread = jnp.sum(alive, dtype=jnp.int32)
+            # a sorted lane's key's rank among the distinct keys
+            run = jnp.cumsum(start.astype(jnp.int32)) - 1
+            # the distinct keys, ascending, with the lane each one's run
+            # starts on; ids past the table after them, ascending too
+            keys, first = jax.lax.sort(
+                (jnp.where(start, sk, bound + lane), lane), num_keys=1,
+                is_stable=False)
+            keys = jnp.concatenate([keys, bound + entries + jnp.arange(
+                padded - entries, dtype=jnp.int32)])
+            first = jnp.concatenate([
+                jnp.where(lane < touched, first, spread),
+                jnp.full(padded + 1 - entries, spread, jnp.int32)])
+            # each entry's rank on its own lane (a dead entry's: past every
+            # chunk)
+            _, rank = jax.lax.sort((order, jnp.where(alive, run, padded)),
+                                   num_keys=1, is_stable=False)
+
+        # every table's row side by side: ONE array goes to the entries and
+        # one comes back (``w``'s float rides beside the 156 of ``v``)
+        floats = [math.prod(params[k].shape[1:]) for k in names]
+        cuts = [sum(floats[:i]) for i in range(1, len(names))]
+
+        def unpack(packed):
+            return {k: part.reshape(part.shape[:1] + row_shape(params[k]))
+                    for k, part in zip(names, jnp.split(packed, cuts, axis=1))}
+
+        def read(c, carry):
+            held, rows = carry
+            with jax.named_scope("sgd.gather_rows"):
+                at = jax.lax.dynamic_slice_in_dim(keys, c * chunk, chunk)
+                got = {k: tuple(
+                    t.at[at].get(fill_value=0, **sorted_distinct).reshape(
+                        (chunk,) + row_shape(t)) for t in tables[k])
+                       for k in names}
+                held = jax.tree.map(
+                    lambda h, g: jax.lax.dynamic_update_slice_in_dim(
+                        h, g, c * chunk, 0), held, got)
+                # out of the chunk's own lanes: a fresh array that fast
+                # memory holds (6 ms for 655,360 rows of 156 floats on a
+                # v5e; 101 out of an array of as many lanes as entries)
+                local = rank - c * chunk
+                mine = (local >= 0) & (local < chunk)
+                taken = jnp.concatenate(
+                    [got[k][0].reshape(chunk, -1) for k in names],
+                    axis=1).at[jnp.where(mine, local, chunk)].get(
+                        mode="fill", fill_value=0)
+            return held, jnp.where(mine[:, None], taken, rows)
+
+        chunks = (touched + chunk - 1) // chunk
+        held, rows = jax.lax.fori_loop(0, chunks, read, (
+            {k: tuple(jnp.zeros((padded,) + row_shape(t), t.dtype)
+                      for t in tables[k]) for k in names},
+            jnp.zeros((entries, sum(floats)), params[names[0]].dtype)))
+
+        with jax.named_scope("sgd.loss"):
+            m, pull = jax.vjp(
+                lambda r, d: self.margins_of_rows(unpack(r), d, batch),
+                rows, {k: params[k] for k in dense})
+            loss, slope = self._loss_and_slope(m, batch)
+            g_rows, g_dense = pull(slope)
+
+        with jax.named_scope("sgd.unique"):
+            ids, anchors = run_windows(sk, alive, RUN_WINDOW)
+            sums = run_sums(ids, g_rows[order], below=RUN_WINDOW)
+
+        def update(c, carry):
+            tables, tiles = carry
+            at = jax.lax.dynamic_slice_in_dim(keys, c * chunk, chunk)
+            with jax.named_scope("sgd.unique"):
+                totals = unpack(window_totals(
+                    sums, sk, anchors, first[c * chunk],
+                    first[(c + 1) * chunk], windows, chunk, bound)[0])
+            out = {}
+            for name in names:
+                with jax.named_scope("sgd.gather_rows"):
+                    was = tuple(jax.lax.dynamic_slice_in_dim(
+                        h, c * chunk, chunk) for h in held[name])
+                updated = _apply(rules[name], _read_by(rules[name], was),
+                                 totals[name])
+                with jax.named_scope("sgd.scatter_rows"):
+                    out[name], wrote = scatter_rows(
+                        tables[name], at, tuple(
+                            u.reshape((chunk,) + params[name].shape[1:])
+                            for u in updated),
+                        jnp.clip(touched - c * chunk, 0, chunk))
+                tiles = tiles + wrote
+            return out, tiles
+
+        tables, tiles = jax.lax.fori_loop(
+            0, chunks, update, (tables, jnp.zeros((), jnp.int32)))
+        tables.update({k: _apply(rules[k], _read_by(rules[k], _with_state(
+            params, rules[k], k)), g_dense[k]) for k in dense})
+        out = _params_of(tables, rules)
+        return out, loss, (touched, tiles, spread)

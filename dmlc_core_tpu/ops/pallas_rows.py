@@ -206,9 +206,9 @@ def scatter_rows_inplace(tables, keys, rows, count) -> tuple:
 
 def scatter_rows(tables, keys, rows, count) -> tuple:
     """Rows set at sorted distinct ``keys`` into ``tables``: the kernel
-    where :func:`engages` says so, XLA's scatter elsewhere.  Returns
-    ``(tables, tiles)``, ``tiles`` 0 on XLA's path."""
-    if engages(*_visit_shape(tables[0], keys)):
+    where :func:`engages` says so, XLA's scatter elsewhere (rows ``[A, K]``
+    too).  Returns ``(tables, tiles)``, ``tiles`` 0 on XLA's path."""
+    if tables[0].ndim < 3 and engages(*_visit_shape(tables[0], keys)):
         return scatter_rows_inplace(tables, keys, rows, count)
     return tuple(t.at[keys].set(r, mode="drop", unique_indices=True,
                                 indices_are_sorted=True)

@@ -101,14 +101,16 @@ def _over(mask: jax.Array, like: jax.Array) -> jax.Array:
     return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
 
 
-def run_sums(keys: jax.Array, *columns: jax.Array) -> tuple:
+def run_sums(keys: jax.Array, *columns: jax.Array, below=None) -> tuple:
     """Inclusive running sum of each of ``columns`` (``[lanes]`` or ``[lanes,
     K]``: every trailing element along its own run) inside every run of
     equal ``keys`` (runs contiguous, as after a sort): the last lane of a run
     holds the run's sum.  ``ceil(log2(n))`` passes of one shifted add — no
-    scatter, and a pairwise order of summation."""
+    scatter, and a pairwise order of summation.  ``below`` (a power of two):
+    only the passes at a distance under it, after which a lane holds the sum
+    of the last ``below`` lanes of its run, itself included."""
     n, d = keys.shape[0], 1
-    while d < n:
+    while d < min(n, below or n):
         same = jnp.concatenate([jnp.zeros(d, bool), keys[d:] == keys[:-d]])
         columns = tuple(
             c + jnp.where(_over(same, c), jnp.concatenate(
@@ -265,3 +267,120 @@ def _csr_row_sums_bwd(res, ct):
 
 
 csr_row_sums.defvjp(_csr_row_sums_fwd, _csr_row_sums_bwd)
+
+
+# ---- sums along runs at two levels ------------------------------------------
+# Rows so wide that a pass of ``run_sums`` over every entry lane goes through
+# HBM (156 floats a row: 5 ms a pass on a v5e, 20 passes) are summed along
+# WINDOWS inside their keys' runs first, ``run_sums(..., below=window)``, and
+# then the windows a run is made of are summed: those are a ``window``-th of
+# the entries and one more a run.  (Nothing here is gathered a lane where a
+# sort or a running maximum does: a gather of one float an entry lane takes
+# 5 ms of a v5e, a sort of the lanes 0.8.)
+
+
+def run_windows(sorted_keys: jax.Array, live: jax.Array,
+                window: int) -> tuple:
+    """``(ids, anchors)`` of the windows that tile the runs of
+    ``sorted_keys``, ``window`` lanes each from a run's start (its last one
+    what is left): ``ids`` the lane a sorted lane's window starts on, a key
+    for :func:`run_sums` (``below=window``: the passes a window needs, after
+    which a window's last lane holds its sum); ``anchors`` those last lanes,
+    ascending, ids past the lanes after them.  A running maximum and one
+    sort of the lanes."""
+    n = live.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.concatenate(
+        [jnp.ones(1, bool), sorted_keys[1:] != sorted_keys[:-1]])
+    run_start = jax.lax.cummax(jnp.where(start, lane, 0))
+    ids = lane - (lane - run_start) % window
+    last = live & jnp.concatenate([ids[1:] != ids[:-1], jnp.ones(1, bool)])
+    return ids, jax.lax.sort(jnp.where(last, lane, n + lane),
+                             is_stable=False)
+
+
+def window_totals(sums: tuple, sorted_keys: jax.Array, anchors: jax.Array,
+                  lo: jax.Array, hi: jax.Array, held: int, keys: int,
+                  bound: int) -> tuple:
+    """Each of ``sums`` (``run_sums(ids, ..., below=window)`` over
+    :func:`run_windows`' ``ids``) summed
+    over the windows of every run that lies on the sorted lanes ``[lo, hi)``
+    (whole runs, at most ``keys`` of them, tiled by at most ``held`` windows):
+    ``[keys, ...]`` in key order, what the whole runs' sums hold at those
+    runs' ends; rows past the runs hold nothing a caller keeps.  The windows'
+    lanes are gathered, summed along their keys' runs, and a sort brings each
+    key's last window to the front."""
+    n = sorted_keys.shape[0]
+    sorted_distinct = dict(mode="fill", unique_indices=True,
+                           indices_are_sorted=True)
+    spare = 2 * n + jnp.arange(held, dtype=jnp.int32)
+    at = jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([anchors, spare]),
+        jnp.searchsorted(anchors, lo).astype(jnp.int32), held)
+    at = jnp.where(at < hi, at, spare)
+    of_key = sorted_keys.at[at].get(fill_value=bound, **sorted_distinct)
+    totals = run_sums(of_key, *(s.at[at].get(fill_value=0, **sorted_distinct)
+                                for s in sums))
+    last = jnp.concatenate([of_key[1:] != of_key[:-1], jnp.ones(1, bool)]) & (
+        of_key < bound)
+    place = jnp.arange(held, dtype=jnp.int32)
+    ends = jax.lax.sort(jnp.where(last, place, held + place),
+                        is_stable=False)[:keys]
+    return tuple(t.at[ends].get(fill_value=0, **sorted_distinct)
+                 for t in totals)
+
+
+# ---- sums along runs as ONE loop body ---------------------------------------
+# ``run_sums`` writes its passes out, a fusion or two each, and the TPU's
+# compiler makes 0.2-0.4 MB of program of each over ``[entries, K]``: forty of
+# them (a sum and its backward) are a second of every warm start's fetch.  A
+# path that hardly ever runs takes its passes in a loop instead: the shift a
+# dynamic slice, one body whatever the lanes.
+
+
+def _run_passes(keys: jax.Array, column: jax.Array, back: bool) -> jax.Array:
+    """``run_sums(keys, column)[0]``, or with ``back`` the same sums taken
+    from each run's END (a lane holds its own and every later lane's of its
+    run), as a loop over the distances."""
+    n = keys.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    keys2 = jnp.concatenate([keys, keys])
+
+    def one(i, c):
+        d = jnp.left_shift(1, i)
+        at = d if back else n - d
+        other = jax.lax.dynamic_slice_in_dim(keys2, at, n)
+        inside = lane + d < n if back else lane >= d
+        moved = jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([c, c]), at, n)
+        return c + jnp.where(_over((other == keys) & inside, c), moved, 0)
+
+    return jax.lax.fori_loop(0, max(n - 1, 0).bit_length(), one, column)
+
+
+@jax.custom_vjp
+def slot_sums(contrib: jax.Array, slot: jax.Array,
+              ptr: jax.Array) -> jax.Array:
+    """:func:`csr_row_sums` (``contrib`` ``[lanes, K]`` summed over the runs
+    of equal ``slot``, read at ``ptr[1:] - 1``) with its passes in a loop,
+    forward and backward: for the field-aware product's general branch."""
+    running = _run_passes(slot, contrib, False)
+    held = ptr[1:] > ptr[:-1]
+    return jnp.where(_over(held, running),
+                     running[jnp.maximum(ptr[1:] - 1, 0)], 0.0)
+
+
+def _slot_sums_fwd(contrib, slot, ptr):
+    return slot_sums(contrib, slot, ptr), (slot, ptr)
+
+
+def _slot_sums_bwd(res, ct):
+    slot, ptr = res
+    lanes = slot.shape[0]
+    last = jnp.where(ptr[1:] > ptr[:-1], ptr[1:] - 1, lanes)
+    marks = jnp.zeros((lanes,) + ct.shape[1:], ct.dtype).at[last].set(
+        ct, mode="drop", unique_indices=True)
+    return _run_passes(slot, marks, True), None, None
+
+
+slot_sums.defvjp(_slot_sums_fwd, _slot_sums_bwd)

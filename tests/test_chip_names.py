@@ -429,3 +429,49 @@ def test_leafwise_tree_program_compiles_at_the_cells_width(
     # the pool 1.04 GB; a chunk's rows gathered, unpacked and relaid for the
     # kernel, 0.52 GB a copy; the kernel's output 0.1: 3.3 GB in all
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_wide_rows_step_is_a_program_fetched_in_a_second(one_chip,
+                                                         quiet_cache):
+    """The field-aware machine's step at the ``criteo-ffm`` cell's shapes
+    (2^20 keys x 39 fields x 4 floats, 16,384 x 39 entries padded to 655,360
+    lanes), through the TPU's compiler.  The program's size is part of what
+    it costs: a warm start fetches it, 30-40 ms a megabyte, and the cell's
+    ``setup_s`` is 8 s with a tenth of room (a version of five candidate
+    visits and eleven sorts was 130 MB and read 12.3 s); a sort of the entry
+    lanes is 2-3 MB of it, so they are counted.  The table aliases its
+    output; no array an entry holds of the rows is laid with its 4 floats on
+    the lanes (32 times its bytes: only a chunk's 65,536 rows pass through
+    such a layout, inside the table's gather); the scopes the cell's metrics
+    read come through."""
+    from dmlc_core_tpu.data.staging import PaddedBatch
+    from dmlc_core_tpu.models.ffm import FieldAwareFactorizationMachine
+    rows, lanes, features, fields, width = 16384, 655360, 1 << 20, 39, 4
+    model = FieldAwareFactorizationMachine(features, fields, width,
+                                           learning_rate=0.2)
+    params = jax.tree.map(lambda a: on(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init, 0))
+    batch = PaddedBatch(
+        label=on(one_chip, (rows,), jnp.float32),
+        weight=on(one_chip, (rows,), jnp.float32),
+        row_ptr=on(one_chip, (rows + 1,), jnp.int32),
+        index=on(one_chip, (lanes,), jnp.int32),
+        value=on(one_chip, (lanes,), jnp.float32),
+        num_rows=on(one_chip, (), jnp.int32),
+        field=on(one_chip, (lanes,), jnp.int32))
+    compiled = model._wide_rows_step.lower(model, params, batch).compile()
+    memory = compiled.memory_analysis()
+    table = 4 * features * fields * width
+    assert memory.alias_size_in_bytes >= table
+    assert memory.generated_code_size_in_bytes < 48 << 20
+    assert memory.temp_size_in_bytes < 6 << 30
+    text = compiled.as_text()
+    sorts = [line for line in text.splitlines() if " sort(" in line
+             and re.search(rf"= \(?s32\[{lanes}\]", line)]
+    assert len(sorts) == 5, sorts
+    assert not re.search(rf"f32\[{lanes},39,4\]", text)
+    names = op_names(compiled)
+    for scope in ("ffm.reduce", "ffm.diag", "ffm.linear", "sgd.update",
+                  "sgd.unique", "sgd.gather_rows", "sgd.scatter_rows"):
+        part = re.compile(r"[/(]" + re.escape(scope) + r"[/)]")
+        assert any(part.search(n) for n in names), scope

@@ -44,8 +44,8 @@ def programs() -> dict:
     """Scope paths of the three device programs, lowered for the CPU at
     tiny sizes: a whole GBDT fit (depth 2, the Pallas route interpreted, so
     that the kernel wrapper's layout ops are there), one FFM ``_train_step``,
-    the touched-rows step of a linear model and of a gated factorization
-    machine, and a plan-routed reduction."""
+    the touched-rows step of a linear model, of a gated factorization
+    machine and of the field-aware one, and a plan-routed reduction."""
     model = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=16,
                  missing_aware=True, histogram="pallas")
     bins = jnp.zeros((64, 4), jnp.uint8)
@@ -80,6 +80,7 @@ def programs() -> dict:
         num_rows=jnp.asarray(np.int32(rows)),
         field=jnp.asarray(np.tile(np.arange(fields, dtype=np.int32), rows)))
     step = ffm._train_step.lower(ffm, ffm.init(0), batch)
+    field_rows = ffm._wide_rows_step.lower(ffm, ffm.init(0), batch)
     from dmlc_core_tpu.models.common import FTRL
     from dmlc_core_tpu.models.linear import SparseLinearModel
     linear = SparseLinearModel(features, optimizer=FTRL())
@@ -99,7 +100,7 @@ def programs() -> dict:
             "sparse_tree": paths_of(sparse_tree),
             "leafwise": paths_of(leafwise),
             "step": paths_of(step), "touched": paths_of(touched),
-            "tables": paths_of(tables),
+            "tables": paths_of(tables), "field_rows": paths_of(field_rows),
             "reduce": paths_of(reduce)}
 
 
@@ -460,3 +461,90 @@ def test_touched_rows_step_runs_the_rows_kernel_under_scatter_rows(
     text = lowered.as_text()
     assert text.count("tpu_custom_call") == 1
     assert f'kernel_name = "{pallas_rows.SCATTER_ROWS_KERNEL}"' in text
+
+
+# ---- the field-aware machine on the touched-rows step ----------------------
+
+FIELD_ROWS = ("ffm.reduce", "ffm.diag", "ffm.linear", "sgd.update",
+              "sgd.unique", "sgd.gather_rows", "sgd.scatter_rows")
+
+
+@pytest.mark.parametrize("scope", FIELD_ROWS)
+def test_field_aware_step_keeps_the_scopes_its_metrics_read(programs, scope):
+    """Without a penalty the field-aware machine's step is
+    ``jit(_wide_rows_step)``: the product's three scopes inside
+    ``sgd.loss``, forward and backward, the plain-SGD rule under
+    ``sgd.update`` (what `ffm_update_ms_per_step` goes on reading) and the
+    step's own three; ``ffm.gather`` is scoring's alone now."""
+    paths = programs["field_rows"]
+    assert carries(paths, scope, under="jit(_wide_rows_step)")
+    if scope.startswith("ffm."):
+        assert carries(paths, scope, under="sgd.loss")
+        assert carries(paths, scope, under="transpose(")
+    assert not carries(paths, "ffm.gather")
+    assert not carries(paths, "sgd.ftrl") and not carries(paths, "sgd.adagrad")
+
+
+def all_equations(jaxpr, path=""):
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        yield here, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_equations(sub, here)
+
+
+def field_batch(rows: int, fields: int, features: int, pad: int):
+    rng = np.random.default_rng(0)
+    nnz = rows * fields
+    return PaddedBatch(
+        label=jnp.zeros(rows), weight=jnp.ones(rows),
+        row_ptr=jnp.arange(rows + 1, dtype=jnp.int32) * fields,
+        index=jnp.asarray(np.r_[rng.integers(0, features, nnz),
+                                np.zeros(pad)].astype(np.int32)),
+        value=jnp.asarray(np.r_[np.ones(nnz), np.zeros(pad)]
+                          .astype(np.float32)),
+        num_rows=jnp.asarray(np.int32(rows)),
+        field=jnp.asarray(np.r_[np.tile(np.arange(fields), rows),
+                                np.zeros(pad)].astype(np.int32)))
+
+
+def test_field_aware_step_makes_no_table_and_moves_no_row_an_entry():
+    """The traced step holds no value shaped like the table but the donated
+    table as the loops hand it on and its scatter's result (the dense step
+    makes a gradient and an update of that shape), and nothing under
+    ``ffm.*`` is gathered or scattered with an index an entry lane: the
+    rows reach the entries by rank under ``sgd.gather_rows``, and the
+    product sums whole blocks (the branch for a batch that is not one entry
+    a field reads one row a (field, row) slot, fewer than the lanes).  The
+    dense step's ``ffm.gather`` and ``ffm.reduce`` do both."""
+    rows, fields, features, pad = 8, 3, 64, 8
+    lanes = rows * fields + pad
+    ffm = FieldAwareFactorizationMachine(num_features=features,
+                                         num_fields=fields)
+    batch = field_batch(rows, fields, features, pad)
+    table = (features, fields, ffm.num_factors)
+
+    def makers(traced):
+        return {eqn.primitive.name for _path, eqn
+                in all_equations(traced.jaxpr.jaxpr)
+                if any(getattr(v.aval, "shape", None) == table
+                       for v in eqn.outvars)}
+
+    def by_entry(traced):
+        found = []
+        for primitive in ("gather", "scatter", "scatter-add", "scatter_add"):
+            for path, ins, _outs in device_ops(traced, primitive):
+                indices = ins[1].shape[:-1]
+                if "ffm." in path and int(np.prod(indices)) >= lanes:
+                    found.append((primitive, path))
+        return found
+
+    step = ffm._wide_rows_step.trace(ffm, ffm.init(0), batch)
+    assert makers(step) <= {"scatter", "while"}, makers(step)
+    assert by_entry(step) == []
+    dense = ffm._train_step.trace(ffm, ffm.init(0), batch)
+    assert not makers(dense) <= {"scatter", "while"}
+    moved = by_entry(dense)
+    assert any(p == "gather" and "ffm.gather" in path for p, path in moved)
+    assert any(p == "scatter-add" and "ffm.reduce" in path
+               for p, path in moved), moved
