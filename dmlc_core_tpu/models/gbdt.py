@@ -1061,7 +1061,7 @@ class GBDT:
                 f, t, d, sg, sc, leaf, leaf_rel = build_tree(g, h,
                                                              col_mask, ck)
                 with jax.named_scope("gbdt.boost"):
-                    margin = margin + leaf[leaf_rel]
+                    margin = _add_leaf_values(margin, leaf, leaf_rel)
                 feats.append(f)
                 thrs.append(t)
                 dirs.append(d)
@@ -1286,8 +1286,8 @@ class GBDT:
                         g, h = g * w_t, h * w_t
                     f, t, d, sg, sc, leaf, leaf_rel = build_tree(
                         g, h, col_mask, ck)
-                    with jax.named_scope("gbdt.boost"):
-                        margin = margin.at[:, k].add(leaf[leaf_rel])
+                    value = _add_leaf_values(None, leaf, leaf_rel)
+                    margin = margin.at[:, k].add(value)
                     feats.append(f)
                     thrs.append(t)
                     dirs.append(d)
@@ -2617,3 +2617,103 @@ def _new_by_grow_policy(cls, *args, grow_policy: str = "depthwise",
 GBDT.grow_policy = "depthwise"
 GBDT.max_leaves = 0
 GBDT.__new__ = staticmethod(_new_by_grow_policy)
+
+
+# ---- the margin update: a finished tree's leaf value onto every row ---------
+# `_boost` and `_boost_multi` call `_add_leaf_values` from the lines that held
+# ``leaf[leaf_rel]``.  Below everything else for the reason given above
+# `_shard_inputs`.
+
+# Most leaf values (``leaf.shape[0]``) for which a row takes its own by
+# selects; past it, by XLA's gather.  The gather costs a v5e 6.7-8.2 ns a row
+# whatever its operand's size; the selects cost 0.0003 ns a row a leaf value:
+# at 28,750,000 rows 2.8 ms against 222 at 256 leaves, 139 against 192 at
+# 16,384, 208 against 192 at 24,576; at 10,500,000 rows 49.6 against 75.3 at
+# 16,384 and 74.3 against 75.3 at 24,576.  They cross between 22,600 and
+# 24,900 leaves; at 64, where XLA itself expands a jitted gather into
+# compares and selects, 0.69 ms against 0.88, so there is no floor (PERF.md,
+# PR 43: one chip, `_leaf_values` on both sides of this constant).
+_MARGIN_SELECT_LEAVES = 16384
+# Leaf values one pass over the rows selects among, a power of two.  At 64 a
+# pass is near what its bytes take (0.6 ms of 0.42 at 28,750,000 rows); 128
+# and 256 read 5% and 17% slower at 1,024 leaves, and an unrolled pass of 256
+# compiles in 4.2 s and 1.3 MB where this one takes 0.7 s and 0.3 MB
+# whatever the leaves (same runs).
+_MARGIN_SELECT_CHUNK = 64
+
+
+def _select_by_bits(vals: list, index: jax.Array) -> jax.Array:
+    """``vals[index]`` a row, ``vals`` a list of float scalars, by halving:
+    bit 0 of ``index`` picks within pairs of values, bit 1 within pairs of
+    those pairs, and so on.  One select a value a row, nothing compared
+    with an id, and what comes out is a float that went in, bit for bit (a
+    ``-0.0`` too); an odd value out at a level rides up as it is; bits past
+    the values' are not looked at.  Scalars against ``[rows]`` arrays: XLA
+    fuses the lot into one pass over the rows, which stay 1-D."""
+    bit = 1
+    while len(vals) > 1:
+        odd = (index & bit) != 0
+        pairs = [jnp.where(odd, vals[j + 1], vals[j])
+                 for j in range(0, len(vals) - 1, 2)]
+        vals = pairs + vals[len(vals) - len(vals) % 2:]
+        bit *= 2
+    return vals[0]
+
+
+def _selected_leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
+                          margin: Optional[jax.Array]) -> jax.Array:
+    """``leaf[leaf_rel]`` ([rows]), added to ``margin`` if there is one, with
+    no gather: `_select_by_bits` among ``_MARGIN_SELECT_CHUNK`` values a
+    pass, the low bits of ``leaf_rel`` choosing within a chunk and the high
+    ones the pass in which a row takes its value.  The margins are what the
+    passes hand on, so each is added to once and nothing else the size of
+    the rows is kept; no ``[leaves, rows]`` array exists, and the program's
+    size does not grow with the leaves.  leaf_rel in [0, leaves)."""
+    chunk = _MARGIN_SELECT_CHUNK          # a power of two
+    n = leaf.shape[0]
+    chunks = -(-n // chunk)
+    if chunks > 1:
+        leaf = jnp.pad(leaf, (0, chunks * chunk - n))
+
+    def taken(c, value):
+        part = jax.lax.dynamic_slice(leaf, (c * chunk,), (min(chunk, n),))
+        part = _select_by_bits(list(part), leaf_rel)
+        return part if margin is None else value + part
+    if chunks == 1:
+        return jnp.broadcast_to(taken(0, margin), leaf_rel.shape)
+    mine = leaf_rel >> (chunk.bit_length() - 1)
+    return jax.lax.fori_loop(
+        0, chunks,
+        lambda c, value: jnp.where(mine == c, taken(c, value), value),
+        jnp.zeros(leaf_rel.shape, leaf.dtype) if margin is None else margin)
+
+
+def _margin_selects(leaf) -> bool:
+    return leaf.shape[0] <= _MARGIN_SELECT_LEAVES
+
+
+@functools.partial(jax.jit, donate_argnames="margin")
+def _leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
+                 margin: Optional[jax.Array] = None) -> jax.Array:
+    """Every row's value of a finished tree, ``leaf[leaf_rel]`` ([rows]), or
+    with ``margin`` (donated) the margins it brings them to, in one program.
+    leaf: f32 [leaves]; leaf_rel: i32 [rows] in [0, leaves).  Which way a
+    row finds its value is read off ``leaf.shape[0]`` (`_margin_selects`).
+    Elementwise over the rows: under a mesh plan, rows sharded and ``leaf``
+    replicated, no collective, and the margins stay where they lay."""
+    with jax.named_scope("gbdt.boost"), jax.named_scope("gbdt.margin"):
+        if _margin_selects(leaf):
+            return _selected_leaf_values(leaf, leaf_rel, margin)
+        value = leaf[leaf_rel]
+        return value if margin is None else margin + value
+
+
+def _add_leaf_values(margin: Optional[jax.Array], leaf: jax.Array,
+                     leaf_rel: jax.Array) -> jax.Array:
+    """``margin + leaf[leaf_rel]`` (the values alone for ``margin=None``) by
+    `_leaf_values`; counts ``gbdt.margin_select`` on the host, once a call
+    that takes the select path: a boosting round's one update, or each of
+    a softmax round's ``num_class``."""
+    if _margin_selects(leaf):
+        counter_add("gbdt.margin_select", 1)
+    return _leaf_values(leaf, leaf_rel, margin)

@@ -45,7 +45,8 @@ def programs() -> dict:
     tiny sizes: a whole GBDT fit (depth 2, the Pallas route interpreted, so
     that the kernel wrapper's layout ops are there), one FFM ``_train_step``,
     the touched-rows step of a linear model, of a gated factorization
-    machine and of the field-aware one, and a plan-routed reduction."""
+    machine and of the field-aware one, a plan-routed reduction, and the
+    margin update a boosting round ends with."""
     model = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=16,
                  missing_aware=True, histogram="pallas")
     bins = jnp.zeros((64, 4), jnp.uint8)
@@ -68,6 +69,7 @@ def programs() -> dict:
     leafwise = best._grow_tree.lower(
         best, jnp.zeros((64, 1), jnp.int32), jnp.zeros(64), jnp.zeros(64),
         jnp.ones(4, bool))
+    margin = margin_program(leaves=4, rows=64)
 
     rows, fields, features = 8, 3, 32
     ffm = FieldAwareFactorizationMachine(num_features=features,
@@ -98,7 +100,7 @@ def programs() -> dict:
             jnp.zeros(plan.num_shards * 4))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
             "sparse_tree": paths_of(sparse_tree),
-            "leafwise": paths_of(leafwise),
+            "leafwise": paths_of(leafwise), "margin": paths_of(margin),
             "step": paths_of(step), "touched": paths_of(touched),
             "tables": paths_of(tables), "field_rows": paths_of(field_rows),
             "reduce": paths_of(reduce)}
@@ -116,6 +118,16 @@ SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
 # scopes of the leaf-wise builder's program, `jit(_grow_tree)`
 LEAFWISE = {"gbdt.leafwise.hist", "gbdt.leafwise.partition",
             "gbdt.leafwise.split", "gbdt.leafwise.pick"}
+# scopes of the boosting driver, outside both tree programs
+DRIVER = {"gbdt.boost", "gbdt.margin"}
+
+
+def margin_program(leaves: int, rows: int):
+    """The margin update (`gbdt._leaf_values`) lowered at ``leaves`` values
+    over ``rows`` rows."""
+    from dmlc_core_tpu.models import gbdt
+    return gbdt._leaf_values.lower(
+        jnp.zeros(leaves), jnp.zeros(rows, jnp.int32), jnp.zeros(rows))
 
 
 def carries(paths: set, scope: str, under: str = "") -> bool:
@@ -140,9 +152,16 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
                        under="jit(_touched_rows_step)")
     if scope in TOUCHED_TABLES:
         where = "tables"
+    if scope == "gbdt.margin":
+        # a program of its own, nested in the driver's scope (the one-tree
+        # fit lowered above never reads its last margins: not in there)
+        where = "margin"
+        assert carries(programs[where], scope,
+                       under="jit(_leaf_values)/gbdt.boost/")
+        assert not carries(programs["tree"], scope)
     assert carries(programs[where], scope), (
         f"no op of the {where} program carries the scope {scope}")
-    if scope.startswith("gbdt.") and scope != "gbdt.boost":
+    if scope.startswith("gbdt.") and scope not in DRIVER:
         # the tree programs are what the chip runs; the whole-fit lowering
         # above only adds the driver's eager ops to the dense one
         if scope not in SPARSE_ONLY:
@@ -211,6 +230,32 @@ def test_route_gathers_nothing_a_row():
     assert by_row == []
     old = gathers_under(lowered(gather_routed(**kw)), "gbdt.route")
     assert sum(int(np.prod(shape)) >= rows for shape in old) >= kw["max_depth"]
+
+
+# what `_boost` hands the margin update in the four GBDT cells: 2 ** depth
+# leaves, and the 2 * 255 - 1 nodes of the leaf-wise tree's pointer forest
+CELL_LEAVES = {"higgs": 64, "airline": 256, "bosch": 256, "epsilon": 509}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LEAVES))
+def test_margin_update_gathers_nothing_a_row(cell):
+    """``gbdt.margin`` is a scope of the lowered margin program and nothing
+    under it is gathered with an index a row (PR 43: one such gather, 256
+    leaves onto 28.75M rows, was 246 ms of a 1,329 ms Airline round on the
+    chip).  The same reading finds it when the crossover is set below the
+    cell's leaves, which puts the gather back."""
+    from test_gbdt_margin import select_ceiling
+    leaves, rows = CELL_LEAVES[cell], 1024
+
+    def by_row(lowered):
+        assert carries(paths_of(lowered), "gbdt.margin",
+                       under="jit(_leaf_values)/gbdt.boost/")
+        return [shape for shape in gathers_under(lowered, "gbdt.margin")
+                if int(np.prod(shape)) >= rows]
+
+    assert by_row(margin_program(leaves, rows)) == []
+    with select_ceiling(leaves - 1):
+        assert len(by_row(margin_program(leaves, rows))) == 1
 
 
 def device_ops(traced, primitive: str) -> list:
