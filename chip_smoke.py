@@ -633,71 +633,55 @@ def phase_mesh(ctx: dict) -> dict:
         "sharded feed")
     out["sharded_batches"] = len(batches)
 
-    # both reduction routes on the level histogram's own payload
+    # the reduction on the level histogram's own payload
     payload = 32 * FEATURES * size["bins"] * 2
     x = np.random.default_rng(3).standard_normal(
         (n, payload)).astype(np.float32)
     xs = jax.device_put(x.reshape(-1), plan.data_sharding())
-    routes = {}
-    for strategy, op in (("flat", "all_reduce"),
-                         ("hier", "collective_permute")):
-        fn = jax.jit(plan.shard_map(
-            functools.partial(plan.allreduce, strategy=strategy),
-            in_specs=plan.row_spec, out_specs=P(), check_replication=False))
-        text = fn.lower(xs).as_text()
-        require(op in text, f"{strategy} allreduce lowered without {op}")
-        err = rel_err(fn(xs), x.sum(axis=0))
-        require(err <= 1e-5, f"{strategy} allreduce off by {err:.3g}")
-        routes[strategy] = {"op": op, "rel_err": float(f"{err:.3g}")}
-    out["allreduce"] = {"payload_bytes": payload * 4,
-                        "auto_strategy": plan.strategy_for(payload * 4),
-                        **routes}
+    fn = jax.jit(plan.shard_map(plan.allreduce, in_specs=plan.row_spec,
+                                out_specs=P(), check_replication=False))
+    require("all_reduce" in fn.lower(xs).as_text(),
+            "allreduce lowered without all_reduce")
+    err = rel_err(fn(xs), x.sum(axis=0))
+    require(err <= 1e-5, f"allreduce off by {err:.3g}")
+    out["allreduce"] = {"payload_bytes": payload * 4, "op": "all_reduce",
+                        "rel_err": float(f"{err:.3g}")}
 
-    # the same GBDT with the kernel under shard_map, one fit per route
+    # the same GBDT with the kernel under shard_map
     require(size["rows"] % n == 0,
             f"{size['rows']} rows do not divide over {n} devices")
     bins = jax.device_put(ctx["dense_bins"], plan.data_sharding())
     y = jax.device_put(label, plan.data_sharding())
-    fits = {}
     # what a level reduces: its built node histograms, the root and then one
     # child of every parent (the siblings are derived after the reduction)
     level_bytes = [max(2 ** (d - 1), 1) * FEATURES * size["bins"] * 8
                    for d in range(size["depth"])]
-    # "auto" (the ring from 256 KiB on), and the plan of the benchmark's
-    # four-chip cell, built from that cell's own parameters the way its
-    # generator builds it (benchmark/traffic/mesh_fit.py: make_plan), so that
-    # this leg and the cell cannot drift apart
+    # the plan of the benchmark's four-chip cell, built from that cell's own
+    # parameters the way its generator builds it (benchmark/traffic/
+    # mesh_fit.py: make_plan), so that this leg and the cell cannot drift
+    # apart
     cell = json.loads((HERE / "benchmark" / "workloads"
                        / "airline-gbdt.fit-mesh4.json").read_text())["params"]
-    plans = {"auto": MeshPlan.build(collective="auto"),
-             cell["collective"]: MeshPlan.build(
-                 devices=jax.devices()[:n], collective=cell["collective"],
-                 overlap_chunks=int(cell["overlap_chunks"]))}
-    for collective, p in plans.items():
-        m = GBDT(histogram=routed, histogram_mesh=p, **ctx["config"])
-        require(set(m.level_backends()) == {"pallas"},
-                f"mesh fit resolved levels to {m.level_backends()}")
-        t0 = time.monotonic()
-        before = telemetry.snapshot()
-        forest = jax.block_until_ready(m.fit(bins, y))
-        counted = telemetry.counters_delta(before, telemetry.snapshot())
-        want = (size["trees"] * size["depth"],
-                size["trees"] * sum(level_bytes))
-        got = (counted.get("mesh.allreduce_calls", 0),
-               counted.get("mesh.collective_bytes", 0))
-        require(got == want, f"mesh fit ({collective}) counted {got} "
-                f"reductions and bytes, want {want}")
-        loss = float(m.loss(forest, bins, y))
-        require(abs(loss - ctx["dense_loss"]) <= 1e-3,
-                f"mesh fit ({collective}) loss {loss:.6f} vs one-device "
-                f"fit {ctx['dense_loss']:.6f}")
-        fits[collective] = {
-            "loss": round(loss, 6), "seconds": round(time.monotonic() - t0, 2),
-            "level_strategies": [p.strategy_for(b) for b in level_bytes]}
-    if not ctx["args"].rehearse_cpu:
-        require("hier" in fits["auto"]["level_strategies"],
-                "no level of the auto fit took the ppermute ring")
-    out["fit"] = fits
+    m = GBDT(histogram=routed, **ctx["config"], histogram_mesh=MeshPlan.build(
+        devices=jax.devices()[:n], collective=cell["collective"],
+        overlap_chunks=int(cell["overlap_chunks"])))
+    require(set(m.level_backends()) == {"pallas"},
+            f"mesh fit resolved levels to {m.level_backends()}")
+    t0 = time.monotonic()
+    before = telemetry.snapshot()
+    forest = jax.block_until_ready(m.fit(bins, y))
+    counted = telemetry.counters_delta(before, telemetry.snapshot())
+    want = (size["trees"] * size["depth"], size["trees"] * sum(level_bytes))
+    got = (counted.get("mesh.allreduce_calls", 0),
+           counted.get("mesh.collective_bytes", 0))
+    require(got == want, f"mesh fit counted {got} reductions and bytes, "
+            f"want {want}")
+    loss = float(m.loss(forest, bins, y))
+    require(abs(loss - ctx["dense_loss"]) <= 1e-3,
+            f"mesh fit loss {loss:.6f} vs one-device fit "
+            f"{ctx['dense_loss']:.6f}")
+    out["fit"] = {"loss": round(loss, 6),
+                  "seconds": round(time.monotonic() - t0, 2)}
 
     # the sparse kernel under shard_map, on one staged batch
     first = batches[0]

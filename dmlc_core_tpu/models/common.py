@@ -2,7 +2,10 @@
 used by linear / FM / GBDT alike)."""
 from __future__ import annotations
 
+import collections
+import dataclasses
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -47,40 +50,6 @@ class SGDModelMixin:
     may override ``_l2_terms(params)`` (default: just ``params["w"]``)
     to widen the penalty set.
     """
-
-    #: parallel.MeshPlan (or None): owns device placement and row layout
-    #: for the data-parallel path.  Set through the model ctor's
-    #: ``mesh_plan=`` (legacy ``(mesh, axis)`` tuples adapt).
-    mesh_plan = None
-
-    def _set_mesh_plan(self, mesh_plan) -> None:
-        from ..parallel.meshplan import MeshPlan
-        self.mesh_plan = MeshPlan.from_spec(mesh_plan)
-
-    def place_params(self, params: dict) -> dict:
-        """Replicate params over the plan's mesh — the layout under
-        which ``train_step``'s gradient reduction lowers to the psum
-        over the plan axes (the rabit-allreduce path).  No plan: pass
-        through."""
-        if self.mesh_plan is None:
-            return params
-        return jax.device_put(params, self.mesh_plan.replicated_sharding())
-
-    def batch_sharding(self):
-        """Sharding for staged batches under the plan: rows over the
-        plan axes, host-major (None without a plan)."""
-        return (None if self.mesh_plan is None
-                else self.mesh_plan.data_sharding())
-
-    def grad_allreduce(self, grads: dict, op: str = "sum") -> dict:
-        """Reduce a grad pytree through the plan's collective strategy —
-        for custom shard_map/pmap training loops that compute per-shard
-        grads themselves (``train_step`` under GSPMD doesn't need it:
-        the compiler inserts the psum).  Call inside traced code."""
-        if self.mesh_plan is None:
-            return grads
-        return jax.tree.map(lambda g: self.mesh_plan.allreduce(g, op),
-                            grads)
 
     def _l2_terms(self, params: dict) -> tuple:
         return (params["w"],)
@@ -129,10 +98,7 @@ class SGDModelMixin:
 
         Under jit with replicated params and a data-sharded batch, the
         grad reduction lowers to a psum over the mesh — the
-        rabit-allreduce path.  With a ``mesh_plan`` set, that layout is
-        exactly ``place_params`` + ``batch_sharding`` — the plan owns
-        placement; GSPMD still owns the reduction (explicit routes use
-        ``grad_allreduce``).
+        rabit-allreduce path.
 
         The host's part of a step (the dispatch of the jitted
         ``_train_step``, or the wait for a full device queue) is the span
@@ -152,12 +118,7 @@ class SGDModelMixin:
 
 # ---- the touched-rows step --------------------------------------------------
 # A step that reads and writes only the rows its batch names, with optimizer
-# state beside the parameters.  Everything here stays BELOW ``_train_step``,
-# late imports included: a program's compile-cache key holds its source
-# lines, and the plain SGD step keeps the lines it had (PERF.md section 6).
-import collections  # noqa: E402
-import dataclasses  # noqa: E402
-import math  # noqa: E402
+# state beside the parameters.
 
 #: lanes of sorted distinct keys the step's visit of its tables may take,
 #: and the batch's own lanes after these.  The step's cost does not follow

@@ -502,6 +502,213 @@ def _softmax_ce(margin: jax.Array, label: jax.Array) -> jax.Array:
     return logz - picked
 
 
+# ---- sibling subtraction: what a level builds, and what it derives ----------
+# A level's histograms are determined by its parent's and ONE child's of each
+# parent: hist(sibling) = hist(parent) - hist(built), bucket by bucket
+# (XGBoost hist's subtraction).  Both kernels' time grows with the node
+# columns on the MXU's M axis, so every tree builder (`_build_tree`,
+# `_build_tree_sparse`, `fit_streamed`) asks its backend for half a level's
+# columns through these functions, and for the root whole.
+
+# The column of a row that stands in no built node.  Every histogram backend
+# drops it: the kernels compare ids with their node columns, all >= 0, and
+# `jax.ops.segment_sum` drops a negative key (tests/test_gbdt_siblings.py).
+_NO_SLOT = -1
+
+
+def _built_columns(depth: int) -> int:
+    """Node histograms the level at ``depth`` asks its backend for: the root,
+    then one child of each of the ``2 ** (depth - 1)`` parents."""
+    return 2 ** max(depth - 1, 0)
+
+
+def _smaller_child(hl_dirs, h_tot, split_f, split_b, split_d) -> jax.Array:
+    """Which child of each node the next level builds: bool [nodes], True
+    for the right one.  The child with the smaller hessian mass at the
+    node's chosen split, so that the derived sibling, which inherits the
+    parent's absolute rounding error, is the larger: its relative error is
+    at most twice the parent's plus the built child's (deriving a child of
+    a thousandth of the mass would multiply it by a thousand).  A null
+    split sends every row left, so its empty right child is built.
+
+    hl_dirs: the left hessian mass ``[nodes, F, B]`` of every cut, one array
+    a default direction; h_tot: [nodes]; split_*: the chosen [nodes]
+    tables, nulls as `_pick_splits` encodes them."""
+    n_nodes, _, B = hl_dirs[0].shape
+    at = (split_f * B + jnp.minimum(split_b, B - 1))[:, None]
+    hl = [jnp.take_along_axis(a.reshape(n_nodes, -1), at, 1)[:, 0]
+          for a in hl_dirs]
+    left = hl[0] if len(hl) == 1 else jnp.where(split_d == 1, hl[1], hl[0])
+    left = jnp.where(split_b >= B, h_tot, left)
+    return h_tot - left < left
+
+
+def _child_slot(rel: jax.Array, go_right: jax.Array,
+                right_built: jax.Array) -> jax.Array:
+    """A routed row's column among the histograms the next level builds: its
+    parent's index ``rel`` if it went to the child that is built
+    (``right_built``, its parent's word of `_smaller_child`), else
+    ``_NO_SLOT``."""
+    return jnp.where(go_right == right_built, rel, _NO_SLOT)
+
+
+def _entry_slots(rid: jax.Array, slot: jax.Array, depth: int) -> jax.Array:
+    """The rows' ``slot`` on the sorted entries' lanes (``rid``: each lane's
+    row), for the sparse kernel.  At the root every slot is 0 and nothing
+    is gathered: the gather cost 1.9 s a tree at 2.18e8 entries on a v5e,
+    8.6 ns an element (PERF.md, PR 27)."""
+    with jax.named_scope("gbdt.entry_gather"):
+        if depth == 0:
+            return jnp.zeros(rid.shape, jnp.int32)
+        return slot[rid]
+
+
+def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
+    """A level's ``[2 * parents, F, B, 2]`` histograms from the level
+    above's ``parent`` ``[parents, F, B, 2]`` and the ``built`` child of
+    each (``right_built``: which), the other derived as parent - built in
+    float32, interleaved in heap order.  The root has no parent and is built
+    whole."""
+    if parent is None:
+        return built
+    derived = parent - built
+    right = right_built[:, None, None, None]
+    pair = jnp.stack([jnp.where(right, derived, built),
+                      jnp.where(right, built, derived)], axis=1)
+    return pair.reshape((-1,) + built.shape[1:])
+
+
+def _split_child_sums(dirs, split_f, split_b, split_d) -> jax.Array:
+    """(G, H) of both children of every node at its chosen split, float32
+    ``[2 * nodes, 2]`` in heap order (left, right): read off the cumulative
+    histograms the split search already holds, so a dense tree's leaves cost
+    no pass over the rows (the scatter-add of a ``[rows, 2]`` pair was 6.8 ns
+    and 512 B of scratch a row on a v5e: PERF.md, PR 38) and, under a mesh
+    plan, they come from the reduced histograms: global with no collective.
+    The left child's sums are ``dirs[split_d]`` at ``(split_f, split_b)``,
+    missing mass on its side included; the right child's the node's totals
+    in that feature's histogram (its last cumulative sum) less the left's,
+    the one subtraction the gain was computed with (XGBoost's hist takes a
+    leaf's weight from the same statistics).  A null split's cut lies past
+    the last bin and defaults left: the read is clamped to the last bin,
+    where the cumulative sum IS the total, so the left child is the node and
+    the right child exactly (0, 0), weight 0.
+
+    dirs: ``(gl, hl)`` ``[nodes, F, B]`` a default direction, left-to-right
+    cumulative sums, ``dirs[0]`` with the missing bin on the left; split_*:
+    the chosen [nodes] tables, nulls as `_pick_splits` encodes them."""
+    n_nodes, _, B = dirs[0][0].shape
+    last = (split_f * B + B - 1)[:, None]
+    at = jnp.minimum(last, (split_f * B + split_b)[:, None])
+
+    def pick(a, i):
+        return jnp.take_along_axis(a.reshape(n_nodes, -1), i, 1)[:, 0]
+
+    left = [jnp.stack([pick(gl, at), pick(hl, at)], axis=-1)
+            for gl, hl in dirs]
+    left = (left[0] if len(left) == 1
+            else jnp.where((split_d == 1)[:, None], left[1], left[0]))
+    total = jnp.stack([pick(a, last) for a in dirs[0]], axis=-1)
+    return jnp.stack([left, total - left], axis=1).reshape(-1, 2)
+
+
+# ---- the margin update: a finished tree's leaf value onto every row ---------
+
+# Most leaf values (``leaf.shape[0]``) for which a row takes its own by
+# selects; past it, by XLA's gather.  The gather costs a v5e 6.7-8.2 ns a row
+# whatever its operand's size; the selects cost 0.0003 ns a row a leaf value:
+# at 28,750,000 rows 2.8 ms against 222 at 256 leaves, 139 against 192 at
+# 16,384, 208 against 192 at 24,576; at 10,500,000 rows 49.6 against 75.3 at
+# 16,384 and 74.3 against 75.3 at 24,576.  They cross between 22,600 and
+# 24,900 leaves; at 64, where XLA itself expands a jitted gather into
+# compares and selects, 0.69 ms against 0.88, so there is no floor (PERF.md,
+# PR 43: one chip, `_leaf_values` on both sides of this constant).
+_MARGIN_SELECT_LEAVES = 16384
+# Leaf values one pass over the rows selects among, a power of two.  At 64 a
+# pass is near what its bytes take (0.6 ms of 0.42 at 28,750,000 rows); 128
+# and 256 read 5% and 17% slower at 1,024 leaves, and an unrolled pass of 256
+# compiles in 4.2 s and 1.3 MB where this one takes 0.7 s and 0.3 MB
+# whatever the leaves (same runs).
+_MARGIN_SELECT_CHUNK = 64
+
+
+def _select_by_bits(vals: list, index: jax.Array) -> jax.Array:
+    """``vals[index]`` a row, ``vals`` a list of float scalars, by halving:
+    bit 0 of ``index`` picks within pairs of values, bit 1 within pairs of
+    those pairs, and so on.  One select a value a row, nothing compared
+    with an id, and what comes out is a float that went in, bit for bit (a
+    ``-0.0`` too); an odd value out at a level rides up as it is; bits past
+    the values' are not looked at.  Scalars against ``[rows]`` arrays: XLA
+    fuses the lot into one pass over the rows, which stay 1-D."""
+    bit = 1
+    while len(vals) > 1:
+        odd = (index & bit) != 0
+        pairs = [jnp.where(odd, vals[j + 1], vals[j])
+                 for j in range(0, len(vals) - 1, 2)]
+        vals = pairs + vals[len(vals) - len(vals) % 2:]
+        bit *= 2
+    return vals[0]
+
+
+def _selected_leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
+                          margin: Optional[jax.Array]) -> jax.Array:
+    """``leaf[leaf_rel]`` ([rows]), added to ``margin`` if there is one, with
+    no gather: `_select_by_bits` among ``_MARGIN_SELECT_CHUNK`` values a
+    pass, the low bits of ``leaf_rel`` choosing within a chunk and the high
+    ones the pass in which a row takes its value.  The margins are what the
+    passes hand on, so each is added to once and nothing else the size of
+    the rows is kept; no ``[leaves, rows]`` array exists, and the program's
+    size does not grow with the leaves.  leaf_rel in [0, leaves)."""
+    chunk = _MARGIN_SELECT_CHUNK          # a power of two
+    n = leaf.shape[0]
+    chunks = -(-n // chunk)
+    if chunks > 1:
+        leaf = jnp.pad(leaf, (0, chunks * chunk - n))
+
+    def taken(c, value):
+        part = jax.lax.dynamic_slice(leaf, (c * chunk,), (min(chunk, n),))
+        part = _select_by_bits(list(part), leaf_rel)
+        return part if margin is None else value + part
+    if chunks == 1:
+        return jnp.broadcast_to(taken(0, margin), leaf_rel.shape)
+    mine = leaf_rel >> (chunk.bit_length() - 1)
+    return jax.lax.fori_loop(
+        0, chunks,
+        lambda c, value: jnp.where(mine == c, taken(c, value), value),
+        jnp.zeros(leaf_rel.shape, leaf.dtype) if margin is None else margin)
+
+
+def _margin_selects(leaf) -> bool:
+    return leaf.shape[0] <= _MARGIN_SELECT_LEAVES
+
+
+@functools.partial(jax.jit, donate_argnames="margin")
+def _leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
+                 margin: Optional[jax.Array] = None) -> jax.Array:
+    """Every row's value of a finished tree, ``leaf[leaf_rel]`` ([rows]), or
+    with ``margin`` (donated) the margins it brings them to, in one program.
+    leaf: f32 [leaves]; leaf_rel: i32 [rows] in [0, leaves).  Which way a
+    row finds its value is read off ``leaf.shape[0]`` (`_margin_selects`).
+    Elementwise over the rows: under a mesh plan, rows sharded and ``leaf``
+    replicated, no collective, and the margins stay where they lay."""
+    with jax.named_scope("gbdt.boost"), jax.named_scope("gbdt.margin"):
+        if _margin_selects(leaf):
+            return _selected_leaf_values(leaf, leaf_rel, margin)
+        value = leaf[leaf_rel]
+        return value if margin is None else margin + value
+
+
+def _add_leaf_values(margin: Optional[jax.Array], leaf: jax.Array,
+                     leaf_rel: jax.Array) -> jax.Array:
+    """``margin + leaf[leaf_rel]`` (the values alone for ``margin=None``) by
+    `_leaf_values`; counts ``gbdt.margin_select`` on the host, once a call
+    that takes the select path: a boosting round's one update, or each of
+    a softmax round's ``num_class``."""
+    if _margin_selects(leaf):
+        counter_add("gbdt.margin_select", 1)
+    return _leaf_values(leaf, leaf_rel, margin)
+
+
 class GBDT:
     """Gradient-boosted complete binary trees over binned features.
 
@@ -550,6 +757,26 @@ class GBDT:
     split enumeration).  Otherwise bin 0 is an ordinary ordered bin and
     ``default_right`` stays 0.
     """
+
+    # ``GBDT(..., grow_policy="lossguide", max_leaves=L)`` constructs
+    # `gbdt_leafwise.LeafwiseGBDT`, the best-first builder and its pointer
+    # forest (XGBoost's names; "depthwise" is this file's level-by-level
+    # builder and the default).  ``__init__`` takes the two names and does
+    # nothing with them: they are checked here, where the class is chosen.
+    grow_policy = "depthwise"
+    max_leaves = 0
+
+    def __new__(cls, *args, grow_policy: str = "depthwise",
+                max_leaves: int = 0, **kwargs):
+        if grow_policy not in ("depthwise", "lossguide"):
+            raise ValueError("grow_policy must be 'depthwise' or 'lossguide'")
+        if grow_policy == "depthwise" and max_leaves:
+            raise ValueError("max_leaves belongs to grow_policy='lossguide': "
+                             "a depth-wise tree holds 2 ** max_depth leaves")
+        if cls is GBDT and grow_policy == "lossguide":
+            from .gbdt_leafwise import LeafwiseGBDT
+            cls = LeafwiseGBDT
+        return object.__new__(cls)
 
     def __init__(self, num_features: int, num_trees: int = 20,
                  max_depth: int = 6, num_bins: int = 256,
@@ -650,31 +877,22 @@ class GBDT:
         if histogram not in ("auto", "xla", "pallas"):
             raise ValueError("histogram must be 'auto', 'xla' or 'pallas'")
         self.histogram = histogram
-        # The explicit multi-device kernel route.  Accepts a
-        # parallel.MeshPlan, a bare Mesh, or the legacy (mesh, axis_name)
-        # tuple (adapted via MeshPlan.from_spec).  When set, levels whose
-        # backend resolves to "pallas" build the histogram via
-        # shard_map(local pallas kernel) + the plan's allreduce (flat
-        # psum or hierarchical by payload) instead of relying on GSPMD
-        # to partition segment_sum — pallas_call has no auto-partitioning
-        # rule, so this is the ONLY way the kernel can serve a
-        # row-sharded fit.  A plan with overlap_chunks > 1 additionally
-        # routes XLA levels through the explicit chunked
-        # collective/compute-overlap path (see _level_histogram).
-        # fit() inputs must be sharded over the plan axes, and
-        # shard_map's even-sharding rule applies: rows must divide by
-        # the shard count (the GSPMD/XLA route tolerates uneven rows;
-        # staged PaddedBatch pipelines sized to the mesh satisfy this by
-        # construction).  Tests pin interpret-mode parity on the
-        # 8-device CPU mesh; tests/test_pallas.py proves the route
-        # itself, tests/test_meshplan.py the plan adapter and overlap.
+        # The explicit multi-device route: under a ``parallel.MeshPlan``
+        # every level builds its histogram by shard_map(local histogram) +
+        # the plan's allreduce instead of relying on GSPMD to partition
+        # segment_sum — pallas_call has no auto-partitioning rule, so this
+        # is the ONLY way the kernel can serve a row-sharded fit.  fit()
+        # lays its inputs over the plan axes (`_shard_inputs`), and
+        # shard_map's even-sharding rule applies: rows must divide by the
+        # shard count (without a plan GSPMD tolerates uneven rows).
         if histogram_mesh is not None:
             from ..parallel.meshplan import MeshPlan
-            self.mesh_plan = MeshPlan.from_spec(histogram_mesh)
-            self.histogram_mesh = self.mesh_plan.legacy_spec
-        else:
-            self.mesh_plan = None
-            self.histogram_mesh = None
+            if not isinstance(histogram_mesh, MeshPlan):
+                raise TypeError(
+                    "histogram_mesh takes a parallel.MeshPlan, not "
+                    f"{type(histogram_mesh).__name__}: pass "
+                    "MeshPlan(mesh, axes)")
+        self.mesh_plan = histogram_mesh
         self._grad_hess = (_logistic_grad_hess if objective == "logistic"
                            else _squared_grad_hess)
 
@@ -697,7 +915,7 @@ class GBDT:
         if self.histogram != "auto":
             return self.histogram
         if (jax.default_backend() == "tpu" and n_nodes <= node_limit
-                and (self.histogram_mesh is not None
+                and (self.mesh_plan is not None
                      or jax.device_count() == 1)):
             return "pallas"
         return "xla"
@@ -716,69 +934,28 @@ class GBDT:
         [0, n_nodes) — a level's built columns; ``_NO_SLOT`` adds nothing
         on either backend — through ``impl``, the level's `_hist_impl`.
         Plain ``histogram_gh`` call normally (GSPMD partitions the XLA
-        path and inserts the psum on sharded fits).  With a mesh plan
-        set and the level resolving to the Pallas backend — or the plan
-        asking for overlap (``overlap_chunks > 1``) — the kernel runs
-        per-device on local row shards under ``jax.shard_map`` and the
-        shards combine with the plan's allreduce (flat psum or
-        hierarchical; `test_histogram_gh_shardmap_psum_matches_global`).
-
-        Overlap: with K = overlap_chunks > 1 the feature axis splits
-        into K chunks and the reduce of chunk k is issued before the
-        local histogram of chunk k+1 is built, so the collective for
-        chunk k overlaps the MXU contraction of chunk k+1 (XLA
-        schedules the independent reduce and compute concurrently;
-        double-buffered — at most one reduction in flight).  Forests
-        are bit-identical to the unchunked route: per-feature histogram
-        columns are computed independently with the row-reduction order
-        unchanged, and chunking an elementwise cross-device reduce
-        reorders nothing (tests/test_meshplan.py pins this).
-        """
+        path and inserts the psum on sharded fits).  With a mesh plan the
+        histogram is built per-device on local row shards under
+        ``jax.shard_map`` and the shards combine with the plan's allreduce
+        (`test_histogram_gh_shardmap_psum_matches_global`), which carries
+        the built columns only: their siblings are derived after it
+        (`_with_siblings`)."""
+        B, plan = self.num_bins, self.mesh_plan
+        if plan is None:
+            return histogram_gh(bins_i, rel, gh, n_nodes, B, force=impl)
         from jax.sharding import PartitionSpec as P
 
-        # the plan's allreduce below carries the built columns only: their
-        # siblings are derived after it (`_with_siblings`)
-        B, plan = self.num_bins, self.mesh_plan
-        K = 1 if plan is None else min(plan.overlap_chunks,
-                                       self.num_features)
-        # explicit shard_map route: always for the pallas kernel (no
-        # GSPMD partitioning rule) and for any freshly-built plan;
-        # legacy tuple adapters (prefer_gspmd) keep their pre-plan
-        # GSPMD behavior on XLA levels unless overlap is requested
-        if plan is not None and (impl == "pallas" or K > 1
-                                 or not plan.prefer_gspmd):
+        def local(b, r, g):
+            return plan.allreduce(
+                histogram_gh(b, r, g, n_nodes, B, force=impl))
 
-            def local(b, r, g):
-                if K <= 1:
-                    return plan.allreduce(
-                        histogram_gh(b, r, g, n_nodes, B, force=impl))
-                F = b.shape[1]
-                bounds = [(F * k // K, F * (k + 1) // K)
-                          for k in range(K)]
-                outs, pending = [], None
-                for f0, f1 in bounds:
-                    if f0 == f1:
-                        continue
-                    hk = histogram_gh(b[:, f0:f1], r, g, n_nodes, B,
-                                      force=impl)
-                    if pending is not None:
-                        outs.append(plan.allreduce(pending))
-                    pending = hk
-                outs.append(plan.allreduce(pending))
-                return jnp.concatenate(outs, axis=1)
-
-            # replication check off: pallas_call's out_shape carries no
-            # varying-axes annotation, so the static check cannot see
-            # through it; the allreduce replicates the output
-            # regardless.  NOTE shard_map's even-sharding rule: rows
-            # must divide by the shard count (see the histogram_mesh
-            # ctor comment).
-            spec = plan.row_spec
-            return plan.shard_map(local, in_specs=(spec, spec, spec),
-                                  out_specs=P(),
-                                  check_replication=False)(
-                                      bins_i, rel, gh)
-        return histogram_gh(bins_i, rel, gh, n_nodes, B, force=impl)
+        # replication check off: pallas_call's out_shape carries no
+        # varying-axes annotation, so the static check cannot see through
+        # it; the allreduce replicates the output regardless.
+        spec = plan.row_spec
+        return plan.shard_map(local, in_specs=(spec, spec, spec),
+                              out_specs=P(), check_replication=False)(
+                                  bins_i, rel, gh)
 
     def _hist_impl_sparse(self, n_nodes: int) -> str:
         """Sparse-histogram backend for a level: `_hist_impl`'s rule
@@ -791,7 +968,7 @@ class GBDT:
         build a layout.  Checked *before* entry arrays exist (streamed
         fits use it to decide whether pass 0 should accumulate the global
         entry arrays the sort needs)."""
-        if streamed and self.histogram_mesh is not None:
+        if streamed and self.mesh_plan is not None:
             return False
         return "pallas" in self.level_backends(sparse=True)
 
@@ -828,11 +1005,10 @@ class GBDT:
         under its scope ``gbdt.entry_gather``) feed one kernel call.  With
         ``histogram_mesh`` the packed per-shard layout slices ride
         ``shard_map`` ``P(axis)`` in_specs, each device runs the kernel on
-        its local rows' entries, and the plan's allreduce (flat psum or
-        hierarchical by payload) combines the shards — the same
-        rabit-histogram-allreduce shape as the dense `_level_histogram`
-        route (both gathers move inside the shard_map body there, since
-        rows are only device-local under the mesh)."""
+        its local rows' entries, and the plan's allreduce combines the
+        shards — the same rabit-histogram-allreduce shape as the dense
+        `_level_histogram` route (both gathers move inside the shard_map
+        body there, since rows are only device-local under the mesh)."""
         F, B = self.num_features, self.num_bins
         if self.mesh_plan is not None:
             from jax.sharding import PartitionSpec as P
@@ -997,6 +1173,36 @@ class GBDT:
         _, _, loss, npairs = _pairwise_terms(
             m, label.astype(jnp.float32), qid, w, max_group - 1)
         return loss / jnp.maximum(npairs, 1)
+
+    def _on(self, label):
+        """Where ``_boost`` makes its margins: under a mesh plan beside a
+        label that lies over the plan's axes (``jnp.full(label.shape, ...)``
+        made them whole on the first chip, 460 MB at 115M rows, and every
+        round's first op resharded them); else where ``jnp.full`` puts
+        them, as ever."""
+        plan = self.mesh_plan
+        if (plan is None or not getattr(label, "committed", False)
+                or label.shape[0] % plan.num_shards):
+            return None
+        return label.sharding
+
+    def _tree_span(self, build_tree, trees: int = 1):
+        """The span ``gbdt.tree`` around one boosting round's ``trees`` calls
+        of ``build_tree``, which also counts the node histograms those trees
+        build and derive (``gbdt.hist_nodes_built``, ``gbdt.hist_nodes_derived``:
+        `_built_columns`); under a mesh plan it also counts the reductions
+        those calls run (``MeshPlan.counting``), under the name of the fit
+        that made ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one
+        tree program each)."""
+        span = telemetry.span("gbdt.tree")
+        built = trees * sum(map(_built_columns, range(self.max_depth)))
+        counter_add("gbdt.hist_nodes_built", built)
+        counter_add("gbdt.hist_nodes_derived",
+                    trees * (2 ** self.max_depth - 1) - built)
+        if self.mesh_plan is None:
+            return span
+        kind = getattr(build_tree, "__qualname__", "").split(".<locals>")[0]
+        return self.mesh_plan.counting(kind, trees, around=span)
 
     def _boost(self, label: jax.Array, w: jax.Array, build_tree,
                eval_margin=None, eval_label=None, eval_weight=None,
@@ -1600,7 +1806,7 @@ class GBDT:
         F, B = self.num_features, self.num_bins
         rows = grad.shape[0]
         mono = self.monotone_constraints is not None
-        mesh = self.histogram_mesh is not None
+        mesh = self.mesh_plan is not None
         gh_row = jnp.stack([grad, hess], axis=-1)          # [rows, 2]
         impls = [self._hist_impl_sparse(2 ** d) if layout is not None
                  else "xla" for d in range(self.max_depth)]
@@ -1763,6 +1969,27 @@ class GBDT:
 
         return jax.lax.fori_loop(0, self.num_trees, one_tree, base)
 
+    def _shard_inputs(self, bins, label, weight):
+        """Under a mesh plan, ``fit``'s row arrays laid out over the plan's
+        axes (span ``gbdt.shard_inputs``): ``shard_map``'s even-rows rule
+        checked here, by name, and each array placed with
+        ``plan.data_sharding()`` — one that already lies so is handed back
+        as it is, a host array or one on a single chip is divided once a
+        fit instead of once a tree."""
+        plan = self.mesh_plan
+        if plan is None:
+            return bins, label, weight
+        with telemetry.span("gbdt.shard_inputs"):
+            rows, shards = int(bins.shape[0]), plan.num_shards
+            if rows % shards:
+                raise ValueError(
+                    f"{rows} rows do not divide over the mesh plan's "
+                    f"{shards} shards: the kernel runs under shard_map, "
+                    "which wants as many rows on every shard")
+            sharding = plan.data_sharding()
+            return tuple(a if a is None else jax.device_put(a, sharding)
+                         for a in (bins, label, weight))
+
     # ---- public API ---------------------------------------------------------
 
     @telemetry.span("gbdt.fit")
@@ -1785,6 +2012,7 @@ class GBDT:
         ``objective='rank:pairwise'`` (contiguous groups; stage with
         ``with_qid=True``); its eval_set form is the 4-tuple
         ``(eval_bins, eval_label, eval_weight_or_None, eval_qid)``.
+        Returns the forest pytree.
         """
         bins, label, weight = self._shard_inputs(bins, label, weight)
         label = label.astype(jnp.float32)
@@ -2416,304 +2644,3 @@ class GBDT:
              if self.objective == "softmax"
              else self.margins(params, bins))
         return self._objective_loss(m, label, weight)
-
-    # ---- what a mesh plan adds to a fit -------------------------------------
-    # Defined below every other method, and called from lines that were
-    # there (``fit`` gives up a docstring line for its call): a program's
-    # source lines and its callers' are part of its compile-cache key
-    # (compile_cache.py), so nothing above may move, and no frame may come
-    # between ``fit`` and a tree program.  So kept, the tree programs of a
-    # fit without a plan are the same bytes as before these were added.
-
-    def _shard_inputs(self, bins, label, weight):
-        """Under a mesh plan, ``fit``'s row arrays laid out over the plan's
-        axes (span ``gbdt.shard_inputs``): ``shard_map``'s even-rows rule
-        checked here, by name, and each array placed with
-        ``plan.data_sharding()`` — one that already lies so is handed back
-        as it is, a host array or one on a single chip is divided once a
-        fit instead of once a tree.  A legacy ``(mesh, axis)`` spec keeps
-        its uneven rows for GSPMD and is left alone then."""
-        plan = self.mesh_plan
-        if plan is None:
-            return bins, label, weight
-        with telemetry.span("gbdt.shard_inputs"):
-            rows, shards = int(bins.shape[0]), plan.num_shards
-            if rows % shards:
-                if plan.prefer_gspmd:
-                    return bins, label, weight
-                raise ValueError(
-                    f"{rows} rows do not divide over the mesh plan's "
-                    f"{shards} shards: the kernel runs under shard_map, "
-                    "which wants as many rows on every shard")
-            sharding = plan.data_sharding()
-            return tuple(a if a is None else jax.device_put(a, sharding)
-                         for a in (bins, label, weight))
-
-    def _on(self, label):
-        """Where ``_boost`` makes its margins: under a mesh plan beside a
-        label that lies over the plan's axes (``jnp.full(label.shape, ...)``
-        made them whole on the first chip, 460 MB at 115M rows, and every
-        round's first op resharded them); else where ``jnp.full`` puts
-        them, as ever."""
-        plan = self.mesh_plan
-        if (plan is None or not getattr(label, "committed", False)
-                or label.shape[0] % plan.num_shards):
-            return None
-        return label.sharding
-
-    def _tree_span(self, build_tree, trees: int = 1):
-        """The span ``gbdt.tree`` around one boosting round's ``trees`` calls
-        of ``build_tree``, which also counts the node histograms those trees
-        build and derive (``gbdt.hist_nodes_built``, ``gbdt.hist_nodes_derived``:
-        `_built_columns`); under a mesh plan it also counts the reductions
-        those calls run (``MeshPlan.counting``), under the name of the fit
-        that made ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one
-        tree program each).  A context manager and not a wrapper: nothing
-        of it is on the stack while a tree program is traced."""
-        span = telemetry.span("gbdt.tree")
-        built = trees * sum(map(_built_columns, range(self.max_depth)))
-        counter_add("gbdt.hist_nodes_built", built)
-        counter_add("gbdt.hist_nodes_derived",
-                    trees * (2 ** self.max_depth - 1) - built)
-        if self.mesh_plan is None:
-            return span
-        kind = getattr(build_tree, "__qualname__", "").split(".<locals>")[0]
-        return self.mesh_plan.counting(kind, trees, around=span)
-
-
-# ---- sibling subtraction: what a level builds, and what it derives ----------
-# A level's histograms are determined by its parent's and ONE child's of each
-# parent: hist(sibling) = hist(parent) - hist(built), bucket by bucket
-# (XGBoost hist's subtraction).  Both kernels' time grows with the node
-# columns on the MXU's M axis, so every tree builder (`_build_tree`,
-# `_build_tree_sparse`, `fit_streamed`) asks its backend for half a level's
-# columns through these functions, and for the root whole.  Defined below
-# the class for the reason given above `_shard_inputs`.
-
-# The column of a row that stands in no built node.  Every histogram backend
-# drops it: the kernels compare ids with their node columns, all >= 0, and
-# `jax.ops.segment_sum` drops a negative key (tests/test_gbdt_siblings.py).
-_NO_SLOT = -1
-
-
-def _built_columns(depth: int) -> int:
-    """Node histograms the level at ``depth`` asks its backend for: the root,
-    then one child of each of the ``2 ** (depth - 1)`` parents."""
-    return 2 ** max(depth - 1, 0)
-
-
-def _smaller_child(hl_dirs, h_tot, split_f, split_b, split_d) -> jax.Array:
-    """Which child of each node the next level builds: bool [nodes], True
-    for the right one.  The child with the smaller hessian mass at the
-    node's chosen split, so that the derived sibling, which inherits the
-    parent's absolute rounding error, is the larger: its relative error is
-    at most twice the parent's plus the built child's (deriving a child of
-    a thousandth of the mass would multiply it by a thousand).  A null
-    split sends every row left, so its empty right child is built.
-
-    hl_dirs: the left hessian mass ``[nodes, F, B]`` of every cut, one array
-    a default direction; h_tot: [nodes]; split_*: the chosen [nodes]
-    tables, nulls as `_pick_splits` encodes them."""
-    n_nodes, _, B = hl_dirs[0].shape
-    at = (split_f * B + jnp.minimum(split_b, B - 1))[:, None]
-    hl = [jnp.take_along_axis(a.reshape(n_nodes, -1), at, 1)[:, 0]
-          for a in hl_dirs]
-    left = hl[0] if len(hl) == 1 else jnp.where(split_d == 1, hl[1], hl[0])
-    left = jnp.where(split_b >= B, h_tot, left)
-    return h_tot - left < left
-
-
-def _child_slot(rel: jax.Array, go_right: jax.Array,
-                right_built: jax.Array) -> jax.Array:
-    """A routed row's column among the histograms the next level builds: its
-    parent's index ``rel`` if it went to the child that is built
-    (``right_built``, its parent's word of `_smaller_child`), else
-    ``_NO_SLOT``."""
-    return jnp.where(go_right == right_built, rel, _NO_SLOT)
-
-
-def _entry_slots(rid: jax.Array, slot: jax.Array, depth: int) -> jax.Array:
-    """The rows' ``slot`` on the sorted entries' lanes (``rid``: each lane's
-    row), for the sparse kernel.  At the root every slot is 0 and nothing
-    is gathered: the gather cost 1.9 s a tree at 2.18e8 entries on a v5e,
-    8.6 ns an element (PERF.md, PR 27)."""
-    with jax.named_scope("gbdt.entry_gather"):
-        if depth == 0:
-            return jnp.zeros(rid.shape, jnp.int32)
-        return slot[rid]
-
-
-def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
-    """A level's ``[2 * parents, F, B, 2]`` histograms from the level
-    above's ``parent`` ``[parents, F, B, 2]`` and the ``built`` child of
-    each (``right_built``: which), the other derived as parent - built in
-    float32, interleaved in heap order.  The root has no parent and is built
-    whole."""
-    if parent is None:
-        return built
-    derived = parent - built
-    right = right_built[:, None, None, None]
-    pair = jnp.stack([jnp.where(right, derived, built),
-                      jnp.where(right, built, derived)], axis=1)
-    return pair.reshape((-1,) + built.shape[1:])
-
-
-def _split_child_sums(dirs, split_f, split_b, split_d) -> jax.Array:
-    """(G, H) of both children of every node at its chosen split, float32
-    ``[2 * nodes, 2]`` in heap order (left, right): read off the cumulative
-    histograms the split search already holds, so a dense tree's leaves cost
-    no pass over the rows (the scatter-add of a ``[rows, 2]`` pair was 6.8 ns
-    and 512 B of scratch a row on a v5e: PERF.md, PR 38) and, under a mesh
-    plan, they come from the reduced histograms: global with no collective.
-    The left child's sums are ``dirs[split_d]`` at ``(split_f, split_b)``,
-    missing mass on its side included; the right child's the node's totals
-    in that feature's histogram (its last cumulative sum) less the left's,
-    the one subtraction the gain was computed with (XGBoost's hist takes a
-    leaf's weight from the same statistics).  A null split's cut lies past
-    the last bin and defaults left: the read is clamped to the last bin,
-    where the cumulative sum IS the total, so the left child is the node and
-    the right child exactly (0, 0), weight 0.
-
-    dirs: ``(gl, hl)`` ``[nodes, F, B]`` a default direction, left-to-right
-    cumulative sums, ``dirs[0]`` with the missing bin on the left; split_*:
-    the chosen [nodes] tables, nulls as `_pick_splits` encodes them."""
-    n_nodes, _, B = dirs[0][0].shape
-    last = (split_f * B + B - 1)[:, None]
-    at = jnp.minimum(last, (split_f * B + split_b)[:, None])
-
-    def pick(a, i):
-        return jnp.take_along_axis(a.reshape(n_nodes, -1), i, 1)[:, 0]
-
-    left = [jnp.stack([pick(gl, at), pick(hl, at)], axis=-1)
-            for gl, hl in dirs]
-    left = (left[0] if len(left) == 1
-            else jnp.where((split_d == 1)[:, None], left[1], left[0]))
-    total = jnp.stack([pick(a, last) for a in dirs[0]], axis=-1)
-    return jnp.stack([left, total - left], axis=1).reshape(-1, 2)
-
-
-# ---- growth policy ----------------------------------------------------------
-# ``GBDT(..., grow_policy="lossguide", max_leaves=L)`` constructs
-# `gbdt_leafwise.LeafwiseGBDT`, the best-first builder and its pointer forest
-# (XGBoost's names; "depthwise" is this file's level-by-level builder and the
-# default).  ``__init__`` takes the two names and does nothing with them: they
-# are checked here, where the class is chosen.  Below everything else for the
-# reason given above `_shard_inputs`.
-
-
-def _new_by_grow_policy(cls, *args, grow_policy: str = "depthwise",
-                        max_leaves: int = 0, **kwargs):
-    if grow_policy not in ("depthwise", "lossguide"):
-        raise ValueError("grow_policy must be 'depthwise' or 'lossguide'")
-    if grow_policy == "depthwise" and max_leaves:
-        raise ValueError("max_leaves belongs to grow_policy='lossguide': a "
-                         "depth-wise tree holds 2 ** max_depth leaves")
-    if cls is GBDT and grow_policy == "lossguide":
-        from .gbdt_leafwise import LeafwiseGBDT
-        cls = LeafwiseGBDT
-    return object.__new__(cls)
-
-
-GBDT.grow_policy = "depthwise"
-GBDT.max_leaves = 0
-GBDT.__new__ = staticmethod(_new_by_grow_policy)
-
-
-# ---- the margin update: a finished tree's leaf value onto every row ---------
-# `_boost` and `_boost_multi` call `_add_leaf_values` from the lines that held
-# ``leaf[leaf_rel]``.  Below everything else for the reason given above
-# `_shard_inputs`.
-
-# Most leaf values (``leaf.shape[0]``) for which a row takes its own by
-# selects; past it, by XLA's gather.  The gather costs a v5e 6.7-8.2 ns a row
-# whatever its operand's size; the selects cost 0.0003 ns a row a leaf value:
-# at 28,750,000 rows 2.8 ms against 222 at 256 leaves, 139 against 192 at
-# 16,384, 208 against 192 at 24,576; at 10,500,000 rows 49.6 against 75.3 at
-# 16,384 and 74.3 against 75.3 at 24,576.  They cross between 22,600 and
-# 24,900 leaves; at 64, where XLA itself expands a jitted gather into
-# compares and selects, 0.69 ms against 0.88, so there is no floor (PERF.md,
-# PR 43: one chip, `_leaf_values` on both sides of this constant).
-_MARGIN_SELECT_LEAVES = 16384
-# Leaf values one pass over the rows selects among, a power of two.  At 64 a
-# pass is near what its bytes take (0.6 ms of 0.42 at 28,750,000 rows); 128
-# and 256 read 5% and 17% slower at 1,024 leaves, and an unrolled pass of 256
-# compiles in 4.2 s and 1.3 MB where this one takes 0.7 s and 0.3 MB
-# whatever the leaves (same runs).
-_MARGIN_SELECT_CHUNK = 64
-
-
-def _select_by_bits(vals: list, index: jax.Array) -> jax.Array:
-    """``vals[index]`` a row, ``vals`` a list of float scalars, by halving:
-    bit 0 of ``index`` picks within pairs of values, bit 1 within pairs of
-    those pairs, and so on.  One select a value a row, nothing compared
-    with an id, and what comes out is a float that went in, bit for bit (a
-    ``-0.0`` too); an odd value out at a level rides up as it is; bits past
-    the values' are not looked at.  Scalars against ``[rows]`` arrays: XLA
-    fuses the lot into one pass over the rows, which stay 1-D."""
-    bit = 1
-    while len(vals) > 1:
-        odd = (index & bit) != 0
-        pairs = [jnp.where(odd, vals[j + 1], vals[j])
-                 for j in range(0, len(vals) - 1, 2)]
-        vals = pairs + vals[len(vals) - len(vals) % 2:]
-        bit *= 2
-    return vals[0]
-
-
-def _selected_leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
-                          margin: Optional[jax.Array]) -> jax.Array:
-    """``leaf[leaf_rel]`` ([rows]), added to ``margin`` if there is one, with
-    no gather: `_select_by_bits` among ``_MARGIN_SELECT_CHUNK`` values a
-    pass, the low bits of ``leaf_rel`` choosing within a chunk and the high
-    ones the pass in which a row takes its value.  The margins are what the
-    passes hand on, so each is added to once and nothing else the size of
-    the rows is kept; no ``[leaves, rows]`` array exists, and the program's
-    size does not grow with the leaves.  leaf_rel in [0, leaves)."""
-    chunk = _MARGIN_SELECT_CHUNK          # a power of two
-    n = leaf.shape[0]
-    chunks = -(-n // chunk)
-    if chunks > 1:
-        leaf = jnp.pad(leaf, (0, chunks * chunk - n))
-
-    def taken(c, value):
-        part = jax.lax.dynamic_slice(leaf, (c * chunk,), (min(chunk, n),))
-        part = _select_by_bits(list(part), leaf_rel)
-        return part if margin is None else value + part
-    if chunks == 1:
-        return jnp.broadcast_to(taken(0, margin), leaf_rel.shape)
-    mine = leaf_rel >> (chunk.bit_length() - 1)
-    return jax.lax.fori_loop(
-        0, chunks,
-        lambda c, value: jnp.where(mine == c, taken(c, value), value),
-        jnp.zeros(leaf_rel.shape, leaf.dtype) if margin is None else margin)
-
-
-def _margin_selects(leaf) -> bool:
-    return leaf.shape[0] <= _MARGIN_SELECT_LEAVES
-
-
-@functools.partial(jax.jit, donate_argnames="margin")
-def _leaf_values(leaf: jax.Array, leaf_rel: jax.Array,
-                 margin: Optional[jax.Array] = None) -> jax.Array:
-    """Every row's value of a finished tree, ``leaf[leaf_rel]`` ([rows]), or
-    with ``margin`` (donated) the margins it brings them to, in one program.
-    leaf: f32 [leaves]; leaf_rel: i32 [rows] in [0, leaves).  Which way a
-    row finds its value is read off ``leaf.shape[0]`` (`_margin_selects`).
-    Elementwise over the rows: under a mesh plan, rows sharded and ``leaf``
-    replicated, no collective, and the margins stay where they lay."""
-    with jax.named_scope("gbdt.boost"), jax.named_scope("gbdt.margin"):
-        if _margin_selects(leaf):
-            return _selected_leaf_values(leaf, leaf_rel, margin)
-        value = leaf[leaf_rel]
-        return value if margin is None else margin + value
-
-
-def _add_leaf_values(margin: Optional[jax.Array], leaf: jax.Array,
-                     leaf_rel: jax.Array) -> jax.Array:
-    """``margin + leaf[leaf_rel]`` (the values alone for ``margin=None``) by
-    `_leaf_values`; counts ``gbdt.margin_select`` on the host, once a call
-    that takes the select path: a boosting round's one update, or each of
-    a softmax round's ``num_class``."""
-    if _margin_selects(leaf):
-        counter_add("gbdt.margin_select", 1)
-    return _leaf_values(leaf, leaf_rel, margin)

@@ -29,8 +29,7 @@ class SparseLinearModel(TouchedRowsMixin):
 
     def __init__(self, num_features: int, objective: str = "logistic",
                  l2: float = 0.0, learning_rate: float = 0.1,
-                 sdot_backend: str | None = None, mesh_plan=None,
-                 optimizer=None):
+                 sdot_backend: str | None = None, optimizer=None):
         if objective not in ("logistic", "squared"):
             raise ValueError(f"unknown objective '{objective}'")
         check_force(sdot_backend, "sdot_backend")
@@ -42,12 +41,6 @@ class SparseLinearModel(TouchedRowsMixin):
         # GSPMD-safe scatter-add; "pallas" = scatter-free kernel,
         # single-device TPU only (no pallas partitioning rule)
         self.sdot_backend = sdot_backend
-        # parallel.MeshPlan / Mesh / legacy (mesh, axis) tuple: owns
-        # device placement for the psum path — replicate params with
-        # place_params(), shard batches with batch_sharding(), and the
-        # jitted train_step's gradient reduction becomes the psum over
-        # the plan axes
-        self._set_mesh_plan(mesh_plan)
         self._set_optimizer(optimizer)
 
     def init(self, seed: int = 0) -> dict:

@@ -12,6 +12,8 @@ Padding convention: value == 0 ⇒ the entry contributes nothing.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -91,9 +93,6 @@ def csr_to_dense_missing(index: jax.Array, value: jax.Array,
 
 
 # ---- reduction onto the distinct keys of a batch ----------------------------
-# (the import stays below the CSR products: a program's compile-cache key
-# holds its source lines, and theirs stay where they were)
-from typing import NamedTuple  # noqa: E402
 
 
 def _over(mask: jax.Array, like: jax.Array) -> jax.Array:

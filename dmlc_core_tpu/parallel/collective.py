@@ -60,9 +60,8 @@ def collective_sweep(mesh: Mesh, op: str = "allreduce",
                      payloads_mib: Sequence[float] = (0.25, 16.0),
                      iters: int = 10, warmup: int = 2) -> list:
     """``collective_bench`` at several payload sizes — the small/large
-    sweep that makes the latency-vs-bandwidth regimes (and the
-    hierarchical-vs-flat crossover, when compared against
-    ``meshplan.plan_allreduce_bench``) visible in one DETAIL row."""
+    sweep that makes the latency-vs-bandwidth regimes visible in one
+    DETAIL row."""
     return [collective_bench(mesh, op, mib, iters, warmup=warmup)
             for mib in payloads_mib]
 

@@ -59,8 +59,8 @@ def main() -> int:
     ap.add_argument("--kernel-mesh", action="store_true",
                     help="with --shard: run the Pallas histogram kernel "
                          "per-device under shard_map + explicit psum "
-                         "(histogram_mesh) instead of the GSPMD scatter-add "
-                         "route; interpret-mode (slow) off-TPU")
+                         "(histogram_mesh=MeshPlan) instead of the GSPMD "
+                         "scatter-add route; interpret-mode (slow) off-TPU")
     ap.add_argument("--missing", action="store_true",
                     help="sparsity-aware mode: absent libsvm features are "
                          "MISSING (NaN -> reserved bin, learned per-node "
@@ -84,6 +84,7 @@ def main() -> int:
     from dmlc_core_tpu.data import DeviceStagingIter
     from dmlc_core_tpu.models import GBDT, QuantileBinner
     from dmlc_core_tpu.ops.sparse import csr_to_dense, csr_to_dense_missing
+    from dmlc_core_tpu.parallel import MeshPlan
 
     compile_cache.configure()
 
@@ -288,7 +289,8 @@ def main() -> int:
     if args.kernel_mesh:
         # the sharded-kernel route: the row padding above already makes
         # rows divide by the device count (shard_map's even-sharding rule)
-        mesh_kw = dict(histogram="pallas", histogram_mesh=(mesh, "data"))
+        mesh_kw = dict(histogram="pallas",
+                       histogram_mesh=MeshPlan(mesh, ("data",)))
         print("histogram route: pallas kernel per-device under shard_map "
               "+ psum", flush=True)
     model = GBDT(num_features=args.dim, num_trees=args.trees,

@@ -78,7 +78,9 @@ def test_compile_cache_is_placed_from_outside(monkeypatch):
     from dmlc_core_tpu import compile_cache
     keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
             "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key",
+            "jax_traceback_in_locations_limit")
     saved = {k: getattr(jax.config, k) for k in keys}
     try:
         # set from outside: the directory is JAX's to read, none set in code

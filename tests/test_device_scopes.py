@@ -406,7 +406,8 @@ def cache_in(tmp_path):
     names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
              "jax_persistent_cache_min_compile_time_secs",
              "jax_persistent_cache_min_entry_size_bytes",
-             "jax_compilation_cache_include_metadata_in_key")
+             "jax_compilation_cache_include_metadata_in_key",
+             "jax_traceback_in_locations_limit")
     before = {n: getattr(jax.config, n) for n in names}
     compile_cache.configure()
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
@@ -428,8 +429,7 @@ def cache_in(tmp_path):
 
 
 def run_scoped_or_not(scoped: bool) -> None:
-    """One program, with or without a scope, always called from this very
-    line: with metadata in the key the caller's line is part of it too."""
+    """One program, with or without a scope."""
     def double_plus_one(x):
         with (jax.named_scope("test.scope") if scoped
               else contextlib.nullcontext()):
@@ -457,6 +457,83 @@ def test_a_scope_is_part_of_the_compile_cache_key(cache_in):
     assert cache_in() - base == 3
 
 
+def fresh_program():
+    """The same function, jitted anew: no call finds it in memory."""
+    def triple_less_one(x):
+        with jax.named_scope("test.scope"):
+            return x * 3.0 - 1.0
+    return jax.jit(triple_less_one)
+
+
+def call_through_a_frame(fn, x):
+    return fn(x)
+
+
+def test_where_a_program_is_called_from_is_not_in_its_key(cache_in):
+    """File, line and callers are no part of a program's key
+    (``jax_traceback_in_locations_limit`` 0): one function traced from two
+    lines of this test and through a helper's frame is compiled once and
+    fetched twice.  With JAX's default every call site compiled it again,
+    and so did every edit that shifted a line above a program."""
+    assert jax.config.jax_traceback_in_locations_limit == 0
+    x = jnp.arange(8.0)
+    x.block_until_ready()
+    base = cache_in()
+    hits = []
+    fresh_program()(x).block_until_ready()
+    hits.append(cache_in() - base)
+    fresh_program()(x).block_until_ready()
+    hits.append(cache_in() - base)
+    call_through_a_frame(fresh_program(), x).block_until_ready()
+    hits.append(cache_in() - base)
+    assert hits == [0, 1, 2]
+
+
+def ftrl_step_on_the_rows_kernel(monkeypatch):
+    """The FTRL step, traced, its scatters forced onto the rows kernel at a
+    table of two tiles and the kernel compiled, not interpreted."""
+    from dmlc_core_tpu.models.common import FTRL
+    from dmlc_core_tpu.models.linear import SparseLinearModel
+    from dmlc_core_tpu.ops import pallas_rows
+    monkeypatch.setattr(pallas_rows, "engages", lambda *_: True)
+    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
+    rows, per_row = 64, 16
+    batch = PaddedBatch(
+        label=jnp.zeros(rows), weight=jnp.ones(rows),
+        row_ptr=jnp.arange(rows + 1, dtype=jnp.int32) * per_row,
+        index=jnp.zeros(rows * per_row, jnp.int32),
+        value=jnp.ones(rows * per_row), num_rows=jnp.asarray(np.int32(rows)))
+    linear = SparseLinearModel(2 * pallas_rows.TILE, optimizer=FTRL())
+    return linear._touched_rows_step.trace(linear, linear.init(), batch)
+
+
+def test_lowered_programs_keep_their_scopes_and_name_no_file(
+        cache_in, monkeypatch):
+    """What ``configure()`` leaves of a program's debug information, in the
+    text the TPU's compiler is handed: the scope paths the benchmark's
+    readers match on, and no source location."""
+    from dmlc_core_tpu.ops import pallas_segment
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+
+    def level(bins, rel, gh):
+        with jax.named_scope("gbdt.hist"):
+            return pallas_segment.histogram_gh(bins, rel, gh, 2, 16,
+                                               force="pallas")
+    rows = 512
+    hist = jax.jit(level).trace(
+        jnp.zeros((rows, 4), jnp.uint8), jnp.zeros(rows, jnp.int32),
+        jnp.ones((rows, 2)))
+    step = ftrl_step_on_the_rows_kernel(monkeypatch)
+    for traced, scopes in ((hist, ("gbdt.hist", "ops.hist_layout")),
+                           (step, sorted(TOUCHED_ROWS))):
+        lowered = traced.lower(lowering_platforms=("tpu",))
+        paths, text = paths_of(lowered), lowered.as_text(debug_info=True)
+        for scope in scopes:
+            assert carries(paths, scope), scope
+        assert '.py"' not in text
+        assert "tpu_custom_call" in text
+
+
 def test_touched_rows_step_nests_its_scopes_and_keeps_the_shared_ones(
         programs):
     """The FTRL step is one program, `jit(_touched_rows_step)`: the model's
@@ -481,20 +558,9 @@ def test_touched_rows_step_runs_the_rows_kernel_under_scatter_rows(
     by its own name, which is what `ftrl_scatter_ms_per_step` goes on
     reading; no XLA scatter is left under the scope, and the other four
     scopes are where they were."""
-    from dmlc_core_tpu.models.common import FTRL
-    from dmlc_core_tpu.models.linear import SparseLinearModel
     from dmlc_core_tpu.ops import pallas_rows
-    monkeypatch.setattr(pallas_rows, "engages", lambda *_: True)
-    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
-    rows, per_row = 64, 16
-    batch = PaddedBatch(
-        label=jnp.zeros(rows), weight=jnp.ones(rows),
-        row_ptr=jnp.arange(rows + 1, dtype=jnp.int32) * per_row,
-        index=jnp.zeros(rows * per_row, jnp.int32),
-        value=jnp.ones(rows * per_row), num_rows=jnp.asarray(np.int32(rows)))
-    linear = SparseLinearModel(2 * pallas_rows.TILE, optimizer=FTRL())
-    lowered = linear._touched_rows_step.trace(
-        linear, linear.init(), batch).lower(lowering_platforms=("tpu",))
+    lowered = ftrl_step_on_the_rows_kernel(monkeypatch).lower(
+        lowering_platforms=("tpu",))
     paths = paths_of(lowered)
     for scope in sorted(TOUCHED_ROWS):
         assert carries(paths, scope, under="jit(_touched_rows_step)"), scope

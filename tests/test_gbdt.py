@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from dmlc_core_tpu.models.gbdt import GBDT, QuantileBinner
+from dmlc_core_tpu.parallel import MeshPlan
 
 
 def test_binner_roundtrip_monotone():
@@ -158,7 +159,7 @@ def test_sharded_fit_matches_single_device():
 
 @pytest.mark.slow
 def test_sharded_pallas_fit_matches_xla_fit():
-    """histogram_mesh=(mesh, 'data') + histogram='pallas': every level's
+    """histogram_mesh=MeshPlan(mesh) + histogram='pallas': every level's
     histogram runs the Pallas kernel per-device under shard_map with an
     explicit psum (pallas_call has no GSPMD partitioning rule, so this is
     the only way the kernel serves a row-sharded fit).  The forest must be
@@ -179,7 +180,7 @@ def test_sharded_pallas_fit_matches_xla_fit():
     kw = dict(num_features=3, num_trees=2, max_depth=3, num_bins=8,
               learning_rate=0.5, objective="logistic")
     p_xla = GBDT(histogram="xla", **kw).fit(bins_sh, y_sh)
-    p_pal = GBDT(histogram="pallas", histogram_mesh=(mesh, "data"),
+    p_pal = GBDT(histogram="pallas", histogram_mesh=MeshPlan(mesh, ("data",)),
                  **kw).fit(bins_sh, y_sh)
 
     for k in ("feature", "threshold"):
@@ -194,8 +195,8 @@ def test_histogram_mesh_validates_axis():
     from jax.sharding import Mesh
 
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
-    with pytest.raises(ValueError, match="histogram_mesh axis"):
-        GBDT(num_features=3, histogram_mesh=(mesh, "model"))
+    with pytest.raises(ValueError, match="plan axis 'model'"):
+        GBDT(num_features=3, histogram_mesh=MeshPlan(mesh, ("model",)))
 
 
 @pytest.mark.slow
@@ -1308,7 +1309,8 @@ def test_sparse_sharded_fit_batch_pallas_matches_xla():
     kw = dict(num_features=4, num_trees=2, max_depth=3, num_bins=8,
               learning_rate=0.5, missing_aware=True)
     p_xla = GBDT(histogram="xla", **kw).fit_batch(batch, binner)
-    p_mesh = GBDT(histogram="pallas", histogram_mesh=(mesh, "data"),
+    p_mesh = GBDT(histogram="pallas",
+                  histogram_mesh=MeshPlan(mesh, ("data",)),
                   **kw).fit_batch(batch, binner)
     _assert_forests_identical(p_xla, p_mesh)
 

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from dmlc_core_tpu.models import GBDT
+from dmlc_core_tpu.parallel import MeshPlan
 from dmlc_core_tpu.models.gbdt import (_built_columns, _child_slot,
                                        _entry_slots, _with_siblings)
 from dmlc_core_tpu.ops.pallas_segment import (_KEY_TILE, _NNZ_TILE,
@@ -30,7 +31,7 @@ def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
     mono = self.monotone_constraints is not None
     rid, fi, ebin, emask = entries
     emw = emask.astype(jnp.float32)[:, None]
-    mesh = self.histogram_mesh is not None
+    mesh = self.mesh_plan is not None
     gh_row = jnp.stack([grad, hess], axis=-1)
     gh_k = gh_e = None
     if layout is not None and not mesh:
@@ -165,7 +166,7 @@ def test_jitted_sparse_tree_under_a_mesh_equals_the_eager_one():
     batch, binner, *_ = _sparse_identity_fixture(rng, rows=256, feats=4)
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
     kw = dict(BASE, num_trees=2, histogram="pallas",
-              histogram_mesh=(mesh, "data"))
+              histogram_mesh=MeshPlan(mesh, ("data",)))
     got = GBDT(**kw).fit_batch(batch, binner)
     want = EagerGBDT(**kw).fit_batch(batch, binner)
     # one program lets GSPMD add the shards' row sums in another order than
@@ -212,8 +213,8 @@ def test_a_fit_counts_what_a_kernel_level_of_its_layout_runs_and_launches(
     kw = dict(BASE, num_trees=2, histogram="pallas")
     if mesh:
         from jax.sharding import Mesh
-        kw["histogram_mesh"] = (Mesh(np.asarray(jax.devices()[:8]),
-                                     ("data",)), "data")
+        kw["histogram_mesh"] = MeshPlan(
+            Mesh(np.asarray(jax.devices()[:8]), ("data",)))
     model, layouts = GBDT(**kw), []
     build = ps.sparse_hist_layout
 

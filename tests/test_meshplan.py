@@ -1,8 +1,7 @@
-"""MeshPlan: topology discovery, collective routing, GBDT plan paths.
+"""MeshPlan: topology discovery, the reduction, GBDT plan paths.
 
 Everything runs on the conftest-forced virtual 8-device CPU mesh, so the
-hierarchical ppermute route, the 2-D (host, chip) plan, and the chunked
-level-loop overlap are all exercised without TPU hardware.
+2-D (host, chip) plan is exercised without TPU hardware.
 """
 import numpy as np
 import pytest
@@ -12,68 +11,42 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dmlc_core_tpu.models import GBDT, QuantileBinner
-from dmlc_core_tpu.parallel import MeshPlan, make_mesh, plan_allreduce_bench
+from dmlc_core_tpu.parallel import MeshPlan, make_mesh
 
 
 # ---------------------------------------------------------------------------
-# collectives: hierarchical route vs flat psum
+# the reduction: XLA's collective over the plan's axes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("hosts", [None, 2])
 @pytest.mark.parametrize("op", ["sum", "max", "mean"])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 0.05)])
-def test_hier_allreduce_matches_flat(hosts, op, dtype, tol):
+def test_plan_allreduce_matches_numpy(hosts, op, dtype, tol):
     plan = MeshPlan.build(hosts=hosts)
     assert plan.num_shards == 8
     rng = np.random.default_rng(0)
-    # 513 elements per shard: not divisible by the ring size, so the
-    # pad-to-c-blocks path is on the line too
+    # 513 elements per shard: no multiple of the shard count
     x = jnp.asarray(rng.standard_normal((plan.num_shards * 513,)), dtype)
 
-    def body(v):
-        return (plan.allreduce(v, op, strategy="flat"),
-                plan.allreduce(v, op, strategy="hier"))
-
-    flat, hier = jax.jit(plan.shard_map(
-        body, in_specs=plan.row_spec, out_specs=(P(), P()),
-        check_replication=False))(jax.device_put(x, plan.data_sharding()))
-    np.testing.assert_allclose(
-        np.asarray(flat.astype(jnp.float32)),
-        np.asarray(hier.astype(jnp.float32)), rtol=tol, atol=tol)
-
-
-def test_hier_allreduce_deterministic():
-    # ring-ordered combines: the hierarchical route must be bit-stable
-    # run-to-run on a fixed plan (the property the GBDT forest identity
-    # leans on)
-    plan = MeshPlan.build(hosts=2)
-    x = jnp.asarray(np.random.default_rng(1).standard_normal((8 * 100,)),
-                    jnp.float32)
-    step = jax.jit(plan.shard_map(
-        lambda v: plan.allreduce(v, "sum", strategy="hier"),
-        in_specs=plan.row_spec, out_specs=P(), check_replication=False))
-    xd = jax.device_put(x, plan.data_sharding())
-    np.testing.assert_array_equal(np.asarray(step(xd)),
-                                  np.asarray(step(xd)))
-
-
-def test_plan_allreduce_bench_smoke():
-    out = plan_allreduce_bench(MeshPlan.build(), strategy="hier",
-                               mib_per_device=0.125, iters=2, warmup=1)
-    assert out["devices"] == 8
-    assert out["bus_gbps"] > 0
-    assert out["strategy"] == "hier"
+    out = jax.jit(plan.shard_map(
+        lambda v: plan.allreduce(v, op), in_specs=plan.row_spec,
+        out_specs=P(), check_replication=False))(
+            jax.device_put(x, plan.data_sharding()))
+    shards = np.asarray(x.astype(jnp.float32)).reshape(8, 513)
+    want = getattr(np, op)(shards, axis=0)
+    assert out.shape == (513,) and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), want,
+                               rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------
-# topology discovery + knobs
+# topology discovery; the routes that were removed
 # ---------------------------------------------------------------------------
 
 def test_build_topology():
     plan = MeshPlan.build()
     assert plan.axes == ("data",)
-    assert plan.chip_axis == "data" and plan.host_axis is None
     plan2 = MeshPlan.build(hosts=2)
     assert plan2.axes == ("host", "chip")
     d = plan2.describe()
@@ -81,37 +54,36 @@ def test_build_topology():
     assert d["fabric"] == "host"  # CPU devices: no ICI
 
 
-def test_build_hosts_knob(monkeypatch):
-    monkeypatch.setenv("DMLCTPU_MESH_HOSTS", "4")
-    plan = MeshPlan.build()
+def test_build_hosts_knob():
+    plan = MeshPlan.build(hosts=4)
     assert plan.axes == ("host", "chip")
     assert plan.mesh.shape["host"] == 4 and plan.mesh.shape["chip"] == 2
-    monkeypatch.setenv("DMLCTPU_MESH_HOSTS", "3")
     with pytest.raises(ValueError, match="do not split over 3 host"):
-        MeshPlan.build()
+        MeshPlan.build(hosts=3)
 
 
-def test_collective_knobs(monkeypatch):
-    plan = MeshPlan.build()
-    assert plan.strategy_for(1 << 10) == "flat"  # under 256 KiB default
-    assert plan.strategy_for(1 << 20) == "hier"
-    monkeypatch.setenv("DMLCTPU_MESH_COLLECTIVE", "flat")
-    assert MeshPlan.build().strategy_for(1 << 20) == "flat"
-    monkeypatch.setenv("DMLCTPU_MESH_COLLECTIVE", "hier")
-    assert MeshPlan.build().strategy_for(16) == "hier"
-    monkeypatch.setenv("DMLCTPU_MESH_COLLECTIVE", "bogus")
-    with pytest.raises(ValueError, match="DMLCTPU_MESH_COLLECTIVE"):
-        MeshPlan.build()
-    monkeypatch.delenv("DMLCTPU_MESH_COLLECTIVE")
-    monkeypatch.setenv("DMLCTPU_MESH_HIER_THRESHOLD_KB", "1")
-    assert MeshPlan.build().strategy_for(2048) == "hier"
-    monkeypatch.setenv("DMLCTPU_MESH_OVERLAP_CHUNKS", "4")
-    assert MeshPlan.build().overlap_chunks == 4
+def test_removed_routes_raise_and_say_so():
+    """``collective`` and ``overlap_chunks`` take the one value the
+    benchmark's generator passes; what PR 44 removed is named."""
+    plan = MeshPlan.build(collective="flat", overlap_chunks=1)
+    d = plan.describe()
+    assert d["collective"] == "flat" and d["overlap_chunks"] == 1
+    for route in ("hier", "auto"):
+        with pytest.raises(ValueError, match="ppermute ring.*removed in PR 44"):
+            MeshPlan.build(collective=route)
+    with pytest.raises(ValueError,
+                       match="chunked level loop.*removed in PR 44"):
+        MeshPlan.build(overlap_chunks=2)
 
 
 def test_single_shard_plan_stays_flat():
     plan = MeshPlan.build(devices=jax.devices()[:1])
-    assert plan.strategy_for(1 << 30) == "flat"
+    assert plan.num_shards == 1 and plan.axes == ("data",)
+    x = jnp.arange(7, dtype=jnp.float32)
+    out = jax.jit(plan.shard_map(
+        lambda v: plan.allreduce(v, "sum"), in_specs=plan.row_spec,
+        out_specs=P(), check_replication=False))(x)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
 def test_make_mesh_raises_instead_of_asserting():
@@ -122,29 +94,19 @@ def test_make_mesh_raises_instead_of_asserting():
 
 
 # ---------------------------------------------------------------------------
-# spec adaptation (back-compat with the (mesh, axis) tuple)
+# a plan is one type
 # ---------------------------------------------------------------------------
 
-def test_from_spec_shapes():
-    assert MeshPlan.from_spec(None) is None
-    plan = MeshPlan.build()
-    assert MeshPlan.from_spec(plan) is plan  # passthrough, not a copy
-    bare = MeshPlan.from_spec(plan.mesh)
-    assert isinstance(bare, MeshPlan) and bare.axes == ("data",)
-    assert not bare.prefer_gspmd
-
-
-def test_tuple_adapter_back_compat():
+def test_histogram_mesh_takes_a_plan_only():
     mesh = make_mesh((8,), ("data",))
-    m = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=8,
-             learning_rate=0.3, histogram="xla",
-             histogram_mesh=(mesh, "data"))
-    assert isinstance(m.mesh_plan, MeshPlan)
-    assert m.mesh_plan.prefer_gspmd  # tuples keep the legacy GSPMD route
-    assert m.histogram_mesh == (mesh, "data")  # legacy_spec round-trips
-    with pytest.raises(ValueError, match="histogram_mesh axis"):
-        GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=8,
-             learning_rate=0.3, histogram_mesh=(mesh, "model"))
+    kw = dict(num_features=4, num_trees=1, max_depth=2, num_bins=8)
+    for spec in (mesh, (mesh, "data")):
+        with pytest.raises(TypeError, match=r"MeshPlan\(mesh, axes\)"):
+            GBDT(histogram_mesh=spec, **kw)
+    plan = MeshPlan(mesh, ("data",))
+    m = GBDT(histogram_mesh=plan, **kw)
+    assert m.mesh_plan is plan and not hasattr(m, "histogram_mesh")
+    assert GBDT(**kw).mesh_plan is None
 
 
 # ---------------------------------------------------------------------------
@@ -161,9 +123,9 @@ def _binned_data(rows=2048, feats=8, seed=3):
     return np.asarray(QuantileBinner(num_bins=_BINS).fit_transform(x)), y
 
 
-def _fit(plan, bins, y):
+def _fit(plan, bins, y, histogram="xla"):
     m = GBDT(num_features=bins.shape[1], num_trees=2, max_depth=4,
-             num_bins=_BINS, learning_rate=0.3, histogram="xla",
+             num_bins=_BINS, learning_rate=0.3, histogram=histogram,
              histogram_mesh=plan)
     if plan is not None:
         bins = jax.device_put(bins, plan.data_sharding())
@@ -171,32 +133,18 @@ def _fit(plan, bins, y):
     return m.fit(bins, y)
 
 
-def test_plan_routed_fit_matches_single_device():
+@pytest.mark.parametrize("hosts", [None, 2])
+@pytest.mark.parametrize("histogram", ["xla", "pallas"])
+def test_plan_routed_fit_matches_single_device(hosts, histogram):
     bins, y = _binned_data()
-    ref = _fit(None, bins, y)
-    for plan in (MeshPlan.build(), MeshPlan.build(hosts=2)):
-        forest = _fit(plan, bins, y)
-        # identical tree structure; leaves may differ by reduction
-        # rounding between the single-device and collective routes
-        np.testing.assert_array_equal(np.asarray(ref["feature"]),
-                                      np.asarray(forest["feature"]))
-        np.testing.assert_array_equal(np.asarray(ref["threshold"]),
-                                      np.asarray(forest["threshold"]))
-        np.testing.assert_allclose(np.asarray(ref["leaf"]),
-                                   np.asarray(forest["leaf"]),
-                                   rtol=1e-4, atol=1e-6)
-
-
-def test_overlap_chunks_forest_bit_identical():
-    # the collective/compute overlap contract: chunking the level-loop
-    # histogram reduction must not move a single bit of the forest
-    bins, y = _binned_data()
-    base = _fit(MeshPlan.build(overlap_chunks=1), bins, y)
-    variants = [MeshPlan.build(overlap_chunks=2),
-                MeshPlan.build(overlap_chunks=4),
-                MeshPlan.build(hosts=2, overlap_chunks=4)]
-    for plan in variants:
-        forest = _fit(plan, bins, y)
-        for key in ("feature", "threshold", "leaf"):
-            np.testing.assert_array_equal(np.asarray(base[key]),
-                                          np.asarray(forest[key]))
+    ref = _fit(None, bins, y, histogram)
+    forest = _fit(MeshPlan.build(hosts=hosts), bins, y, histogram)
+    # identical tree structure; leaves may differ by reduction rounding
+    # between the single-device and collective routes
+    np.testing.assert_array_equal(np.asarray(ref["feature"]),
+                                  np.asarray(forest["feature"]))
+    np.testing.assert_array_equal(np.asarray(ref["threshold"]),
+                                  np.asarray(forest["threshold"]))
+    np.testing.assert_allclose(np.asarray(ref["leaf"]),
+                               np.asarray(forest["leaf"]),
+                               rtol=1e-4, atol=1e-6)

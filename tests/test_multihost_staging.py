@@ -299,6 +299,7 @@ jax.distributed.initialize(coordinator_address="127.0.0.1:" + port,
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dmlc_core_tpu.models import GBDT, QuantileBinner
+from dmlc_core_tpu.parallel import MeshPlan
 
 # both processes deterministically regenerate the GLOBAL dataset, bin with
 # shared global cuts, then contribute only their half of the rows
@@ -361,7 +362,8 @@ def test_two_process_gbdt_histogram_allreduce():
 _GBDT_MESH_CHILD = _GBDT_CHILD_PRELUDE + r"""
 forest_x = GBDT(histogram="xla", **kw).fit(bins_g, label_g)
 forest_p = GBDT(histogram="pallas",
-                histogram_mesh=(mesh, "data"), **kw).fit(bins_g, label_g)
+                histogram_mesh=MeshPlan(mesh, ("data",)),
+                **kw).fit(bins_g, label_g)
 match = (np.array_equal(np.asarray(forest_x["feature"]),
                         np.asarray(forest_p["feature"]))
          and np.array_equal(np.asarray(forest_x["threshold"]),
