@@ -10,6 +10,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import telemetry
 
@@ -133,6 +134,14 @@ TOUCHED_ROWS_VISITS = (1 << 16, 1 << 17, 1 << 18, 1 << 19)
 #: key's run before the windows themselves (``ops.sparse.window_totals``)
 WIDE_ROWS_CHUNK = 1 << 16
 RUN_WINDOW = 16
+#: distinct keys a worker may hand ONE owner in the sharded step's exchanges
+#: (``_sharded_rows_step``), and the worker's own entry lanes after these: the
+#: capacity is static, so a step whose fullest (worker, owner) pair passes a
+#: candidate runs the next one, and the last one holds whatever a minibatch
+#: names.  At Criteo Terabyte's draw over 2^28 buckets a worker's 16,384 rows
+#: name 85,200 distinct keys and the fullest (worker, owner) pair of four
+#: chips holds 21,600 of them
+EXCHANGE_LANES = (1 << 15,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,6 +273,19 @@ def visit_distinct(touched, entries: int, carry, body):
     return carry
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _zeros_on(shape: tuple, dtype, sharding) -> jax.Array:
+    """Zeros made where ``sharding`` lays them: each shard on its own chip,
+    so that a table no one chip holds never passes through one."""
+    return jax.lax.with_sharding_constraint(jnp.zeros(shape, dtype), sharding)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _tables_on_plan(model, fresh, seed) -> dict:
+    """``TouchedRowsMixin.init_tables`` under a plan: one program."""
+    return model.init_optimizer(fresh(seed))
+
+
 class TouchedRowsMixin(SGDModelMixin):
     """``train_step`` for a model that names an ``optimizer``: distinct keys
     of the batch, their rows gathered once a key and carried to the entries
@@ -302,14 +324,33 @@ class TouchedRowsMixin(SGDModelMixin):
     like the parameters under that rule); ``params[k]`` itself stays the
     weight, so ``predict``, checkpoints and the scoring server see what they
     saw.
+
+    ``mesh`` (a ``parallel.MeshPlan``) shards every row table and its state
+    by key over the plan's chips, each chip a worker and a server as
+    ps-lite's nodes are: ``_sharded_rows_step``, a program of its own made
+    of this step's pieces.
     """
 
     optimizer = None
+    mesh = None
     row_tables = ("w",)
     gated_tables = ()
     count_threshold = None
 
-    def _set_optimizer(self, optimizer) -> None:
+    def _set_optimizer(self, optimizer, mesh=None) -> None:
+        if mesh is not None:
+            from ..parallel import MeshPlan
+            if not isinstance(mesh, MeshPlan):
+                raise TypeError("mesh= takes a parallel.MeshPlan, got "
+                                f"{type(mesh).__name__}")
+            if optimizer is None or isinstance(optimizer, SGD):
+                raise ValueError(
+                    "a plan shards the tables for the touched-rows step "
+                    "under FTRL / AdaGrad; without an optimizer, or under "
+                    "SGD (whose mean takes the global weight), there is no "
+                    "sharded step")
+            mesh.rows_per_shard(self.num_features)
+        self.mesh = mesh
         names = self.row_tables
         fits = optimizer is None or isinstance(optimizer, SGD) or (
             len(names) == 1 if isinstance(optimizer, FTRL) else
@@ -332,6 +373,24 @@ class TouchedRowsMixin(SGDModelMixin):
             return self.optimizer.get(name, self.optimizer[self.row_tables[0]])
         return self.optimizer
 
+    def init_tables(self, fresh, seed: int) -> dict:
+        """What ``init`` returns: ``fresh(seed)``, the model's bare
+        parameters, with each rule's zero state.
+
+        Under a plan ONE program makes them all, handed nothing but the
+        seed: every chip then lays its shards out alike, in the program's
+        order, whatever the first chip alone held a moment before (a key,
+        a scalar).  Where a table lies moves what its reads a distinct key
+        cost (the same gather 2.7 or 3.5 ms on two chips of one step), and
+        in the sharded step every chip waits at each exchange for the
+        slowest (PERF.md, PR 45)."""
+        if self.mesh is None:
+            return self.init_optimizer(fresh(seed))
+        if not 0 <= seed < 1 << 32:
+            raise ValueError("under a plan a seed is 0 .. 2**32 - 1, got "
+                             f"{seed!r}")
+        return _tables_on_plan(self, fresh, np.uint32(seed))
+
     def init_optimizer(self, params: dict) -> dict:
         """``params`` with each rule's zero state and, under a gate, the
         count table beside them (no optimizer: as given)."""
@@ -342,10 +401,29 @@ class TouchedRowsMixin(SGDModelMixin):
             held, slots = _RULE_STATE[type(self.rule_of(name))]
             for slot in slots:
                 out.setdefault(held, {}).setdefault(slot, {})[name] = (
-                    jnp.zeros_like(p))
+                    self.table_zeros(p.shape, p.dtype, name in self.row_tables))
         if self.count_threshold is not None:
-            out["count"] = jnp.zeros(self.num_features, jnp.int32)
+            out["count"] = self.table_zeros((self.num_features,), jnp.int32)
         return out
+
+    def table_zeros(self, shape: tuple, dtype, by_key: bool = True):
+        """Zeros for a parameter or its state: under a plan a row table's
+        are made shard by shard, each on the chip that owns those keys, and
+        every other parameter's on every chip."""
+        if self.mesh is None:
+            return jnp.zeros(shape, dtype)
+        return _zeros_on(tuple(shape), jnp.dtype(dtype), (
+            self.mesh.data_sharding() if by_key
+            else self.mesh.replicated_sharding()))
+
+    def rows_of_entries(self, params: dict, names, index: jax.Array) -> dict:
+        """``{name: params[name][index]}``, and ``params["count"][index]``
+        if ``"count"`` is asked for: a gather a table, or under a plan
+        ``MeshPlan.take_rows`` of tables sharded by key (scoring's read;
+        the sharded step routes its keys instead)."""
+        if self.mesh is None:
+            return {k: params[k][index] for k in names}
+        return {k: self.mesh.take_rows(params[k], index) for k in names}
 
     def lay_entries(self, batch):
         """The batch as ``_wide_rows_step`` walks it: anything that holds
@@ -397,11 +475,23 @@ class TouchedRowsMixin(SGDModelMixin):
         if self.optimizer is None:
             return super().train_step(params, batch)
         wide = any(params[k].ndim > 2 for k in self.row_tables)
-        with telemetry.span("sgd.step"):
-            new_params, loss, counts = (
-                self._wide_rows_step if wide else self._touched_rows_step)(
-                    params, batch)
-        self._touched.append(counts)
+        exchange = None
+        if self.mesh is not None:
+            if wide:
+                raise ValueError("rows with a shape of their own ([F, A, K]) "
+                                 "have no sharded step")
+            # the plan counts the step's exchanges and reductions once a
+            # call; what they carried is noted when the program is traced
+            with self.mesh.counting("sgd.sharded_step",
+                                    around=telemetry.span("sgd.step")):
+                new_params, loss, counts, exchange = (
+                    self._sharded_rows_step(params, batch))
+        else:
+            with telemetry.span("sgd.step"):
+                new_params, loss, counts = (
+                    self._wide_rows_step if wide else
+                    self._touched_rows_step)(params, batch)
+        self._touched.append((counts, exchange))
         self.flush_step_counters(wait=False)
         return new_params, loss
 
@@ -412,11 +502,27 @@ class TouchedRowsMixin(SGDModelMixin):
         (live entries whose rows reached them from the distinct keys
         through ``spread_by_key``) and, under a gate, ``sgd.active_rows``
         (distinct keys whose gate was open) and ``sgd.activated_rows``
-        (those whose count crossed the threshold in the step).
+        (those whose count crossed the threshold in the step).  Under a
+        plan all of these are the GLOBAL step's, and beside them go
+        ``sgd.owner_rows`` (distinct keys the fullest owner updated),
+        ``sgd.exchange_lanes`` (the capacity a destination of the candidate
+        that ran), ``sgd.exchange_overflow`` (steps that had to take a
+        wider candidate than the first) and the step's three exchanges in
+        ``mesh.alltoall_calls`` and ``mesh.alltoall_bytes`` (what a chip
+        handed them at the capacity that ran; the reductions go through
+        ``plan.counting``).
         ``wait=False`` (every ``train_step``) takes only the steps the
         device has finished and never waits for it."""
-        while self._touched and (wait or self._touched[0][0].is_ready()):
-            touched, tiles, spread, *gate = self._touched.popleft()
+        while self._touched and (wait or self._touched[0][0][0].is_ready()):
+            (touched, tiles, spread, *gate), exchange = self._touched.popleft()
+            if exchange is not None:
+                owner_rows, lanes, overflow = exchange
+                telemetry.counter_add("mesh.alltoall_calls", 3)
+                telemetry.counter_add("mesh.alltoall_bytes",
+                                      self._exchange_bytes[int(lanes)])
+                telemetry.counter_add("sgd.owner_rows", int(owner_rows))
+                telemetry.counter_add("sgd.exchange_lanes", int(lanes))
+                telemetry.counter_add("sgd.exchange_overflow", int(overflow))
             telemetry.counter_add("sgd.touched_rows", int(touched))
             telemetry.counter_add("sgd.scatter_tiles", int(tiles))
             telemetry.counter_add("sgd.spread_entries", int(spread))
@@ -589,6 +695,373 @@ class TouchedRowsMixin(SGDModelMixin):
             out["count"] = count
             counts += (opened, crossed)
         return out, loss, counts
+
+    @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
+    def _sharded_rows_step(self, params: dict, batch) -> tuple:
+        """``_touched_rows_step`` over tables sharded by key (``self.mesh``):
+        ONE program under ``plan.shard_map`` in which every chip is a worker
+        (its own rows of the global minibatch) and a server (the keys
+        ``[c F/S, (c+1) F/S)`` of every table), as DiFacto's nodes are over
+        ps-lite.  It gives what one ``_touched_rows_step`` gives on the
+        global minibatch (counts first, then the gate, then the step), a
+        key's gradient summed in another order:
+
+        (a) worker: ``reduce_by_key`` over its own rows' entries;
+        (b) its distinct keys and their occurrences to their owners
+            (``plan.alltoall``): sorted keys lie owner by owner, so what goes
+            to owner ``d`` is a slice of them, at a static capacity a
+            destination, ``EXCHANGE_LANES`` (a step whose fullest pair
+            passes a candidate runs the next; the last holds every lane:
+            no key is ever dropped);
+        (c) owner: the senders' lists merged into the shard's distinct keys,
+            the counts added, every table read once a distinct key, the
+            gate read there;
+        (d) ``w``, the gated rows and the gate back through the inverse
+            exchange and spread to the entries;
+        (e) worker: margins, loss (a ``psum``), gradients summed a key;
+        (f) gradient sums to the owners, summed a key over the senders, a
+            rule a table, rows whose gate is shut written back as they
+            were, ``scatter_rows`` into the local shard.
+
+        The batch is the global one laid over the chips on its leading axes
+        (``DeviceStagingIter(sharding=plan.data_sharding())``; ``row_ptr``
+        and ``num_rows`` on every chip): chip ``c`` works the rows ``[c B/S,
+        (c+1) B/S)``, whose entries must lie on its own lanes ``[c E/S,
+        (c+1) E/S)`` — rows of a fixed number of entries staged with
+        ``nnz_bucket = batch_size * entries``.  A live entry on another
+        chip's lanes poisons the loss (NaN): it is never silently dropped.
+
+        Returns ``(params, loss, counts, (owner_rows, lanes, overflow))``."""
+        plan, names = self.mesh, self.row_tables
+        rules = {k: self.rule_of(k) for k in params if k not in STATE_KEYS}
+        by_key, everywhere = plan.row_spec, jax.sharding.PartitionSpec()
+
+        def spec_of(name):
+            return by_key if name in names else everywhere
+
+        specs = {k: spec_of(k) for k in rules}
+        for k in RULE_STATE_KEYS:
+            if k in params:
+                specs[k] = {slot: {n: spec_of(n) for n in held}
+                            for slot, held in params[k].items()}
+        if "count" in params:
+            specs["count"] = by_key
+        batch_specs = dataclasses.replace(
+            batch, row_ptr=everywhere, num_rows=everywhere,
+            **{f: by_key for f in ("label", "weight", "index", "value",
+                                   "field", "qid")
+               if getattr(batch, f) is not None})
+        return plan.shard_map(
+            functools.partial(self._sharded_rows_body, rules=rules),
+            (specs, batch_specs),
+            (specs, everywhere, everywhere, everywhere),
+            check_replication=False)(params, batch)
+
+    def _sharded_rows_body(self, params: dict, batch, rules: dict) -> tuple:
+        """One chip's part of ``_sharded_rows_step``: ``params`` its shard
+        of every table, ``batch`` its lanes of the global batch.  The
+        exchanges and the owner's work run in a loop of one trip or none a
+        candidate capacity (``visit_distinct``'s idiom: the tables stay in
+        place in the loop's carry) on either side of the worker's margins,
+        which are compiled once: what the owner's first half hands its
+        second (the keys, the gate, each table's weight a key) rides in
+        arrays of the widest candidate's lanes."""
+        from ..ops.pallas_rows import scatter_rows
+        from ..ops.sparse import reduce_by_key, spread_by_key
+        plan, names, first = self.mesh, self.row_tables, self.row_tables[0]
+        shards, bound = plan.num_shards, self.num_features
+        owned = plan.rows_per_shard(bound)
+        gate = self.count_threshold is not None
+        dense = [k for k in rules if k not in names]
+        flat = [k for k in names if params[k].ndim == 1]
+        wide = [k for k in names if params[k].ndim > 1]
+        widths = [params[k].shape[1] for k in wide]
+        me = plan.shard_index()
+        rows_here, entries = batch.label.shape[0], batch.index.shape[0]
+        lane = jnp.arange(entries, dtype=jnp.int32)
+        sorted_distinct = dict(mode="fill", unique_indices=True,
+                               indices_are_sorted=True)
+        zero = jnp.zeros((), jnp.int32)
+        # this chip's rows of the global batch, as a batch of its own
+        ptr = jax.lax.dynamic_slice_in_dim(
+            batch.row_ptr, me * rows_here, rows_here + 1) - me * entries
+        index, live = batch.index, batch.value != 0
+        stray = jnp.sum(live & ((lane < ptr[0]) | (lane >= ptr[-1])),
+                        dtype=jnp.int32)
+        batch = dataclasses.replace(
+            batch, row_ptr=jnp.clip(ptr, 0, entries),
+            num_rows=jnp.clip(batch.num_rows - me * rows_here, 0, rows_here))
+        tables = {k: _with_state(params, rules[k], k) for k in names}
+        sizes = [c for c in EXCHANGE_LANES if c < entries] + [entries]
+        widest = shards * sizes[-1]
+        # what a chip hands the three exchanges at each capacity, 4 B an
+        # element: the keys (and counts), what the margins take, the
+        # gradient sums.  Only one candidate runs, so the step counts its
+        # own exchanges once it knows which (``flush_step_counters``)
+        self._exchange_bytes = {c: 4 * shards * c * (
+            1 + gate + 2 * (len(flat) + sum(widths)) + gate) for c in sizes}
+
+        # (a) the worker's distinct keys, how its entries lie on them and,
+        # under a gate, each key's occurrences
+        with jax.named_scope("sgd.unique"):
+            keys, seen, _touched, runs = reduce_by_key(
+                index, live, (live.astype(jnp.int32),) if gate else (),
+                bound, runs=True)
+            # sorted keys lie owner by owner: where each owner's begin
+            starts = jnp.sum(
+                keys[None, :] < (jnp.arange(shards + 1, dtype=jnp.int32)
+                                 * owned)[:, None], axis=1, dtype=jnp.int32)
+            most = plan.allreduce(jnp.max(starts[1:] - starts[:-1]), "max")
+
+        def candidates(body, carry):
+            """``body(capacity, trip, carry)`` for the one candidate that
+            holds ``most``; ``trip`` is the loop's own index, 0."""
+            for fewer, capacity in zip([-1] + sizes, sizes):
+                here = (most > fewer) & (most <= capacity)
+                carry = jax.lax.fori_loop(
+                    0, here.astype(jnp.int32),
+                    lambda trip, c, capacity=capacity: body(capacity, trip, c),
+                    carry)
+            return carry
+
+        def lay(capacity, trip):
+            """How the compact lanes go out to the owners and come back at
+            ``capacity`` a destination.  ``trip`` is 0: with it what follows
+            stays in its candidate's loop (``_touched_rows_step.lanes_of``)."""
+            begins = starts + trip
+            fits = (jnp.arange(capacity, dtype=jnp.int32)[None, :]
+                    < (begins[1:] - begins[:-1])[:, None])
+
+            def outbound(x, fill):
+                """What the compact lanes ``x`` hold for each owner:
+                ``[S, capacity]``."""
+                x = jnp.concatenate([x, jnp.full(capacity, fill, x.dtype)])
+                return jnp.where(fits, jnp.stack([
+                    jax.lax.dynamic_slice_in_dim(x, begins[d], capacity)
+                    for d in range(shards)]), fill)
+
+            def inbound(x):
+                """The inverse: ``[S, R, capacity]`` back from the owners
+                onto the compact lanes, ``[R, entries]``; each owner's
+                slice is written where it was taken, the next one over
+                whatever lay past its keys."""
+                out = jnp.zeros((x.shape[1], entries + capacity), x.dtype)
+                x = jnp.where(fits[:, None, :], x, 0)
+                for d in range(shards):
+                    out = jax.lax.dynamic_update_slice(
+                        out, x[d], (zero, begins[d]))
+                return out[:, :entries]
+
+            return fits, outbound, inbound
+
+        def handed(x, fill=0):
+            """The owner's lanes of one candidate up to the widest one's:
+            one shape whichever ran."""
+            return jnp.concatenate([x, jnp.full(
+                (widest - x.shape[0],) + x.shape[1:], fill, x.dtype)])
+
+        def pull(capacity, trip, carry):
+            count = carry[0]
+            held_lanes = shards * capacity
+            # a candidate past the first hardly ever runs: its passes along
+            # the runs are a loop, not 22 fusions each
+            looped = capacity != sizes[0]
+            owner_lane = jnp.arange(held_lanes, dtype=jnp.int32)
+            _fits, outbound, inbound = lay(capacity, trip)
+            # (b) keys and occurrences to their owners
+            with jax.named_scope("sgd.unique"):
+                sent = jnp.stack(
+                    [outbound(keys, bound)]
+                    + ([outbound(seen[0], 0)] if gate else []), axis=1)
+            got = plan.alltoall(sent, noted=False)
+            # (c) the owner's distinct keys, the senders' counts added
+            with jax.named_scope("sgd.owner_merge"):
+                theirs = got[:, 0].reshape(held_lanes)
+                mine, added, holds, sender_runs = reduce_by_key(
+                    theirs, theirs < bound,
+                    (got[:, 1].reshape(held_lanes),) if gate else (),
+                    bound, runs=True, looped=looped)
+                # rows of the local shard: the spare ids stay past it
+                at = mine - me * owned
+            tiles = crossed = opened = zero
+            if gate:
+                with jax.named_scope("sgd.gather_rows"):
+                    old = count.at[at].get(fill_value=0, **sorted_distinct)
+                with jax.named_scope("sgd.count"):
+                    new_count = old + added[0]
+                    crossed = jnp.sum(
+                        (owner_lane < holds)
+                        & (old <= self.count_threshold)
+                        & (new_count > self.count_threshold), dtype=jnp.int32)
+                with jax.named_scope("sgd.scatter_rows"):
+                    (count,), tiles = scatter_rows(
+                        (count,), at, (new_count,), holds)
+            # each table's weight a distinct key (``w`` is gathered, not
+            # taken as the closed form of ``(z, n)``: a table restored
+            # without its state holds a weight that is not)
+            with jax.named_scope("sgd.gather_rows"):
+                weights = {k: tables[k][0].at[at].get(
+                    fill_value=0, **sorted_distinct) for k in names}
+            open_ = owner_lane < holds
+            if gate:
+                with jax.named_scope("sgd.count"):
+                    open_ = open_ & self.active(new_count, weights[first])
+                    opened = jnp.sum(open_, dtype=jnp.int32)
+            # (d) what the margins take, back to the senders' lanes
+            with jax.named_scope("sgd.owner_merge"):
+                back = spread_by_key(
+                    sender_runs, tuple(weights[k] for k in flat)
+                    + ((open_.astype(jnp.int32),) if gate else ())
+                    + ((owner_lane,) if wide else ()), looped=looped)
+                pulled = [b.astype(jnp.float32)[:, None]
+                          for b in back[:len(flat) + gate]]
+                if wide:
+                    rank = jnp.where(theirs < bound, back[-1], held_lanes)
+                    pulled += [jnp.where(open_[:, None], weights[k], 0).at[
+                        rank].get(mode="fill", fill_value=0) for k in wide]
+                # keys on the minor axis: a row of K floats there would be
+                # padded to a tile's 128
+                pulled = jnp.concatenate(pulled, axis=1).reshape(
+                    shards, capacity, -1).transpose(0, 2, 1)
+            pulled = inbound(plan.alltoall(pulled, noted=False))
+            return (count, pulled, handed(theirs, bound), handed(mine, bound),
+                    handed(open_), {k: handed(w) for k, w in weights.items()},
+                    jnp.stack([holds, tiles, opened, crossed]))
+
+        rows_to_pull = len(flat) + gate + sum(widths)
+        (count, pulled, theirs_, mine_, open_, weights_,
+         owner_counts) = candidates(pull, (
+             params.get("count"),
+             jnp.zeros((rows_to_pull, entries), jnp.float32),
+             jnp.zeros(widest, jnp.int32), jnp.zeros(widest, jnp.int32),
+             jnp.zeros(widest, bool),
+             {k: jnp.zeros((widest,) + tables[k][0].shape[1:],
+                           tables[k][0].dtype) for k in names},
+             jnp.zeros(4, jnp.int32)))
+
+        # ... and to the entries
+        with jax.named_scope("sgd.unique"):
+            spread = spread_by_key(
+                runs, tuple(pulled[i] for i in range(len(flat)))
+                + ((pulled[len(flat)].astype(jnp.int32),) if gate else ())
+                + ((lane,) if wide else ()))
+        rows = dict(zip(flat, spread))
+        if wide:
+            with jax.named_scope("sgd.gather_rows"):
+                rank = jnp.where(live, spread[-1], entries)
+                cut = len(flat) + gate
+                for k, width in zip(wide, widths):
+                    rows[k] = pulled[cut:cut + width].T.at[rank].get(
+                        mode="fill", fill_value=0)
+                    cut += width
+        gated = ()
+        if gate:
+            with jax.named_scope("sgd.count"):
+                gated = (live & (spread[len(flat)] > 0),)
+        # (e) the loss differentiated with respect to the rows an entry
+        with jax.named_scope("sgd.loss"):
+            m, pull_back = jax.vjp(
+                lambda r, d: self.margins_of_rows(r, d, batch, *gated),
+                rows, {k: params[k] for k in dense})
+            loss, slope = self._loss_and_slope(m, batch)
+            g_rows, g_dense = pull_back(slope)
+            # the mean's two halves, for the sum over the workers
+            weight = jnp.sum(batch.weight)
+            loss = loss * jnp.maximum(weight, 1.0)
+        with jax.named_scope("sgd.unique"):
+            _keys, sums, _touched, *wide_sums = reduce_by_key(
+                index, live, tuple(g_rows[k] for k in flat), bound,
+                tuple(g_rows[k] for k in wide))
+
+        def push(capacity, trip, carry):
+            tables, tiles = carry
+            held_lanes = shards * capacity
+            fits, outbound, _inbound = lay(capacity, trip)
+
+            def lanes_of(x):
+                return jax.lax.dynamic_slice_in_dim(x, trip, held_lanes)
+
+            # (f) the gradient sums to the owners
+            with jax.named_scope("sgd.unique"):
+                pushed = [outbound(g, 0)[:, None, :] for g in sums]
+                if wide:
+                    running, ends = wide_sums[0]
+                    ends = outbound(ends, entries)
+                    pushed += [jnp.where(
+                        fits[:, :, None], r.at[ends].get(
+                            mode="fill", fill_value=0), 0).transpose(0, 2, 1)
+                               for r in running]
+                pushed = jnp.concatenate(pushed, axis=1)
+            pushed = plan.alltoall(pushed, noted=False)
+            theirs, at = lanes_of(theirs_), lanes_of(mine_) - me * owned
+            holds, on = owner_counts[0], lanes_of(open_)
+            with jax.named_scope("sgd.owner_merge"):
+                # a key's sum over its senders, on the owner's compact lanes
+                cut = len(flat)
+                _mine, flat_sums, _holds, *row_sums = reduce_by_key(
+                    theirs, theirs < bound,
+                    tuple(pushed[:, i].reshape(held_lanes)
+                          for i in range(cut)), bound,
+                    tuple(pushed[:, cut + sum(widths[:j]):
+                                 cut + sum(widths[:j + 1])].transpose(
+                                     0, 2, 1).reshape(held_lanes, width)
+                          for j, width in enumerate(widths)),
+                    looped=capacity != sizes[0])
+            out = {}
+            for name in names:
+                with jax.named_scope("sgd.gather_rows"):
+                    # the rule's state a distinct key, beside the weight
+                    # the owner's first half read
+                    was = (lanes_of(weights_[name]),) + tuple(
+                        t.at[at].get(fill_value=0, **sorted_distinct)
+                        for t in tables[name][1:])
+                    if name in flat:
+                        g = flat_sums[flat.index(name)]
+                    else:
+                        running, ends = row_sums[0]
+                        g = running[wide.index(name)].at[ends].get(
+                            mode="fill", fill_value=0)
+                was, g = jax.lax.optimization_barrier((was, g))
+                updated = _apply(rules[name], _read_by(rules[name], was), g)
+                if name in self.gated_tables:
+                    with jax.named_scope("sgd.count"):
+                        updated = tuple(
+                            jnp.where(on.reshape((-1,) + (1,) * (
+                                new.ndim - 1)), new, old)
+                            for new, old in zip(updated, was))
+                with jax.named_scope("sgd.scatter_rows"):
+                    out[name], wrote = scatter_rows(
+                        tables[name], at, updated, holds)
+                tiles = tiles + wrote
+            return out, tiles
+
+        tables, tiles = candidates(push, (tables, owner_counts[1]))
+        # every other parameter lives on every chip and takes the sum of the
+        # workers' gradients
+        total = plan.allreduce(jnp.concatenate(
+            [g_dense[k].reshape(-1) for k in dense]
+            + [jnp.stack([loss, weight])]))
+        cut = 0
+        for k in dense:
+            g = total[cut:cut + params[k].size].reshape(params[k].shape)
+            cut += params[k].size
+            tables[k] = _apply(rules[k], _read_by(rules[k], _with_state(
+                params, rules[k], k)), g)
+        holds, _tiles, opened, crossed = owner_counts
+        counts = plan.allreduce(jnp.stack(
+            [holds, tiles, jnp.sum(live, dtype=jnp.int32), opened, crossed,
+             stray]))
+        # a live entry that lay on another chip's lanes poisons the loss
+        loss = jnp.where(counts[5] > 0, jnp.nan,
+                         total[-2] / jnp.maximum(total[-1], 1.0))
+        out = _params_of(tables, rules)
+        if gate:
+            out["count"] = count
+        ran = zero + sum((most > c).astype(jnp.int32) for c in sizes[:-1])
+        lanes = jnp.asarray(sizes, jnp.int32)[ran]
+        return (out, loss, tuple(counts[i] for i in range(5 if gate else 3)),
+                (plan.allreduce(holds, "max"), lanes,
+                 (ran > 0).astype(jnp.int32)))
 
     @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
     def _wide_rows_step(self, params: dict, batch) -> tuple:

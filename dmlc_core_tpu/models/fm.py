@@ -37,7 +37,12 @@ class FactorizationMachine(TouchedRowsMixin):
         scorer rebuilt from a snapshot takes it to read the gate off the
         ``count`` table that rides with the weights.  The row is also off
         while the key's ``w`` is 0: the paper's memory-adaptive constraint
-        (wormhole's ``l1_shrk``), which couples it to FTRL's l1."""
+        (wormhole's ``l1_shrk``), which couples it to FTRL's l1.
+    mesh: a ``parallel.MeshPlan`` (with an optimizer): ``w``, ``v``, the
+        count and the rules' state sharded by key over the plan's chips,
+        each made on its own chip by ``init``; ``train_step`` then runs
+        ``TouchedRowsMixin._sharded_rows_step`` on a batch laid over the
+        same chips, and ``predict`` reads the shards under the gate."""
 
     row_tables = ("w", "v")
     gated_tables = ("v",)
@@ -46,7 +51,7 @@ class FactorizationMachine(TouchedRowsMixin):
                  objective: str = "logistic", l2: float = 0.0,
                  learning_rate: float = 0.05, init_scale: float = 0.01,
                  sdot_backend: str | None = None, optimizer=None,
-                 threshold: int | None = None):
+                 threshold: int | None = None, mesh=None):
         if objective not in ("logistic", "squared"):
             raise ValueError(f"unknown objective '{objective}'")
         check_force(sdot_backend, "sdot_backend")
@@ -65,29 +70,39 @@ class FactorizationMachine(TouchedRowsMixin):
             raise ValueError("an optimizer's embedding rows are gated by a "
                              f"count threshold >= 0, got {threshold!r}")
         self.count_threshold = threshold
-        self._set_optimizer(optimizer)
+        self._set_optimizer(optimizer, mesh)
 
     @functools.partial(jax.jit, static_argnums=0)
     def _draw(self, key: jax.Array) -> jax.Array:
         # one program: drawn eagerly, the bits and the unscaled normals of a
         # table of 4.3 GB are two tables more for a moment
-        return self.init_scale * jax.random.normal(
+        drawn = self.init_scale * jax.random.normal(
             key, (self.num_features, self.num_factors), jnp.float32)
+        if self.mesh is None:
+            return drawn
+        # each chip draws the rows it owns (the counter-based generator
+        # splits by element): the same rows as without a plan
+        return jax.lax.with_sharding_constraint(
+            drawn, self.mesh.data_sharding())
 
     def init(self, seed: int = 0) -> dict:
-        return self.init_optimizer({
-            "w": jnp.zeros(self.num_features, jnp.float32),
+        return self.init_tables(self._fresh, seed)
+
+    def _fresh(self, seed) -> dict:
+        return {
+            "w": self.table_zeros((self.num_features,), jnp.float32),
             "v": self._draw(jax.random.PRNGKey(seed)),
-            "b": jnp.zeros((), jnp.float32),
-        })
+            "b": self.table_zeros((), jnp.float32, by_key=False),
+        }
 
     def margins(self, params: dict, batch: PaddedBatch) -> jax.Array:
         if "count" in params:
             # a gated model scores what its training saw: the same rows
             # through the same gate and the same sums
-            rows = {k: params[k][batch.index] for k in self.row_tables}
-            on = (batch.value != 0) & self.active(
-                params["count"][batch.index], rows["w"])
+            rows = self.rows_of_entries(
+                params, self.row_tables + ("count",), batch.index)
+            on = (batch.value != 0) & self.active(rows.pop("count"),
+                                                  rows["w"])
             return self.margins_of_rows(rows, params, batch, on)
         B = batch.batch_size
         rid = batch.row_ids()  # derived on device; CSE'd across the three uses

@@ -25,11 +25,15 @@ class SparseLinearModel(TouchedRowsMixin):
         ``common.FTRL(alpha, beta, l1, l2)``: per-coordinate FTRL-Proximal
         over the rows a batch names (``TouchedRowsMixin``); ``init`` then
         returns the state ``(z, n)`` beside ``w`` and ``b``.
+    mesh: a ``parallel.MeshPlan`` (with an optimizer): ``(w, z, n)``
+        sharded by key over the plan's chips, trained by
+        ``TouchedRowsMixin._sharded_rows_step`` on a batch laid over them.
     """
 
     def __init__(self, num_features: int, objective: str = "logistic",
                  l2: float = 0.0, learning_rate: float = 0.1,
-                 sdot_backend: str | None = None, optimizer=None):
+                 sdot_backend: str | None = None, optimizer=None,
+                 mesh=None):
         if objective not in ("logistic", "squared"):
             raise ValueError(f"unknown objective '{objective}'")
         check_force(sdot_backend, "sdot_backend")
@@ -41,17 +45,22 @@ class SparseLinearModel(TouchedRowsMixin):
         # GSPMD-safe scatter-add; "pallas" = scatter-free kernel,
         # single-device TPU only (no pallas partitioning rule)
         self.sdot_backend = sdot_backend
-        self._set_optimizer(optimizer)
+        self._set_optimizer(optimizer, mesh)
 
     def init(self, seed: int = 0) -> dict:
+        return self.init_tables(self._fresh, seed)
+
+    def _fresh(self, seed) -> dict:
         del seed  # linear model: zero init is canonical
-        return self.init_optimizer(
-            {"w": jnp.zeros(self.num_features, jnp.float32),
-             "b": jnp.zeros((), jnp.float32)})
+        return {"w": self.table_zeros((self.num_features,), jnp.float32),
+                "b": self.table_zeros((), jnp.float32, by_key=False)}
 
     # ---- pure functions (jit-friendly) --------------------------------------
     def margins(self, params: dict, batch: PaddedBatch) -> jax.Array:
         """Per-row scores w·x + b."""
+        if self.mesh is not None:
+            return self.margins_of_rows(self.rows_of_entries(
+                params, ("w",), batch.index), params, batch)
         return csr_matvec(params["w"], batch.index, batch.value,
                           batch.row_ids(), batch.batch_size,
                           force=self.sdot_backend) + params["b"]

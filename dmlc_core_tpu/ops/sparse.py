@@ -100,14 +100,19 @@ def _over(mask: jax.Array, like: jax.Array) -> jax.Array:
     return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
 
 
-def run_sums(keys: jax.Array, *columns: jax.Array, below=None) -> tuple:
+def run_sums(keys: jax.Array, *columns: jax.Array, below=None,
+             looped: bool = False) -> tuple:
     """Inclusive running sum of each of ``columns`` (``[lanes]`` or ``[lanes,
     K]``: every trailing element along its own run) inside every run of
     equal ``keys`` (runs contiguous, as after a sort): the last lane of a run
     holds the run's sum.  ``ceil(log2(n))`` passes of one shifted add — no
     scatter, and a pairwise order of summation.  ``below`` (a power of two):
     only the passes at a distance under it, after which a lane holds the sum
-    of the last ``below`` lanes of its run, itself included."""
+    of the last ``below`` lanes of its run, itself included.  ``looped``: the
+    passes as ONE loop body (:func:`_looped_passes`), for a path that hardly
+    ever runs."""
+    if looped:
+        return _looped_passes(keys, columns, fill=False)
     n, d = keys.shape[0], 1
     while d < min(n, below or n):
         same = jnp.concatenate([jnp.zeros(d, bool), keys[d:] == keys[:-d]])
@@ -119,12 +124,16 @@ def run_sums(keys: jax.Array, *columns: jax.Array, below=None) -> tuple:
     return columns
 
 
-def run_fill(keys: jax.Array, *columns: jax.Array) -> tuple:
+def run_fill(keys: jax.Array, *columns: jax.Array,
+             looped: bool = False) -> tuple:
     """The value on the LAST lane of every run of equal ``keys`` (runs
     contiguous) set on all the run's lanes: :func:`run_sums`'s passes the
     other way round, by select and not by adding zeros, so every bit
     (``-0.0``'s sign, an int32) arrives as it was.  After the pass at
-    distance ``d`` a lane holds lane ``min(i + 2d - 1, its run's end)``."""
+    distance ``d`` a lane holds lane ``min(i + 2d - 1, its run's end)``.
+    ``looped``: as :func:`run_sums`'s."""
+    if looped:
+        return _looped_passes(keys, columns, fill=True)
     n, d = keys.shape[0], 1
     while d < n:
         same = jnp.concatenate([keys[:-d] == keys[d:], jnp.zeros(d, bool)])
@@ -133,6 +142,35 @@ def run_fill(keys: jax.Array, *columns: jax.Array) -> tuple:
             for c in columns)
         d *= 2
     return columns
+
+
+def _looped_passes(keys: jax.Array, columns: tuple, fill: bool) -> tuple:
+    """:func:`run_sums` (or with ``fill`` :func:`run_fill`) of ``columns``
+    with the passes in a loop over the distances, the shift a dynamic slice:
+    the same values pass for pass, in one body whatever the lanes (written
+    out, a pass over ``[lanes, K]`` is 0.2-0.4 MB of the TPU's program)."""
+    n = keys.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    keys2 = jnp.concatenate([keys, keys])
+
+    def one(i, columns):
+        d = jnp.left_shift(1, i)
+        # a fill looks ``d`` lanes ahead, a sum ``d`` lanes back
+        at = d if fill else n - d
+        same = (jax.lax.dynamic_slice_in_dim(keys2, at, n) == keys) & (
+            lane + d < n if fill else lane >= d)
+        moved = tuple(jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([c, c]), at, n) for c in columns)
+        if fill:
+            return tuple(jnp.where(_over(same, c), m, c)
+                         for c, m in zip(columns, moved))
+        return tuple(c + jnp.where(_over(same, c), m, 0)
+                     for c, m in zip(columns, moved))
+
+    if not columns:
+        return ()
+    return jax.lax.fori_loop(0, max(n - 1, 0).bit_length(), one,
+                             tuple(columns))
 
 
 class KeyRuns(NamedTuple):
@@ -152,7 +190,8 @@ class KeyRuns(NamedTuple):
 
 
 def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
-                  bound: int, rows: tuple = (), runs: bool = False) -> tuple:
+                  bound: int, rows: tuple = (), runs: bool = False,
+                  looped: bool = False) -> tuple:
     """Per-entry ``columns`` summed onto the distinct ``index`` values of
     the ``live`` entries.  Sorts are this chip's cheap primitive (0.8 ms for
     655,360 lanes of key and payload on a v5e, where a gather or a scatter
@@ -177,13 +216,15 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
     entry.
 
     ``runs``: the sorts carry the lanes as they do for ``rows`` and a last
-    element, the :class:`KeyRuns`, is returned for :func:`spread_by_key`."""
+    element, the :class:`KeyRuns`, is returned for :func:`spread_by_key`.
+
+    ``looped``: the run sums' passes in a loop (:func:`run_sums`)."""
     n = index.shape[0]
     key = jnp.where(live, index, bound)
     lane = (jnp.arange(n, dtype=jnp.int32),) if rows or runs else ()
     sk, *sc = jax.lax.sort((key, *lane, *columns), num_keys=1, is_stable=False)
     order, sc = sc[:len(lane)], sc[len(lane):]
-    sums = run_sums(sk, *sc)
+    sums = run_sums(sk, *sc, looped=looped)
     ends = jnp.concatenate([sk[1:] != sk[:-1], jnp.ones(1, bool)]) & (
         sk < bound)
     spare = bound + jnp.arange(n, dtype=sk.dtype)
@@ -193,13 +234,15 @@ def reduce_by_key(index: jax.Array, live: jax.Array, columns: tuple,
     count = jnp.sum(ends, dtype=jnp.int32)
     out = (keys, sums, count)
     if rows:
-        out += ((run_sums(sk, *(r[order[0]] for r in rows)), at[0]),)
+        out += ((run_sums(sk, *(r[order[0]] for r in rows), looped=looped),
+                 at[0]),)
     if runs:
         out += (KeyRuns(keys, count, sk, order[0], at[0]),)
     return out
 
 
-def spread_by_key(runs: KeyRuns, columns: tuple) -> tuple:
+def spread_by_key(runs: KeyRuns, columns: tuple,
+                  looped: bool = False) -> tuple:
     """The transpose of :func:`reduce_by_key`: ``columns`` hold one value a
     DISTINCT key on the compact lanes (``c[j]`` belongs to ``runs.keys[j]``;
     ``[m]`` for any ``m`` that holds the distinct keys, any 32-bit dtype),
@@ -209,7 +252,8 @@ def spread_by_key(runs: KeyRuns, columns: tuple) -> tuple:
     its run ends, :func:`run_fill` along the runs, and a sort by the lane
     each entry came from.  A key's rank rides as a column too
     (``jnp.arange``), and rows of K floats then take ONE gather by it out
-    of their compact array (a sort would pay for each of the K columns)."""
+    of their compact array (a sort would pay for each of the K columns).
+    ``looped``: the fill's passes in a loop (:func:`run_fill`)."""
     n = runs.order.shape[0]
     held = jnp.arange(n, dtype=jnp.int32) < runs.count
     columns = tuple(
@@ -217,7 +261,7 @@ def spread_by_key(runs: KeyRuns, columns: tuple) -> tuple:
             [c, jnp.zeros(n - c.shape[0], c.dtype)]), 0) for c in columns)
     _, *at_ends = jax.lax.sort((runs.ends, *columns), num_keys=1,
                                is_stable=False)
-    filled = run_fill(runs.sorted_keys, *at_ends)
+    filled = run_fill(runs.sorted_keys, *at_ends, looped=looped)
     _, *spread = jax.lax.sort((runs.order, *filled), num_keys=1,
                               is_stable=False)
     return tuple(spread)

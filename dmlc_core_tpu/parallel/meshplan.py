@@ -16,6 +16,14 @@ reduced — live in a single ``MeshPlan``:
   the plan's axes, which is topology-aware on ICI (a hand-built ring is
   rabit's answer to TCP): 0.117 of a 1,081 ms Airline round on four
   chips (PERF.md, PR 43).
+* **Exchange and key layout** (the parameter server's half, PR 45): a table
+  sharded on axis 0 over the plan's axes is range-partitioned by key as
+  ps-lite's servers partition theirs, shard ``c`` of ``S`` owning rows
+  ``[c F/S, (c+1) F/S)`` (:meth:`MeshPlan.rows_per_shard`,
+  :meth:`MeshPlan.owner_of`); :meth:`MeshPlan.alltoall` carries what every
+  shard has for every other (a minibatch's keys to their owners, the rows
+  back, the gradients out) as XLA's ``all_to_all``;
+  :meth:`MeshPlan.take_rows` reads such a table at ids every shard holds.
 """
 from __future__ import annotations
 
@@ -24,6 +32,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .collective import _OPS, shard_map_compat
@@ -72,8 +81,9 @@ class MeshPlan:
         self.axes = axes
         self.collective = collective
         self.overlap_chunks = 1
-        # what allreduce has been traced with; what counting() saw of it
-        self._traced, self._census = [0, 0], {}
+        # calls and bytes allreduce and alltoall have been traced with; what
+        # counting() saw of them
+        self._traced, self._census = [0, 0, 0, 0], {}
         platforms = {d.platform for d in np.asarray(mesh.devices).ravel()}
         self.fabric = "ici" if platforms == {"tpu"} else "host"
 
@@ -122,6 +132,50 @@ class MeshPlan:
     def replicated_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
 
+    @property
+    def _over(self):
+        """The axis name a collective over the whole plan takes."""
+        return self.axes if len(self.axes) > 1 else self.axes[0]
+
+    def shard_index(self) -> jax.Array:
+        """This shard's place along the plan's axes, host-major: the ``c``
+        of the rows ``row_spec`` gives it.  Call inside traced code."""
+        at = 0
+        for a in self.axes:
+            at = at * self.mesh.shape[a] + jax.lax.axis_index(a)
+        return at
+
+    def rows_per_shard(self, length: int) -> int:
+        """Rows of a table of ``length`` sharded on axis 0 that one shard
+        holds: shard ``c`` owns keys ``[c * rows, (c + 1) * rows)``."""
+        if length % self.num_shards:
+            raise ValueError(f"a table of {length} rows does not split over "
+                             f"{self.num_shards} shard(s)")
+        return length // self.num_shards
+
+    def owner_of(self, keys: jax.Array, length: int) -> jax.Array:
+        """The shard that owns each of ``keys`` of a table of ``length``
+        rows (ps-lite's range partition)."""
+        return keys // self.rows_per_shard(length)
+
+    def take_rows(self, table: jax.Array, ids: jax.Array) -> jax.Array:
+        """``table[ids]`` for a table sharded on axis 0 over the plan and
+        ids every shard holds: each shard reads the rows it owns, zeros
+        elsewhere, and a sum brings them together (a row plus zeros: every
+        bit but a ``-0.0``'s sign).  Call inside jit; an id past the table
+        reads 0."""
+        rows = self.rows_per_shard(table.shape[0])
+
+        def local(shard, ids):
+            at = ids - self.shard_index() * rows
+            mine = (at >= 0) & (at < rows)
+            got = shard[jnp.where(mine, at, 0)]
+            got = jnp.where(
+                mine.reshape(mine.shape + (1,) * (got.ndim - 1)), got, 0)
+            return _OPS["sum"](got, self._over)
+
+        return self.shard_map(local, (self.row_spec, P()), P())(table, ids)
+
     def shard_map(self, fn, in_specs, out_specs,
                   check_replication: bool = True):
         return shard_map_compat(fn, self.mesh, in_specs, out_specs,
@@ -154,15 +208,37 @@ class MeshPlan:
         # every plan-routed reduction under one scope: collective time is
         # read off a device trace by this name
         with jax.named_scope("mesh.allreduce"):
-            return _OPS[op](
-                x, self.axes if len(self.axes) > 1 else self.axes[0])
+            return _OPS[op](x, self._over)
+
+    def alltoall(self, x: jax.Array, noted: bool = True) -> jax.Array:
+        """The exchange: ``x`` is ``[S, ...]`` on every shard, ``x[d]`` what
+        this shard has for shard ``d``; returns ``[S, ...]`` whose row ``s``
+        is what shard ``s`` had for this one (shards counted host-major, as
+        :meth:`shard_index` counts them).  Call inside traced code; on a
+        plan of one shard it is the identity.  Noted for :meth:`counting`
+        as :meth:`allreduce` is, unless ``noted=False``: an exchange on a
+        path that only some executions of its program take (one candidate
+        capacity of several) is counted by its caller, who learns which
+        ran."""
+        if x.shape[0] != self.num_shards:
+            raise ValueError(f"alltoall wants one row a shard "
+                             f"({self.num_shards}), got {x.shape}")
+        if noted:
+            self._traced[2] += 1
+            self._traced[3] += int(x.size) * x.dtype.itemsize
+        # every plan-routed exchange under one scope, as the reductions are
+        with jax.named_scope("mesh.alltoall"):
+            return jax.lax.all_to_all(x, self._over, 0, 0)
 
     def counting(self, program: str, executions: int = 1, around=None):
         """Context manager around ``executions`` calls of ONE jitted program
-        whose trace calls :meth:`allreduce`: adds what they reduce to the
-        counters ``mesh.allreduce_calls`` and ``mesh.collective_bytes`` (the
-        payload a reduction is handed, as every chip holds it; not what
-        moves over the wires).
+        whose trace calls :meth:`allreduce` or :meth:`alltoall`: adds what
+        they reduce to the counters ``mesh.allreduce_calls`` and
+        ``mesh.collective_bytes`` and what they exchange to
+        ``mesh.alltoall_calls`` and ``mesh.alltoall_bytes`` (the payload a
+        collective is handed, as every chip holds it; not what moves over
+        the wires: of an exchange's payload the row a chip keeps for itself
+        never leaves it).
 
         Only the caller of a compiled program knows how often it runs.
         What one execution reduces is noted when a call inside the block
@@ -187,17 +263,20 @@ class _Counting:
 
     def __exit__(self, *exc):
         plan = self.plan
-        calls, nbytes = (plan._traced[0] - self.mark[0],
-                         plan._traced[1] - self.mark[1])
-        if calls:
-            plan._census[self.program] = (calls, nbytes)
-        calls, nbytes = plan._census.get(self.program, (0, 0))
+        seen = tuple(now - then for now, then in zip(plan._traced, self.mark))
+        if any(seen):
+            plan._census[self.program] = seen
+        seen = plan._census.get(self.program, (0, 0, 0, 0))
         try:
             from .. import telemetry
             telemetry.counter_add("mesh.allreduce_calls",
-                                  calls * self.executions)
+                                  seen[0] * self.executions)
             telemetry.counter_add("mesh.collective_bytes",
-                                  nbytes * self.executions)
+                                  seen[1] * self.executions)
+            telemetry.counter_add("mesh.alltoall_calls",
+                                  seen[2] * self.executions)
+            telemetry.counter_add("mesh.alltoall_bytes",
+                                  seen[3] * self.executions)
         except Exception:
             pass
         if self.around is not None:

@@ -475,3 +475,76 @@ def test_wide_rows_step_is_a_program_fetched_in_a_second(one_chip,
                   "sgd.unique", "sgd.gather_rows", "sgd.scatter_rows"):
         part = re.compile(r"[/(]" + re.escape(scope) + r"[/)]")
         assert any(part.search(n) for n in names), scope
+
+
+@pytest.mark.slow     # two to four minutes of the TPU's compiler
+def test_sharded_rows_step_exchanges_keys_and_gathers_no_table(
+        topo, quiet_cache, monkeypatch):
+    """The sharded touched-rows step at the ``criteo-tb-difacto-ps4`` cell's
+    shapes (2^28 buckets x ``(w, z, n)``, a count, rows of 16 floats and
+    their AdaGrad sums over four chips; a global batch of 65,536 x 39
+    entries), through the TPU's compiler for a described v5e 2x2: every chip
+    holds its quarter of every table and nothing of another's (no all-gather
+    of anything, the tables aliased in place and never copied); three
+    exchanges a capacity candidate under the scope ``mesh.alltoall``, keys
+    on the minor axis (a row of 16 floats there would be padded to a tile's
+    128 lanes); four reductions through the plan; the scopes the cell's
+    metrics read come through; the temporaries stay under an eighth of a
+    chip's tables."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from dmlc_core_tpu.data.staging import PaddedBatch
+    from dmlc_core_tpu.models.common import EXCHANGE_LANES, FTRL, AdaGrad
+    from dmlc_core_tpu.models.fm import FactorizationMachine
+    from dmlc_core_tpu.ops import pallas_rows
+    from dmlc_core_tpu.parallel import MeshPlan
+    monkeypatch.setattr(pallas_rows, "pallas_interpret", lambda: False)
+    plan = MeshPlan(Mesh(np.asarray(topo.devices[:4]), ("data",)), ("data",))
+    features, width, rows = 2 ** 28, 16, 65536
+    lanes = rows * 39
+    model = FactorizationMachine(
+        features, width, optimizer={"w": FTRL(l1=4.0), "v": AdaGrad()},
+        threshold=16, mesh=plan)
+    by_key, whole = plan.data_sharding(), plan.replicated_sharding()
+    params = jax.tree.map(
+        lambda a: on(by_key if a.ndim else whole, a.shape, a.dtype),
+        jax.eval_shape(model.init, 0))
+    batch = PaddedBatch(
+        label=on(by_key, (rows,), jnp.float32),
+        weight=on(by_key, (rows,), jnp.float32),
+        row_ptr=on(whole, (rows + 1,), jnp.int32),
+        index=on(by_key, (lanes,), jnp.int32),
+        value=on(by_key, (lanes,), jnp.float32),
+        num_rows=on(whole, (), jnp.int32))
+    compiled = model._sharded_rows_step.lower(model, params, batch).compile()
+    memory = compiled.memory_analysis()
+    shard = (4 + 2 * width) * 4 * features // 4
+    assert memory.alias_size_in_bytes >= shard
+    assert memory.argument_size_in_bytes < shard + (64 << 20)
+    assert memory.temp_size_in_bytes < shard // 8
+    text = compiled.as_text()
+    exchanges = re.findall(
+        r"= (\w+)\[4,(\d+),(\d+)\]\S* all-to-all\(.*?op_name=\"([^\"]*)\"",
+        text)
+    assert all("/mesh.alltoall/" in name for *_, name in exchanges)
+    capacities = (EXCHANGE_LANES[0], lanes // 4)
+    assert sorted((dtype, int(r), int(c)) for dtype, r, c, _ in exchanges) \
+        == sorted((dtype, r, c) for c in capacities for dtype, r in (
+            ("s32", 2), ("f32", 2 + width), ("f32", 1 + width)))
+    reduces = re.findall(r"all-reduce(?:-start)?\(.*?op_name=\"([^\"]*)\"",
+                         text)
+    assert len(reduces) == 4 and all("mesh.allreduce" in n for n in reduces)
+    for op in ("all-gather", "collective-permute", "reduce-scatter"):
+        assert not re.search(rf"= .*\b{op}(-start)?\(", text), op
+    assert not re.search(rf"= \w+\[{features // 4}(,{width})?\]\S* copy\(",
+                         text)
+    names = op_names(compiled)
+    for scope in ("mesh.alltoall", "sgd.owner_merge", "sgd.unique",
+                  "sgd.gather_rows", "sgd.count", "sgd.ftrl", "sgd.adagrad",
+                  "sgd.scatter_rows", "fm.margins"):
+        part = re.compile(r"[/(]" + re.escape(scope) + r"[/)]")
+        assert any(part.search(n) for n in names), scope
+    pattern = json.loads((LAYER_METRICS / "ps4_scatter_roofline.json")
+                         .read_text())["args"]["pattern"]
+    assert [n for n in instructions(compiled) if re.search(pattern, n)]

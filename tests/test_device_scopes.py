@@ -45,8 +45,9 @@ def programs() -> dict:
     tiny sizes: a whole GBDT fit (depth 2, the Pallas route interpreted, so
     that the kernel wrapper's layout ops are there), one FFM ``_train_step``,
     the touched-rows step of a linear model, of a gated factorization
-    machine and of the field-aware one, a plan-routed reduction, and the
-    margin update a boosting round ends with."""
+    machine (on one chip, and over tables sharded by key) and of the
+    field-aware one, a plan-routed reduction, and the margin update a
+    boosting round ends with."""
     model = GBDT(num_features=4, num_trees=1, max_depth=2, num_bins=16,
                  missing_aware=True, histogram="pallas")
     bins = jnp.zeros((64, 4), jnp.uint8)
@@ -98,7 +99,19 @@ def programs() -> dict:
         lambda v: plan.allreduce(v, "sum"), in_specs=plan.row_spec,
         out_specs=P(), check_replication=False)).lower(
             jnp.zeros(plan.num_shards * 4))
+    # the same step over tables sharded by key: every chip a worker and a
+    # server (`TouchedRowsMixin._sharded_rows_step`)
+    served = FactorizationMachine(
+        features * plan.num_shards, 4, threshold=1, mesh=plan,
+        optimizer={"w": FTRL(), "v": AdaGrad()})
+    wide = rows * plan.num_shards
+    sharded = served._sharded_rows_step.lower(served, served.init(), PaddedBatch(
+        label=jnp.zeros(wide), weight=jnp.ones(wide),
+        row_ptr=jnp.arange(wide + 1, dtype=jnp.int32) * fields,
+        index=jnp.zeros(wide * fields, jnp.int32),
+        value=jnp.ones(wide * fields), num_rows=jnp.asarray(np.int32(wide))))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
+            "sharded": paths_of(sharded),
             "sparse_tree": paths_of(sparse_tree),
             "leafwise": paths_of(leafwise), "margin": paths_of(margin),
             "step": paths_of(step), "touched": paths_of(touched),
@@ -112,6 +125,9 @@ TOUCHED_ROWS = {"sgd.unique", "sgd.gather_rows", "linear.margins", "sgd.ftrl",
 # scopes the same step opens for row-shaped tables under a count gate alone
 # (the factorization machine's, which carries every scope of TOUCHED_ROWS too)
 TOUCHED_TABLES = {"fm.margins", "sgd.adagrad", "sgd.count"}
+# scopes of the step over tables sharded by key alone, which carries every
+# scope of TOUCHED_ROWS and TOUCHED_TABLES (but the linear model's) too
+SHARDED_ROWS = {"mesh.alltoall", "sgd.owner_merge"}
 # scopes that only one of the two tree programs opens
 DENSE_ONLY = {"gbdt.cast"}
 SPARSE_ONLY = {"gbdt.entry_gather", "gbdt.node_totals"}
@@ -152,6 +168,16 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
                        under="jit(_touched_rows_step)")
     if scope in TOUCHED_TABLES:
         where = "tables"
+    if scope in SHARDED_ROWS | TOUCHED_TABLES | TOUCHED_ROWS - {
+            "linear.margins"}:
+        # (the shard_map body is lowered as a function of its own: its
+        # ops' paths start at the body, and the compiled program's names
+        # hold ``jit(_sharded_rows_step)/shard_map/`` before them,
+        # tests/test_chip_names.py)
+        assert carries(programs["sharded"], scope)
+    if scope in SHARDED_ROWS:
+        where = "sharded"
+        assert not carries(programs["tables"], scope)
     if scope == "gbdt.margin":
         # a program of its own, nested in the driver's scope (the one-tree
         # fit lowered above never reads its last margins: not in there)
