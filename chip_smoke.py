@@ -13,8 +13,10 @@ width: 28 features, 256 bins, depth 6; three trees, weights from a seed):
 
 and checks at every step that what came out is right by the repo's own
 means: staged content against an independent host parse, each forest
-against the same fit on XLA scatter, each kernel against its XLA reference,
-/score against predict_batch, sharded against single-device.
+against the same fit on XLA scatter, each kernel against its XLA reference
+(the entry lookup against XLA's gather, bit for bit, at the benchmark's
+sparse cell's 1,183,747 rows and 2.18e8 entry lanes: 3 GB of the chip for a
+moment), /score against predict_batch, sharded against single-device.
 
 It claims no speed.  It exits non-zero at the first failure and prints
 nothing on standard output then.  On success standard output is two lines,
@@ -56,9 +58,13 @@ HERE = Path(__file__).resolve().parent
 # by eight, so a mesh fit sees every row the one-device fit saw.
 FEATURES = 28
 FULL = dict(rows=8 * 16384 + 1000, batch_size=16384, bins=256, depth=6,
-            trees=3, kernel_rows=65536, score_rows=(1, 7, 64, 300))
+            trees=3, kernel_rows=65536, score_rows=(1, 7, 64, 300),
+            # the benchmark's Bosch configuration: what the entry lookup
+            # kernel is sized for (its table in VMEM, 2.18e8 entry lanes)
+            lookup=dict(rows=1183747, features=968, stations=52, share=0.19))
 TINY = dict(rows=2 * 512 + 104, batch_size=512, bins=32, depth=3,
-            trees=2, kernel_rows=1024, score_rows=(1, 7, 40))
+            trees=2, kernel_rows=1024, score_rows=(1, 7, 40),
+            lookup=dict(rows=40000, features=9, stations=4, share=0.19))
 
 
 T0 = time.monotonic()
@@ -440,6 +446,69 @@ def run_kernel(name: str, n_nodes, fn, args, reference, interpreted: bool):
     return row
 
 
+def station_rids(rows: int, features: int, stations: int, share: float):
+    """The row ids of a feature-sorted layout shaped like the benchmark's
+    sparse cell: a station's features share the rows that visit it
+    (`station_plan`'s probabilities), ascending within each feature's run,
+    then `sparse_hist_layout`'s padding (row 0)."""
+    from benchmark.traffic.sparse_fit import station_plan
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    rng = np.random.default_rng(9)
+    runs = []
+    for size, p in zip(*station_plan(features, stations, share)):
+        runs += [np.flatnonzero(rng.random(rows) < p).astype(np.int32)
+                 ] * int(size)
+    rid = np.concatenate(runs)
+    lanes = ps._round_up_some(len(rid), ps._NNZ_TILE, 64)
+    return np.pad(rid, (0, lanes - len(rid)))
+
+
+def check_entry_lookup(shape: dict, interpreted: bool) -> list:
+    """The lookup kernel against XLA's gather, bit for bit, for a level's
+    slots (one plane) and a tree's (grad, hess) (six)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    rows = shape["rows"]
+    require(interpreted or ps.entry_lookup_engages(True, 6 * rows),
+            f"the entry lookup does not engage at {rows} rows on a chip")
+    rng = np.random.default_rng(11)
+    rid = jnp.asarray(station_rids(**shape))
+    cspan = jax.jit(ps._chunk_spans)(rid)
+    visits = int(jnp.sum((cspan >> 16) - (cspan & 0xFFFF) + 1))
+    slot = jnp.asarray(rng.integers(-1, 128, rows).astype(np.int32))
+    gh = jnp.asarray(rng.standard_normal((rows, 2)).astype(np.float32))
+
+    def bits(a):
+        return jax.lax.bitcast_convert_type(a, jnp.uint32)
+
+    out = []
+    for name, table in (("slots", slot), ("grad_hess", gh)):
+        args = (rid, cspan, table)
+        # one program: a [lanes, 2] array of its own would be padded to tiles
+        want = jax.jit(lambda t, r: t[r] if t.ndim == 1 else t[r].T)(
+            table, rid)
+        lowered = jax.jit(ps._lookup_values).lower(*args)
+        mosaic = "tpu_custom_call" in lowered.as_text()
+        require(mosaic != interpreted, f"entry_lookup {name}: "
+                + ("interpreted on a chip" if not mosaic
+                   else "compiled by Mosaic in a CPU rehearsal"))
+        run = lowered.compile()
+        got = jax.block_until_ready(run(*args))
+        t0 = time.monotonic()
+        jax.block_until_ready(run(*args))
+        ms = (time.monotonic() - t0) * 1e3
+        same = bool(jnp.all(bits(got) == bits(want)))
+        row = {"kernel": f"entry_lookup({name})", "entries": int(rid.shape[0]),
+               "chunk_visits": visits, "interpret": not mosaic,
+               "ms": round(ms, 1), "exact": same}
+        log(f"  {row}")
+        require(same, f"entry_lookup {name}: not table[rid] bit for bit")
+        out.append(row)
+    return out
+
+
 def phase_kernels(ctx: dict) -> dict:
     import jax
     import jax.numpy as jnp
@@ -508,6 +577,7 @@ def phase_kernels(ctx: dict) -> dict:
                 f"{time.monotonic() - t0:.1f}s")
             table.append(run_kernel(name, nn, fn, args, reference,
                                     interpreted))
+    table += check_entry_lookup(size["lookup"], interpreted)
     ctx["kernels"] = table
     return {"calls": len(table), "node_caps": caps}
 

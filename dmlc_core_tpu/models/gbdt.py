@@ -42,9 +42,11 @@ import numpy as np
 
 from .. import telemetry
 from .common import count_predict_retrace
+from ..ops import pallas_segment
 from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
-                                  histogram_gh, histogram_gh_sparse_kernel,
-                                  segment_sum, sparse_hist_layout)
+                                  entry_values, histogram_gh,
+                                  histogram_gh_sparse_kernel, segment_sum,
+                                  sparse_hist_layout)
 
 # Most nodes a level for which `GBDT._route_level` finds a row's split by
 # comparing its node id with every node of the level; beyond it, by one
@@ -552,15 +554,26 @@ def _child_slot(rel: jax.Array, go_right: jax.Array,
     return jnp.where(go_right == right_built, rel, _NO_SLOT)
 
 
-def _entry_slots(rid: jax.Array, slot: jax.Array, depth: int) -> jax.Array:
-    """The rows' ``slot`` on the sorted entries' lanes (``rid``: each lane's
-    row), for the sparse kernel.  At the root every slot is 0 and nothing
-    is gathered: the gather cost 1.9 s a tree at 2.18e8 entries on a v5e,
-    8.6 ns an element (PERF.md, PR 27)."""
+def _entry_values(layout, table: jax.Array) -> jax.Array:
+    """The rows' ``table`` on the sorted entries' lanes, for the sparse
+    kernel: a level's slots ``[rows]`` -> ``[nnz]``, a tree's (grad, hess)
+    ``[rows, 2]`` -> ``[2, nnz]``.  `ops.entry_values` holds the rule and both
+    routes: the lookup kernel where the layout's rows ascend, on a chip, at
+    a table that fits it (110.7 ms a level and 410.7 ms a tree's pair at the
+    Bosch cell's 2.18e8 entries on a v5e; PERF.md, PR 46), else XLA's gather,
+    8.6 ns an element (1,876 ms a level, 3,616 the pair; PR 27)."""
     with jax.named_scope("gbdt.entry_gather"):
-        if depth == 0:
-            return jnp.zeros(rid.shape, jnp.int32)
-        return slot[rid]
+        return entry_values(layout.rid, layout.cspan, table,
+                            layout.rows_ascend)
+
+
+def _entry_slots(layout, slot: jax.Array, depth: int) -> jax.Array:
+    """The rows' ``slot`` on the layout's entry lanes (`_entry_values`).  At
+    the root every slot is 0 and nothing is looked up."""
+    if depth == 0:
+        with jax.named_scope("gbdt.entry_gather"):
+            return jnp.zeros(layout.rid.shape, jnp.int32)
+    return _entry_values(layout, slot)
 
 
 def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
@@ -996,18 +1009,35 @@ class GBDT:
         counter_add("gbdt.sparse_hist_grid_steps", layout.grid_steps)
         return layout
 
+    def _entry_lookups(self, layout, rows: int) -> int:
+        """Calls of the lookup kernel that one `_build_tree_sparse` program
+        holds (counter ``gbdt.entry_lookup``; 0 where `_entry_values` takes
+        XLA's gather): on one device the slots of every kernel level below
+        the root and the tree's (grad, hess); under ``histogram_mesh`` both
+        at every kernel level, of a shard's rows."""
+        if layout is None:
+            return 0
+        levels = self.level_backends(sparse=True).count("pallas")
+        local = rows // layout.num_shards
+        slots, pair = (pallas_segment.entry_lookup_engages(
+            layout.rows_ascend, planes * local) for planes in (1, 6))
+        if self.mesh_plan is not None:
+            return levels * (slots + pair)
+        return max(levels - 1, 0) * slots + (levels > 0) * pair
+
     def _level_histogram_sparse(self, layout, rel: jax.Array,
                                 gh_row: jax.Array, gh_e, rel_e, n_nodes: int):
         """Sparse [n_nodes, F, bins, 2] via the Pallas kernel, of the rows
         with ``rel`` in [0, n_nodes): a level's built columns, no ``_NO_SLOT``.
-        Single-device: the entry gathers against the feature-sorted layout
+        Single-device: the rows' values laid onto the feature-sorted layout
         (``gh_e`` once a tree, ``rel_e`` once a level, both by the caller
-        under its scope ``gbdt.entry_gather``) feed one kernel call.  With
+        through `_entry_values`, scope ``gbdt.entry_gather``) feed one kernel
+        call.  With
         ``histogram_mesh`` the packed per-shard layout slices ride
         ``shard_map`` ``P(axis)`` in_specs, each device runs the kernel on
         its local rows' entries, and the plan's allreduce combines the
         shards — the same rabit-histogram-allreduce shape as the dense
-        `_level_histogram` route (both gathers move inside the shard_map
+        `_level_histogram` route (both lookups move inside the shard_map
         body there, since rows are only device-local under the mesh)."""
         F, B = self.num_features, self.num_bins
         if self.mesh_plan is not None:
@@ -1016,18 +1046,21 @@ class GBDT:
             plan = self.mesh_plan
             mt = layout.max_tiles
 
-            def local(gk, rid_l, ts, tc, rel_l, gh_l):
+            def local(gk, rid_l, cs_l, ts, tc, rel_l, gh_l):
+                with jax.named_scope("gbdt.entry_gather"):
+                    rel_e, gh_e = (
+                        entry_values(rid_l, cs_l, t, layout.rows_ascend)
+                        for t in (rel_l, gh_l.astype(jnp.float32)))
                 h = histogram_gh_sparse_kernel(
-                    gk, rel_l[rid_l], gh_l[rid_l].astype(jnp.float32).T,
-                    ts, tc, n_nodes, F, B, mt)
+                    gk, rel_e, gh_e, ts, tc, n_nodes, F, B, mt)
                 return plan.allreduce(h)
 
             spec = plan.row_spec
             return plan.shard_map(local,
-                                  in_specs=(spec,) * 6, out_specs=P(),
+                                  in_specs=(spec,) * 7, out_specs=P(),
                                   check_replication=False)(
-                layout.gkey, layout.rid, layout.tstart, layout.tcount,
-                rel, gh_row)
+                layout.gkey, layout.rid, layout.cspan, layout.tstart,
+                layout.tcount, rel, gh_row)
         return histogram_gh_sparse_kernel(
             layout.gkey, rel_e, gh_e, layout.tstart, layout.tcount,
             n_nodes, F, B, layout.max_tiles)
@@ -1815,11 +1848,10 @@ class GBDT:
         # scatter levels want unsorted gh_k, kernel levels the sorted gh_e
         gh_k = gh_e = None
         if "pallas" in impls and not mesh:
-            with jax.named_scope("gbdt.entry_gather"):
-                # one gather of a row's pair, then lanes first for the
-                # kernel: two gathers of one lane each took 7.6 s against
-                # 3.0 s at 2.18e8 entries on a v5e (PERF.md, PR 27)
-                gh_e = gh_row[layout.rid].T
+            # lanes first for the kernel; where it is gathered, one gather of
+            # a row's pair: two gathers of one lane each took 7.6 s against
+            # 3.0 s at 2.18e8 entries on a v5e (PERF.md, PR 27)
+            gh_e = _entry_values(layout, gh_row)
         if entries is not None:
             rid, fi, ebin, emask = entries
 
@@ -1839,7 +1871,7 @@ class GBDT:
             rel = node - first
             if impl == "pallas":
                 slot_e = (None if mesh
-                          else _entry_slots(layout.rid, slot, depth))
+                          else _entry_slots(layout, slot, depth))
                 with jax.named_scope("gbdt.hist"):
                     built = self._level_histogram_sparse(
                         layout, slot, gh_row, gh_e, slot_e, cols)
@@ -2121,9 +2153,12 @@ class GBDT:
                 and kernel_levels == self.max_depth and layout.rows_ascend):
             entries = None      # the layout holds every live entry
 
+        lookups = self._entry_lookups(layout, int(label.shape[0]))
+
         def build_tree(g, h, col_mask, col_key):
             if kernel_levels:
                 counter_add("gbdt.hist_sparse_pallas", kernel_levels)
+                counter_add("gbdt.entry_lookup", lookups)
             return self._build_tree_sparse(entries, layout, g, h, col_mask,
                                            col_key)
 
@@ -2290,7 +2325,8 @@ class GBDT:
             gh_row = jnp.stack([grad, hess], axis=-1)      # [rows, 2]
             # per-TREE hoist for kernel levels: the sorted entry gather of
             # this tree's (grad, hess); only rel changes across levels
-            gh_e = (gh_row[layout.rid].T if layout is not None else None)
+            gh_e = (_entry_values(layout, gh_row) if layout is not None
+                    else None)
             node = jnp.zeros(rows, jnp.int32)
             lo = jnp.full(1, -jnp.inf)
             hi = jnp.full(1, jnp.inf)
@@ -2319,7 +2355,7 @@ class GBDT:
                     counter_add("gbdt.hist_sparse_pallas", 1)
                     built = self._level_histogram_sparse(
                         layout, slot, gh_row, gh_e,
-                        _entry_slots(layout.rid, slot, depth), cols)
+                        _entry_slots(layout, slot, depth), cols)
                     gh_node = segment_sum(gh_row, node - first,
                                           num_segments=n_nodes,
                                           force="pallas")

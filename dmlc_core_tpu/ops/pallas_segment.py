@@ -68,6 +68,7 @@ VALID_FORCE = (None, "xla", "pallas")
 SEGMENT_SUM_KERNEL = "_segment_sum_pallas"
 DENSE_HIST_KERNEL = "_histogram_gh_pallas"
 SPARSE_HIST_KERNEL = "_histogram_gh_sparse_pallas"
+ENTRY_LOOKUP_KERNEL = "_entry_lookup_pallas"
 
 
 def check_force(force, what: str = "backend") -> None:
@@ -391,7 +392,8 @@ def _sparse_geometry(num_features: int, num_bins: int) -> tuple[int, int]:
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["gkey", "rid", "tstart", "tcount", "fstart", "nnz_live"],
+    data_fields=["gkey", "rid", "tstart", "tcount", "fstart", "nnz_live",
+                 "cspan"],
     meta_fields=["num_features", "num_bins", "num_shards", "nb", "num_kt",
                  "max_tiles", "nnz_pad", "run_bits", "rows_ascend"])
 @dataclasses.dataclass(frozen=True)
@@ -428,7 +430,11 @@ class SparseHistLayout:
     strictly ascend in every run, as they do when the input is row-major (a
     CSR batch's is) and no row holds a feature twice: only then is a row's
     entry on a feature the one lane that a bisection of the run finds.
-    ``nnz_live`` counts the live entries.  The rest
+    ``nnz_live`` counts the live entries.  ``cspan`` is
+    ``[num_shards * nnz_pad / _NNZ_TILE]``: of each sub-tile of 1,024 entry
+    lanes, the first and the last chunk of 16,384 rows that its row ids
+    touch (``_chunk_spans``), which is all `entry_values`' lookup kernel
+    visits for it.  The rest
     is static, and what depends on the data is rounded up (`_round_up_some`)
     so that nearly equal data sets share their compiled programs — a
     program that takes a layout is compiled for its static fields:
@@ -452,6 +458,7 @@ class SparseHistLayout:
     tcount: jax.Array
     fstart: jax.Array
     nnz_live: jax.Array
+    cspan: jax.Array
 
     @property
     def grid_steps(self) -> int:
@@ -506,18 +513,21 @@ def _layout_sort(row_id, findex, ebin, emask, num_features: int,
 def _layout_pack(gkey, rid, offset, count, num_shards: int, nnz_pad: int):
     """The sorted entries as ``num_shards`` slices of ``nnz_pad`` lanes:
     shard ``s`` is ``[offset[s], offset[s] + count[s])`` of the sorted
-    arrays, then ``gkey == -1`` / ``rid == 0`` filler."""
+    arrays, then ``gkey == -1`` / ``rid == 0`` filler; and the packed
+    row ids' `_chunk_spans`."""
     lane = jnp.arange(nnz_pad, dtype=jnp.int32)
     if num_shards == 1:     # a slice or a pad of the sorted arrays, no gather
         def fit(a):
             n = a.shape[0]
             return a[:nnz_pad] if n >= nnz_pad else jnp.pad(a, (0, nnz_pad - n))
         ok = lane < count[0]
-        return jnp.where(ok, fit(gkey), -1), jnp.where(ok, fit(rid), 0)
-    src = jnp.minimum(offset[:, None] + lane[None, :], gkey.shape[0] - 1)
-    ok = lane[None, :] < count[:, None]
-    return (jnp.where(ok, gkey[src], -1).reshape(-1),
-            jnp.where(ok, rid[src], 0).reshape(-1))
+        gkey_p, rid_p = jnp.where(ok, fit(gkey), -1), jnp.where(ok, fit(rid), 0)
+    else:
+        src = jnp.minimum(offset[:, None] + lane[None, :], gkey.shape[0] - 1)
+        ok = lane[None, :] < count[:, None]
+        gkey_p = jnp.where(ok, gkey[src], -1).reshape(-1)
+        rid_p = jnp.where(ok, rid[src], 0).reshape(-1)
+    return gkey_p, rid_p, _chunk_spans(rid_p)
 
 
 def sparse_hist_layout(row_id, findex, ebin, emask,
@@ -565,7 +575,7 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
     some = stop > begin
     tstart = np.where(some, begin // _NNZ_TILE, 0)
     tcount = np.where(some, -(-stop // _NNZ_TILE) - tstart, 0)
-    gkey_p, rid_p = _layout_pack(
+    gkey_p, rid_p, cspan = _layout_pack(
         gkey, rid, jnp.asarray(offset, jnp.int32),
         jnp.asarray(count, jnp.int32), num_shards, nnz_pad)
     return SparseHistLayout(
@@ -576,7 +586,7 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
         run_bits=int(np.diff(local_runs, axis=1).max()).bit_length(),
         rows_ascend=bool(ascend),
         fstart=jnp.asarray(local_runs.reshape(-1), jnp.int32),
-        gkey=gkey_p, rid=rid_p,
+        gkey=gkey_p, rid=rid_p, cspan=cspan,
         tstart=jnp.asarray(tstart.reshape(-1), jnp.int32),
         tcount=jnp.asarray(tcount.reshape(-1), jnp.int32))
 
@@ -796,8 +806,12 @@ def histogram_gh_sparse(row_id, findex, ebin, emask, rel, gh,
                 f"layout built for F={layout.num_features}/"
                 f"B={layout.num_bins}, called with F={num_features}/"
                 f"B={num_bins}")
-        gh_e = gh[layout.rid].astype(jnp.float32).T
-        rel_e = jnp.asarray(rel, jnp.int32)[layout.rid]
+        gh_e = entry_values(layout.rid, layout.cspan,
+                            gh.astype(jnp.float32), layout.rows_ascend)
+        # node ids past 256 are no bfloat16: those take the gather
+        rel_e = entry_values(layout.rid, layout.cspan,
+                             jnp.asarray(rel, jnp.int32),
+                             layout.rows_ascend and n_nodes <= 256)
         out = histogram_gh_sparse_kernel(
             layout.gkey, rel_e, gh_e, layout.tstart, layout.tcount,
             n_nodes, num_features, num_bins, layout.max_tiles)
@@ -810,6 +824,188 @@ def histogram_gh_sparse(row_id, findex, ebin, emask, rel, gh,
     return jax.ops.segment_sum(
         gh_k, keys, num_segments=n_nodes * num_features * num_bins
     ).reshape(n_nodes, num_features, num_bins, 2)
+
+
+# ---- values a row, laid onto the feature-sorted entries ---------------------
+# ``table[layout.rid]``: a level's slots (once a level below the root) and a
+# tree's (grad, hess) (once a tree) reach the 2.18e8 sorted entries of the
+# Bosch cell this way.  XLA gathers one element at a time, 8.6 ns an element
+# on a v5e (1.88 s a level; PERF.md, PR 27), whatever the table.  The lookup
+# below reads the table out of VMEM by two one-hots instead, and what it
+# costs does not depend on how thin a feature is: 110.7 ms a level there,
+# 350,889 chunk visits, bound by the VPU's issue at about 1 ns a bundle of
+# the compiled visit (292 for one plane, 1,360 for six: 410.7 ms for the
+# pair); 8, 32 or 64 sub-tiles a grid step read the same (my chip run, PR 46).
+
+_LOOKUP_LO = 128                        # rows a table column: rid % 128
+_LOOKUP_CHUNK = 128 * _LOOKUP_LO        # rows a chunk of 128 columns: 16,384
+_LOOKUP_STEP_TILES = 32                 # entry sub-tiles a grid step
+
+# The most rows x planes one lookup call takes: its table lies whole in VMEM,
+# one buffer, a bfloat16 a row a plane, and is given 16 MiB of a v5e's 128;
+# the call's limit (`vmem_limit_bytes`) is the table and 16 MiB more for the
+# blocks of row ids and output (2 x 1 MiB each at 32 sub-tiles a step) and a
+# chunk visit's temporaries (3 MiB at six planes).  8,388,608: one plane of
+# slots up to 8.4 M rows, the six planes of (grad, hess) up to 1.4 M.
+_LOOKUP_TABLE_BYTES = 16 << 20
+ENTRY_LOOKUP_PLANE_ROWS = _LOOKUP_TABLE_BYTES // 2
+
+
+def _chunk_spans(rid: jax.Array) -> jax.Array:
+    """Of each sub-tile of ``_NNZ_TILE`` lanes of ``rid``, the first and the
+    last chunk of ``_LOOKUP_CHUNK`` rows that it names, packed
+    ``first | last << 16`` (so rows stay under 2**29).  Where row ids ascend
+    within a feature's run that is one or two chunks for a common feature
+    and, for a rare one, a share of the table; a sub-tile that holds the end
+    of one run and the start of the next spans from the least to the most."""
+    chunk = rid.reshape(-1, _NNZ_TILE) >> 14        # // _LOOKUP_CHUNK
+    return chunk.min(axis=1) | (chunk.max(axis=1) << 16)
+
+
+def _entry_lookup_kernel(parts: int, cspan_ref, rid_ref, table_ref, out_ref):
+    """One grid step: ``_LOOKUP_STEP_TILES`` sub-tiles of ``_NNZ_TILE`` entry
+    lanes.  A row id is ``hi * 128 + lo``, a plane of the table lies
+    ``T[lo, hi]``, and for a sub-tile's entries ``e`` and each chunk ``c`` of
+    128 ``hi`` values that its span holds
+
+        G[(plane, lo), e] = sum_h T[(plane, lo), c*128 + h] * [hi_e == c*128 + h]
+        out[e]           += sum_lo G[lo, e] * [lo_e == lo]
+
+    the first on the MXU (``[128 planes, 128] . [128, 1024]``, bfloat16 into
+    float32), the second a select and a sublane sum.  Both one-hots are a
+    sublane iota compared with a lane-broadcast row, as `_sparse_hist_kernel`
+    builds its own.  An entry's ``hi`` lies in exactly one chunk, so every
+    product is a bfloat16 times 0 or 1 and every sum has one term that is not
+    zero: a plane's ``out`` is the table's value, exactly.  The planes are
+    ``parts`` bfloat16 parts of each of the output's rows, part-major; the
+    parts of a row are added in float32, first to last, before the select
+    (`_split_bf16x3`'s sum back to the float32 they were split from)."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, (_LOOKUP_LO, _NNZ_TILE), 0)
+    out_rows = out_ref.shape[0]
+
+    def sub_tile(j, carry):
+        lanes = pl.ds(pl.multiple_of(j * _NNZ_TILE, _NNZ_TILE), _NNZ_TILE)
+        rid = rid_ref[:, lanes]                                 # [1, tile]
+        hi = rid >> 7
+        at_lo = ids == jnp.broadcast_to(rid & (_LOOKUP_LO - 1), ids.shape)
+        span = cspan_ref[0, j]
+
+        def chunk(c, acc):
+            start = pl.multiple_of(c * 128, 128)
+            hit = ids == jnp.broadcast_to(hi - start, ids.shape)
+            g = jnp.dot(table_ref[:, pl.ds(start, 128)],
+                        jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+
+            def plane(k):
+                return g[k * _LOOKUP_LO:(k + 1) * _LOOKUP_LO]
+
+            return tuple(
+                a + jnp.sum(jnp.where(at_lo, sum(
+                    (plane(p * out_rows + r) for p in range(1, parts)),
+                    start=plane(r)), 0.0), axis=0, keepdims=True)
+                for r, a in enumerate(acc))
+
+        acc = jax.lax.fori_loop(
+            span & 0xFFFF, (span >> 16) + 1, chunk,
+            (jnp.zeros((1, _NNZ_TILE), jnp.float32),) * out_rows)
+        for r, a in enumerate(acc):
+            out_ref[pl.ds(r, 1), lanes] = a.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, _LOOKUP_STEP_TILES, sub_tile, None)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("out_rows", "out_dtype", "interpret"))
+def _entry_lookup_pallas(rid: jax.Array, cspan: jax.Array, table: jax.Array,
+                         out_rows: int, out_dtype, interpret: bool
+                         ) -> jax.Array:
+    """rid: [nnz_pad] int32 (a multiple of ``_NNZ_TILE``); cspan: rid's
+    `_chunk_spans`; table: [parts * out_rows, rows] bfloat16, part-major.
+    Returns [out_rows, nnz_pad] of ``out_dtype``: the sum over the parts of
+    ``table[:, rid]``, exactly."""
+    planes, rows = table.shape
+    nnz_pad = rid.shape[0]
+    tiles = nnz_pad // _NNZ_TILE
+    steps = pl.cdiv(tiles, _LOOKUP_STEP_TILES)
+    block = _LOOKUP_STEP_TILES * _NNZ_TILE
+    # whole chunks of columns: a slice of 128 never leaves the table
+    cols = pl.cdiv(rows, _LOOKUP_CHUNK) * 128
+    with jax.named_scope("ops.lookup_layout"):
+        # T[(plane, lo), hi] = table[plane, hi * 128 + lo]
+        table_t = (jnp.pad(table, ((0, 0), (0, cols * _LOOKUP_LO - rows)))
+                   .reshape(planes, cols, _LOOKUP_LO).transpose(0, 2, 1)
+                   .reshape(planes * _LOOKUP_LO, cols))
+        # a sub-tile past the last, in the last step: an empty span
+        cspan3 = jnp.pad(cspan, (0, steps * _LOOKUP_STEP_TILES - tiles),
+                         constant_values=1
+                         ).reshape(steps, 1, _LOOKUP_STEP_TILES)
+    return pl.pallas_call(
+        functools.partial(_entry_lookup_kernel, planes // out_rows),
+        grid=(steps,),
+        in_specs=[
+            # a step's spans: a block whose last two dims are the array's
+            pl.BlockSpec((None, 1, _LOOKUP_STEP_TILES), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((out_rows, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, nnz_pad), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=table_t.size * 2 + (16 << 20)),
+        interpret=interpret,
+        name=ENTRY_LOOKUP_KERNEL,
+    )(cspan3, rid.reshape(1, nnz_pad), table_t)
+
+
+def entry_lookup_engages(rows_ascend: bool, plane_rows: int) -> bool:
+    """Whether `entry_values` takes the lookup kernel: read off what the
+    code can see.  Row ids ascend within every feature's run (else every
+    sub-tile would visit every chunk: reckoned 3.9 s a level at the Bosch
+    cell's size, worse than the gather), the kernel is compiled for a TPU
+    (interpreted it is a test's tool, not a route), and a call's table,
+    ``plane_rows`` = planes x rows, fits its share of VMEM."""
+    return (rows_ascend and not pallas_interpret()
+            and plane_rows <= ENTRY_LOOKUP_PLANE_ROWS)
+
+
+def entry_values(rid: jax.Array, cspan: jax.Array, table: jax.Array,
+                 rows_ascend: bool) -> jax.Array:
+    """``table[rid]`` with the entries on the last axis: the rows' values on
+    the sorted entries' lanes (``rid``, ``cspan``, ``rows_ascend``: a
+    `SparseHistLayout`'s, or one shard's slices of them).
+
+    table: ``[rows]`` int32 slots, of magnitude at most 256 -> ``[nnz]``
+    int32, or ``[rows, 2]`` float32 (grad, hess) -> ``[2, nnz]`` float32, as
+    `_sparse_hist_kernel` reads them.  Both routes give the same bits, but
+    for a ``-0.0``, which the lookup's sums return as ``+0.0`` (a histogram
+    takes the same from either).  The gather is XLA's; the lookup
+    (`entry_lookup_engages`) hands the kernel bfloat16 planes that hold the
+    values exactly: such an integer is a bfloat16 as it stands, a float32 is
+    the three parts of `_split_bf16x3` (above 9.9e-32; below it both routes'
+    histograms lose the denormal part on the chip).  A non-finite (grad,
+    hess), which the gather hands to its own row's entries alone, also
+    reaches entries of other rows of its chunk here (inf * 0 on the MXU):
+    either way the fit it came from is lost."""
+    if entry_lookup_engages(rows_ascend,
+                            (1 if table.ndim == 1 else 6) * table.shape[0]):
+        return _lookup_values(rid, cspan, table)
+    return table[rid] if table.ndim == 1 else table[rid].T
+
+
+def _lookup_values(rid: jax.Array, cspan: jax.Array, table: jax.Array
+                   ) -> jax.Array:
+    """`entry_values`' lookup route: the table as bfloat16 planes, through
+    the kernel (interpreted off the chip, where only tests come here)."""
+    if table.ndim == 1:
+        return _entry_lookup_pallas(
+            rid, cspan, table.astype(jnp.bfloat16)[None], 1, jnp.int32,
+            pallas_interpret())[0]
+    parts = jnp.concatenate(_split_bf16x3(table.astype(jnp.float32).T))
+    return _entry_lookup_pallas(rid, cspan, parts.astype(jnp.bfloat16), 2,
+                                jnp.float32, pallas_interpret())
 
 
 def segment_sum(contrib: jax.Array, row_id: jax.Array, num_segments: int,

@@ -155,6 +155,76 @@ def test_sparse_histogram_kernel_keeps_the_name_the_benchmark_matches(
                for operands, precision, result in dots), dots
 
 
+# the lookup that lays a row's values onto the Bosch cell's 2.18e8 sorted
+# entries: a level's slots (one plane of 1,183,747 rows) and a tree's
+# (grad, hess) (the six planes of three bfloat16 parts, 14.4 MB of VMEM)
+@pytest.mark.parametrize("planes", [1, 6])
+def test_entry_lookup_kernel_compiles_at_the_cells_size(one_chip, quiet_cache,
+                                                        planes):
+    nnz, rows = 218103808, 1183747
+    assert planes * rows <= pallas_segment.ENTRY_LOOKUP_PLANE_ROWS
+    out_rows, dtype = (1, jnp.int32) if planes == 1 else (2, jnp.float32)
+
+    def lookup(rid, cspan, table):
+        return pallas_segment._entry_lookup_pallas(
+            rid, cspan, table, out_rows, dtype, False)
+
+    shapes = (on(one_chip, (nnz,), jnp.int32),
+              on(one_chip, (nnz // 1024,), jnp.int32),
+              on(one_chip, (planes, rows), jnp.bfloat16))
+    compiled = jax.jit(lookup).lower(*shapes).compile()
+    kernels = [n for n in instructions(compiled) if n.startswith(
+        "%" + pallas_segment.ENTRY_LOOKUP_KERNEL)]
+    assert len(kernels) == 1 and compiled.as_text().count(
+        "tpu_custom_call") == 1
+    # what is laid out for the kernel is the table and the spans, not an
+    # array of entry lanes; the result is as wide as XLA's gather's
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.output_size_in_bytes == out_rows * nnz * 4
+    dots = kernel_dots(lookup, *shapes)
+    assert dots == [(["bfloat16", "bfloat16"], None, "float32")]
+
+
+def test_sparse_tree_program_looks_its_entries_up_under_their_scope(
+        one_chip, quiet_cache, monkeypatch):
+    """The sparse tree program as a chip compiles it where the rule engages
+    (rows ascending in the layout, a table that fits): a lookup kernel for
+    the tree's (grad, hess) and one a level below the root, each under
+    ``gbdt.entry_gather``, the scope `entry_gather_ms_per_round` reads, and
+    no gather of an entry's row anywhere in the program."""
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+    rows, nnz, depth = 200_000, 600 * 1024, 4
+    model = GBDT(num_features=FEATURES, num_trees=1, max_depth=depth,
+                 num_bins=BINS, missing_aware=True, histogram="pallas")
+    nb, num_kt = pallas_segment._sparse_geometry(FEATURES, BINS)
+
+    def lanes(n):
+        return on(one_chip, (n,), jnp.int32)
+
+    layout = pallas_segment.SparseHistLayout(
+        num_features=FEATURES, num_bins=BINS, num_shards=1, nb=nb,
+        num_kt=num_kt, max_tiles=48, nnz_pad=nnz, run_bits=16,
+        rows_ascend=True, gkey=lanes(nnz), rid=lanes(nnz),
+        tstart=lanes(num_kt), tcount=lanes(num_kt),
+        fstart=lanes(FEATURES + 1), nnz_live=on(one_chip, (), jnp.int32),
+        cspan=lanes(nnz // 1024))
+    assert model._entry_lookups(layout, rows) == depth
+    compiled = model._build_tree_sparse.lower(
+        model, None, layout, on(one_chip, (rows,), jnp.float32),
+        on(one_chip, (rows,), jnp.float32),
+        on(one_chip, (FEATURES,), jnp.bool_),
+        on(one_chip, (2,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    lookups = re.findall(
+        r"^\s*(?:ROOT )?%" + pallas_segment.ENTRY_LOOKUP_KERNEL
+        + r"[\w.\-]* = (\S+) custom-call\(.*op_name=\"([^\"]*)\"", text, re.M)
+    assert sorted(shape.split("{")[0] for shape, _name in lookups) == (
+        [f"f32[2,{nnz}]"] + [f"s32[1,{nnz}]"] * (depth - 1))
+    assert all("/gbdt.entry_gather/" in name for _shape, name in lookups)
+    assert not re.search(rf"\[{nnz}(,\d+)?\]\S* gather\(", text)
+
+
 def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(
         one_chip, quiet_cache, monkeypatch):
     """``gbdt.route`` and ``ops.hist_layout`` are in the ``op_name`` of
@@ -509,7 +579,7 @@ def test_sharded_rows_step_exchanges_keys_and_gathers_no_table(
     by_key, whole = plan.data_sharding(), plan.replicated_sharding()
     params = jax.tree.map(
         lambda a: on(by_key if a.ndim else whole, a.shape, a.dtype),
-        jax.eval_shape(model.init, 0))
+        jax.eval_shape(lambda: model.init(0)))  # the seed a host int
     batch = PaddedBatch(
         label=on(by_key, (rows,), jnp.float32),
         weight=on(by_key, (rows,), jnp.float32),

@@ -39,8 +39,14 @@ def test_rehearsal_passes_and_says_what_it_is(tmp_path):
     assert all(p["ok"] for p in summary["phases"].values())
     assert summary["phases"]["mesh"]["skipped"]
     # every kernel ran, and said it was interpreted (off the chip it must be)
-    assert len(summary["kernels"]) == 7
+    assert len(summary["kernels"]) == 9
     assert all(k["interpret"] for k in summary["kernels"])
+    # the last two: the entry lookup against XLA's gather, bit for bit
+    lookups = summary["kernels"][7:]
+    assert [k["kernel"] for k in lookups] == ["entry_lookup(slots)",
+                                              "entry_lookup(grad_hess)"]
+    assert all(k["exact"] and k["chunk_visits"] >= k["entries"] // 1024
+               for k in lookups)
     # data and outputs stay under --out, and the large inputs are removed
     assert sorted(p.name for p in (tmp_path / "out").iterdir()) == [
         "forest.ckpt", "summary.json"]

@@ -685,3 +685,34 @@ def test_field_aware_step_makes_no_table_and_moves_no_row_an_entry():
     assert any(p == "gather" and "ffm.gather" in path for p, path in moved)
     assert any(p == "scatter-add" and "ffm.reduce" in path
                for p, path in moved), moved
+
+
+def test_the_entry_lookup_runs_under_the_entry_gather_scope(monkeypatch):
+    """Where the rule engages (forced here; on a chip it reads the layout
+    and the backend), the sparse tree lays its rows' values onto the entries
+    by the lookup kernel, under the scope `entry_gather_ms_per_round` reads:
+    the (grad, hess) pair once, the slots once a level below the root, and
+    no gather takes an index an entry there.  Lowered for the chip, each is
+    a Mosaic call under that scope."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    monkeypatch.setattr(ps, "entry_lookup_engages",
+                        lambda rows_ascend, plane_rows: rows_ascend)
+    monkeypatch.setattr(ps, "pallas_interpret", lambda: False)
+    features, max_depth, _sparse = CELL_TREES["bosch"]
+    traced, lanes = tree_program(features, max_depth, True)
+    lookups = [(path, outs[0]) for path, _ins, outs
+               in device_ops(traced, "pallas_call")
+               if "gbdt.entry_gather" in path]
+    assert all(path.endswith(f"/gbdt.entry_gather/{ps.ENTRY_LOOKUP_KERNEL}")
+               for path, _out in lookups)
+    assert [(out.shape, str(out.dtype)) for _path, out in lookups] == (
+        [((2, lanes), "float32")] + [((1, lanes), "int32")] * (max_depth - 1))
+    assert [ins for path, ins, _outs in device_ops(traced, "gather")
+            if "gbdt.entry_gather" in path] == []
+    paths = paths_of(traced.lower(lowering_platforms=("tpu",)))
+    # (the kernel's jitted wrapper is lowered as a function of its own: the
+    # call site carries the scope, the body's paths start at the wrapper)
+    assert ("jit(_build_tree_sparse)/gbdt.entry_gather/"
+            f"jit({ps.ENTRY_LOOKUP_KERNEL})") in paths
+    assert f"{ps.ENTRY_LOOKUP_KERNEL}/pallas_call" in paths
+    assert carries(paths, "ops.lookup_layout")

@@ -53,7 +53,7 @@ def build_tree_sparse_eager(self, entries, layout, grad, hess, col_mask,
         if impl == "pallas":
             built = self._level_histogram_sparse(
                 layout, slot, gh_row, gh_e,
-                None if mesh else _entry_slots(layout.rid, slot, depth),
+                None if mesh else _entry_slots(layout, slot, depth),
                 cols)
         else:
             if gh_k is None:
@@ -403,3 +403,75 @@ def test_binning_by_sort_equals_binning_by_bisection(features, bins, n):
     np.testing.assert_array_equal(
         np.asarray(binner.transform_entries(jnp.asarray(index),
                                             jnp.asarray(value))), by_sort)
+
+
+def _thin_and_dense_batch(rng, rows=3000):
+    """A staged batch of four features over a few chunks' worth of rows:
+    one present in 2% of them, one in 90%, two in between; the label leans
+    on whether the thin one is there and on the dense one's value, so both
+    split kinds occur below the root."""
+    from dmlc_core_tpu.data.staging import PaddedBatch
+    from dmlc_core_tpu.models import QuantileBinner
+    share = np.array([0.02, 0.9, 0.4, 0.6])
+    present = rng.random((rows, 4)) < share
+    present[:, 1] |= ~present.any(axis=1)           # no row is empty
+    value = np.where(present, rng.uniform(-2, 2, (rows, 4)), np.nan
+                     ).astype(np.float32)
+    row_id, index = np.nonzero(present)
+    row_ptr = np.concatenate([[0], np.cumsum(present.sum(1))]).astype(np.int32)
+    score = (np.where(present[:, 0], 1.5, 0.0) + np.nan_to_num(value[:, 1])
+             + 0.5 * np.nan_to_num(value[:, 2]) + rng.normal(0, 0.3, rows))
+    pad = 9
+    batch = PaddedBatch(
+        label=jnp.asarray((score > 0.4).astype(np.float32)),
+        weight=jnp.ones(rows, jnp.float32), row_ptr=jnp.asarray(row_ptr),
+        index=jnp.asarray(np.pad(index.astype(np.int32), (0, pad))),
+        value=jnp.asarray(np.pad(value[row_id, index], (0, pad))),
+        num_rows=jnp.asarray(np.int32(rows)), field=None)
+    return batch, QuantileBinner(num_bins=16, missing_aware=True).fit(value)
+
+
+@pytest.mark.parametrize("mesh", [False, True])
+def test_fit_batch_by_the_entry_lookup_grows_the_gathers_forest(mesh,
+                                                                monkeypatch):
+    """Values a row reach the sorted entries by the lookup kernel (forced on
+    through the rule's function, interpreted off the chip) or by XLA's
+    gather: the same forest, bit for bit, and the counter
+    ``gbdt.entry_lookup`` says which it was, once a tree."""
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    batch, binner = _thin_and_dense_batch(np.random.default_rng(46))
+    kw = dict(num_features=4, num_trees=2, max_depth=4, num_bins=16,
+              learning_rate=0.5, missing_aware=True, histogram="pallas")
+    if mesh:
+        from jax.sharding import Mesh
+        kw["histogram_mesh"] = MeshPlan(
+            Mesh(np.asarray(jax.devices()[:8]), ("data",)))
+    calls = []
+    kernel = ps._entry_lookup_pallas
+
+    def spy(rid, cspan, table, *static):
+        calls.append(table.shape[0])
+        return kernel(rid, cspan, table, *static)
+
+    monkeypatch.setattr(ps, "_entry_lookup_pallas", spy)
+    before = telemetry.counter_get("gbdt.entry_lookup")
+    by_gather = GBDT(**kw).fit_batch(batch, binner)
+    assert telemetry.counter_get("gbdt.entry_lookup") == before and not calls
+    monkeypatch.setattr(ps, "entry_lookup_engages",
+                        lambda rows_ascend, plane_rows: rows_ascend)
+    by_lookup = GBDT(**kw).fit_batch(batch, binner)
+    # a tree's program: on one device the slots of the three levels below
+    # the root and the (grad, hess) once; under the mesh both at each of
+    # the four levels, inside the shard_map body
+    a_tree = [1, 6] * 4 if mesh else [6, 1, 1, 1]
+    # (one trace serves both trees; the sharded program is traced again
+    # for the second tree's margins)
+    assert calls == a_tree * (2 if mesh else 1)
+    assert (telemetry.counter_get("gbdt.entry_lookup") - before
+            == 2 * len(a_tree))
+    for k in by_gather:
+        np.testing.assert_array_equal(np.asarray(by_lookup[k]),
+                                      np.asarray(by_gather[k]), err_msg=k)
+    assert (np.asarray(by_lookup["feature"]) == 0).any()   # the thin one
+    assert (np.asarray(by_lookup["default_right"]) == 1).any()

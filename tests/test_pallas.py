@@ -2,6 +2,7 @@
 on the CPU mesh; the same code path compiles natively on TPU)."""
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -656,3 +657,123 @@ def test_split_bf16x3_below_the_exact_range_and_non_finite():
     hi, _, _ = _split_bf16x3(jnp.asarray([np.inf, -np.inf, np.nan],
                                          jnp.float32))
     assert not np.isfinite(np.asarray(hi)).any()
+
+
+# ---- values a row onto the sorted entries: the two-level lookup (PR 46) ----
+
+LOOKUP_ROWS = 300_007       # no multiple of 128: the table's last column pads
+
+
+def _lookup_layout():
+    """Row ids shaped like a `SparseHistLayout`'s: a run of a feature in 1%
+    of the rows (a sub-tile of it spans many chunks of 16,384 rows), a run
+    of one in 60% (a sub-tile spans one or two), the boundary sub-tile that
+    holds the end of one and the start of the next, a short run, then
+    padding lanes (row 0) past the last whole grid step."""
+    from dmlc_core_tpu.ops.pallas_segment import _NNZ_TILE
+    rng = np.random.default_rng(46)
+    runs = [np.sort(rng.choice(LOOKUP_ROWS, n, replace=False))
+            for n in (3_000, 180_000, 57)]
+    rid = np.concatenate(runs).astype(np.int32)
+    lanes = (len(rid) // _NNZ_TILE + 3) * _NNZ_TILE
+    return np.pad(rid, (0, lanes - len(rid)))
+
+
+@pytest.fixture
+def lookup_on(monkeypatch):
+    """`entry_values` on the lookup route off the chip: the rule says yes,
+    and the kernel runs interpreted because the backend is no TPU."""
+    from dmlc_core_tpu.ops import pallas_segment
+    monkeypatch.setattr(pallas_segment, "entry_lookup_engages",
+                        lambda rows_ascend, plane_rows: True)
+    assert pallas_segment.pallas_interpret()
+
+
+@pytest.mark.parametrize("planes", [1, 6])
+def test_entry_lookup_equals_the_gather_bit_for_bit(planes, lookup_on):
+    from dmlc_core_tpu.models.gbdt import _NO_SLOT
+    from dmlc_core_tpu.ops.pallas_segment import (_LOOKUP_STEP_TILES,
+                                                  _NNZ_TILE, _chunk_spans,
+                                                  entry_values)
+    rng = np.random.default_rng(planes)
+    rid = _lookup_layout()
+    tiles = len(rid) // _NNZ_TILE
+    assert tiles % _LOOKUP_STEP_TILES      # the last grid step is ragged
+    cspan = np.asarray(_chunk_spans(jnp.asarray(rid)))
+    visits = (cspan >> 16) - (cspan & 0xFFFF) + 1
+    # the thin run's sub-tiles span many chunks, the dense run's one or two,
+    # the boundary sub-tile every chunk, an all-padding sub-tile chunk 0
+    assert visits[0] >= 5 and visits[2] == LOOKUP_ROWS // 16384 + 1
+    assert np.median(visits[3:-3]) == 1 and visits[-1] == 1
+    if planes == 1:
+        table = rng.integers(0, 128, LOOKUP_ROWS).astype(np.int32)
+        table[rng.random(LOOKUP_ROWS) < 0.5] = _NO_SLOT
+        table[rid[:4]] = [255, 256, _NO_SLOT, 0]
+        want = table[rid]
+    else:
+        table = (rng.standard_normal((LOOKUP_ROWS, 2))
+                 * 10.0 ** rng.integers(-20, 20, (LOOKUP_ROWS, 2))
+                 ).astype(np.float32)
+        big = np.finfo(np.float32).max
+        table[rid[:3]] = [[big, -big], [2.0 ** -100, -0.0], [0.0, 1e-30]]
+        # a sum that starts at +0.0 gives a -0.0 back as +0.0, the one value
+        # not returned bit for bit: a histogram takes the same from both
+        want = table[rid].T + np.float32(0.0)
+        assert np.signbit(table[rid[1], 1]) and not np.signbit(want[1, 1])
+    got = np.asarray(entry_values(jnp.asarray(rid), jnp.asarray(cspan),
+                                  jnp.asarray(table), True))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    # padding lanes read row 0's value, as the gather does
+    assert np.array_equal(got[..., -_NNZ_TILE:].T,
+                          np.broadcast_to(want[..., -1], (_NNZ_TILE,)
+                                          + table.shape[1:]))
+    assert rid[-1] == 0
+
+
+def test_entry_lookup_below_the_exact_range_loses_a_denormal_at_most(
+        lookup_on):
+    """A (grad, hess) under 2**-103 (9.9e-32): its last bfloat16 part is a
+    denormal, which a backend may flush (the histogram kernel splits the
+    same parts and loses the same): off by 2**-126 at most."""
+    from dmlc_core_tpu.ops.pallas_segment import _chunk_spans, entry_values
+    table = np.zeros((LOOKUP_ROWS, 2), np.float32)
+    table[:4] = [[1e-33, -3e-35], [9.8e-32, 1e-38], [-5e-36, 2e-34], [0, 0]]
+    rid = jnp.asarray(np.pad(np.arange(4, dtype=np.int32), (0, 1020)))
+    got = np.asarray(entry_values(rid, _chunk_spans(rid), jnp.asarray(table),
+                                  True), np.float64)
+    assert np.abs(got - table[np.asarray(rid)].T).max() <= 2.0 ** -126
+
+
+def test_entry_lookup_engages_by_what_the_code_can_see(monkeypatch):
+    """Rows ascending in every run, a compiled kernel (a TPU), a table that
+    fits: each one missing gives XLA's gather, and no argument, environment
+    variable or knob says otherwise."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    cap = ps.ENTRY_LOOKUP_PLANE_ROWS
+    assert cap * 2 == 16 << 20 and 6 * 1_183_747 <= cap
+    assert not ps.entry_lookup_engages(True, 1000)          # off the chip
+    monkeypatch.setattr(ps, "pallas_interpret", lambda: False)
+    assert ps.entry_lookup_engages(True, 1000)
+    assert ps.entry_lookup_engages(True, cap)
+    assert not ps.entry_lookup_engages(False, 1000)         # unsorted rows
+    assert not ps.entry_lookup_engages(True, cap + 1)       # over the cap
+    monkeypatch.undo()
+
+    rid = jnp.asarray(_lookup_layout()[:2048])
+    cspan = ps._chunk_spans(rid)
+
+    def kernels(table, ascend, engaged):
+        if engaged:
+            monkeypatch.setattr(ps, "entry_lookup_engages",
+                                lambda rows_ascend, plane_rows: rows_ascend)
+        text = str(jax.make_jaxpr(lambda r, c, t: ps.entry_values(
+            r, c, t, ascend))(rid, cspan, table))
+        monkeypatch.undo()
+        return text.count("pallas_call"), text.count("gather")
+
+    for table in (jnp.zeros(LOOKUP_ROWS, jnp.int32),
+                  jnp.zeros((LOOKUP_ROWS, 2), jnp.float32)):
+        assert kernels(table, True, engaged=False) == (0, 1)
+        assert kernels(table, False, engaged=True) == (0, 1)
+        assert kernels(table, True, engaged=True) == (1, 0)
