@@ -14,9 +14,10 @@ width: 28 features, 256 bins, depth 6; three trees, weights from a seed):
 and checks at every step that what came out is right by the repo's own
 means: staged content against an independent host parse, each forest
 against the same fit on XLA scatter, each kernel against its XLA reference
-(the entry lookup against XLA's gather, bit for bit, at the benchmark's
-sparse cell's 1,183,747 rows and 2.18e8 entry lanes: 3 GB of the chip for a
-moment), /score against predict_batch, sharded against single-device.
+(the entry lookup against XLA's gather and the entries' push against its
+scatter-add, bit for bit, at the benchmark's sparse cell's 1,183,747 rows
+and 2.18e8 entry lanes: 3 GB of the chip for a moment), /score against
+predict_batch, sharded against single-device.
 
 It claims no speed.  It exits non-zero at the first failure and prints
 nothing on standard output then.  On success standard output is two lines,
@@ -446,21 +447,26 @@ def run_kernel(name: str, n_nodes, fn, args, reference, interpreted: bool):
     return row
 
 
-def station_rids(rows: int, features: int, stations: int, share: float):
+def station_layout(rows: int, features: int, stations: int, share: float):
     """The row ids of a feature-sorted layout shaped like the benchmark's
     sparse cell: a station's features share the rows that visit it
     (`station_plan`'s probabilities), ascending within each feature's run,
-    then `sparse_hist_layout`'s padding (row 0)."""
+    then `sparse_hist_layout`'s padding (row 0); with them the lane at which
+    each feature's run begins (``[features + 1]``) and the first station's
+    features."""
     from benchmark.traffic.sparse_fit import station_plan
     from dmlc_core_tpu.ops import pallas_segment as ps
     rng = np.random.default_rng(9)
     runs = []
-    for size, p in zip(*station_plan(features, stations, share)):
+    sizes, probs = station_plan(features, stations, share)
+    for size, p in zip(sizes, probs):
         runs += [np.flatnonzero(rng.random(rows) < p).astype(np.int32)
                  ] * int(size)
     rid = np.concatenate(runs)
+    fstart = np.concatenate([[0], np.cumsum([len(r) for r in runs])])
     lanes = ps._round_up_some(len(rid), ps._NNZ_TILE, 64)
-    return np.pad(rid, (0, lanes - len(rid)))
+    return (np.pad(rid, (0, lanes - len(rid))), fstart.astype(np.int32),
+            np.arange(int(sizes[0]), dtype=np.int32))
 
 
 def check_entry_lookup(shape: dict, interpreted: bool) -> list:
@@ -474,7 +480,7 @@ def check_entry_lookup(shape: dict, interpreted: bool) -> list:
     require(interpreted or ps.entry_lookup_engages(True, 6 * rows),
             f"the entry lookup does not engage at {rows} rows on a chip")
     rng = np.random.default_rng(11)
-    rid = jnp.asarray(station_rids(**shape))
+    rid = jnp.asarray(station_layout(**shape)[0])
     cspan = jax.jit(ps._chunk_spans)(rid)
     visits = int(jnp.sum((cspan >> 16) - (cspan & 0xFFFF) + 1))
     slot = jnp.asarray(rng.integers(-1, 128, rows).astype(np.int32))
@@ -505,6 +511,60 @@ def check_entry_lookup(shape: dict, interpreted: bool) -> list:
                "ms": round(ms, 1), "exact": same}
         log(f"  {row}")
         require(same, f"entry_lookup {name}: not table[rid] bit for bit")
+        out.append(row)
+    return out
+
+
+def check_entry_push(shape: dict, interpreted: bool) -> list:
+    """The push kernel against XLA's scatter-add, exactly, with every
+    sub-tile live (the layout's own spans) and with one station's runs live
+    (`run_spans`: what a level that splits on that station's features
+    visits)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    rows = shape["rows"]
+    require(interpreted or ps.route_push_engages(True, rows),
+            f"the entries' push does not engage at {rows} rows on a chip")
+    rng = np.random.default_rng(13)
+    rid_h, fstart, station = station_layout(**shape)
+    rid = jnp.asarray(rid_h)
+    cspan = jax.jit(ps._chunk_spans)(rid)
+    carried = rng.integers(0, 3, len(rid_h)).astype(np.int32)
+    carried[fstart[-1]:] = 0                        # the padding lanes
+    by_station = np.zeros_like(carried)
+    by_station[:fstart[len(station)]] = carried[:fstart[len(station)]]
+    cases = (("all_live", cspan, carried),
+             ("one_station", jax.jit(ps.run_spans)(
+                 cspan, jnp.asarray(fstart), jnp.asarray(station)),
+              by_station))
+    scatter_add = jax.jit(lambda r, v: jnp.zeros(rows, jnp.float32).at[r].add(
+        v.astype(jnp.float32)))
+    lowered = jax.jit(ps.push_to_rows, static_argnums=3).lower(
+        rid, cspan, rid, rows)
+    mosaic = "tpu_custom_call" in lowered.as_text()
+    require(mosaic != interpreted, "entry_push: "
+            + ("interpreted on a chip" if not mosaic
+               else "compiled by Mosaic in a CPU rehearsal"))
+    run = lowered.compile()
+    out = []
+    for name, span, val in cases:
+        val = jnp.asarray(val)
+        want = scatter_add(rid, val)
+        got = jax.block_until_ready(run(rid, span, val))
+        t0 = time.monotonic()
+        jax.block_until_ready(run(rid, span, val))
+        ms = (time.monotonic() - t0) * 1e3
+        same = bool(jnp.all(got == want))
+        visited = span != ps._EMPTY_SPAN
+        row = {"kernel": f"entry_push({name})", "entries": int(rid.shape[0]),
+               "sub_tiles": int(jnp.sum(visited)),
+               "chunk_visits": int(jnp.sum(jnp.where(
+                   visited, (span >> 16) - (span & 0xFFFF) + 1, 0))),
+               "interpret": not mosaic, "ms": round(ms, 1), "exact": same}
+        log(f"  {row}")
+        require(same, f"entry_push {name}: not zeros(rows).at[rid].add(val)")
         out.append(row)
     return out
 
@@ -578,6 +638,7 @@ def phase_kernels(ctx: dict) -> dict:
             table.append(run_kernel(name, nn, fn, args, reference,
                                     interpreted))
     table += check_entry_lookup(size["lookup"], interpreted)
+    table += check_entry_push(size["lookup"], interpreted)
     ctx["kernels"] = table
     return {"calls": len(table), "node_caps": caps}
 

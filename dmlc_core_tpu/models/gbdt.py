@@ -45,7 +45,8 @@ from .common import count_predict_retrace
 from ..ops import pallas_segment
 from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
                                   entry_values, histogram_gh,
-                                  histogram_gh_sparse_kernel, segment_sum,
+                                  histogram_gh_sparse_kernel, push_to_rows,
+                                  route_push_engages, run_spans, segment_sum,
                                   sparse_hist_layout)
 
 # Most nodes a level for which `GBDT._route_level` finds a row's split by
@@ -576,6 +577,35 @@ def _entry_slots(layout, slot: jax.Array, depth: int) -> jax.Array:
     return _entry_values(layout, slot)
 
 
+def _routes_by_push(entries, layout, rows: int) -> bool:
+    """Whether `GBDT._build_tree_sparse` routes its levels by the entries'
+    push: the layout is the only copy of the entries (`fit_batch` has seen
+    to its ``rows_ascend`` and to every level's running the kernel on one
+    device) and `route_push_engages` says so."""
+    return entries is None and route_push_engages(layout.rows_ascend, rows)
+
+
+def _entry_rels(layout, rel: jax.Array, right_built, depth: int):
+    """For a level that routes by the push (`GBDT._route_layout`): the rows'
+    node ``rel`` on the layout's entry lanes, and the level's slots derived
+    from it there, so that one lookup a level serves the histogram and the
+    routing both.  ``rel = 2 * parent + went_right``, so an entry's slot is
+    `_child_slot` of its parent: ``rel >> 1`` if ``rel & 1`` is the child that
+    was built (``right_built``: the level above's [nodes / 2] word, taken by
+    `_select_by_bits`, 0.0003 ns a lane a node where a gather is 8), else
+    ``_NO_SLOT``: the int32 values `_entry_slots` lays there.  A kernel
+    level holds at most 256 nodes, so ``rel`` is a bfloat16 as it stands.
+    At the root both are 0 and nothing is looked up."""
+    if depth == 0:
+        rel_e = _entry_slots(layout, rel, depth)
+        return rel_e, rel_e
+    rel_e = _entry_values(layout, rel)
+    with jax.named_scope("gbdt.entry_gather"):
+        parent = rel_e >> 1
+        built = _select_by_bits(list(right_built), parent)
+        return rel_e, jnp.where(((rel_e & 1) == 1) == built, parent, _NO_SLOT)
+
+
 def _with_siblings(parent, built: jax.Array, right_built) -> jax.Array:
     """A level's ``[2 * parents, F, B, 2]`` histograms from the level
     above's ``parent`` ``[parents, F, B, 2]`` and the ``built`` child of
@@ -646,10 +676,10 @@ _MARGIN_SELECT_CHUNK = 64
 
 
 def _select_by_bits(vals: list, index: jax.Array) -> jax.Array:
-    """``vals[index]`` a row, ``vals`` a list of float scalars, by halving:
+    """``vals[index]`` a row, ``vals`` a list of scalars, by halving:
     bit 0 of ``index`` picks within pairs of values, bit 1 within pairs of
     those pairs, and so on.  One select a value a row, nothing compared
-    with an id, and what comes out is a float that went in, bit for bit (a
+    with an id, and what comes out is a value that went in, bit for bit (a
     ``-0.0`` too); an odd value out at a level rides up as it is; bits past
     the values' are not looked at.  Scalars against ``[rows]`` arrays: XLA
     fuses the lot into one pass over the rows, which stay 1-D."""
@@ -1013,8 +1043,9 @@ class GBDT:
         """Calls of the lookup kernel that one `_build_tree_sparse` program
         holds (counter ``gbdt.entry_lookup``; 0 where `_entry_values` takes
         XLA's gather): on one device the slots of every kernel level below
-        the root and the tree's (grad, hess); under ``histogram_mesh`` both
-        at every kernel level, of a shard's rows."""
+        the root (the nodes they are derived from, where the level routes by
+        the push: `_entry_rels`) and the tree's (grad, hess); under
+        ``histogram_mesh`` both at every kernel level, of a shard's rows."""
         if layout is None:
             return 0
         levels = self.level_backends(sparse=True).count("pallas")
@@ -1854,6 +1885,8 @@ class GBDT:
             gh_e = _entry_values(layout, gh_row)
         if entries is not None:
             rid, fi, ebin, emask = entries
+        push = _routes_by_push(entries, layout, rows)
+        rel_e = None
 
         node = jnp.zeros(rows, jnp.int32)
         lo = jnp.full(1, -jnp.inf)
@@ -1870,8 +1903,12 @@ class GBDT:
             cols = _built_columns(depth)
             rel = node - first
             if impl == "pallas":
-                slot_e = (None if mesh
-                          else _entry_slots(layout, slot, depth))
+                if push:
+                    rel_e, slot_e = _entry_rels(layout, rel, right_built,
+                                                depth)
+                else:
+                    slot_e = (None if mesh
+                              else _entry_slots(layout, slot, depth))
                 with jax.named_scope("gbdt.hist"):
                     built = self._level_histogram_sparse(
                         layout, slot, gh_row, gh_e, slot_e, cols)
@@ -1901,14 +1938,15 @@ class GBDT:
             covers.append(gh_node[:, 1])
             with jax.named_scope("gbdt.route"):
                 if entries is None:
-                    go_right = self._route_layout(layout, rel, split_f,
-                                                  split_b, split_d)
+                    go_right = self._route_layout(layout, rel, rel_e,
+                                                  split_f, split_b, split_d)
                 else:
                     go_right = self._route_sparse(fi, ebin, emask, rid,
                                                   split_f[rel], split_b[rel],
                                                   split_d[rel], rows)
                 node = 2 * node + 1 + go_right.astype(jnp.int32)
-                slot = _child_slot(rel, go_right, right_built[rel])
+                if not push:    # (the push's levels derive it on the lanes)
+                    slot = _child_slot(rel, go_right, right_built[rel])
 
         n_leaves = 2 ** self.max_depth
         with jax.named_scope("gbdt.leaf"):
@@ -1927,18 +1965,49 @@ class GBDT:
                 jnp.concatenate(covers), leaf, leaf_rel)
 
     @staticmethod
-    def _route_layout(layout, rel, split_f, split_b, split_d):
+    def _route_layout(layout, rel, rel_e, split_f, split_b, split_d):
         """One level of routing by the feature-sorted layout alone: bool
         [rows], which rows go to their node's right child.  A row's bin on
-        its node's split feature is looked up where it lies: the feature's
-        run of entries is in strictly ascending row order (the caller has
-        checked the layout's ``rows_ascend``), so the row is found in it by
-        bisection — a gather a *row* a round, 0.66 s a level
-        at 1.18M rows on a v5e, where the maximum over every entry
-        (`_route_sparse`) took 5.8 s for 2.18e8 of them (PERF.md, PR 27).
-        No entry of the row in the run: the cell is absent, and the row
-        follows the node's default direction."""
+        its node's split feature lies in that feature's run of entries, in
+        strictly ascending row order (the caller has checked the layout's
+        ``rows_ascend``: a row holds the feature once at most, so the bin is
+        `_route_sparse`'s maximum over it).  No entry of the row in the run:
+        the cell is absent, and the row follows the node's default
+        direction, as it does on a NaN's bin 0.
+
+        Two routes to the same bool a row; which one is the caller's word,
+        by `_routes_by_push`: it hands ``rel_e``, the rows' ``rel`` on the
+        entry lanes (`_entry_rels`), or None.
+
+        *The entries push* (``rel_e``): every lane compares its own feature
+        and bin with its row's node's split — one key a node, taken by
+        `_select_by_bits` — and carries 1 (left) or 2 (right) if the
+        feature is the node's, else 0; `push_to_rows` adds the lanes up a
+        row, visiting only the sub-tiles that hold a run of a feature some
+        node splits on (`run_spans`); a row left at 0 has no entry there.
+
+        *The rows pull* (None): a row is found in the run by bisection — a
+        gather a *row* a round, 21 rounds 0.64 s a level at 1.18M rows on a
+        v5e where the push takes 8 to 37 ms (PERF.md, PR 47), and where the
+        maximum over every entry (`_route_sparse`) took 5.8 s for 2.18e8 of
+        them (PR 27)."""
         rows = rel.shape[0]
+        if rel_e is not None:
+            # a node's split as the key of its last bin that goes left (a
+            # null split's num_bins: no bin lies past nb - 1 either)
+            nb = layout.nb
+            key = _select_by_bits(
+                list(split_f * nb + jnp.minimum(split_b, nb - 1)), rel_e)
+            shift = nb.bit_length() - 1
+            mine = ((layout.gkey >> shift == key >> shift)  # padding lanes: -1
+                    & (layout.gkey & (nb - 1) > 0))
+            val = jnp.where(mine, 1 + (layout.gkey > key), 0)
+            side = push_to_rows(
+                layout.rid, run_spans(layout.cspan, layout.fstart, split_f),
+                val, rows)
+            return jnp.where(side == 0,
+                             _select_by_bits(list(split_d), rel) == 1,
+                             side == 2)
         feat, thr, right = split_f[rel], split_b[rel], split_d[rel] == 1
         lo, end = layout.fstart[feat], layout.fstart[feat + 1]
         row = jnp.arange(rows, dtype=jnp.int32)
@@ -2154,11 +2223,15 @@ class GBDT:
             entries = None      # the layout holds every live entry
 
         lookups = self._entry_lookups(layout, int(label.shape[0]))
+        # levels a tree's program routes by the entries' push
+        pushes = self.max_depth * _routes_by_push(entries, layout,
+                                                  int(label.shape[0]))
 
         def build_tree(g, h, col_mask, col_key):
             if kernel_levels:
                 counter_add("gbdt.hist_sparse_pallas", kernel_levels)
                 counter_add("gbdt.entry_lookup", lookups)
+                counter_add("gbdt.route_push", pushes)
             return self._build_tree_sparse(entries, layout, g, h, col_mask,
                                            col_key)
 

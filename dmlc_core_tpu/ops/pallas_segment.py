@@ -69,6 +69,7 @@ SEGMENT_SUM_KERNEL = "_segment_sum_pallas"
 DENSE_HIST_KERNEL = "_histogram_gh_pallas"
 SPARSE_HIST_KERNEL = "_histogram_gh_sparse_pallas"
 ENTRY_LOOKUP_KERNEL = "_entry_lookup_pallas"
+ENTRY_PUSH_KERNEL = "_entry_push_pallas"
 
 
 def check_force(force, what: str = "backend") -> None:
@@ -850,6 +851,8 @@ _LOOKUP_STEP_TILES = 32                 # entry sub-tiles a grid step
 _LOOKUP_TABLE_BYTES = 16 << 20
 ENTRY_LOOKUP_PLANE_ROWS = _LOOKUP_TABLE_BYTES // 2
 
+_EMPTY_SPAN = 1         # a sub-tile's first chunk 1, its last 0: no visit
+
 
 def _chunk_spans(rid: jax.Array) -> jax.Array:
     """Of each sub-tile of ``_NNZ_TILE`` lanes of ``rid``, the first and the
@@ -939,7 +942,7 @@ def _entry_lookup_pallas(rid: jax.Array, cspan: jax.Array, table: jax.Array,
                    .reshape(planes * _LOOKUP_LO, cols))
         # a sub-tile past the last, in the last step: an empty span
         cspan3 = jnp.pad(cspan, (0, steps * _LOOKUP_STEP_TILES - tiles),
-                         constant_values=1
+                         constant_values=_EMPTY_SPAN
                          ).reshape(steps, 1, _LOOKUP_STEP_TILES)
     return pl.pallas_call(
         functools.partial(_entry_lookup_kernel, planes // out_rows),
@@ -1006,6 +1009,152 @@ def _lookup_values(rid: jax.Array, cspan: jax.Array, table: jax.Array
     parts = jnp.concatenate(_split_bf16x3(table.astype(jnp.float32).T))
     return _entry_lookup_pallas(rid, cspan, parts.astype(jnp.bfloat16), 2,
                                 jnp.float32, pallas_interpret())
+
+
+# ---- values an entry, pushed onto the rows ----------------------------------
+# ``zeros(rows).at[layout.rid].add(val)``: the lookup turned round.  A level
+# of the sparse tree routes its rows by it: the entries of the features that
+# the level's nodes split on carry their row's decision, every other lane 0,
+# and the rows read it out of a table that the kernel fills in VMEM.  The
+# same two one-hots, contracted over the entries where the lookup contracts
+# over the rows; a sub-tile whose lanes are all 0 is not visited, so a level
+# costs what the runs of its split features cost, not what 2.18e8 lanes do:
+# at the Bosch cell's layout 7.9 ms where one station's seven runs carry,
+# 36.6 where 80 dense features' do, 110.9 with every sub-tile live (350,889
+# chunk visits of 306 bundles, 1.0 ns a bundle: the lookup's rate), where
+# the bisection a row it replaces took 0.64 s a level of 1.18 M rows (my
+# chip run, PR 47).
+
+# The most rows one push call takes: its float32 table lies whole in VMEM,
+# 4 B a row, and is given 32 MiB of a v5e's 128; the call's limit is the
+# table and 16 MiB more for the blocks of row ids and values (2 x 128 KiB
+# each) and a chunk visit's temporaries.  8,388,608, what one plane of the
+# lookup takes (`ENTRY_LOOKUP_PLANE_ROWS`).
+_PUSH_TABLE_BYTES = 32 << 20
+ROUTE_PUSH_ROWS = _PUSH_TABLE_BYTES // 4
+
+def run_spans(cspan: jax.Array, fstart: jax.Array, features: jax.Array
+              ) -> jax.Array:
+    """A layout's ``cspan`` on the sub-tiles that hold an entry of one of
+    ``features`` ([n] feature ids; ``fstart``: where each feature's run of
+    lanes begins), ``_EMPTY_SPAN`` on every other: n compares a sub-tile,
+    fused into their reduction.  A run that is empty marks the sub-tile it
+    would lie in, which costs that sub-tile's visit and changes nothing."""
+    tile = jnp.arange(cspan.shape[0], dtype=jnp.int32)
+    first = fstart[features] // _NNZ_TILE
+    last = (fstart[features + 1] - 1) // _NNZ_TILE
+    live = jnp.any((tile >= first[:, None]) & (tile <= last[:, None]), axis=0)
+    return jnp.where(live, cspan, _EMPTY_SPAN)
+
+
+def _entry_push_kernel(span_ref, rid_ref, val_ref, out_ref):
+    """One grid step: ``_LOOKUP_STEP_TILES`` sub-tiles of ``_NNZ_TILE`` entry
+    lanes, as `_entry_lookup_kernel` takes them.  A row id is ``hi * 128 +
+    lo``, the table lies ``T[lo, hi]`` whole in VMEM for the call, and for a
+    sub-tile's entries ``e`` and each chunk ``c`` of 128 ``hi`` values that
+    its span holds
+
+        T[lo, c*128 + h] += sum_e [lo_e == lo] * val_e * [hi_e == c*128 + h]
+
+    one ``[128, 1024] . [1024, 128]`` bfloat16 MXU pass into float32, both
+    operands a sublane iota compared with a lane-broadcast row, the entries
+    on the lane axis of both as they arrive.  ``val`` is a small integer
+    (a bfloat16 as it stands) and the caller sees to it that a row receives
+    from one lane at most, so every sum has one term that is not zero and
+    the table is exact.  A sub-tile with an empty span costs its scalar
+    read and a branch."""
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    ids = jax.lax.broadcasted_iota(jnp.int32, (_LOOKUP_LO, _NNZ_TILE), 0)
+
+    def sub_tile(j, carry):
+        span = span_ref[0, j]
+        first, last = span & 0xFFFF, span >> 16
+
+        @pl.when(first <= last)
+        def _visit():
+            lanes = pl.ds(pl.multiple_of(j * _NNZ_TILE, _NNZ_TILE), _NNZ_TILE)
+            rid = rid_ref[:, lanes]                             # [1, tile]
+            hi = rid >> 7
+            val = val_ref[:, lanes].astype(jnp.float32)
+            at_lo = ids == jnp.broadcast_to(rid & (_LOOKUP_LO - 1), ids.shape)
+            a = jnp.where(at_lo, jnp.broadcast_to(val, ids.shape),
+                          0.0).astype(jnp.bfloat16)             # [lo, tile]
+
+            def chunk(c, carry):
+                start = pl.multiple_of(c * 128, 128)
+                hit = ids == jnp.broadcast_to(hi - start, ids.shape)
+                out_ref[:, pl.ds(start, 128)] += jax.lax.dot_general(
+                    a, jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [lo, h]
+                return carry
+
+            jax.lax.fori_loop(first, last + 1, chunk, None)
+
+        return carry
+
+    jax.lax.fori_loop(0, _LOOKUP_STEP_TILES, sub_tile, None)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def _entry_push_pallas(rid: jax.Array, span: jax.Array, val: jax.Array,
+                       rows: int, interpret: bool) -> jax.Array:
+    """rid, val: [nnz_pad] int32 (a multiple of ``_NNZ_TILE``); span: rid's
+    `_chunk_spans`, or ``_EMPTY_SPAN`` on a sub-tile to leave out.  Returns
+    [rows] float32: ``zeros(rows).at[rid].add(val)`` over the lanes of the
+    sub-tiles whose span is not empty, exactly."""
+    nnz_pad = rid.shape[0]
+    tiles = nnz_pad // _NNZ_TILE
+    steps = pl.cdiv(tiles, _LOOKUP_STEP_TILES)
+    block = _LOOKUP_STEP_TILES * _NNZ_TILE
+    cols = pl.cdiv(rows, _LOOKUP_CHUNK) * 128       # whole chunks, as T's
+    with jax.named_scope("ops.lookup_layout"):
+        span3 = jnp.pad(span, (0, steps * _LOOKUP_STEP_TILES - tiles),
+                        constant_values=_EMPTY_SPAN
+                        ).reshape(steps, 1, _LOOKUP_STEP_TILES)
+    table_t = pl.pallas_call(
+        _entry_push_kernel,
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((None, 1, _LOOKUP_STEP_TILES), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((_LOOKUP_LO, cols), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LOOKUP_LO * cols * 4 + (16 << 20)),
+        interpret=interpret,
+        name=ENTRY_PUSH_KERNEL,
+    )(span3, rid.reshape(1, nnz_pad), val.reshape(1, nnz_pad))
+    with jax.named_scope("ops.lookup_layout"):
+        # T[lo, hi] back to table[hi * 128 + lo]
+        return table_t.T.reshape(-1)[:rows]
+
+
+def route_push_engages(rows_ascend: bool, rows: int) -> bool:
+    """Whether a level's routing takes `push_to_rows`, read off what the
+    code can see as `entry_lookup_engages` is: a row holds a feature once
+    and row ids ascend within every run (a sub-tile's lanes then name few
+    chunks, and a row receives from one lane), the kernel is compiled for a
+    TPU, and the float32 table of ``rows`` fits its share of VMEM."""
+    return (rows_ascend and not pallas_interpret()
+            and rows <= ROUTE_PUSH_ROWS)
+
+
+def push_to_rows(rid: jax.Array, span: jax.Array, val: jax.Array, rows: int
+                 ) -> jax.Array:
+    """``zeros(rows).at[rid].add(val)`` as float32, by the push kernel
+    (interpreted off the chip, where only tests come here).  rid: a
+    `SparseHistLayout`'s row ids; span: its ``cspan``, or `run_spans` of it
+    where ``val`` is 0 outside some features' runs; val: [nnz] int32 of
+    magnitude at most 256, 0 on every lane that has nothing to say (the
+    padding lanes among them), and not 0 on one lane a row at most."""
+    return _entry_push_pallas(rid, span, val, rows, pallas_interpret())
 
 
 def segment_sum(contrib: jax.Array, row_id: jax.Array, num_segments: int,

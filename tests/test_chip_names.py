@@ -186,6 +186,37 @@ def test_entry_lookup_kernel_compiles_at_the_cells_size(one_chip, quiet_cache,
     assert dots == [(["bfloat16", "bfloat16"], None, "float32")]
 
 
+# the push that routes the Bosch cell's rows: the 2.18e8 entry lanes' row
+# ids and what each carries, into a float32 table of 1,183,747 rows (4.8 MB
+# of VMEM for the call)
+def test_entry_push_kernel_compiles_at_the_cells_size(one_chip, quiet_cache):
+    nnz, rows = 218103808, 1183747
+    assert rows <= pallas_segment.ROUTE_PUSH_ROWS
+
+    def push(rid, span, val):
+        return pallas_segment._entry_push_pallas(rid, span, val, rows, False)
+
+    shapes = (on(one_chip, (nnz,), jnp.int32),
+              on(one_chip, (nnz // 1024,), jnp.int32),
+              on(one_chip, (nnz,), jnp.int32))
+    compiled = jax.jit(push).lower(*shapes).compile()
+    kernels = [n for n in instructions(compiled) if n.startswith(
+        "%" + pallas_segment.ENTRY_PUSH_KERNEL)]
+    assert len(kernels) == 1 and compiled.as_text().count(
+        "tpu_custom_call") == 1
+    # nothing the size of the entry lanes is made for the kernel, and what
+    # comes back is a float a row
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.output_size_in_bytes < 8 * rows
+    assert kernel_dots(push, *shapes) == [
+        (["bfloat16", "bfloat16"], None, "float32")]
+    # the largest table the rule lets in still fits the chip's fast memory
+    wide = pallas_segment.ROUTE_PUSH_ROWS
+    jax.jit(lambda rid, span, val: pallas_segment._entry_push_pallas(
+        rid, span, val, wide, False)).lower(*shapes).compile()
+
+
 def test_sparse_tree_program_looks_its_entries_up_under_their_scope(
         one_chip, quiet_cache, monkeypatch):
     """The sparse tree program as a chip compiles it where the rule engages
@@ -223,6 +254,18 @@ def test_sparse_tree_program_looks_its_entries_up_under_their_scope(
         [f"f32[2,{nnz}]"] + [f"s32[1,{nnz}]"] * (depth - 1))
     assert all("/gbdt.entry_gather/" in name for _shape, name in lookups)
     assert not re.search(rf"\[{nnz}(,\d+)?\]\S* gather\(", text)
+    # and every level routes by the push kernel under ``gbdt.route``, the
+    # scope `sparse_route_ms_per_round` reads: no bisection loop is left,
+    # and the selects that derive the slots carry ``gbdt.entry_gather``
+    pushes = re.findall(
+        r"^\s*(?:ROOT )?%" + pallas_segment.ENTRY_PUSH_KERNEL
+        + r"[\w.\-]* = (\S+) custom-call\(.*op_name=\"([^\"]*)\"", text, re.M)
+    assert len(pushes) == depth
+    assert all("/gbdt.route/" in name for _shape, name in pushes)
+    assert not any("/gbdt.route/" in name and "while" in name
+                   for name in op_names(compiled))
+    assert any("/gbdt.entry_gather/" in name and "select_n" in name
+               for name in op_names(compiled))
 
 
 def test_tree_program_keeps_its_scopes_through_the_tpu_compiler(

@@ -716,3 +716,46 @@ def test_the_entry_lookup_runs_under_the_entry_gather_scope(monkeypatch):
             f"jit({ps.ENTRY_LOOKUP_KERNEL})") in paths
     assert f"{ps.ENTRY_LOOKUP_KERNEL}/pallas_call" in paths
     assert carries(paths, "ops.lookup_layout")
+
+
+def test_the_entries_push_runs_under_the_route_scope(monkeypatch):
+    """Where its rule engages (on a chip it reads the layout, the backend
+    and the rows; here the kernels are only said to be compiled), every level
+    of the sparse tree routes by the push kernel, under the scope
+    `sparse_route_ms_per_round` reads: one call a level into a float32 table
+    of the rows, no bisection loop and no gather a row or an entry under
+    ``gbdt.route``, and the slots' derivation under ``gbdt.entry_gather``.
+    Lowered for the chip, each is a Mosaic call under the route's scope."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    features, max_depth, _sparse = CELL_TREES["bosch"]
+    rows = 192
+    bisecting, _ = tree_program(features, max_depth, True, rows)
+    assert [path for path, _i, _o in device_ops(bisecting, "scan")
+            if "gbdt.route" in path]          # the halvings' loop
+    monkeypatch.setattr(ps, "pallas_interpret", lambda: False)
+    traced, lanes = tree_program(features, max_depth, True, rows)
+    pushes = [(path, ins, outs) for path, ins, outs
+              in device_ops(traced, "pallas_call") if "gbdt.route" in path]
+    assert len(pushes) == max_depth
+    for path, ins, outs in pushes:
+        assert path.endswith(f"/gbdt.route/{ps.ENTRY_PUSH_KERNEL}")
+        assert [i.shape for i in ins[1:]] == [(1, lanes)] * 2
+        assert [(o.shape, str(o.dtype)) for o in outs] == [
+            ((128, 128), "float32")]
+    for primitive in ("scan", "while", "scatter", "scatter-add"):
+        assert [path for path, _i, _o in device_ops(traced, primitive)
+                if "gbdt.route" in path
+                and ps.ENTRY_PUSH_KERNEL not in path] == [], primitive
+    # what is still gathered there is `run_spans`' two reads of ``fstart`` a
+    # node of the level
+    gathered = [int(np.prod(ins[1].shape[:-1])) for path, ins, _o
+                in device_ops(traced, "gather") if "gbdt.route" in path]
+    assert sorted(gathered) == sorted(
+        2 ** d for d in range(max_depth) for _ in range(2))
+    assert [path for path, _i, _o in device_ops(traced, "select_n")
+            if "gbdt.entry_gather" in path]
+    paths = paths_of(traced.lower(lowering_platforms=("tpu",)))
+    assert ("jit(_build_tree_sparse)/gbdt.route/"
+            f"jit({ps.ENTRY_PUSH_KERNEL})") in paths
+    assert f"{ps.ENTRY_PUSH_KERNEL}/pallas_call" in paths
+    assert carries(paths, "gbdt.entry_gather")

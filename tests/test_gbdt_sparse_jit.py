@@ -10,7 +10,8 @@ import jax.numpy as jnp
 from dmlc_core_tpu.models import GBDT
 from dmlc_core_tpu.parallel import MeshPlan
 from dmlc_core_tpu.models.gbdt import (_built_columns, _child_slot,
-                                       _entry_slots, _with_siblings)
+                                       _entry_rels, _entry_slots,
+                                       _with_siblings)
 from dmlc_core_tpu.ops.pallas_segment import (_KEY_TILE, _NNZ_TILE,
                                               _round_up_some, segment_sum,
                                               sparse_hist_layout)
@@ -429,6 +430,132 @@ def _thin_and_dense_batch(rng, rows=3000):
         value=jnp.asarray(np.pad(value[row_id, index], (0, pad))),
         num_rows=jnp.asarray(np.int32(rows)), field=None)
     return batch, QuantileBinner(num_bins=16, missing_aware=True).fit(value)
+
+
+# ---- a level's routing by the entries' push (PR 47) --------------------------
+
+
+def _route_case(rng, rows=40_000):
+    """Entries of six features over a few chunks' worth of rows (one present
+    in 0.2% of them, one in 90%), some of them a NaN's bin 0, some rows
+    empty, and their feature-sorted layout."""
+    share = np.array([0.01, 0.6, 0.3, 0.9, 0.002, 0.5])
+    rid, fi = np.nonzero(rng.random((rows, 6)) < share)
+    ebin = rng.integers(1, 16, len(rid)).astype(np.int32)
+    ebin[rng.random(len(rid)) < 0.05] = 0
+    entries = (jnp.asarray(rid, jnp.int32), jnp.asarray(fi, jnp.int32),
+               jnp.asarray(ebin), jnp.ones(len(rid), bool))
+    layout = sparse_hist_layout(*entries, 6, 16)
+    assert layout.rows_ascend and len(set(rid.tolist())) < rows
+    return entries, layout
+
+
+ROUTE_SPLITS = {
+    # depth, then the level's split_f / split_b / split_d tables
+    "root": (0, [3], [7], [1]),
+    "root_on_the_thin_feature_default_left": (0, [4], [3], [0]),
+    "one_feature_by_four_nodes": (2, [1, 1, 1, 1], [2, 9, 9, 14],
+                                  [0, 1, 1, 0]),
+    "every_default_left": (3, None, None, 0),
+    "every_default_right": (3, None, None, 1),
+    "null_splits_among_them": (3, [0, 2, 0, 5, 0, 0, 3, 1],
+                               [16, 4, 16, 15, 16, 0, 8, 16],
+                               [0, 1, 0, 0, 0, 1, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUTE_SPLITS))
+def test_the_entries_push_routes_as_the_bisection_and_the_maximum_do(name):
+    """`_route_layout`'s two routes and `_route_sparse`: the same bool a
+    row, whatever the level's tables say."""
+    rng = np.random.default_rng(len(name))
+    (rid, fi, ebin, emask), layout = _route_case(rng)
+    rows = 40_000
+    depth, split_f, split_b, split_d = ROUTE_SPLITS[name]
+    n = 2 ** depth
+    if split_f is None:
+        split_f, split_b = rng.integers(0, 6, n), rng.integers(0, 17, n)
+        split_d = np.full(n, split_d)
+    split_f, split_b, split_d = (jnp.asarray(t, jnp.int32)
+                                 for t in (split_f, split_b, split_d))
+    rel = jnp.asarray(rng.integers(0, n, rows), jnp.int32)
+    rel_e = rel[layout.rid] if depth else jnp.zeros_like(layout.rid)
+    by_push = np.asarray(GBDT._route_layout(layout, rel, rel_e, split_f,
+                                            split_b, split_d))
+    by_bisection = np.asarray(GBDT._route_layout(layout, rel, None, split_f,
+                                                 split_b, split_d))
+    by_maximum = np.asarray(GBDT._route_sparse(
+        fi, ebin, emask, rid, split_f[rel], split_b[rel], split_d[rel],
+        rows))
+    assert by_push.dtype == bool
+    np.testing.assert_array_equal(by_push, by_bisection)
+    np.testing.assert_array_equal(by_push, by_maximum)
+    assert 0 < by_push.sum() < rows
+
+
+@pytest.mark.parametrize("depth", [0, 1, 4, 8])
+def test_slots_derived_on_the_entry_lanes_are_the_slots_gathered(depth):
+    """`_entry_rels` lays ``rel`` on the lanes and derives the level's slots
+    there from the level above's ``right_built``: the int32 values that
+    `_entry_slots` gathers, `_NO_SLOT` and the padding lanes' too."""
+    rng = np.random.default_rng(depth)
+    _, layout = _route_case(rng)
+    parents = 2 ** max(depth - 1, 0)
+    parent = jnp.asarray(rng.integers(0, parents, 40_000), jnp.int32)
+    went_right = jnp.asarray(rng.random(40_000) < 0.4)
+    right_built = jnp.asarray(rng.random(parents) < 0.5)
+    if depth == 0:
+        rel, slot = parent, parent
+    else:
+        rel = 2 * parent + went_right.astype(jnp.int32)
+        slot = _child_slot(parent, went_right, right_built[parent])
+    rel_e, slot_e = _entry_rels(layout, rel, right_built, depth)
+    np.testing.assert_array_equal(np.asarray(rel_e),
+                                  np.asarray(rel)[np.asarray(layout.rid)])
+    want = np.asarray(_entry_slots(layout, slot, depth))
+    assert slot_e.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(slot_e), want)
+    assert depth == 0 or (want == -1).any()
+
+
+def test_fit_batch_by_the_entries_push_grows_the_bisections_forest(
+        monkeypatch):
+    """Rows routed by the push kernel (forced on through the rule's function,
+    interpreted off the chip) or by the bisection: the same forest, bit for
+    bit; the counter ``gbdt.route_push`` says which it was, a level a tree,
+    and the lookup still runs once a level."""
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    from dmlc_core_tpu.models import gbdt
+    batch, binner = _thin_and_dense_batch(np.random.default_rng(47))
+    kw = dict(num_features=4, num_trees=2, max_depth=4, num_bins=16,
+              learning_rate=0.5, missing_aware=True, histogram="pallas")
+    calls = []
+    kernel = ps._entry_push_pallas
+
+    def spy(rid, span, val, rows, interpret):
+        calls.append(rows)
+        return kernel(rid, span, val, rows, interpret)
+
+    monkeypatch.setattr(ps, "_entry_push_pallas", spy)
+    counters = ("gbdt.route_push", "gbdt.entry_lookup")
+    before = [telemetry.counter_get(c) for c in counters]
+    by_bisection = GBDT(**kw).fit_batch(batch, binner)
+    assert [telemetry.counter_get(c) for c in counters] == before
+    assert not calls
+    monkeypatch.setattr(gbdt, "route_push_engages",
+                        lambda rows_ascend, rows: rows_ascend)
+    monkeypatch.setattr(ps, "entry_lookup_engages",
+                        lambda rows_ascend, plane_rows: rows_ascend)
+    by_push = GBDT(**kw).fit_batch(batch, binner)
+    assert calls == [3000] * 4          # one trace serves both trees
+    assert [telemetry.counter_get(c) - b
+            for c, b in zip(counters, before)] == [2 * 4, 2 * 4]
+    for k in by_bisection:
+        np.testing.assert_array_equal(np.asarray(by_push[k]),
+                                      np.asarray(by_bisection[k]), err_msg=k)
+    assert (np.asarray(by_push["feature"]) == 0).any()     # the thin one
+    assert (np.asarray(by_push["default_right"]) == 1).any()
 
 
 @pytest.mark.parametrize("mesh", [False, True])

@@ -777,3 +777,99 @@ def test_entry_lookup_engages_by_what_the_code_can_see(monkeypatch):
         assert kernels(table, True, engaged=False) == (0, 1)
         assert kernels(table, False, engaged=True) == (0, 1)
         assert kernels(table, True, engaged=True) == (1, 0)
+
+
+# ---- values an entry pushed onto the rows: the lookup turned round (PR 47) --
+
+_PUSH_RUNS = (3_000, 180_000, 57)       # `_lookup_layout`'s three runs
+
+
+def _push_case(case):
+    """(rid, span, val) on `_lookup_layout`'s lanes.  ``all_live``: every
+    lane of every run carries something, the spans are the layout's own (the
+    thin run's sub-tiles visit many chunks, the boundary sub-tile every one);
+    ``thin_run`` / ``short_run`` / ``two_runs``: only those runs carry, and
+    `run_spans` leaves every other sub-tile out — the boundary sub-tile that
+    holds the end of one run and the start of the next stays; ``nothing``:
+    every span empty."""
+    from dmlc_core_tpu.ops.pallas_segment import _chunk_spans, run_spans
+    rng = np.random.default_rng(len(case))
+    rid = _lookup_layout()
+    fstart = np.concatenate([[0], np.cumsum(_PUSH_RUNS)]).astype(np.int32)
+    cspan = _chunk_spans(jnp.asarray(rid))
+    runs = {"all_live": [0, 1, 2], "thin_run": [0], "short_run": [2, 2, 2],
+            "two_runs": [2, 0], "nothing": []}[case]
+    val = np.zeros(len(rid), np.int32)
+    for f in set(runs):
+        val[fstart[f]:fstart[f + 1]] = rng.integers(
+            0, 257, fstart[f + 1] - fstart[f])
+    if case == "all_live":
+        span = cspan
+    else:
+        span = run_spans(cspan, jnp.asarray(fstart),
+                         jnp.asarray(runs, jnp.int32))
+    return rid, np.asarray(span), val, fstart, runs
+
+
+@pytest.mark.parametrize("case", ["all_live", "thin_run", "short_run",
+                                  "two_runs", "nothing"])
+def test_entry_push_equals_the_scatter_add_exactly(case):
+    from dmlc_core_tpu.ops.pallas_segment import (_EMPTY_SPAN, _NNZ_TILE,
+                                                  push_to_rows)
+    rid, span, val, fstart, runs = _push_case(case)
+    tiles = np.arange(len(span))
+    live = np.zeros(len(span), bool)
+    for f in runs:
+        live |= ((tiles >= fstart[f] // _NNZ_TILE)
+                 & (tiles <= (fstart[f + 1] - 1) // _NNZ_TILE))
+    # a sub-tile is visited where it holds a lane of a run that carries and
+    # nowhere else, the padding sub-tiles never; under the layout's own
+    # spans those too (row 0, and nothing to add)
+    assert np.array_equal(span != _EMPTY_SPAN,
+                          live | (case == "all_live"))
+    assert live.sum() == {"all_live": 179, "thin_run": 3, "short_run": 1,
+                          "two_runs": 4, "nothing": 0}[case]
+    if case == "two_runs":      # the boundary sub-tile spans every chunk
+        assert (span[178] >> 16) - (span[178] & 0xFFFF) + 1 == 19
+    want = np.zeros(LOOKUP_ROWS, np.float32)
+    np.add.at(want, rid, val)
+    got = np.asarray(push_to_rows(jnp.asarray(rid), jnp.asarray(span),
+                                  jnp.asarray(val), LOOKUP_ROWS))
+    assert got.dtype == np.float32 and got.shape == (LOOKUP_ROWS,)
+    assert np.array_equal(got, want)
+    # rows with no entry, and every row where nothing carries, stay 0
+    assert (want == 0).sum() > LOOKUP_ROWS // 3
+    assert (got != 0).any() == bool(runs)
+
+
+def test_entry_push_leaves_out_what_an_empty_span_names():
+    """The spans are what the kernel visits, not a hint: lanes of a sub-tile
+    whose span is empty add nothing, whatever they carry."""
+    from dmlc_core_tpu.ops.pallas_segment import _EMPTY_SPAN, push_to_rows
+    rid, span, val, *_ = _push_case("all_live")
+    span = span.copy()
+    span[5:90] = _EMPTY_SPAN
+    want = np.zeros(LOOKUP_ROWS, np.float32)
+    keep = np.ones(len(rid), bool)
+    keep[5 * 1024:90 * 1024] = False
+    np.add.at(want, rid[keep], val[keep])
+    got = np.asarray(push_to_rows(jnp.asarray(rid), jnp.asarray(span),
+                                  jnp.asarray(val), LOOKUP_ROWS))
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("missing", ["nothing", "rows_ascend", "a_tpu",
+                                     "the_tables_room"])
+def test_route_push_engages_by_what_the_code_can_see(missing, monkeypatch):
+    """Rows ascending in every run, a compiled kernel (a TPU), a float32
+    table that fits its share of VMEM: each one missing gives the bisection,
+    and no argument, environment variable or knob says otherwise."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    cap = ps.ROUTE_PUSH_ROWS
+    assert cap * 4 == 32 << 20 and cap == ps.ENTRY_LOOKUP_PLANE_ROWS
+    assert 1_183_747 <= cap
+    if missing != "a_tpu":
+        monkeypatch.setattr(ps, "pallas_interpret", lambda: False)
+    engages = ps.route_push_engages(missing != "rows_ascend",
+                                    cap + (missing == "the_tables_room"))
+    assert engages == (missing == "nothing")
