@@ -1533,3 +1533,194 @@ class DeviceStagingIter:
         yield from _staged_iter(produce_device, 2,
                                 depth_gauge="h2d.queue_depth",
                                 device_feed=True)
+
+
+# ---- dense binned pages: host memory to HBM, a few ahead of the kernel ------
+
+
+# Row slices a page is put in, each its own ``device_put``: the copy of one
+# slice into the runtime's staging buffer runs beside the transfer of the
+# slice before it.  On a v5e host a 281 MB page took 55-65 ms in one put
+# (4.3-5.1 GB/s) and 33.5 ms in eight (8.4 GB/s), against a 60 ms visit
+# (PERF.md, PR 48).
+_PAGE_PUT_SLICES = 8
+
+
+class PagePrefetcher:
+    """Dense binned pages from host memory to HBM, ``depth`` ahead of the
+    program that visits them, on a thread of its own: the feed of
+    ``GBDT.fit_paged`` (XGBoost's external-memory ``hist`` with its pages
+    ``on_host``), and the first feed in this package whose transfer's
+    *bytes* set a pace and not its dispatches.
+
+    ``source``: a replayable source of pages, each a host ``uint8``
+    ``[rows <= page_rows, num_features]`` array and all but the last
+    ``page_rows`` long — a sequence, or a zero-argument callable that
+    returns a fresh iterator; it is replayed ``passes`` times in all, every
+    replay the same pages in the same order.  A short last page is padded
+    with zero rows on the host, so that every visit is one program.
+
+    Residency: at most ``depth + 1`` pages lie on the device at once — the
+    one the kernel holds and ``depth`` staged or on their way — whatever the
+    consumer does.  A page arrives as a tuple of ``_PAGE_PUT_SLICES`` row
+    slices (fewer for a page of fewer rows), the same cuts for every page,
+    which the visiting program concatenates.  The consumer takes a pass's
+    pages from :meth:`pages` as ``(index, rows, page)`` and hands each back
+    with :meth:`release` and a ``token``, an array that the program which
+    visited the page returns:
+    the stager puts no further page before it has waited for the oldest
+    released page's token and deleted that page, so a page's buffer goes
+    back to the allocator when its visit has RUN, not when it was queued,
+    and nothing is held past its visit.  The most pages resident at once is
+    the gauge ``page.resident_max`` (reset here).
+
+    What it tells: the span ``page.h2d`` and the counters ``page.h2d_pages``,
+    ``page.h2d_bytes``, ``page.h2d_busy_us`` around a page's ``device_put``
+    to its end (``block_until_ready``: the transfer, not its dispatch); the
+    span ``page.wait`` and the counter ``page.wait_us`` where the consumer
+    found no page staged and blocked."""
+
+    _END = object()
+
+    def __init__(self, source, passes: int, page_rows: int,
+                 num_features: int, depth: int = 2, device=None):
+        if depth < 1:
+            raise ValueError("PagePrefetcher: depth must be at least 1")
+        self._replay = source if callable(source) else (lambda: iter(source))
+        self._passes, self._page_rows = int(passes), int(page_rows)
+        self._features, self._device = int(num_features), device
+        self._slots = depth + 1
+        k = min(_PAGE_PUT_SLICES, self._page_rows)
+        self._cuts = [self._page_rows * i // k for i in range(k + 1)]
+        self._ready: queue.Queue = queue.Queue()
+        self._released: list = []       # (page, token), oldest first
+        self._cv = threading.Condition()
+        self._resident = 0      # pages put and not deleted; never falls
+        self._stop = False
+        telemetry.gauge_set("page.resident_max", 0)
+        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread.start()
+
+    def __enter__(self) -> "PagePrefetcher":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    # -- the stager thread ----------------------------------------------------
+
+    def _run(self) -> None:
+        try:
+            for _ in range(self._passes):
+                short = False
+                for index, host in enumerate(self._replay()):
+                    if short:
+                        raise ValueError(
+                            "PagePrefetcher: only a source's last page may "
+                            f"hold fewer than {self._page_rows} rows")
+                    short = self._check(host)
+                    if not self._take_slot():
+                        return
+                    self._ready.put((index, host.shape[0], self._put(host)))
+                self._ready.put(self._END)
+        except BaseException as e:      # relayed to the consumer
+            self._ready.put(e)
+
+    def _check(self, host) -> bool:
+        """Whether ``host`` is a short page; raises on any other shape."""
+        if (getattr(host, "dtype", None) != np.uint8 or host.ndim != 2
+                or host.shape[1] != self._features
+                or not 0 < host.shape[0] <= self._page_rows):
+            raise ValueError(
+                "PagePrefetcher: a page is a host uint8 array of at most "
+                f"[{self._page_rows}, {self._features}], not "
+                f"{getattr(host, 'dtype', type(host).__name__)} "
+                f"{list(getattr(host, 'shape', ()))}")
+        return host.shape[0] < self._page_rows
+
+    def _take_slot(self) -> bool:
+        """Room for one more page on the device: a free slot, else the
+        oldest released page's, once its visit has run.  False when the
+        consumer has closed the feed."""
+        with self._cv:
+            while not self._stop:
+                if self._resident < self._slots or self._released:
+                    break
+                self._cv.wait(0.1)
+            if self._stop:
+                return False
+            if self._resident < self._slots:
+                # (a released page whose visit has not run still counts)
+                self._resident += 1
+                telemetry.gauge_set("page.resident_max", self._resident)
+                return True
+            page, token = self._released.pop(0)
+        self._delete(page, token)       # its slot is the next page's
+        return True
+
+    @staticmethod
+    def _delete(page: tuple, token=None) -> None:
+        jax.block_until_ready(token)
+        for part in page:
+            part.delete()
+
+    def _put(self, host: np.ndarray) -> tuple:
+        if host.shape[0] < self._page_rows:
+            host = np.concatenate([host, np.zeros(
+                (self._page_rows - host.shape[0], self._features), np.uint8)])
+        t0 = time.monotonic()
+        with telemetry.span("page.h2d"):
+            page = jax.block_until_ready(tuple(
+                jax.device_put(host[lo:hi], self._device)
+                for lo, hi in zip(self._cuts, self._cuts[1:])))
+        telemetry.counter_add("page.h2d_busy_us",
+                              int((time.monotonic() - t0) * 1e6))
+        telemetry.counter_add("page.h2d_pages", 1)
+        telemetry.counter_add("page.h2d_bytes", host.nbytes)
+        return page
+
+    # -- the consumer ---------------------------------------------------------
+
+    def pages(self) -> Iterator[tuple]:
+        """One pass: ``(index, rows, page)`` of every page in order, ``page``
+        its row slices on the device, ``page_rows`` long together, ``rows``
+        of them the source's."""
+        while True:
+            try:
+                item = self._ready.get_nowait()
+            except queue.Empty:
+                t0 = time.monotonic()
+                with telemetry.span("page.wait"):
+                    item = self._ready.get()
+                telemetry.counter_add("page.wait_us",
+                                      int((time.monotonic() - t0) * 1e6))
+            if item is self._END:
+                return
+            if isinstance(item, BaseException):
+                raise item
+            yield item
+
+    def release(self, page: tuple, token) -> None:
+        """The consumer has queued ``page``'s visit; ``token`` is an array
+        that visit returns (and no later program is given as a donation)."""
+        with self._cv:
+            self._released.append((page, token))
+            self._cv.notify()
+
+    def close(self) -> None:
+        """Stop the stager; wait for every queued visit and delete its page,
+        and every page staged and not taken."""
+        with self._cv:
+            self._stop = True
+            self._cv.notify()
+        self._thread.join(timeout=30.0)
+        while True:
+            try:
+                item = self._ready.get_nowait()
+            except queue.Empty:
+                break
+            if isinstance(item, tuple):
+                self._delete(item[2])
+        for page, token in self._released:
+            self._delete(page, token)
+        self._released = []

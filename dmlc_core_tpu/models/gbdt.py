@@ -428,6 +428,11 @@ def _squared_grad_hess(margin: jax.Array, label: jax.Array
     return margin - label, jnp.ones_like(margin)
 
 
+# (grad, hess) as ONE program a round, for `GBDT.fit_paged`
+_GRAD_HESS_PROGRAM = {f: jax.jit(f)
+                      for f in (_logistic_grad_hess, _squared_grad_hess)}
+
+
 @functools.partial(jax.jit, static_argnames=("max_shift",))
 def _pairwise_terms(margin: jax.Array, label: jax.Array, qid: jax.Array,
                     weight: jax.Array, max_shift: int):
@@ -1610,7 +1615,6 @@ class GBDT:
             bins_i = bins.astype(jnp.int32)
 
         node = jnp.zeros(rows, jnp.int32)  # heap id of each row's node
-        mono = self.monotone_constraints is not None
         lo = jnp.full(1, -jnp.inf)
         hi = jnp.full(1, jnp.inf)
         active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
@@ -1636,51 +1640,10 @@ class GBDT:
                     bins_i, slot, gh, _built_columns(depth),
                     self._hist_impl(n_nodes)), right_built)
             with jax.named_scope("gbdt.split"):
-                hist_g = hist[..., 0]
-                hist_h = hist[..., 1]
-                # left cumulative mass for "go right if bin > b" at each cut
-                gl = jnp.cumsum(hist_g, axis=2)
-                hl = jnp.cumsum(hist_h, axis=2)
-                g_tot = gl[:, :, -1:]
-                h_tot = hl[:, :, -1:]
-                lam = self.lambda_
-
-                def split_gain(gl_, hl_):
-                    gr_ = g_tot - gl_
-                    hr_ = h_tot - hl_
-                    g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
-                         - g_tot ** 2 / (h_tot + lam))      # [nodes, F, B]
-                    ok = ((hl_ >= self.min_child_weight) &
-                          (hr_ >= self.min_child_weight))
-                    return jnp.where(ok, g, -jnp.inf)
-
-                if self.missing_aware:
-                    # evaluate every cut twice from the same histograms:
-                    # missing (bin 0) mass on the left (its natural cumsum
-                    # side) vs on the right.  dir axis: 0 = left, 1 = right
-                    # (argmax ties resolve to left, the XGBoost default).
-                    dirs = [(gl, hl),
-                            (gl - hist_g[:, :, 0:1], hl - hist_h[:, :, 0:1])]
-                else:
-                    dirs = [(gl, hl)]
-                gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
-                if mono:
-                    wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
-                    gain = self._apply_monotone(gain, wl, wr, lo, hi)
-                gain = self._collapse_dir_ties(gain)
-                node_mask = self._level_feature_mask(col_mask, col_key,
-                                                     depth, active)
-                split_f, split_b, split_d, split_g = self._pick_splits(
-                    gain, node_mask)
-                if mono:
-                    lo, hi = self._child_bounds(split_f, split_b, split_d,
-                                                wl, wr, lo, hi)
-                if active is not None:
-                    active = self._next_active(active, split_f, split_b)
-                covers.append(h_tot[:, 0, 0])   # node hessian mass (any f)
-                right_built = _smaller_child([hl_ for _, hl_ in dirs],
-                                             covers[-1], split_f, split_b,
-                                             split_d)
+                (split_f, split_b, split_d, split_g, lo, hi, active,
+                 right_built, cover, dirs) = self._dense_level_splits(
+                    hist, depth, col_mask, col_key, lo, hi, active)
+                covers.append(cover)
             features.append(split_f)
             thresholds.append(split_b)
             defaults.append(split_d)
@@ -1702,14 +1665,84 @@ class GBDT:
         n_leaves = 2 ** self.max_depth
         with jax.named_scope("gbdt.leaf"):
             leaf_rel = node - (n_leaves - 1)  # each row's leaf, for `_boost`
-            gh_leaf = _split_child_sums(dirs, split_f, split_b, split_d)
-            leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
-            if mono:
-                leaf_w = jnp.clip(leaf_w, lo, hi)
-            leaf = self.learning_rate * leaf_w
+            leaf = self._leaves_from_split_sums(dirs, split_f, split_b,
+                                                split_d, lo, hi)
         return (jnp.concatenate(features), jnp.concatenate(thresholds),
                 jnp.concatenate(defaults), jnp.concatenate(gains),
                 jnp.concatenate(covers), leaf, leaf_rel)
+
+    def _dense_level_splits(self, hist, depth: int, col_mask, col_key,
+                            lo, hi, active):
+        """Split finding for one level of a DENSE fit from its whole
+        ``[n_nodes, F, B, 2]`` (grad, hess) histogram, in which the missing
+        values' mass lies in bin 0 (a missing-aware binner's code for them):
+        cumulative sums, both default directions' gains from the same
+        histograms, monotone bounds, feature masks, interaction groups, the
+        arg-max, and which child of each node the next level builds.  The
+        resident `_build_tree` and the paged `fit_paged` both call it, so
+        the two grow one forest from one histogram; only how the histogram
+        was summed differs.  Returns ``(split_f, split_b, split_d, split_g,
+        lo, hi, active, right_built, cover, dirs)``: ``cover`` the nodes'
+        hessian mass, ``dirs`` the cumulative (G, H) a default direction,
+        which `_split_child_sums` reads the leaves from."""
+        mono = self.monotone_constraints is not None
+        hist_g = hist[..., 0]
+        hist_h = hist[..., 1]
+        # left cumulative mass for "go right if bin > b" at each cut
+        gl = jnp.cumsum(hist_g, axis=2)
+        hl = jnp.cumsum(hist_h, axis=2)
+        g_tot = gl[:, :, -1:]
+        h_tot = hl[:, :, -1:]
+        lam = self.lambda_
+
+        def split_gain(gl_, hl_):
+            gr_ = g_tot - gl_
+            hr_ = h_tot - hl_
+            g = (gl_ ** 2 / (hl_ + lam) + gr_ ** 2 / (hr_ + lam)
+                 - g_tot ** 2 / (h_tot + lam))      # [nodes, F, B]
+            ok = ((hl_ >= self.min_child_weight) &
+                  (hr_ >= self.min_child_weight))
+            return jnp.where(ok, g, -jnp.inf)
+
+        if self.missing_aware:
+            # evaluate every cut twice from the same histograms:
+            # missing (bin 0) mass on the left (its natural cumsum
+            # side) vs on the right.  dir axis: 0 = left, 1 = right
+            # (argmax ties resolve to left, the XGBoost default).
+            dirs = [(gl, hl),
+                    (gl - hist_g[:, :, 0:1], hl - hist_h[:, :, 0:1])]
+        else:
+            dirs = [(gl, hl)]
+        gain = jnp.stack([split_gain(a, b) for a, b in dirs], axis=3)
+        if mono:
+            wl, wr = self._dir_child_weights(dirs, g_tot, h_tot)
+            gain = self._apply_monotone(gain, wl, wr, lo, hi)
+        gain = self._collapse_dir_ties(gain)
+        node_mask = self._level_feature_mask(col_mask, col_key, depth,
+                                             active)
+        split_f, split_b, split_d, split_g = self._pick_splits(
+            gain, node_mask)
+        if mono:
+            lo, hi = self._child_bounds(split_f, split_b, split_d,
+                                        wl, wr, lo, hi)
+        if active is not None:
+            active = self._next_active(active, split_f, split_b)
+        cover = h_tot[:, 0, 0]          # node hessian mass (any f)
+        right_built = _smaller_child([hl_ for _, hl_ in dirs], cover,
+                                     split_f, split_b, split_d)
+        return (split_f, split_b, split_d, split_g, lo, hi, active,
+                right_built, cover, dirs)
+
+    def _leaves_from_split_sums(self, dirs, split_f, split_b, split_d,
+                                lo, hi) -> jax.Array:
+        """The shrunken leaf weights of a dense tree off its last level's
+        cumulative sums (`_split_child_sums`: no pass over the rows),
+        clamped into the nodes' bounds under monotone constraints."""
+        gh_leaf = _split_child_sums(dirs, split_f, split_b, split_d)
+        leaf_w = -gh_leaf[:, 0] / (gh_leaf[:, 1] + self.lambda_)
+        if self.monotone_constraints is not None:
+            leaf_w = jnp.clip(leaf_w, lo, hi)
+        return self.learning_rate * leaf_w
 
     def _route_level(self, bins_t: jax.Array, rel: jax.Array,
                      split_f: jax.Array, split_b: jax.Array,
@@ -2521,6 +2554,192 @@ class GBDT:
             eval_margin=eval_margin, eval_label=eval_label,
             eval_weight=eval_weight,
             early_stopping_rounds=early_stopping_rounds)
+
+    # ---- the paged dense fit: XGBoost's external-memory `hist` --------------
+
+    @functools.partial(jax.jit, static_argnums=(0, 1),
+                       donate_argnames=("hist", "node"))
+    def _page_visit(self, depth: int, hist, node: jax.Array, page: jax.Array,
+                    grad: jax.Array, hess: jax.Array, offset, prev):
+        """One page's visit of the pass at ``depth``, ONE program whatever the
+        page: the page's slice of ``node`` (``page.shape[0]`` rows from the
+        traced ``offset``) routed through the level above's splits ``prev``
+        (`_route_level`, as the resident tree routes), the routed slice
+        written back in place, and the built columns' histogram of the page
+        (`_built_columns`, `_child_slot`: the dense backend, the Pallas kernel
+        on a chip) added into the level's ``hist``.  ``depth == 0`` routes
+        nothing (every row stands in the root); ``depth == max_depth`` builds
+        nothing (``hist`` is None) and leaves each row's LEAF in ``node``.
+
+        hist: f32 [built columns, F, B, 2], donated; node: i32 [rows], heap
+        ids, donated; page: the row slices of a u8 [page_rows, F] page, as
+        `PagePrefetcher` puts them; grad / hess: f32 [rows];
+        prev: (split_f, split_b, split_d, right_built) of level
+        ``depth - 1``.  Returns (hist, node, tick): ``tick`` a fresh scalar,
+        the token by which the prefetcher learns that the visit has run.
+        The scopes nest in the resident tree's (`gbdt.route`, `gbdt.hist`),
+        so that what reads those finds the paged fit too."""
+        with jax.named_scope("gbdt.hist"), jax.named_scope("gbdt.page.hist"):
+            bins_i = jnp.concatenate(page).astype(jnp.int32)
+        rows = bins_i.shape[0]
+        node_p = jax.lax.dynamic_slice(node, (offset,), (rows,))
+        slot = node_p               # the root's rows: node 0, column 0
+        if depth:
+            with jax.named_scope("gbdt.route"), \
+                    jax.named_scope("gbdt.page.route"):
+                rel = node_p - (2 ** (depth - 1) - 1)
+                go_right, built_bit = self._route_level(bins_i.T, rel, *prev)
+                node_p = 2 * node_p + 1 + go_right.astype(jnp.int32)
+                slot = _child_slot(rel, go_right, built_bit)
+                if depth == self.max_depth:
+                    node_p = node_p - (2 ** depth - 1)
+                node = jax.lax.dynamic_update_slice(node, node_p, (offset,))
+        if depth < self.max_depth:
+            with jax.named_scope("gbdt.hist"):
+                with jax.named_scope("gbdt.page.hist"):
+                    gh = jnp.stack(
+                        [jax.lax.dynamic_slice(a, (offset,), (rows,))
+                         for a in (grad, hess)], axis=-1)
+                    built = histogram_gh(bins_i, slot, gh,
+                                         _built_columns(depth), self.num_bins,
+                                         force=self._hist_impl(2 ** depth))
+                with jax.named_scope("gbdt.page.accumulate"):
+                    hist = hist + built
+        return hist, node, jnp.asarray(offset, jnp.int32) + 1
+
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def _paged_level_splits(self, depth: int, parent, built, right_built,
+                            col_mask, col_key, lo, hi, active):
+        """A level's splits from the histograms its pass summed: the built
+        columns' siblings derived from the level above (`_with_siblings`),
+        then the resident tree's own split finding (`_dense_level_splits`);
+        at the last level also the leaves, read off that level's cumulative
+        sums (`_split_child_sums`), as `_build_tree` reads them.  Returns
+        (hist, splits, leaf): ``hist`` the level's whole histograms, the
+        next level's ``parent``; ``splits`` as `_dense_level_splits` gives
+        them less ``dirs``; ``leaf`` None above the last level."""
+        with jax.named_scope("gbdt.hist"):
+            hist = _with_siblings(parent, built, right_built)
+        with jax.named_scope("gbdt.split"):
+            *splits, dirs = self._dense_level_splits(
+                hist, depth, col_mask, col_key, lo, hi, active)
+        leaf = None
+        if depth == self.max_depth - 1:
+            with jax.named_scope("gbdt.leaf"):
+                leaf = self._leaves_from_split_sums(dirs, *splits[:3],
+                                                    *splits[4:6])
+        return hist, tuple(splits), leaf
+
+    @telemetry.span("gbdt.fit")
+    def fit_paged(self, pages, label: jax.Array,
+                  weight: Optional[jax.Array] = None, *, page_rows: int,
+                  prefetch_pages: int = 2) -> dict:
+        """Train on dense binned rows that no one chip holds — XGBoost's
+        external-memory ``hist`` (``ExtMemQuantileDMatrix``, ``on_host``):
+        the quantised pages lie in host memory, are cached once and visited
+        every level.
+
+        ``pages``: a replayable source of host ``uint8`` arrays
+        ``[<= page_rows, num_features]`` of ``QuantileBinner`` codes, all but
+        the last ``page_rows`` long: a sequence, or a zero-argument callable
+        returning a fresh iterator; every replay gives the same pages in the
+        same order (``data.PagePrefetcher``).  ``label`` (and ``weight``):
+        ``[rows]``, the pages' rows in order.
+
+        Residency contract: the ROW STATE — label, weight, margin, grad,
+        hess, node: six words a row — lies on the device from the fit's
+        start to its end and never visits the host; the PAGES never all do:
+        at most ``prefetch_pages + 1`` are on the device at once, the one the
+        kernel holds and ``prefetch_pages`` staged ahead of it on the
+        prefetcher's thread.  A tree is ``max_depth + 1`` passes over the
+        pages, each page's visit one jitted program (`_page_visit`, the same
+        for every page of a pass): the pass at depth d routes the page's
+        rows through level d - 1's splits and adds the page to level d's
+        histogram in the same visit, the last pass routes to the leaves.
+        No host concatenation, no fetch inside a tree: splits stay device
+        arrays from `_paged_level_splits` into the next pass's visits.
+
+        Guarantees: every row of every page enters every level's histogram
+        exactly once (no sampling of pages, no skipping by gradient, no
+        approximate histogram); a seed gives one forest; split finding,
+        sibling subtraction, the leaves and the boosting driver are the
+        resident `fit`'s own code, so the forest's splits are `fit`'s on the
+        same rows — float32 sums reorder by page, nothing else (a near-tie
+        between two candidates can in principle resolve differently).
+        Rows that do not fill the last page are padded with weight 0.
+
+        Not here: an ``eval_set`` and early stopping, softmax and
+        ``rank:pairwise``, a mesh plan, the leaf-wise builder."""
+        from ..data.staging import PagePrefetcher
+        if self.objective not in ("logistic", "squared"):
+            raise ValueError("fit_paged trains objective='logistic' or "
+                             f"'squared', not {self.objective!r}")
+        if self.mesh_plan is not None:
+            raise ValueError("fit_paged runs on one device: no histogram_mesh")
+        if page_rows < 1 or prefetch_pages < 1:
+            raise ValueError("fit_paged: page_rows and prefetch_pages are "
+                             "at least 1")
+        label = jnp.asarray(label, jnp.float32)
+        rows = int(label.shape[0])
+        n_pages = -(-rows // page_rows)
+        pad = n_pages * page_rows - rows
+        w = (jnp.ones_like(label) if weight is None
+             else jnp.asarray(weight, jnp.float32))
+        if pad:                     # whole pages: the tail weighs nothing
+            label, w = jnp.pad(label, (0, pad)), jnp.pad(w, (0, pad))
+        F, B = self.num_features, self.num_bins
+
+        def build_tree(grad, hess, col_mask, ck):
+            node = jnp.zeros(label.shape, jnp.int32)
+            lo, hi = jnp.full(1, -jnp.inf), jnp.full(1, jnp.inf)
+            active = (jnp.ones((1, self._interaction_groups.shape[0]), bool)
+                      if self._interaction_groups is not None else None)
+            features, thresholds, defaults, gains, covers = [], [], [], [], []
+            parent, right_built, prev, leaf = None, None, None, None
+            for depth in range(self.max_depth + 1):
+                hist = (None if depth == self.max_depth else jnp.zeros(
+                    (_built_columns(depth), F, B, 2), jnp.float32))
+                visited = streamed = 0
+                with telemetry.span("gbdt.page_pass"):
+                    for index, held, page in feed.pages():
+                        hist, node, tick = self._page_visit(
+                            depth, hist, node, page, grad, hess,
+                            np.int32(index * page_rows), prev)
+                        feed.release(page, tick)
+                        visited, streamed = visited + 1, streamed + held
+                counter_add("gbdt.page_passes", 1)
+                counter_add("gbdt.rows_streamed", streamed)
+                if (visited, streamed) != (n_pages, rows):
+                    raise ValueError(
+                        f"fit_paged: a pass gave {visited} pages of "
+                        f"{streamed} rows for {rows} labels in pages of "
+                        f"{page_rows}")
+                if depth == self.max_depth:
+                    break
+                parent, splits, leaf = self._paged_level_splits(
+                    depth, parent, hist, right_built, col_mask, ck, lo, hi,
+                    active)
+                (split_f, split_b, split_d, split_g, lo, hi, active,
+                 right_built, cover) = splits
+                features.append(split_f)
+                thresholds.append(split_b)
+                defaults.append(split_d)
+                gains.append(split_g)
+                covers.append(cover)
+                prev = (split_f, split_b, split_d, right_built)
+            return (jnp.concatenate(features), jnp.concatenate(thresholds),
+                    jnp.concatenate(defaults), jnp.concatenate(gains),
+                    jnp.concatenate(covers), leaf, node)
+
+        # one program a round for (grad, hess): op by op, as the resident
+        # fit takes them, the host queues every op before the first has run,
+        # and each op's result is a row array that is freed only when the
+        # device has passed it: 15.1 GB in use at 2^28 rows against 10.6 so
+        # (PERF.md, PR 48); the arithmetic is the same
+        with PagePrefetcher(pages, self.num_trees * (self.max_depth + 1),
+                            page_rows, F, depth=prefetch_pages) as feed:
+            return self._boost(label, w, build_tree,
+                               grad_hess=_GRAD_HESS_PROGRAM[self._grad_hess])
 
     def margins_batch(self, params: dict, batch,
                       binner: QuantileBinner) -> jax.Array:

@@ -661,3 +661,55 @@ def test_sharded_rows_step_exchanges_keys_and_gathers_no_table(
     pattern = json.loads((LAYER_METRICS / "ps4_scatter_roofline.json")
                          .read_text())["args"]["pattern"]
     assert [n for n in instructions(compiled) if re.search(pattern, n)]
+
+
+# the paged cell's page visit (benchmark/configs/criteo-xgb-extmem.json: 2^28
+# rows resident as row state, a page of 2^22 x 67 codes in eight row slices,
+# 256 bins, depth 8) at the root's pass, the deepest histogram pass and the
+# pass to the leaves
+@pytest.mark.parametrize("depth", [0, 7, 8])
+def test_page_visit_compiles_at_the_cells_size_and_updates_in_place(
+        one_chip, quiet_cache, monkeypatch, depth):
+    """`GBDT._page_visit` through the TPU's compiler at the cell's shapes:
+    the dense kernel under its name and under the page's scope (67 features
+    are no whole number of the kernel's 8-feature blocks), the routing and
+    the sum under theirs, the level's histogram and the rows' ``node``
+    donated and never copied (a copy of ``node`` would be 1 GB a visit),
+    and temporaries that leave the chip its row state."""
+    monkeypatch.setattr(pallas_segment, "pallas_interpret", lambda: False)
+    rows, page_rows, features, slices = 1 << 28, 1 << 22, 67, 8
+    model = GBDT(num_features=features, num_trees=1, max_depth=8,
+                 num_bins=BINS, learning_rate=0.1, min_child_weight=1.0,
+                 missing_aware=True, histogram="pallas")
+    cols, nodes = 2 ** max(depth - 1, 0), 2 ** max(depth - 1, 0)
+    hist = (None if depth == 8
+            else on(one_chip, (cols, features, BINS, 2), jnp.float32))
+    prev = (None if depth == 0 else
+            (on(one_chip, (nodes,), jnp.int32),) * 3
+            + (on(one_chip, (nodes,), jnp.bool_),))
+    page = (on(one_chip, (page_rows // slices, features), jnp.uint8),) * slices
+    compiled = model._page_visit.lower(
+        model, depth, hist, on(one_chip, (rows,), jnp.int32), page,
+        on(one_chip, (rows,), jnp.float32), on(one_chip, (rows,), jnp.float32),
+        on(one_chip, (), jnp.int32), prev).compile()
+    names, text = op_names(compiled), compiled.as_text()
+    kernels = [n for n in instructions(compiled)
+               if n.startswith("%" + pallas_segment.DENSE_HIST_KERNEL)]
+    assert len(kernels) == (depth < 8)
+    if depth < 8:
+        kernel_ops = [n for n in names if n.endswith("/pallas_call")]
+        assert kernel_ops and all(
+            "jit(_page_visit)/gbdt.hist/gbdt.page.hist/" in n
+            for n in kernel_ops)
+        assert any("/gbdt.hist/gbdt.page.accumulate/" in n for n in names)
+        assert any("/ops.hist_layout/" in n for n in names)
+    if depth:
+        assert any("jit(_page_visit)/gbdt.route/gbdt.page.route/" in n
+                   for n in names)
+    # donated and aliased: the histogram (where there is one) and node
+    assert "input_output_alias" in text
+    assert not re.search(r"s32\[268435456\]\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    # the page as int32, feature-major (1.12 GB), and the kernel's operands
+    assert memory.temp_size_in_bytes < 2 << 30       # 1.66 GB; 0.32 at 8
+    assert memory.generated_code_size_in_bytes < 4 << 20     # 1.1 - 2.7 MB

@@ -71,6 +71,13 @@ def programs() -> dict:
         best, jnp.zeros((64, 1), jnp.int32), jnp.zeros(64), jnp.zeros(64),
         jnp.ones(4, bool))
     margin = margin_program(leaves=4, rows=64)
+    # a page's visit of the paged fit (`GBDT.fit_paged`): the pass at depth
+    # 1, which routes through the root's split and builds one column
+    paged = model._page_visit.lower(
+        model, 1, jnp.zeros((1, 4, 16, 2)), jnp.zeros(64, jnp.int32),
+        (jnp.zeros((16, 4), jnp.uint8), jnp.zeros((16, 4), jnp.uint8)),
+        jnp.zeros(64), jnp.zeros(64), np.int32(32),
+        (jnp.zeros(1, jnp.int32),) * 3 + (jnp.zeros(1, bool),))
 
     rows, fields, features = 8, 3, 32
     ffm = FieldAwareFactorizationMachine(num_features=features,
@@ -114,6 +121,7 @@ def programs() -> dict:
             "sharded": paths_of(sharded),
             "sparse_tree": paths_of(sparse_tree),
             "leafwise": paths_of(leafwise), "margin": paths_of(margin),
+            "paged": paths_of(paged),
             "step": paths_of(step), "touched": paths_of(touched),
             "tables": paths_of(tables), "field_rows": paths_of(field_rows),
             "reduce": paths_of(reduce)}
@@ -136,6 +144,10 @@ LEAFWISE = {"gbdt.leafwise.hist", "gbdt.leafwise.partition",
             "gbdt.leafwise.split", "gbdt.leafwise.pick"}
 # scopes of the boosting driver, outside both tree programs
 DRIVER = {"gbdt.boost", "gbdt.margin"}
+# scopes of the paged fit's page visit, `jit(_page_visit)`, each nested in
+# the resident tree's scope of the same work
+PAGED = {"gbdt.page.route": "gbdt.route", "gbdt.page.hist": "gbdt.hist",
+         "gbdt.page.accumulate": "gbdt.hist"}
 
 
 def margin_program(leaves: int, rows: int):
@@ -158,6 +170,11 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
              "fm": "tables"}[scope.split(".")[0]]
     if scope in LEAFWISE:
         assert carries(programs["leafwise"], scope, under="jit(_grow_tree)")
+        assert not carries(programs["tree"], scope)
+        return
+    if scope in PAGED:
+        assert carries(programs["paged"], scope,
+                       under=f"jit(_page_visit)/{PAGED[scope]}/")
         assert not carries(programs["tree"], scope)
         return
     if scope in SPARSE_ONLY:
