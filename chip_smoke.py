@@ -14,9 +14,10 @@ width: 28 features, 256 bins, depth 6; three trees, weights from a seed):
 and checks at every step that what came out is right by the repo's own
 means: staged content against an independent host parse, each forest
 against the same fit on XLA scatter, each kernel against its XLA reference
-(the entry lookup against XLA's gather and the entries' push against its
-scatter-add, bit for bit, at the benchmark's sparse cell's 1,183,747 rows
-and 2.18e8 entry lanes: 3 GB of the chip for a moment), /score against
+(the entry lookup against XLA's gather, the entries' push against its
+scatter-add and the layout's binning against the bisection, bit for bit,
+at the benchmark's sparse cell's 1,183,747 rows and 2.18e8 entry lanes:
+3 GB of the chip for a moment), /score against
 predict_batch, sharded against single-device.
 
 It claims no speed.  It exits non-zero at the first failure and prints
@@ -569,6 +570,54 @@ def check_entry_push(shape: dict, interpreted: bool) -> list:
     return out
 
 
+def check_layout_bin(shape: dict, interpreted: bool) -> list:
+    """The binning kernel on a feature-sorted layout's lanes against
+    `_bin_by_bisection`, bit for bit, on every 13th lane (the bisection is
+    eight gathers an entry) and on the padding."""
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_core_tpu.models.gbdt import _bin_by_bisection
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    features, bins = shape["features"], 256
+    rid_h, fstart, _station = station_layout(**shape)
+    lanes, live = len(rid_h), int(fstart[-1])
+    require(interpreted or ps.layout_bin_engages(
+        (features, bins - 2), features, lanes, 1),
+        f"the layout's binning does not engage at {lanes} lanes on a chip")
+    rng = np.random.default_rng(17)
+    cuts = np.sort(np.round(rng.standard_normal((features, bins - 2)), 2
+                            ).astype(np.float32), axis=1)
+    value = np.round(rng.standard_normal(lanes), 2).astype(np.float32)
+    value[rng.random(lanes) < 0.01] = np.inf
+    value[rng.random(lanes) < 0.01] = -np.inf
+    args = (jnp.asarray(value), jnp.asarray(np.append(fstart, lanes).astype(
+        np.int32)), jnp.asarray(cuts))
+    lowered = jax.jit(ps._bin_runs_pallas, static_argnums=(3, 4, 5)).lower(
+        *args, bins, 1, interpreted)
+    mosaic = "tpu_custom_call" in lowered.as_text()
+    require(mosaic != interpreted, "layout_bin: "
+            + ("interpreted on a chip" if not mosaic
+               else "compiled by Mosaic in a CPU rehearsal"))
+    run = lowered.compile()
+    got = jax.block_until_ready(run(*args))
+    t0 = time.monotonic()
+    jax.block_until_ready(run(*args))
+    ms = (time.monotonic() - t0) * 1e3
+    at = np.arange(0, live, 13)
+    fi = (np.searchsorted(fstart, at, side="right") - 1).astype(np.int32)
+    want = fi * bins + np.asarray(_bin_by_bisection(
+        args[2], jnp.asarray(fi), jnp.asarray(value[at])))
+    same = bool(np.array_equal(np.asarray(got[:live:13]), want)
+                and bool(jnp.all(got[live:] == -1)))
+    row = {"kernel": "layout_bin", "entries": lanes, "runs": features,
+           "checked": len(at), "interpret": not mosaic, "ms": round(ms, 1),
+           "exact": same}
+    log(f"  {row}")
+    require(same, "layout_bin: not the bisection's codes on the sorted lanes")
+    return [row]
+
+
 def phase_kernels(ctx: dict) -> dict:
     import jax
     import jax.numpy as jnp
@@ -639,6 +688,7 @@ def phase_kernels(ctx: dict) -> dict:
                                     interpreted))
     table += check_entry_lookup(size["lookup"], interpreted)
     table += check_entry_push(size["lookup"], interpreted)
+    table += check_layout_bin(size["lookup"], interpreted)
     ctx["kernels"] = table
     return {"calls": len(table), "node_caps": caps}
 

@@ -45,7 +45,8 @@ from .common import count_predict_retrace
 from ..ops import pallas_segment
 from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
                                   entry_values, histogram_gh,
-                                  histogram_gh_sparse_kernel, push_to_rows,
+                                  histogram_gh_sparse_kernel,
+                                  layout_bin_engages, push_to_rows,
                                   route_push_engages, run_spans, segment_sum,
                                   sparse_hist_layout)
 
@@ -83,13 +84,17 @@ def _first_lane(lo: jax.Array, hi: jax.Array, rounds: int, before
 
 # Entries from which `_bin_entries` bins by sorting them, not by a bisection
 # an entry.  At 2.18e8 entries the sorts win by eight times on a v5e (2.5 s
-# against 20.2 s, inside every `fit_batch`), but a sort costs the TPU's
-# compiler 9.8 s a shape at 2^14 lanes, 39.7 s at 2^17 and about 54 s at
-# 2^20, where the bisection compiles in 1.6 s (PERF.md, PR 27).  A
-# scoring server's first request of each bucket compiles what it runs inside
-# the server's 30 s wait, so the threshold lies above a request's bucket
-# (1,024 rows of up to 16,384 entries each): 2^24 entries, which the
-# bisection bins in about 1.5 s.
+# against 20.2 s), but a sort costs the TPU's compiler 9.8 s a shape at 2^14
+# lanes, 39.7 s at 2^17 and about 54 s at 2^20, where the bisection compiles
+# in 1.6 s (PERF.md, PR 27).  A scoring server's first request of each
+# bucket compiles what it runs inside the server's 30 s wait, so the
+# threshold lies above a request's bucket (1,024 rows of up to 16,384
+# entries each): 2^24 entries, which the bisection bins in about 1.5 s.
+# Who still bins in entry order: scoring and eval sets, `fit_streamed`, and
+# a `fit_batch` whose layout cannot bin for it (`GBDT._layout_bins`) or
+# whose tree keeps the entries beside the layout; where the layout is the
+# only copy of a value-carrying batch's entries, its own sort carries the
+# values and `ops.pallas_segment._bin_runs_pallas` bins them after it.
 _BIN_BY_SORT_ENTRIES = 1 << 24
 
 
@@ -1021,14 +1026,17 @@ class GBDT:
         return "pallas" in self.level_backends(sparse=True)
 
     def _sparse_fit_layout(self, row_id, findex, ebin, emask, rows: int,
-                           streamed: bool = False):
+                           streamed: bool = False, value=None, cuts=None):
         """The once-per-fit feature-sorted entry layout, or None when no
         level of this fit can resolve to the sparse Pallas kernel.  One
         device sort serves every tree's levels (``ops.sparse_hist_layout``;
         span ``gbdt.entry_sort``, counter ``gbdt.entry_sort_us``); the entry
         sub-tiles a kernel level runs and the grid steps it launches go to
         ``gbdt.sparse_hist_blocks`` and ``gbdt.sparse_hist_grid_steps``.
-        Sharded over ``histogram_mesh``'s axis, never in a streamed fit."""
+        Sharded over ``histogram_mesh``'s axis, never in a streamed fit.
+        With ``value`` and ``cuts`` in ``ebin``'s place the layout bins the
+        entries itself, after its sort (counter ``gbdt.layout_bin``, one a
+        fit)."""
         if not self._sparse_layout_enabled(streamed):
             return None
         num_shards = (1 if self.mesh_plan is None
@@ -1037,8 +1045,10 @@ class GBDT:
         with telemetry.span("gbdt.entry_sort"):
             layout = jax.block_until_ready(sparse_hist_layout(
                 row_id, findex, ebin, emask, self.num_features,
-                self.num_bins, num_shards=num_shards, rows=rows))
+                self.num_bins, num_shards=num_shards, rows=rows,
+                value=value, cuts=cuts))
         counter_add("gbdt.entry_sort_us", int((time.monotonic() - t0) * 1e6))
+        counter_add("gbdt.layout_bin", int(value is not None))
         counter_add("gbdt.sparse_hist_blocks",
                     int(np.asarray(layout.tcount).sum()))
         counter_add("gbdt.sparse_hist_grid_steps", layout.grid_steps)
@@ -2224,6 +2234,18 @@ class GBDT:
         return (rid.astype(jnp.int32), fi.astype(jnp.int32),
                 binner.transform_entries(fi, batch.value), emask)
 
+    def _layout_bins(self, batch, binner: QuantileBinner) -> bool:
+        """Whether `fit_batch` hands the layout the batch's values to bin
+        after its sort (`layout_bin_engages`), and does not bin them in
+        entry order first: the batch carries values, the binner its cuts,
+        and a layout will be built."""
+        if (hasattr(batch, "ebin") or binner.cuts is None
+                or not self._sparse_layout_enabled()):
+            return False
+        shards = 1 if self.mesh_plan is None else self.mesh_plan.num_shards
+        return layout_bin_engages(binner.cuts.shape, self.num_features,
+                                  batch.value.shape[0], shards)
+
     @telemetry.span("gbdt.fit")
     def fit_batch(self, batch, binner: QuantileBinner,
                   weight: Optional[jax.Array] = None,
@@ -2247,18 +2269,29 @@ class GBDT:
         label = batch.label.astype(jnp.float32)
         w = (batch.weight if weight is None else weight).astype(jnp.float32)
         # invariant across every tree: the entry arrays (one row_ids() a
-        # fit) and, for the pallas backend, the feature-sorted layout
-        entries = self._entry_bins(batch, binner)
-        layout = self._sparse_fit_layout(*entries, rows=int(label.shape[0]))
+        # fit) and, for the pallas backend, the feature-sorted layout,
+        # which bins a value-carrying batch's entries itself where it can
+        rows = int(label.shape[0])
+        if self._layout_bins(batch, binner):
+            rid, fi, emask = self._entry_arrays(batch)
+            layout = self._sparse_fit_layout(
+                rid, fi, None, emask, rows=rows, value=batch.value,
+                cuts=binner.cuts)
+            entries = None
+        else:
+            entries = self._entry_bins(batch, binner)
+            layout = self._sparse_fit_layout(*entries, rows=rows)
         kernel_levels = self.level_backends(sparse=True).count("pallas")
         if (layout is not None and self.mesh_plan is None
                 and kernel_levels == self.max_depth and layout.rows_ascend):
             entries = None      # the layout holds every live entry
+        elif entries is None:   # the tree wants them in entry order too
+            entries = (rid.astype(jnp.int32), fi.astype(jnp.int32),
+                       binner.transform_entries(fi, batch.value), emask)
 
-        lookups = self._entry_lookups(layout, int(label.shape[0]))
+        lookups = self._entry_lookups(layout, rows)
         # levels a tree's program routes by the entries' push
-        pushes = self.max_depth * _routes_by_push(entries, layout,
-                                                  int(label.shape[0]))
+        pushes = self.max_depth * _routes_by_push(entries, layout, rows)
 
         def build_tree(g, h, col_mask, col_key):
             if kernel_levels:

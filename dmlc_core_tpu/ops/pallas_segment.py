@@ -70,6 +70,7 @@ DENSE_HIST_KERNEL = "_histogram_gh_pallas"
 SPARSE_HIST_KERNEL = "_histogram_gh_sparse_pallas"
 ENTRY_LOOKUP_KERNEL = "_entry_lookup_pallas"
 ENTRY_PUSH_KERNEL = "_entry_push_pallas"
+BIN_RUNS_KERNEL = "_bin_runs_pallas"
 
 
 def check_force(force, what: str = "backend") -> None:
@@ -479,62 +480,86 @@ def _round_up_some(n: int, granule: int, parts: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("num_features", "num_bins",
-                                             "nb", "num_shards", "local"))
-def _layout_sort(row_id, findex, ebin, emask, num_features: int,
-                 num_bins: int, nb: int, num_shards: int, local: int):
+                                             "nb", "num_shards", "local",
+                                             "binned"))
+def _layout_sort(row_id, findex, carried, emask, num_features: int,
+                 num_bins: int, nb: int, num_shards: int, local: int,
+                 binned: bool = True):
     """Live entries sorted by (owning shard, feature), dead ones last.
 
     The sort is stable, so within a feature the entries keep their input
     order and the layout — and the kernel's accumulation order — is a pure
-    function of the entry stream (feature-sort determinism test).  Returns
-    the sorted ``gkey`` and shard-local ``rid``, ``starts`` (the sorted
-    position at which each (shard, feature) run begins, ``F + 1`` a shard:
-    the last is where the shard's live entries end), whether a live entry
-    is out of range, and whether row ids strictly ascend within every
-    run."""
+    function of the entry stream (feature-sort determinism test).
+    ``carried`` is what the sort takes along beside the row id: the entries'
+    bin codes (``binned``), which go as the key ``fi * nb + code``, or their
+    float32 values, for `_layout_pack` to bin where they then lie; the
+    lanes' order is the same either way.  Returns the sorted ``gkey`` (or
+    values) and shard-local ``rid``, ``starts`` (the sorted position at
+    which each (shard, feature) run begins, ``F + 1`` a shard: the last is
+    where the shard's live entries end), whether a live entry is out of
+    range, and whether row ids strictly ascend within every run."""
     rid = row_id.astype(jnp.int32)
     fi = findex.astype(jnp.int32)
-    eb = jnp.asarray(ebin, jnp.int32)
     live = emask.astype(bool)
-    bad = jnp.any(live & ((fi < 0) | (fi >= num_features)
-                          | (eb < 0) | (eb >= num_bins)))
+    off = (fi < 0) | (fi >= num_features)
+    if binned:
+        eb = jnp.asarray(carried, jnp.int32)
+        off |= (eb < 0) | (eb >= num_bins)
+        carried = fi * nb + eb
+    else:
+        carried = carried.astype(jnp.float32)
+    bad = jnp.any(live & off)
     f1 = num_features + 1
     owner = rid // local if num_shards > 1 else 0
     key = jnp.where(live, owner * f1 + fi, num_shards * f1)
-    key, gkey, rid = jax.lax.sort(
-        (key, fi * nb + eb, rid - owner * local), num_keys=1, is_stable=True)
+    key, carried, rid = jax.lax.sort(
+        (key, carried, rid - owner * local), num_keys=1, is_stable=True)
     starts = jnp.searchsorted(
         key, jnp.arange(num_shards * f1, dtype=jnp.int32), side="left")
     same_run = (key[1:] == key[:-1]) & (key[1:] < num_shards * f1)
     ascend = jnp.all(~same_run | (rid[1:] > rid[:-1]))
-    return gkey, rid, starts.astype(jnp.int32), bad, ascend
+    return carried, rid, starts.astype(jnp.int32), bad, ascend
 
 
-@functools.partial(jax.jit, static_argnames=("num_shards", "nnz_pad"))
-def _layout_pack(gkey, rid, offset, count, num_shards: int, nnz_pad: int):
+@functools.partial(jax.jit, static_argnames=("num_shards", "nnz_pad", "nb",
+                                             "interpret"))
+def _layout_pack(carried, rid, offset, count, num_shards: int, nnz_pad: int,
+                 rstart=None, cuts=None, nb: int = 0,
+                 interpret: bool = False):
     """The sorted entries as ``num_shards`` slices of ``nnz_pad`` lanes:
     shard ``s`` is ``[offset[s], offset[s] + count[s])`` of the sorted
     arrays, then ``gkey == -1`` / ``rid == 0`` filler; and the packed
-    row ids' `_chunk_spans`."""
+    row ids' `_chunk_spans`.  ``carried`` is the sorted ``gkey`` or, with
+    ``cuts``, the sorted values, whose keys are made here on the packed
+    lanes (`_bin_runs_pallas`; ``rstart``: the packed lane at which each
+    (shard, feature) run begins, and last the lanes in all)."""
     lane = jnp.arange(nnz_pad, dtype=jnp.int32)
     if num_shards == 1:     # a slice or a pad of the sorted arrays, no gather
         def fit(a):
             n = a.shape[0]
             return a[:nnz_pad] if n >= nnz_pad else jnp.pad(a, (0, nnz_pad - n))
         ok = lane < count[0]
-        gkey_p, rid_p = jnp.where(ok, fit(gkey), -1), jnp.where(ok, fit(rid), 0)
+        carried_p, rid_p = fit(carried), jnp.where(ok, fit(rid), 0)
     else:
-        src = jnp.minimum(offset[:, None] + lane[None, :], gkey.shape[0] - 1)
-        ok = lane[None, :] < count[:, None]
-        gkey_p = jnp.where(ok, gkey[src], -1).reshape(-1)
-        rid_p = jnp.where(ok, rid[src], 0).reshape(-1)
+        src = jnp.minimum(offset[:, None] + lane[None, :],
+                          carried.shape[0] - 1)
+        ok = (lane[None, :] < count[:, None]).reshape(-1)
+        carried_p = carried[src].reshape(-1)
+        rid_p = jnp.where(ok, rid[src].reshape(-1), 0)
+    if cuts is None:
+        gkey_p = jnp.where(ok, carried_p, -1)
+    else:
+        with jax.named_scope("gbdt.layout_bin"):
+            gkey_p = _bin_runs_pallas(carried_p, rstart, cuts, nb,
+                                      num_shards, interpret)
     return gkey_p, rid_p, _chunk_spans(rid_p)
 
 
 def sparse_hist_layout(row_id, findex, ebin, emask,
                        num_features: int, num_bins: int,
                        num_shards: int = 1,
-                       rows: int | None = None) -> SparseHistLayout:
+                       rows: int | None = None,
+                       value=None, cuts=None) -> SparseHistLayout:
     """Build the feature-sorted layout (see :class:`SparseHistLayout`).
 
     row_id/findex/ebin/emask: [nnz] COO entry arrays (any int/bool dtypes;
@@ -542,10 +567,18 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
     that owns them (``rows`` must then divide evenly — shard_map's
     even-sharding rule) and localizes row ids to the shard.
 
+    In place of the codes ``ebin`` the entries may come as they were staged:
+    ``value`` ([nnz] float) with ``cuts`` ([F, C] float32, non-decreasing
+    along C, ``C + 2 <= num_bins``).  A live entry's code is then ``1 + #{c:
+    cuts[f, c] <= value}``, what `QuantileBinner.transform_entries` gives,
+    worked out after the sort, where the entries of a feature lie together
+    (`_bin_runs_pallas`); the layout is the one that those codes would have
+    given, field for field.
+
     The entries are sorted on the device (one stable ``lax.sort`` by
-    feature that carries key and row id); the host reads back only the
-    ``num_shards * (F + 1)`` run starts, from which it sizes the packed
-    arrays and works out the per-key-tile block spans."""
+    feature that carries key, or value, and row id); the host reads back
+    only the ``num_shards * (F + 1)`` run starts, from which it sizes the
+    packed arrays and works out the per-key-tile block spans."""
     nb, num_kt = _sparse_geometry(num_features, num_bins)
     local = 0
     if num_shards > 1:
@@ -554,9 +587,16 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
                              f"num_shards (rows={rows}, "
                              f"num_shards={num_shards})")
         local = rows // num_shards
-    gkey, rid, starts, bad, ascend = _layout_sort(
-        jnp.asarray(row_id), jnp.asarray(findex), jnp.asarray(ebin),
-        jnp.asarray(emask), num_features, num_bins, nb, num_shards, local)
+    binned = value is None
+    if not binned:
+        cuts = jnp.asarray(cuts, jnp.float32)
+        if cuts.shape[0] != num_features or cuts.shape[1] + 2 > num_bins:
+            raise ValueError(f"cuts {cuts.shape} do not bin {num_features} "
+                             f"features into {num_bins} codes")
+    carried, rid, starts, bad, ascend = _layout_sort(
+        jnp.asarray(row_id), jnp.asarray(findex),
+        jnp.asarray(ebin if binned else value), jnp.asarray(emask),
+        num_features, num_bins, nb, num_shards, local, binned)
     starts = np.asarray(starts).astype(np.int64)
     if bool(bad):
         raise ValueError("findex or ebin out of range for live entries")
@@ -576,9 +616,18 @@ def sparse_hist_layout(row_id, findex, ebin, emask,
     some = stop > begin
     tstart = np.where(some, begin // _NNZ_TILE, 0)
     tcount = np.where(some, -(-stop // _NNZ_TILE) - tstart, 0)
+    binning = {}
+    if not binned:
+        # the runs on the packed lanes: shard s's begin at s * nnz_pad, and
+        # its last, of no feature, is the filler up to the next shard's
+        shard0 = np.arange(num_shards, dtype=np.int64)[:, None] * nnz_pad
+        binning = dict(
+            rstart=jnp.asarray(np.append((local_runs + shard0).reshape(-1),
+                                         num_shards * nnz_pad), jnp.int32),
+            cuts=cuts, nb=nb, interpret=pallas_interpret())
     gkey_p, rid_p, cspan = _layout_pack(
-        gkey, rid, jnp.asarray(offset, jnp.int32),
-        jnp.asarray(count, jnp.int32), num_shards, nnz_pad)
+        carried, rid, jnp.asarray(offset, jnp.int32),
+        jnp.asarray(count, jnp.int32), num_shards, nnz_pad, **binning)
     return SparseHistLayout(
         num_features=num_features, num_bins=num_bins,
         num_shards=num_shards, nb=nb, num_kt=num_kt,
@@ -1155,6 +1204,148 @@ def push_to_rows(rid: jax.Array, span: jax.Array, val: jax.Array, rows: int
     magnitude at most 256, 0 on every lane that has nothing to say (the
     padding lanes among them), and not 0 on one lane a row at most."""
     return _entry_push_pallas(rid, span, val, rows, pallas_interpret())
+
+
+# ---- bin codes, made on the feature-sorted lanes -----------------------------
+# ``1 + #{c: cuts[f, c] <= value}`` an entry.  In entry order every lane has
+# another feature, so XLA bins by a bisection of eight gathers an entry
+# (20.2 s at the Bosch cell's 2.18e8 entries) or by two sorts of its own
+# (2.9 s; `models/gbdt.py:_bin_by_sort`).  Straight after the layout's sort a
+# sub-tile of 1,024 lanes holds one feature (213,060 sub-tiles and 968 run
+# boundaries there), so that feature's cuts are one column of a table that
+# lies in VMEM, and the count is one pass of compares on the VPU.
+
+# The most the cuts may take of VMEM for a call, as a float32 table
+# ``[cuts a feature padded to 8, features + 1 padded to 128]``: 16 MiB of a
+# v5e's 128, the lookup's share (`_LOOKUP_TABLE_BYTES`).  968 features x 254
+# cuts are 1 MiB; 256 bins go up to 16,383 features.
+_BIN_TABLE_BYTES = 16 << 20
+
+
+def _bin_table_shape(num_features: int, num_cuts: int) -> tuple[int, int]:
+    """Rows and columns of the kernel's cuts table: a feature's cuts down
+    the sublanes, the features and the filler run's column along the lanes."""
+    return pl.cdiv(num_cuts, 8) * 8, pl.cdiv(num_features + 1, 128) * 128
+
+
+def _bin_runs_kernel(nb: int, f1: int, sharded: bool, span_ref, rstart_ref,
+                     val_ref, cuts_ref, out_ref):
+    """One grid step: ``_LOOKUP_STEP_TILES`` sub-tiles of ``_NNZ_TILE`` value
+    lanes, as `_entry_lookup_kernel` takes them.  ``span_ref[:, j]`` names the
+    first and the last run that sub-tile ``j`` holds a lane of, ``rstart_ref``
+    the lane at which each run begins.  For a run ``r`` of feature ``f``
+
+        count[e] = sum_c [cuts[f, c] <= value[e]]
+        gkey[e]  = f * nb + 1 + count[e]      on the lanes of [rstart[r],
+                                              rstart[r + 1])
+
+    one pass a run: the feature's column of the table, picked by a lane
+    one-hot and summed over the lanes (one term is not zero: the column as it
+    stands, infinities too), is compared eight cuts a time with the values,
+    which lie on the lanes and are broadcast down the sublanes; float32
+    compares, counted in int32.  The table's filler is NaN, at or below
+    nothing.  A lane of no run, and of a shard's last run, which is its
+    filler, keeps -1."""
+    c_rows = cuts_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _NNZ_TILE), 1)
+    column = jax.lax.broadcasted_iota(jnp.int32, (c_rows, 128), 1)
+    first_lane = pl.program_id(0) * (_LOOKUP_STEP_TILES * _NNZ_TILE)
+
+    def sub_tile(j, carry):
+        lanes = pl.ds(pl.multiple_of(j * _NNZ_TILE, _NNZ_TILE), _NNZ_TILE)
+        at = lane + (first_lane + j * _NNZ_TILE)
+        value = jnp.broadcast_to(val_ref[:, lanes], (8, _NNZ_TILE))
+
+        def run(r, gkey):
+            f = jax.lax.rem(r, f1) if sharded else r
+            start = pl.multiple_of(f // 128 * 128, 128)
+            cuts = jnp.sum(jnp.where(column == f - start,
+                                     cuts_ref[:, pl.ds(start, 128)], 0.0),
+                           axis=1, keepdims=True)               # [c_rows, 1]
+            count = jnp.zeros((8, _NNZ_TILE), jnp.int32)
+            for c in range(0, c_rows, 8):
+                count += jnp.where(cuts[c:c + 8] <= value, 1, 0)
+            code = 1 + jnp.sum(count, axis=0, keepdims=True)
+            mine = ((at >= rstart_ref[r]) & (at < rstart_ref[r + 1])
+                    & (f < f1 - 1))
+            return jnp.where(mine, f * nb + code, gkey)
+
+        out_ref[:, lanes] = jax.lax.fori_loop(
+            span_ref[0, j], span_ref[1, j] + 1, run,
+            jnp.full((1, _NNZ_TILE), -1, jnp.int32))
+        return carry
+
+    jax.lax.fori_loop(0, _LOOKUP_STEP_TILES, sub_tile, None)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("nb", "num_shards", "interpret"))
+def _bin_runs_pallas(value: jax.Array, rstart: jax.Array, cuts: jax.Array,
+                     nb: int, num_shards: int, interpret: bool) -> jax.Array:
+    """value: [lanes] float32, a multiple of ``_NNZ_TILE``, feature-sorted a
+    shard; rstart: [num_shards * (F + 1) + 1] int32, non-decreasing: the lane
+    at which each (shard, feature) run begins, a shard's last run being its
+    filler, and last ``lanes``; cuts: [F, C] float32, non-decreasing along C.
+    Returns [lanes] int32: ``f * nb + 1 + #{c: cuts[f, c] <= value}`` on a
+    feature's run, -1 on every other lane."""
+    lanes = value.shape[0]
+    num_features, num_cuts = cuts.shape
+    tiles = lanes // _NNZ_TILE
+    steps = pl.cdiv(tiles, _LOOKUP_STEP_TILES)
+    block = _LOOKUP_STEP_TILES * _NNZ_TILE
+    c_rows, f_cols = _bin_table_shape(num_features, num_cuts)
+    table = jnp.pad(cuts.astype(jnp.float32).T,
+                    ((0, c_rows - num_cuts), (0, f_cols - num_features)),
+                    constant_values=jnp.nan)
+    # the runs that hold a sub-tile's first and last lane; a sub-tile past
+    # the last, in the last step: no run
+    lo = jnp.arange(tiles, dtype=jnp.int32) * _NNZ_TILE
+    first = jnp.searchsorted(rstart, lo, side="right") - 1
+    last = jnp.searchsorted(rstart, lo + (_NNZ_TILE - 1), side="right") - 1
+    none = (0, steps * _LOOKUP_STEP_TILES - tiles)
+    span = jnp.stack(
+        [jnp.pad(first, none, constant_values=1).reshape(steps, -1),
+         jnp.pad(last, none, constant_values=0).reshape(steps, -1)],
+        axis=1).astype(jnp.int32)               # [steps, 2, sub-tiles a step]
+    return pl.pallas_call(
+        functools.partial(_bin_runs_kernel, nb, num_features + 1,
+                          num_shards > 1),
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((None, 2, _LOOKUP_STEP_TILES), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, lanes), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=table.size * 4 + (16 << 20)),
+        interpret=interpret,
+        name=BIN_RUNS_KERNEL,
+    )(span, rstart, value.reshape(1, lanes), table).reshape(lanes)
+
+
+def layout_bin_engages(cuts_shape: tuple, num_features: int, lanes: int,
+                       num_shards: int) -> bool:
+    """Whether `sparse_hist_layout` is given the entries' values and bins
+    them after its sort, read off what the code can see as
+    `entry_lookup_engages` is: the cuts are these features', the kernel is
+    compiled for a TPU, the cuts' table fits its share of VMEM, and the runs
+    are not thinner than a sub-tile on the whole.  The kernel makes a pass of
+    compares a sub-tile and one more for every run that begins inside one,
+    an empty run too: with no more runs than the ``lanes`` that the sort is
+    given hold sub-tiles, that is under two passes a sub-tile of those lanes
+    (the Bosch cell: 213,060 sub-tiles, 969 runs).  Known before the sort, so
+    that where it says no the entries are binned in entry order first, as
+    they always were."""
+    if len(cuts_shape) != 2 or cuts_shape[0] != num_features:
+        return False
+    c_rows, f_cols = _bin_table_shape(num_features, cuts_shape[1])
+    return (not pallas_interpret()
+            and c_rows * f_cols * 4 <= _BIN_TABLE_BYTES
+            and num_shards * (num_features + 1) <= lanes // _NNZ_TILE)
 
 
 def segment_sum(contrib: jax.Array, row_id: jax.Array, num_segments: int,

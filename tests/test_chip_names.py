@@ -217,6 +217,42 @@ def test_entry_push_kernel_compiles_at_the_cells_size(one_chip, quiet_cache):
         rid, span, val, wide, False)).lower(*shapes).compile()
 
 
+# the binning on the layout's lanes: the Bosch cell's 2.18e8 packed values
+# against a table of 968 features' 254 cuts (1 MiB of VMEM for the call), on
+# one chip and as a sharded layout's runs (a run's feature by a remainder)
+@pytest.mark.parametrize("shards", [1, 4])
+def test_bin_runs_kernel_compiles_at_the_cells_size(one_chip, quiet_cache,
+                                                    shards):
+    nnz, features, cuts = 218103808, 968, 254
+    assert pallas_segment._bin_table_shape(features, cuts) == (256, 1024)
+
+    def bin_runs(value, rstart, table):
+        return pallas_segment._bin_runs_pallas(value, rstart, table, 256,
+                                               shards, False)
+
+    shapes = (on(one_chip, (nnz,), jnp.float32),
+              on(one_chip, (shards * (features + 1) + 1,), jnp.int32),
+              on(one_chip, (features, cuts), jnp.float32))
+    compiled = jax.jit(bin_runs).lower(*shapes).compile()
+    kernels = [n for n in instructions(compiled) if n.startswith(
+        "%" + pallas_segment.BIN_RUNS_KERNEL)]
+    assert len(kernels) == 1 and compiled.as_text().count(
+        "tpu_custom_call") == 1
+    # what is laid out for the kernel is the table and the sub-tiles' runs,
+    # not an array of entry lanes; the keys come back an int32 a lane
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert memory.output_size_in_bytes == nnz * 4
+    assert kernel_dots(bin_runs, *shapes) == []      # compares, no MXU
+    if shards == 1:
+        # the widest table the rule lets in still fits the chip's fast memory
+        wide = pallas_segment._BIN_TABLE_BYTES // (256 * 4) - 1
+        assert pallas_segment._bin_table_shape(wide, cuts) == (256, wide + 1)
+        jax.jit(bin_runs).lower(
+            shapes[0], on(one_chip, (wide + 2,), jnp.int32),
+            on(one_chip, (wide, cuts), jnp.float32)).compile()
+
+
 def test_sparse_tree_program_looks_its_entries_up_under_their_scope(
         one_chip, quiet_cache, monkeypatch):
     """The sparse tree program as a chip compiles it where the rule engages

@@ -39,11 +39,11 @@ def test_rehearsal_passes_and_says_what_it_is(tmp_path):
     assert all(p["ok"] for p in summary["phases"].values())
     assert summary["phases"]["mesh"]["skipped"]
     # every kernel ran, and said it was interpreted (off the chip it must be)
-    assert len(summary["kernels"]) == 11
+    assert len(summary["kernels"]) == 12
     assert all(k["interpret"] for k in summary["kernels"])
     # the last four: the entry lookup against XLA's gather and the entries'
     # push against its scatter-add, bit for bit
-    lookups, pushes = summary["kernels"][7:9], summary["kernels"][9:]
+    lookups, pushes = summary["kernels"][7:9], summary["kernels"][9:11]
     assert [k["kernel"] for k in lookups] == ["entry_lookup(slots)",
                                               "entry_lookup(grad_hess)"]
     assert all(k["exact"] and k["chunk_visits"] >= k["entries"] // 1024
@@ -55,6 +55,10 @@ def test_rehearsal_passes_and_says_what_it_is(tmp_path):
     # one station's runs are fewer sub-tiles than the layout's, all live
     assert pushes[1]["sub_tiles"] < pushes[0]["sub_tiles"] == (
         pushes[0]["entries"] // 1024)
+    # the last: the layout's binning against the bisection, bit for bit
+    binning = summary["kernels"][11]
+    assert binning["kernel"] == "layout_bin" and binning["exact"]
+    assert binning["checked"] > binning["runs"]
     # data and outputs stay under --out, and the large inputs are removed
     assert sorted(p.name for p in (tmp_path / "out").iterdir()) == [
         "forest.ckpt", "summary.json"]

@@ -63,6 +63,14 @@ def programs() -> dict:
     sparse_tree = model._build_tree_sparse.lower(
         model, None, layout, jnp.zeros(64), jnp.zeros(64), jnp.ones(4, bool),
         jax.random.PRNGKey(0))
+    # the layout's pack program as `fit_batch` runs it where the layout
+    # bins the entries itself: the sorted values, the run starts, the cuts
+    from dmlc_core_tpu.ops import pallas_segment
+    pack = pallas_segment._layout_pack.lower(
+        jnp.zeros(128), rid, jnp.zeros(1, jnp.int32),
+        jnp.full(1, 128, jnp.int32), 1, 1024,
+        rstart=jnp.zeros(6, jnp.int32), cuts=jnp.zeros((4, 14)), nb=16,
+        interpret=True)
 
     # the leaf-wise builder's tree program (`models/gbdt_leafwise.py`)
     best = GBDT(num_features=4, num_trees=1, num_bins=16, histogram="pallas",
@@ -119,7 +127,7 @@ def programs() -> dict:
         value=jnp.ones(wide * fields), num_rows=jnp.asarray(np.int32(wide))))
     return {"fit": paths_of(fit), "tree": paths_of(tree),
             "sharded": paths_of(sharded),
-            "sparse_tree": paths_of(sparse_tree),
+            "sparse_tree": paths_of(sparse_tree), "pack": paths_of(pack),
             "leafwise": paths_of(leafwise), "margin": paths_of(margin),
             "paged": paths_of(paged),
             "step": paths_of(step), "touched": paths_of(touched),
@@ -144,6 +152,9 @@ LEAFWISE = {"gbdt.leafwise.hist", "gbdt.leafwise.partition",
             "gbdt.leafwise.split", "gbdt.leafwise.pick"}
 # scopes of the boosting driver, outside both tree programs
 DRIVER = {"gbdt.boost", "gbdt.margin"}
+# scopes of the sparse layout's pack program, `jit(_layout_pack)`, which
+# `fit_batch` runs once, before the first tree
+LAYOUT = {"gbdt.layout_bin"}
 # scopes of the paged fit's page visit, `jit(_page_visit)`, each nested in
 # the resident tree's scope of the same work
 PAGED = {"gbdt.page.route": "gbdt.route", "gbdt.page.hist": "gbdt.hist",
@@ -176,6 +187,12 @@ def test_every_scope_a_metric_reads_is_in_a_lowered_program(programs, scope):
         assert carries(programs["paged"], scope,
                        under=f"jit(_page_visit)/{PAGED[scope]}/")
         assert not carries(programs["tree"], scope)
+        return
+    if scope in LAYOUT:
+        # under the pack program's own name, where the prepare's metric
+        # looks for it (`^jit\(_layout_pack\)`); no tree program holds it
+        assert carries(programs["pack"], scope, under="jit(_layout_pack)/")
+        assert not carries(programs["sparse_tree"], scope)
         return
     if scope in SPARSE_ONLY:
         where = "sparse_tree"
@@ -776,3 +793,38 @@ def test_the_entries_push_runs_under_the_route_scope(monkeypatch):
             f"jit({ps.ENTRY_PUSH_KERNEL})") in paths
     assert f"{ps.ENTRY_PUSH_KERNEL}/pallas_call" in paths
     assert carries(paths, "gbdt.entry_gather")
+
+
+def test_the_layout_bins_its_entries_under_the_pack_programs_name():
+    """The values form of the layout's pack program holds the binning kernel
+    under ``jit(_layout_pack)/gbdt.layout_bin``: the head
+    `sparse_prepare_ms_per_round` matches and `sparse_boost_ms_per_round`
+    leaves out, and the scope `layout_bin_ms_per_round` reads.  One call,
+    from the packed values to the packed keys; the codes form holds none.
+    Lowered for the chip it is a Mosaic call under that scope."""
+    import json
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    lanes, features = 4096, 968
+    args = (jnp.zeros(5000), jnp.zeros(5000, jnp.int32),
+            jnp.zeros(1, jnp.int32), jnp.full(1, 4000, jnp.int32), 1, lanes)
+    binning = dict(rstart=jnp.zeros(features + 2, jnp.int32),
+                   cuts=jnp.zeros((features, 254)), nb=256, interpret=False)
+    traced = ps._layout_pack.trace(*args, **binning)
+    calls = device_ops(traced, "pallas_call")
+    (path, ins, outs), = calls
+    assert path.endswith(f"/gbdt.layout_bin/{ps.BIN_RUNS_KERNEL}")
+    assert [(o.shape, str(o.dtype)) for o in outs] == [((1, lanes), "int32")]
+    assert (1, lanes) in [i.shape for i in ins]
+    assert device_ops(ps._layout_pack.trace(
+        *((jnp.zeros(5000, jnp.int32),) + args[1:])), "pallas_call") == []
+    paths = paths_of(traced.lower(lowering_platforms=("tpu",)))
+    assert (f"jit(_layout_pack)/gbdt.layout_bin/jit({ps.BIN_RUNS_KERNEL})"
+            in paths)
+    assert f"{ps.BIN_RUNS_KERNEL}/pallas_call" in paths
+    prepare, boost = (json.loads(
+        (ROOT / "benchmark" / "layer_metrics" / f"{name}.json").read_text()
+    )["args"] for name in ("sparse_prepare_ms_per_round",
+                           "sparse_boost_ms_per_round"))
+    head = f"jit(_layout_pack)/gbdt.layout_bin/jit({ps.BIN_RUNS_KERNEL})/x"
+    assert re.search(prepare["scope"], head)
+    assert re.search(boost["exclude"], head)

@@ -342,8 +342,13 @@ LAYOUTS = {
 }
 
 
+@pytest.mark.parametrize("form", ["codes", "values"])
 @pytest.mark.parametrize("name", sorted(LAYOUTS))
-def test_device_layout_equals_the_host_one(name):
+def test_device_layout_equals_the_host_one(name, form):
+    """The layout by the entries' codes, and by their values and the cuts
+    (binned after the sort, by the kernel, interpreted here): values that
+    the cuts 1, 2, ... bin to the very codes give the codes' layout, every
+    field of it."""
     rows, F, B, nnz, present, shards = LAYOUTS[name]
     rng = np.random.default_rng(sorted(LAYOUTS).index(name))
     rid, fi, eb, em = random_entries(rng, rows, F, B, nnz, present)
@@ -351,13 +356,29 @@ def test_device_layout_equals_the_host_one(name):
         em[:] = False
     if name == "sharded_with_an_empty_shard":
         em &= rid // 32 != 5
+    entries = dict(ebin=jnp.asarray(eb))
+    if form == "values":    # code b: the value b - 0.5 among the cuts 1..B-2
+        cuts = np.tile(np.arange(1, B - 1, dtype=np.float32), (F, 1))
+        entries = dict(ebin=None, value=jnp.asarray(eb - 0.5, jnp.float32),
+                       cuts=jnp.asarray(cuts))
     got = sparse_hist_layout(jnp.asarray(rid), jnp.asarray(fi),
-                             jnp.asarray(eb), jnp.asarray(em), F, B,
-                             num_shards=shards, rows=rows)
+                             emask=jnp.asarray(em), num_features=F,
+                             num_bins=B, num_shards=shards, rows=rows,
+                             **entries)
     want = host_layout(rid, fi, eb, em, F, B, shards, rows)
     for k, v in want.items():
         np.testing.assert_array_equal(np.asarray(getattr(got, k)), v,
                                       err_msg=k)
+    if form == "values":
+        by_codes = sparse_hist_layout(jnp.asarray(rid), jnp.asarray(fi),
+                                      jnp.asarray(eb), jnp.asarray(em), F, B,
+                                      num_shards=shards, rows=rows)
+        flat_a, tree_a = jax.tree_util.tree_flatten(got)
+        flat_b, tree_b = jax.tree_util.tree_flatten(by_codes)
+        assert tree_a == tree_b             # every static field
+        for a, b in zip(flat_a, flat_b):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_device_layout_refuses_entries_out_of_range():
@@ -372,6 +393,16 @@ def test_device_layout_refuses_entries_out_of_range():
     # a dead entry may hold anything
     sparse_hist_layout(rid, jnp.array([0, 1, 9, 3]), jnp.ones(4),
                        jnp.array([True, True, False, True]), 5, 8)
+    # by values: a feature out of range, and cuts of other features or of
+    # more codes than the layout's keys hold
+    value = jnp.ones(4)
+    with pytest.raises(ValueError, match="out of range"):
+        sparse_hist_layout(rid, jnp.array([0, 1, 5, 2]), None, ok, 5, 8,
+                           value=value, cuts=jnp.zeros((5, 6)))
+    for shape in ((4, 6), (5, 7)):
+        with pytest.raises(ValueError, match="cuts"):
+            sparse_hist_layout(rid, jnp.array([0, 1, 2, 3]), None, ok, 5, 8,
+                               value=value, cuts=jnp.zeros(shape))
 
 
 # ---- entry binning ----------------------------------------------------------
@@ -404,6 +435,189 @@ def test_binning_by_sort_equals_binning_by_bisection(features, bins, n):
     np.testing.assert_array_equal(
         np.asarray(binner.transform_entries(jnp.asarray(index),
                                             jnp.asarray(value))), by_sort)
+
+
+def _bin_case(name, rng):
+    """(features, num_bins, findex, value, cuts) of one case of the run-wise
+    binning: entries in no order, the cuts a feature non-decreasing."""
+    features, bins, n = 7, 16, 5000
+    if name in ("bins_32", "bins_256"):
+        features, bins, n = 40, int(name[5:]), 30000
+    if name == "many_features_in_a_sub_tile":   # 300 runs in two sub-tiles
+        features, n = 300, 2000
+    cuts = np.sort(np.round(rng.standard_normal((features, bins - 2)), 1),
+                   axis=1).astype(np.float32)
+    index = rng.integers(0, features, n)
+    value = np.round(rng.standard_normal(n), 1).astype(np.float32)
+    value[value == 0] = 0.1
+    if name == "ties_with_the_cuts":
+        value = cuts[index, rng.integers(0, bins - 2, n)]
+        value[value == 0] = 0.1
+    elif name == "below_the_first_and_above_the_last":
+        value = np.where(rng.random(n) < 0.5, cuts[index, 0] - 1,
+                         cuts[index, -1] + 1).astype(np.float32)
+        value[value == 0] = 0.1
+    elif name == "infinities":
+        value[rng.random(n) < 0.3] = np.inf
+        value[rng.random(n) < 0.3] = -np.inf
+        cuts[0, -1], cuts[1, 0] = np.inf, -np.inf
+    elif name == "nan_and_zeros_are_dead":
+        value[rng.random(n) < 0.2] = np.nan
+        value[rng.random(n) < 0.2] = 0.0
+        value[rng.random(n) < 0.1] = -0.0
+    elif name == "an_empty_feature_and_one_of_a_single_entry":
+        index = rng.choice([0, 1, 3, 4, 6], n)
+        index[17] = 5                       # 2 has none, 5 this one
+    elif name == "a_run_boundary_inside_a_sub_tile":
+        index = np.where(np.arange(n) < 1500, 2, 4)     # 1,500 and 3,500
+    return features, bins, index.astype(np.int32), value, cuts
+
+
+BIN_CASES = ["ties_with_the_cuts", "below_the_first_and_above_the_last",
+             "infinities", "nan_and_zeros_are_dead",
+             "an_empty_feature_and_one_of_a_single_entry",
+             "a_run_boundary_inside_a_sub_tile",
+             "many_features_in_a_sub_tile", "bins_32", "bins_256"]
+
+
+@pytest.mark.parametrize("name", BIN_CASES)
+def test_codes_made_run_by_run_are_the_bisections(name):
+    """`_bin_runs_pallas` (interpreted) on the lanes that the layout's sort
+    leaves: a live entry's key is its feature's stride and the code that
+    `_bin_by_bisection` gives the same entry, bit for bit; the dead entries
+    (NaN, a stored zero) sort last and get none."""
+    from dmlc_core_tpu.models.gbdt import _bin_by_bisection
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    rng = np.random.default_rng(BIN_CASES.index(name))
+    features, bins, index, value, cuts = _bin_case(name, rng)
+    n = len(index)
+    rid = np.arange(n, dtype=np.int32) // 3
+    live = (value != 0) & ~np.isnan(value)
+    assert live.all() == (name != "nan_and_zeros_are_dead")
+    codes = np.asarray(_bin_by_bisection(jnp.asarray(cuts),
+                                         jnp.asarray(index),
+                                         jnp.asarray(value)))
+    got = sparse_hist_layout(jnp.asarray(rid), jnp.asarray(index), None,
+                             jnp.asarray(live), features, bins,
+                             value=jnp.asarray(value), cuts=jnp.asarray(cuts))
+    order = np.argsort(np.where(live, index, features), kind="stable")
+    order = order[:live.sum()]
+    gkey = np.asarray(got.gkey)
+    np.testing.assert_array_equal(gkey[:len(order)],
+                                  index[order] * got.nb + codes[order])
+    assert (gkey[len(order):] == -1).all()
+    assert 1 <= codes[live].min() and codes[live].max() <= bins - 1
+    if name == "infinities":
+        assert {1, bins - 1} <= set(codes[np.isinf(value)].tolist())
+    # and the kernel alone, on the sorted values and the run starts
+    lanes = got.nnz_pad
+    rstart = np.append(np.asarray(got.fstart), lanes).astype(np.int32)
+    held = np.diff(np.searchsorted(rstart, np.arange(0, lanes + 1, 1024)))
+    if name == "many_features_in_a_sub_tile":
+        assert held.max() > 128
+    if name == "a_run_boundary_inside_a_sub_tile":
+        assert rstart[4] == 1500 and rstart[5] == n
+    alone = ps._bin_runs_pallas(
+        jnp.asarray(np.pad(value[order], (0, lanes - len(order)))),
+        jnp.asarray(rstart), jnp.asarray(cuts), got.nb, 1, True)
+    np.testing.assert_array_equal(np.asarray(alone), gkey)
+
+
+def test_the_layout_bins_where_the_rule_says_it_pays():
+    """`layout_bin_engages`: the cuts are the features', a TPU compiles the
+    kernel, the table fits its share of VMEM, and there are no more runs
+    than the lanes hold sub-tiles."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    bosch = ((968, 254), 968, 218103808, 1)
+    assert not ps.layout_bin_engages(*bosch)        # interpreted: a test's tool
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ps, "pallas_interpret", lambda: False)
+        assert ps.layout_bin_engages(*bosch)
+        assert ps.layout_bin_engages((968, 254), 968, 969 * 4 * 1024, 4)
+        assert not ps.layout_bin_engages((968, 254), 968, 969 * 1024 - 1, 1)
+        assert not ps.layout_bin_engages((967, 254), 968, 218103808, 1)
+        assert ps.layout_bin_engages((16383, 254), 16383, 1 << 28, 1)
+        assert not ps.layout_bin_engages((16384, 254), 16384, 1 << 28, 1)
+
+
+FIT_CASES = {
+    # name: (the rule forced on, counter a fit, the tree keeps the entries)
+    "engaged": (True, 1, False),
+    "off_a_tpu": (False, 0, False),
+    "binned_batch": (True, 0, False),
+    "rows_do_not_ascend": (True, 1, True),
+    "a_level_on_xla": (True, 1, True),
+    "under_a_mesh": (True, 1, True),
+    "no_layout": (True, 0, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FIT_CASES))
+def test_fit_batch_binned_on_the_layouts_lanes_grows_the_same_forest(
+        name, monkeypatch):
+    """A `fit_batch` whose layout bins the batch's values after its sort
+    (the rule forced on, the kernel interpreted) grows the forest of one
+    that bins in entry order first, array for array; ``gbdt.layout_bin``
+    says which it was, once a fit: 1 where it engaged, 0 off a TPU, for a
+    `BinnedBatch` and without a layout.  Where the tree wants the entries in
+    entry order too (rows that do not ascend, a level on XLA, a mesh) they
+    are binned afterwards, as ever, and the fit trains."""
+    import dataclasses
+    from dmlc_core_tpu import telemetry
+    from dmlc_core_tpu.models import gbdt
+    forced, counted, keeps = FIT_CASES[name]
+    batch, binner = _thin_and_dense_batch(np.random.default_rng(49))
+    kw = dict(num_features=4, num_trees=2, max_depth=4, num_bins=16,
+              learning_rate=0.5, missing_aware=True, histogram="pallas")
+    if name == "rows_do_not_ascend":    # some rows hold a feature twice
+        ptr = np.asarray(batch.row_ptr)
+        index = np.asarray(batch.index).copy()
+        for r in [r for r in range(3000) if ptr[r + 1] - ptr[r] >= 2][:30]:
+            index[ptr[r] + 1] = index[ptr[r]]
+        batch = dataclasses.replace(batch, index=jnp.asarray(index))
+    if name == "a_level_on_xla":        # the last, of eight nodes, as
+        # "auto" resolves a level past the kernel's node limit on a chip
+        monkeypatch.setattr(GBDT, "_hist_impl_sparse", lambda self, n_nodes: (
+            "pallas" if n_nodes <= 4 else "xla"))
+    if name == "under_a_mesh":
+        from jax.sharding import Mesh
+        kw["histogram_mesh"] = MeshPlan(
+            Mesh(np.asarray(jax.devices()[:8]), ("data",)))
+    if name == "no_layout":
+        kw["histogram"] = "xla"
+    want = GBDT(**kw).fit_batch(batch, binner)
+    if name == "binned_batch":
+        from dmlc_core_tpu.data.binned_cache import BinnedBatch
+        rid, fi, ebin, emask = GBDT._entry_bins(batch, binner)
+        batch = BinnedBatch(
+            label=batch.label, weight=batch.weight, row_ptr=batch.row_ptr,
+            index=batch.index, ebin=ebin.astype(jnp.uint8), emask=emask,
+            num_rows=batch.num_rows, cuts_digest=binner.cuts_digest())
+    seen, bins = [], []
+
+    class Spy(GBDT):
+        def _build_tree_sparse(self, entries, layout, *a):
+            seen.append(entries is not None)
+            return GBDT._build_tree_sparse(self, entries, layout, *a)
+
+    binned = gbdt._bin_entries
+    monkeypatch.setattr(gbdt, "_bin_entries",
+                        lambda *a: bins.append(1) or binned(*a))
+    if forced:
+        real = gbdt.layout_bin_engages
+        monkeypatch.setattr(gbdt, "layout_bin_engages", lambda *a: (
+            real(*a) or a[1] == a[0][0] == 4))
+    before = telemetry.counter_get("gbdt.layout_bin")
+    got = Spy(**kw).fit_batch(batch, binner)
+    assert telemetry.counter_get("gbdt.layout_bin") - before == counted
+    assert seen == [keeps] * 2
+    # binned in entry order: not at all where the layout did it and holds
+    # every entry, once otherwise (a BinnedBatch comes binned)
+    assert len(bins) == (0 if name in ("engaged", "binned_batch") else 1)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=k)
+    assert (np.asarray(got["feature"]) >= 0).any()
 
 
 def _thin_and_dense_batch(rng, rows=3000):
