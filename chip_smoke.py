@@ -97,33 +97,56 @@ def cache_entries(cache_dir: str) -> int:
     return sum(1 for n in os.listdir(cache_dir) if not n.endswith("-atime"))
 
 
-class CompileMeter:
-    """What JAX itself reports about getting executables: seconds spent
-    tracing, lowering and compiling (or fetching from the persistent cache),
-    how many programs, how many came from the cache.  This is the set-up
-    share of a phase's wall clock; the rest is the phase running."""
+# The program's own set-up counters (doc/observability.md, "Trace spans"): the
+# compile meter that ``compile_cache.configure()`` registers and the totals of
+# the spans set-up's work runs under.  The benchmark reads the same ones.
+SETUP_TABLE = (
+    ("program_seconds", "main.span_us"),
+    ("trace_seconds", "compile.trace_us"),
+    ("lower_seconds", "compile.lower_us"),
+    ("backend_seconds", "compile.backend_us"),
+    ("fetch_seconds", "compile.fetch_us"),
+    ("binner_seconds", "binner.fit_us"),
+    ("init_seconds", "model.init_us"),
+    ("fit_seconds", "gbdt.fit_us"),
+)
+COMPILE_STAGES = ("trace_seconds", "lower_seconds", "backend_seconds")
 
-    def __init__(self):
-        import jax.monitoring
-        self.seconds, self.programs, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event: str, seconds: float, **_) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += seconds
-            self.programs += event.endswith("backend_compile_duration")
+def setup_counters() -> dict:
+    """The table's seconds and the meter's counts as they stand; zeros until
+    the package is imported (nothing compiles before that)."""
+    telemetry = getattr(sys.modules.get("dmlc_core_tpu"), "telemetry", None)
+    read = telemetry.counter_get if telemetry else (lambda name: 0)
+    out = {key: read(name) / 1e6 for key, name in SETUP_TABLE}
+    out.update(programs=read("compile.programs"),
+               cache_hits=read("compile.cache_hits"),
+               cache_misses=read("compile.cache_misses"))
+    return out
 
-    def _event(self, event: str, **_) -> None:
-        self.cache_hits += event == "/jax/compilation_cache/cache_hits"
 
-    def snapshot(self) -> tuple:
-        return self.seconds, self.programs, self.cache_hits
+def compile_since(mark: dict) -> dict:
+    """A phase's share of getting executables: seconds tracing, lowering and
+    compiling (or fetching from the persistent cache), how many programs,
+    how many came from the cache.  The rest of its wall clock is it running."""
+    now = setup_counters()
+    return {"compile_seconds": round(sum(now[k] - mark[k]
+                                         for k in COMPILE_STAGES), 2),
+            "programs": now["programs"] - mark["programs"],
+            "cache_hits": now["cache_hits"] - mark["cache_hits"]}
 
-    def since(self, mark: tuple) -> dict:
-        return {"compile_seconds": round(self.seconds - mark[0], 2),
-                "programs": self.programs - mark[1],
-                "cache_hits": self.cache_hits - mark[2]}
+
+def slowest_programs(n: int = 5) -> list:
+    """``compile_cache.programs()``'s ``n`` dearest rows: which programs the
+    compile seconds went to."""
+    from dmlc_core_tpu import compile_cache
+    cost = lambda row: sum(row.get(k, 0.0)                  # noqa: E731
+                           for k in ("trace_s", "lower_s", "backend_s"))
+    rows = sorted(compile_cache.programs().items(),
+                  key=lambda item: -cost(item[1]))[:n]
+    return [{"program": name, "seconds": round(cost(row), 2),
+             **{k: round(v, 2) if isinstance(v, float) else v
+                for k, v in row.items()}} for name, row in rows]
 
 
 # ---- phases -----------------------------------------------------------------
@@ -912,14 +935,13 @@ def main() -> int:
            "size": TINY if args.rehearse_cpu else FULL}
     summary = {"ok": False, "rehearsal": args.rehearse_cpu, "phases": {}}
     failed = None
-    meter = CompileMeter()
     try:
         for name, fn in PHASES:
             if name == "mesh" and ctx["device"]["count"] < 2:
                 summary["phases"][name] = {"ok": True, "skipped": "1 device"}
                 continue
             log(f"phase {name} ...")
-            t0, mark = time.monotonic(), meter.snapshot()
+            t0, mark = time.monotonic(), setup_counters()
             try:
                 detail = fn(ctx)
             except Exception as exc:  # noqa: BLE001 — reported, then exit 1
@@ -931,7 +953,7 @@ def main() -> int:
                 break
             summary["phases"][name] = {
                 "ok": True, "seconds": round(time.monotonic() - t0, 2),
-                **meter.since(mark), **detail}
+                **compile_since(mark), **detail}
             log(f"phase {name} ok: {summary['phases'][name]}")
     finally:
         # the generated inputs are large and reproducible from the seed
@@ -945,10 +967,17 @@ def main() -> int:
         cache["entries_added"] = (cache_entries(cache["dir"])
                                   - cache.pop("entries_before"))
         summary["cache"] = cache
-    # set-up (native build, getting executables) apart from the rest
+    # set-up (native build, getting executables) apart from the rest, from
+    # the program's own counters, and the programs the compile seconds went to
+    table = setup_counters()
     setup = {"native_build_seconds": ctx.get("native", {}).get(
-        "build_seconds", 0.0), **meter.since((0.0, 0, 0))}
+        "build_seconds", 0.0), **compile_since(dict.fromkeys(table, 0)),
+        **{k: round(v, 2) if isinstance(v, float) else v
+           for k, v in table.items()}}
+    if "dmlc_core_tpu" in sys.modules:
+        setup["slowest_programs"] = slowest_programs()
     summary["setup"] = setup
+    log(f"set-up by the program's own counters: {setup}")
     summary["seconds"] = round(time.monotonic() - T0, 2)
     summary["steady_seconds"] = round(
         summary["seconds"] - setup["native_build_seconds"]

@@ -456,6 +456,13 @@ int DmlcTpuTelemetryRecordSpan(const char* name, int64_t ts_us,
  * goes out as args.lineage whether or not a trace context is set. */
 int DmlcTpuTelemetryRecordSpanLineage(const char* name, int64_t ts_us,
                                       int64_t dur_us, int64_t lineage);
+/* the same, and one call closes the span in the registry too: tracing on or
+ * off, dur_us is added to the counter `total` (NULL or "": none) and, when
+ * main_outermost != 0, to main.span_us — the binding's span() says whether
+ * the span was the outermost one open on the main thread. */
+int DmlcTpuTelemetryRecordSpanTotal(const char* name, int64_t ts_us,
+                                    int64_t dur_us, int64_t lineage,
+                                    const char* total, int main_outermost);
 /* set/adjust/read the named process-wide gauge (created on first use) —
  * how the Python staging loop publishes H2D queue depth for the flight
  * recorder. */

@@ -489,6 +489,11 @@ DMLCTPU_STAGE_COUNTER(CacheArenaAlloc, "cache.arena_alloc")
 DMLCTPU_STAGE_COUNTER(CacheArenaReuse, "cache.arena_reuse")
 DMLCTPU_STAGE_GAUGE(CacheArenaBytes, "cache.arena_bytes")
 
+// Spans that keep their own total (c_api.h, DmlcTpuTelemetryRecordSpanTotal):
+// time the binding's main thread spent inside program spans, outermost
+// only — what of a process's life the program's spans account for.
+DMLCTPU_STAGE_COUNTER(MainSpanUs, "main.span_us")
+
 #undef DMLCTPU_STAGE_COUNTER
 #undef DMLCTPU_STAGE_GAUGE
 #undef DMLCTPU_STAGE_HISTOGRAM

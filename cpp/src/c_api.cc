@@ -211,9 +211,26 @@ int DmlcTpuTelemetryRecordSpan(const char* name, int64_t ts_us,
 
 int DmlcTpuTelemetryRecordSpanLineage(const char* name, int64_t ts_us,
                                       int64_t dur_us, int64_t lineage) {
+  return DmlcTpuTelemetryRecordSpanTotal(name, ts_us, dur_us, lineage,
+                                         nullptr, 0);
+}
+
+int DmlcTpuTelemetryRecordSpanTotal(const char* name, int64_t ts_us,
+                                    int64_t dur_us, int64_t lineage,
+                                    const char* total, int main_outermost) {
   return Guard([&] {
-    if (dmlctpu::telemetry::TraceActive()) {
-      dmlctpu::telemetry::RecordSpanOwned(name, ts_us, dur_us, lineage);
+    namespace tel = dmlctpu::telemetry;
+    if (tel::TraceActive()) {
+      tel::RecordSpanOwned(name, ts_us, dur_us, lineage);
+    }
+    if (dur_us > 0) {
+      if (total != nullptr && total[0] != '\0') {
+        tel::Registry::Get()->counter(total).Add(
+            static_cast<uint64_t>(dur_us));
+      }
+      if (main_outermost != 0) {
+        tel::stage::MainSpanUs().Add(static_cast<uint64_t>(dur_us));
+      }
     }
     return 0;
   });

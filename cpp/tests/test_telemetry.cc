@@ -325,6 +325,34 @@ TESTCASE(spans_not_recorded_while_inactive) {
   }
 }
 
+TESTCASE(c_api_span_keeps_its_total_tracing_on_or_off) {
+  // DmlcTpuTelemetryRecordSpanTotal: the ring's event only while tracing,
+  // the span's counter and main.span_us either way, one call
+  int64_t total0 = 0, main0 = 0, total1 = 0, main1 = 0;
+  DmlcTpuTelemetryCounterGet("test.span_total_us", &total0);
+  DmlcTpuTelemetryCounterGet("main.span_us", &main0);
+  telemetry::TraceStart();
+  telemetry::TraceStop();
+  EXPECT_EQV(DmlcTpuTelemetryRecordSpanTotal("test.off", 5, 40, -1,
+                                             "test.span_total_us", 1), 0);
+  telemetry::TraceStart();
+  EXPECT_EQV(DmlcTpuTelemetryRecordSpanTotal("test.on", 50, 2, 7,
+                                             "test.span_total_us", 0), 0);
+  EXPECT_EQV(DmlcTpuTelemetryRecordSpanTotal("test.bare", 60, 9, -1, nullptr,
+                                             0), 0);
+  telemetry::TraceStop();
+  DmlcTpuTelemetryCounterGet("test.span_total_us", &total1);
+  DmlcTpuTelemetryCounterGet("main.span_us", &main1);
+  const std::string js = telemetry::TraceDumpJson();
+  WalkJson(js.c_str());
+  if (!telemetry::Enabled()) return;
+  EXPECT_EQV(total1 - total0, int64_t{42});
+  EXPECT_EQV(main1 - main0, int64_t{40});
+  EXPECT_TRUE(EventText(js, "test.off").empty());
+  EXPECT_TRUE(!EventText(js, "test.on").empty());
+  EXPECT_TRUE(!EventText(js, "test.bare").empty());
+}
+
 TESTCASE(snapshot_during_active_pipeline) {
   TemporaryDirectory tmp;
   std::string f = MakeLibsvm(tmp.path, 20000);

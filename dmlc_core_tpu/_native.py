@@ -229,10 +229,15 @@ _LIB.DmlcTpuTelemetryCounterGet.argtypes = [
 _LIB.DmlcTpuTelemetryTraceStart.argtypes = []
 _LIB.DmlcTpuTelemetryTraceStop.argtypes = []
 _LIB.DmlcTpuTelemetryTraceDumpJson.argtypes = [ctypes.POINTER(ctypes.c_char_p)]
+# the two older forms of RecordSpanTotal: the binding calls neither, and
+# `scripts/analyze.py capi` wants every symbol of c_api.h declared here
 _LIB.DmlcTpuTelemetryRecordSpan.argtypes = [
     ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryRecordSpanLineage.argtypes = [
     ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64]
+_LIB.DmlcTpuTelemetryRecordSpanTotal.argtypes = [
+    ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+    ctypes.c_char_p, ctypes.c_int]
 _LIB.DmlcTpuTelemetryGaugeSet.argtypes = [ctypes.c_char_p, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryGaugeAdd.argtypes = [ctypes.c_char_p, ctypes.c_int64]
 _LIB.DmlcTpuTelemetryGaugeGet.argtypes = [

@@ -1190,34 +1190,33 @@ class BinnedStagingIter:
 
     # -- staging --------------------------------------------------------------
     def _stage(self, w: dict) -> BinnedBatch:
-        with telemetry.span("h2d.stage_binned"):
-            with_qid = w["qid"] is not None
-            num_rows = np.int32(w["num_rows"])
-            leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
-                       w["ebin"], w["emask"], num_rows)
-                      + ((w["qid"],) if with_qid else ()))
-            # donated put: the runtime may consume the arena-backed leaves
-            # in place instead of copying them (DMLCTPU_BINCACHE_DONATE=0
-            # opts out; bit-identity vs the non-donated path is tested)
-            donate = os.environ.get("DMLCTPU_BINCACHE_DONATE", "1") != "0"
-            if self._sharding is None:
-                staged = _device_put_maybe_donated(leaves, donate=donate)
-            else:
-                sh, repl = self._sharding, _replicated_sharding(
-                    self._sharding)
-                shardings = ((sh, sh, repl, sh, sh, sh, repl)
-                             + ((sh,) if with_qid else ()))
-                staged = _device_put_maybe_donated(leaves, shardings,
-                                                   donate=donate)
-            batch = BinnedBatch(
-                label=staged[0], weight=staged[1], row_ptr=staged[2],
-                index=staged[3], ebin=staged[4], emask=staged[5],
-                num_rows=staged[6],
-                qid=staged[7] if with_qid else None,
-                cuts_digest=self._meta.get("cuts_digest", "")
-                if self._meta else "")
-            self.batches_staged += 1
-            return batch
+        with_qid = w["qid"] is not None
+        num_rows = np.int32(w["num_rows"])
+        leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
+                   w["ebin"], w["emask"], num_rows)
+                  + ((w["qid"],) if with_qid else ()))
+        # donated put: the runtime may consume the arena-backed leaves
+        # in place instead of copying them (DMLCTPU_BINCACHE_DONATE=0
+        # opts out; bit-identity vs the non-donated path is tested)
+        donate = os.environ.get("DMLCTPU_BINCACHE_DONATE", "1") != "0"
+        if self._sharding is None:
+            staged = _device_put_maybe_donated(leaves, donate=donate)
+        else:
+            sh, repl = self._sharding, _replicated_sharding(
+                self._sharding)
+            shardings = ((sh, sh, repl, sh, sh, sh, repl)
+                         + ((sh,) if with_qid else ()))
+            staged = _device_put_maybe_donated(leaves, shardings,
+                                               donate=donate)
+        batch = BinnedBatch(
+            label=staged[0], weight=staged[1], row_ptr=staged[2],
+            index=staged[3], ebin=staged[4], emask=staged[5],
+            num_rows=staged[6],
+            qid=staged[7] if with_qid else None,
+            cuts_digest=self._meta.get("cuts_digest", "")
+            if self._meta else "")
+        self.batches_staged += 1
+        return batch
 
     # -- autotuner surface ----------------------------------------------------
     @property

@@ -504,15 +504,12 @@ def _feed_get(q: queue.Queue):
     (The caller's rebind of its ``item`` drops the previous batch: releasing
     device arrays whose step is still queued took 0.8 ms on a v5e, PERF.md
     PR 37.  It falls after this span and counter, not inside them.)"""
-    t0 = time.monotonic()
-    with telemetry.span("feed.wait") as wait:
+    with telemetry.span("feed.wait", total="h2d.consumer_wait_us") as wait:
         got = q.get()
         got_us = telemetry.now_us()
         batch = isinstance(got, _Handoff)
         if batch:
             wait.lineage = telemetry.lineage(got.item)
-    telemetry.counter_add("h2d.consumer_wait_us",
-                          int((time.monotonic() - t0) * 1e6))
     if not batch:
         return got
     put_us = got.put_us or got_us
@@ -795,21 +792,20 @@ class RecordStagingIter:
         }
 
     def _stage(self, w: dict) -> RecordBatch:
-        with telemetry.span("h2d.stage_records"):
-            def put(arr):
-                if self._sharding is not None:
-                    return jax.device_put(arr, self._sharding)
-                return jax.device_put(arr)
+        def put(arr):
+            if self._sharding is not None:
+                return jax.device_put(arr, self._sharding)
+            return jax.device_put(arr)
 
-            batch = RecordBatch(
-                bytes=put(w["bytes"]),
-                offsets=put(w["offsets"]),
-                num_records=jnp.asarray(np.int32(w["num_records"])),
-                block_num_records=jnp.asarray(
-                    np.array([w["num_records"]], np.int32)),
-                blocks=1)
-            self.batches_staged += 1
-            return batch
+        batch = RecordBatch(
+            bytes=put(w["bytes"]),
+            offsets=put(w["offsets"]),
+            num_records=jnp.asarray(np.int32(w["num_records"])),
+            block_num_records=jnp.asarray(
+                np.array([w["num_records"]], np.int32)),
+            blocks=1)
+        self.batches_staged += 1
+        return batch
 
     # ---- host-side record production ----------------------------------------
     def _resolve_virtual_parts(self) -> int:
@@ -1507,23 +1503,24 @@ class DeviceStagingIter:
                     t0 = telemetry.now_us()
                     w = next(it, None)
                     t1 = telemetry.now_us()
-                    telemetry.counter_add("h2d.wait_us", t1 - t0)
                     if w is None:
+                        # the wait that found the stream's end: no batch
+                        # to tell it of, so no span; the counter has it
+                        telemetry.counter_add("h2d.wait_us", t1 - t0)
                         return
                     batch = self._stage(w)
                     t2 = telemetry.now_us()
                     ok = emit(batch)
                     t3 = telemetry.now_us()
                     lineage = telemetry.lineage(batch)
-                    telemetry.record_span("h2d.host_wait", t0, t1 - t0,
-                                          lineage)
-                    telemetry.record_span("h2d.emit_wait", t2, t3 - t2,
-                                          lineage)
                     # publish H2D feed occupancy into the process-wide
                     # telemetry registry (same us units as the native
                     # stages, so stall_attribution sees the whole pipeline)
+                    telemetry.record_span("h2d.host_wait", t0, t1 - t0,
+                                          lineage, total="h2d.wait_us")
+                    telemetry.record_span("h2d.emit_wait", t2, t3 - t2,
+                                          lineage, total="h2d.emit_wait_us")
                     telemetry.counter_add("h2d.busy_us", t2 - t1)
-                    telemetry.counter_add("h2d.emit_wait_us", t3 - t2)
                     telemetry.counter_add("h2d.batches", 1)
                     if not ok:
                         return
@@ -1668,13 +1665,10 @@ class PagePrefetcher:
         if host.shape[0] < self._page_rows:
             host = np.concatenate([host, np.zeros(
                 (self._page_rows - host.shape[0], self._features), np.uint8)])
-        t0 = time.monotonic()
-        with telemetry.span("page.h2d"):
+        with telemetry.span("page.h2d", total="page.h2d_busy_us"):
             page = jax.block_until_ready(tuple(
                 jax.device_put(host[lo:hi], self._device)
                 for lo, hi in zip(self._cuts, self._cuts[1:])))
-        telemetry.counter_add("page.h2d_busy_us",
-                              int((time.monotonic() - t0) * 1e6))
         telemetry.counter_add("page.h2d_pages", 1)
         telemetry.counter_add("page.h2d_bytes", host.nbytes)
         return page
@@ -1689,11 +1683,8 @@ class PagePrefetcher:
             try:
                 item = self._ready.get_nowait()
             except queue.Empty:
-                t0 = time.monotonic()
-                with telemetry.span("page.wait"):
+                with telemetry.span("page.wait", total="page.wait_us"):
                     item = self._ready.get()
-                telemetry.counter_add("page.wait_us",
-                                      int((time.monotonic() - t0) * 1e6))
             if item is self._END:
                 return
             if isinstance(item, BaseException):

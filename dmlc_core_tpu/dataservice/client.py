@@ -351,31 +351,30 @@ class DataServiceIter:
                                                      cuts_digest_of)
         from dmlc_core_tpu.data.staging import (_device_put_maybe_donated,
                                                 _replicated_sharding)
-        with telemetry.span("h2d.stage_binned"):
-            with_qid = w["qid"] is not None
-            num_rows = np.int32(w["num_rows"])
-            leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
-                       w["ebin"], w["emask"], num_rows)
-                      + ((w["qid"],) if with_qid else ()))
-            donate = os.environ.get("DMLCTPU_BINCACHE_DONATE", "1") != "0"
-            if self._sharding is None:
-                staged = _device_put_maybe_donated(leaves, donate=donate)
-            else:
-                sh, repl = self._sharding, _replicated_sharding(
-                    self._sharding)
-                shardings = ((sh, sh, repl, sh, sh, sh, repl)
-                             + ((sh,) if with_qid else ()))
-                staged = _device_put_maybe_donated(leaves, shardings,
-                                                   donate=donate)
-            batch = BinnedBatch(
-                label=staged[0], weight=staged[1], row_ptr=staged[2],
-                index=staged[3], ebin=staged[4], emask=staged[5],
-                num_rows=staged[6],
-                qid=staged[7] if with_qid else None,
-                cuts_digest=(self._meta or {}).get(
-                    "cuts_digest", cuts_digest_of(self._binner.cuts)))
-            self.batches_staged += 1
-            return batch
+        with_qid = w["qid"] is not None
+        num_rows = np.int32(w["num_rows"])
+        leaves = ((w["label"], w["weight"], w["row_ptr"], w["index"],
+                   w["ebin"], w["emask"], num_rows)
+                  + ((w["qid"],) if with_qid else ()))
+        donate = os.environ.get("DMLCTPU_BINCACHE_DONATE", "1") != "0"
+        if self._sharding is None:
+            staged = _device_put_maybe_donated(leaves, donate=donate)
+        else:
+            sh, repl = self._sharding, _replicated_sharding(
+                self._sharding)
+            shardings = ((sh, sh, repl, sh, sh, sh, repl)
+                         + ((sh,) if with_qid else ()))
+            staged = _device_put_maybe_donated(leaves, shardings,
+                                               donate=donate)
+        batch = BinnedBatch(
+            label=staged[0], weight=staged[1], row_ptr=staged[2],
+            index=staged[3], ebin=staged[4], emask=staged[5],
+            num_rows=staged[6],
+            qid=staged[7] if with_qid else None,
+            cuts_digest=(self._meta or {}).get(
+                "cuts_digest", cuts_digest_of(self._binner.cuts)))
+        self.batches_staged += 1
+        return batch
 
     def __iter__(self) -> Iterator:
         from dmlc_core_tpu.data.staging import _staged_iter

@@ -37,6 +37,7 @@ import numpy as np
 from ..data.staging import PaddedBatch
 from ..ops.pallas_segment import check_force
 from ..ops.sparse import csr_matvec, slot_sums
+from .. import telemetry
 from .common import SGD, TouchedRowsMixin
 
 
@@ -79,14 +80,17 @@ class FieldAwareFactorizationMachine(TouchedRowsMixin):
         self._set_optimizer(SGD(learning_rate) if l2 == 0.0 else None)
 
     def init(self, seed: int = 0) -> dict:
-        key = jax.random.PRNGKey(seed)
-        return {
-            "w": jnp.zeros(self.num_features, jnp.float32),
-            "v": self.init_scale * jax.random.normal(
-                key, (self.num_features, self.num_fields, self.num_factors),
-                jnp.float32),
-            "b": jnp.zeros((), jnp.float32),
-        }
+        # to the tables' end, not their dispatch
+        with telemetry.span("model.init", total="model.init_us"):
+            key = jax.random.PRNGKey(seed)
+            return jax.block_until_ready({
+                "w": jnp.zeros(self.num_features, jnp.float32),
+                "v": self.init_scale * jax.random.normal(
+                    key,
+                    (self.num_features, self.num_fields, self.num_factors),
+                    jnp.float32),
+                "b": jnp.zeros((), jnp.float32),
+            })
 
     def margins(self, params: dict, batch: PaddedBatch) -> jax.Array:
         if batch.field is None:

@@ -23,6 +23,7 @@ from ..data.staging import PaddedBatch
 from ..ops.pallas_segment import check_force
 from ..ops.sparse import (csr_matmul, csr_matvec, csr_row_sumsq_matmul,
                           csr_row_sums)
+from .. import telemetry
 from .common import TouchedRowsMixin
 
 
@@ -86,7 +87,9 @@ class FactorizationMachine(TouchedRowsMixin):
             drawn, self.mesh.data_sharding())
 
     def init(self, seed: int = 0) -> dict:
-        return self.init_tables(self._fresh, seed)
+        # to the tables' end, not their dispatch
+        with telemetry.span("model.init", total="model.init_us"):
+            return jax.block_until_ready(self.init_tables(self._fresh, seed))
 
     def _fresh(self, seed) -> dict:
         return {

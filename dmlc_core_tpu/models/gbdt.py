@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -194,6 +193,7 @@ class QuantileBinner:
         # in missing_aware mode
         self.cuts: Optional[jax.Array] = None
 
+    @telemetry.span("binner.fit", total="binner.fit_us")
     def fit(self, sample: np.ndarray) -> "QuantileBinner":
         sample = np.asarray(sample, np.float32)
         if sample.ndim != 2:
@@ -235,6 +235,7 @@ class QuantileBinner:
 
     # ---- sparse (COO-entry) surface -----------------------------------------
 
+    @telemetry.span("binner.fit", total="binner.fit_us")
     def fit_sparse(self, index: np.ndarray, value: np.ndarray,
                    num_features: int) -> "QuantileBinner":
         """Per-feature quantile cuts from a COO sample (host sketch), the
@@ -1041,13 +1042,11 @@ class GBDT:
             return None
         num_shards = (1 if self.mesh_plan is None
                       else self.mesh_plan.num_shards)
-        t0 = time.monotonic()
-        with telemetry.span("gbdt.entry_sort"):
+        with telemetry.span("gbdt.entry_sort", total="gbdt.entry_sort_us"):
             layout = jax.block_until_ready(sparse_hist_layout(
                 row_id, findex, ebin, emask, self.num_features,
                 self.num_bins, num_shards=num_shards, rows=rows,
                 value=value, cuts=cuts))
-        counter_add("gbdt.entry_sort_us", int((time.monotonic() - t0) * 1e6))
         counter_add("gbdt.layout_bin", int(value is not None))
         counter_add("gbdt.sparse_hist_blocks",
                     int(np.asarray(layout.tcount).sum()))
@@ -2115,28 +2114,26 @@ class GBDT:
 
     def _shard_inputs(self, bins, label, weight):
         """Under a mesh plan, ``fit``'s row arrays laid out over the plan's
-        axes (span ``gbdt.shard_inputs``): ``shard_map``'s even-rows rule
-        checked here, by name, and each array placed with
-        ``plan.data_sharding()`` — one that already lies so is handed back
-        as it is, a host array or one on a single chip is divided once a
-        fit instead of once a tree."""
+        axes: ``shard_map``'s even-rows rule checked here, by name, and
+        each array placed with ``plan.data_sharding()`` — one that already
+        lies so is handed back as it is, a host array or one on a single
+        chip is divided once a fit instead of once a tree."""
         plan = self.mesh_plan
         if plan is None:
             return bins, label, weight
-        with telemetry.span("gbdt.shard_inputs"):
-            rows, shards = int(bins.shape[0]), plan.num_shards
-            if rows % shards:
-                raise ValueError(
-                    f"{rows} rows do not divide over the mesh plan's "
-                    f"{shards} shards: the kernel runs under shard_map, "
-                    "which wants as many rows on every shard")
-            sharding = plan.data_sharding()
-            return tuple(a if a is None else jax.device_put(a, sharding)
-                         for a in (bins, label, weight))
+        rows, shards = int(bins.shape[0]), plan.num_shards
+        if rows % shards:
+            raise ValueError(
+                f"{rows} rows do not divide over the mesh plan's "
+                f"{shards} shards: the kernel runs under shard_map, "
+                "which wants as many rows on every shard")
+        sharding = plan.data_sharding()
+        return tuple(a if a is None else jax.device_put(a, sharding)
+                     for a in (bins, label, weight))
 
     # ---- public API ---------------------------------------------------------
 
-    @telemetry.span("gbdt.fit")
+    @telemetry.span("gbdt.fit", total="gbdt.fit_us")
     def fit(self, bins: jax.Array, label: jax.Array,
             weight: Optional[jax.Array] = None,
             eval_set: Optional[tuple] = None,
@@ -2246,7 +2243,7 @@ class GBDT:
         return layout_bin_engages(binner.cuts.shape, self.num_features,
                                   batch.value.shape[0], shards)
 
-    @telemetry.span("gbdt.fit")
+    @telemetry.span("gbdt.fit", total="gbdt.fit_us")
     def fit_batch(self, batch, binner: QuantileBinner,
                   weight: Optional[jax.Array] = None,
                   eval_set=None, early_stopping_rounds: int = 0) -> dict:
@@ -2333,7 +2330,7 @@ class GBDT:
             eval_weight=eval_weight,
             early_stopping_rounds=early_stopping_rounds)
 
-    @telemetry.span("gbdt.fit")
+    @telemetry.span("gbdt.fit", total="gbdt.fit_us")
     def fit_streamed(self, batches, binner: QuantileBinner,
                      eval_set=None, early_stopping_rounds: int = 0,
                      staging_options: Optional[dict] = None) -> dict:
@@ -2663,7 +2660,7 @@ class GBDT:
                                                     *splits[4:6])
         return hist, tuple(splits), leaf
 
-    @telemetry.span("gbdt.fit")
+    @telemetry.span("gbdt.fit", total="gbdt.fit_us")
     def fit_paged(self, pages, label: jax.Array,
                   weight: Optional[jax.Array] = None, *, page_rows: int,
                   prefetch_pages: int = 2) -> dict:

@@ -261,7 +261,7 @@ class LeafwiseGBDT(GBDT):
 
     # ---- training -----------------------------------------------------------
 
-    @telemetry.span("gbdt.fit")
+    @telemetry.span("gbdt.fit", total="gbdt.fit_us")
     def fit(self, bins: jax.Array, label: jax.Array,
             weight: Optional[jax.Array] = None,
             eval_set: Optional[tuple] = None,

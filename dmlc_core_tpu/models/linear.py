@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from ..data.staging import PaddedBatch
 from ..ops.pallas_segment import check_force
 from ..ops.sparse import csr_matvec, csr_row_sums
+from .. import telemetry
 from .common import TouchedRowsMixin
 
 
@@ -48,7 +49,9 @@ class SparseLinearModel(TouchedRowsMixin):
         self._set_optimizer(optimizer, mesh)
 
     def init(self, seed: int = 0) -> dict:
-        return self.init_tables(self._fresh, seed)
+        # to the tables' end, not their dispatch
+        with telemetry.span("model.init", total="model.init_us"):
+            return jax.block_until_ready(self.init_tables(self._fresh, seed))
 
     def _fresh(self, seed) -> dict:
         del seed  # linear model: zero init is canonical

@@ -209,7 +209,10 @@ def trace_start() -> None:
 
 
 def _ring_start() -> None:
-    global _ring_on
+    global _ring_on, _registry_at_start
+    # what every counter read when recording began: a span closed before
+    # this trace left no event, but its ``total=`` is in here
+    _registry_at_start = snapshot().get("counters", {})
     _native.check(_native.lib().DmlcTpuTelemetryTraceStart())
     _ring_on = True
     _sync_marks.clear()
@@ -232,20 +235,35 @@ def trace_dump_json() -> str:
 def trace_dump() -> dict:
     """The ring's Chrome trace, parsed; ``otherData.clock_sync`` lists the
     steady-clock reading of every sync mark written since the ring started
-    (see :func:`to_profiler_ns`)."""
+    (see :func:`to_profiler_ns`), and ``otherData.registry_at_start`` every
+    counter as it read at that start: what the spans that closed BEFORE the
+    trace added to their ``total=`` counters (a span still open then is in
+    neither)."""
     doc = json.loads(trace_dump_json())
-    doc.setdefault("otherData", {})["clock_sync"] = list(_sync_marks)
+    other = doc.setdefault("otherData", {})
+    other["clock_sync"] = list(_sync_marks)
+    if _registry_at_start is not None:
+        other["registry_at_start"] = dict(_registry_at_start)
     return doc
 
 
-def record_span(name: str, ts_us: int, dur_us: int, lineage: int = -1) -> None:
+def record_span(name: str, ts_us: int, dur_us: int, lineage: int = -1,
+                total: Optional[str] = None) -> None:
     """Record one complete span into the active trace.  Timestamps are
     steady-clock microseconds — ``time.monotonic_ns() // 1000`` on Linux
     shares an epoch with the native spans, so Python and C++ spans line up
     on one timeline.  ``lineage`` (see :func:`lineage`) is the batch the
-    span handled and goes out as ``args.lineage``."""
-    _native.check(_native.lib().DmlcTpuTelemetryRecordSpanLineage(
-        name.encode(), int(ts_us), int(dur_us), int(lineage)))
+    span handled and goes out as ``args.lineage``.  ``total`` names the
+    counter that keeps this span's total: ``dur_us`` is added to it in the
+    same native call, tracing on or off."""
+    _span_end(name, ts_us, dur_us, lineage, total, False)
+
+
+def _span_end(name: str, ts_us: int, dur_us: int, lineage: int,
+              total: Optional[str], main_outermost: bool) -> None:
+    _native.check(_native.lib().DmlcTpuTelemetryRecordSpanTotal(
+        name.encode(), int(ts_us), int(dur_us), int(lineage),
+        total.encode() if total else None, int(main_outermost)))
 
 
 # ---- the ring beside a jax.profiler session -----------------------------------
@@ -264,6 +282,7 @@ _ring_on = False                # the ring is recording
 _ring_seen = None               # the profiler session last seen, or None
 _ring_ours = False              # the ring was started for it: stop it with it
 _ring_lock = threading.Lock()
+_registry_at_start: Optional[Dict[str, int]] = None     # see trace_dump()
 
 
 def _follow_profiler(profiler) -> None:
@@ -357,16 +376,31 @@ class _Span:
         self.lineage = lineage
 
 
+_main_depth = 0     # spans open on the main thread (only it writes this)
+_main_ident = threading.main_thread().ident
+
+
 @contextlib.contextmanager
-def span(name: str, lineage: int = -1) -> Iterator[_Span]:
+def span(name: str, lineage: int = -1,
+         total: Optional[str] = None) -> Iterator[_Span]:
     """One span, two sinks.  The body is recorded into the native ring
     (steady clock; ``trace_dump()``, Perfetto) when tracing is on, and — in
     a process where jax is already imported — also as the profiler
     annotation ``dmlctpu.<name>``, which a running ``jax.profiler`` trace
     puts on the device trace's clock (a flag test while none runs).  The
-    ring's copy carries ``lineage``, the batch the span handled."""
+    ring's copy carries ``lineage``, the batch the span handled.
+
+    ``total`` names the counter that keeps the span's total: its duration,
+    the one pair of clock reads taken here, is added to it at exit whether
+    tracing is on or off, so a stretch is never timed twice to have both.
+    The outermost span open on the main thread adds the same duration to
+    ``main.span_us``: nested spans count once, other threads' not at all."""
+    global _main_depth
     note = _profiler_annotation(name)
     this = _Span(lineage)
+    on_main = threading.get_ident() == _main_ident
+    if on_main:
+        _main_depth += 1
     t0 = now_us()
     try:
         if note is None:
@@ -375,7 +409,11 @@ def span(name: str, lineage: int = -1) -> Iterator[_Span]:
             with note:
                 yield this
     finally:
-        record_span(name, t0, now_us() - t0, this.lineage)
+        dur_us = now_us() - t0
+        if on_main:
+            _main_depth -= 1
+        _span_end(name, t0, dur_us, this.lineage, total,
+                  on_main and _main_depth == 0)
 
 
 # ---- trace context (job-wide causality) -------------------------------------

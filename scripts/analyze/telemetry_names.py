@@ -2,8 +2,8 @@
 
 Metric names are string literals minted in C++ (`telemetry.h` stage
 accessors, direct `Registry::Get()->counter("...")` sites) and in Python
-(`telemetry.counter_add("...")`, `depth_gauge="..."` kwargs, the
-stall-attribution read sites).  The public contract is the "Metric name
+(`telemetry.counter_add("...")`, `depth_gauge="..."` and a span's
+`total="..."` kwargs, the stall-attribution read sites).  The public contract is the "Metric name
 contract" table in doc/observability.md, and every name must also survive
 the mechanical Prometheus mapping in telemetry_http.py.  Checked:
 
@@ -33,6 +33,8 @@ PY_CALL_RE = re.compile(
     r'\b(counter_add|counter_get|gauge_set|gauge_add|gauge_get)\(\s*'
     r'"([^"]+)"', re.S)
 PY_KWARG_RE = re.compile(r'depth_gauge\s*=\s*"([^"]+)"')
+# the counter a span keeps its total in: span(..., total="x.y_us")
+PY_TOTAL_RE = re.compile(r'\btotal\s*=\s*"([^"]+)"')
 # stall_attribution read sites in telemetry.py: d.get("x.y"), us("x.y"),
 # and the ("stage", "busy", "wait") contract tuples
 PY_READ_RE = re.compile(r'(?:\.get|\bus)\(\s*"([a-z0-9_.]+)"')
@@ -84,6 +86,8 @@ def harvest(root: Path) -> dict[str, list[tuple[str, int, str]]]:
             add(m.group(2), rpath, line_of(text, m.start()), KIND[m.group(1)])
         for m in PY_KWARG_RE.finditer(text):
             add(m.group(1), rpath, line_of(text, m.start()), "gauge")
+        for m in PY_TOTAL_RE.finditer(text):
+            add(m.group(1), rpath, line_of(text, m.start()), "counter")
         if p.name == "telemetry.py":
             for m in PY_READ_RE.finditer(text):
                 add(m.group(1), rpath, line_of(text, m.start()), "read")

@@ -763,3 +763,204 @@ def test_a_callers_trace_outlives_a_profiler_session(monkeypatch):
         assert _ring_names() == ["test.mine", "test.during", "test.later"]
     finally:
         telemetry.trace_stop()
+
+
+# ---- spans that keep their own total; what came before a trace -----------------
+
+def _moved(names, before):
+    return {k: telemetry.counter_get(k) - before[k] for k in names}
+
+
+@pytest.mark.parametrize("ring", ["off", "on"])
+def test_a_span_adds_its_duration_to_its_total(ring):
+    """``total=`` on ``span`` and ``record_span``: the span's own duration
+    goes to the counter, whether the ring records or not, and the ring's
+    copy (when there is one) holds the same number."""
+    if not telemetry.enabled():
+        pytest.skip("counters are compiled out")
+    names = ("test.total_us", "test.recorded_total_us")
+    before = {k: telemetry.counter_get(k) for k in names}
+    telemetry.trace_stop()
+    if ring == "on":
+        telemetry.trace_start()
+    try:
+        with telemetry.span("test.total", total="test.total_us"):
+            time.sleep(0.002)
+        telemetry.record_span("test.recorded_total", telemetry.now_us(), 41,
+                              total="test.recorded_total_us")
+        with telemetry.span("test.no_total"):
+            pass
+    finally:
+        telemetry.trace_stop()
+    got = _moved(names, before)
+    assert got["test.total_us"] >= 2000
+    assert got["test.recorded_total_us"] == 41
+    events = {e["name"]: e for e in telemetry.trace_dump()["traceEvents"]}
+    if ring == "on":
+        assert events["test.total"]["dur"] == got["test.total_us"]
+        assert events["test.recorded_total"]["dur"] == 41
+        assert "test.no_total" in events
+    else:       # an earlier trace's events stay in the ring; none of these
+        assert not {"test.total", "test.recorded_total",
+                    "test.no_total"} & set(events)
+
+
+def test_nested_spans_add_to_main_span_us_once_and_other_threads_never():
+    if not telemetry.enabled():
+        pytest.skip("counters are compiled out")
+    import threading
+    names = ("main.span_us", "test.outer_us", "test.inner_us",
+             "test.thread_us")
+    before = {k: telemetry.counter_get(k) for k in names}
+    with telemetry.span("test.outer", total="test.outer_us"):
+        with telemetry.span("test.inner", total="test.inner_us"):
+            time.sleep(0.002)
+        with telemetry.span("test.inner", total="test.inner_us"):
+            time.sleep(0.001)
+    got = _moved(names, before)
+    assert got["test.inner_us"] >= 3000
+    assert got["test.outer_us"] >= got["test.inner_us"]
+    assert got["main.span_us"] == got["test.outer_us"]     # outermost only
+
+    def on_a_thread():
+        with telemetry.span("test.thread", total="test.thread_us"):
+            time.sleep(0.002)
+
+    worker = threading.Thread(target=on_a_thread)
+    worker.start()
+    worker.join(timeout=30)
+    assert not worker.is_alive()
+    after = _moved(names, before)
+    assert after["test.thread_us"] >= 2000
+    assert after["main.span_us"] == got["main.span_us"]
+    # a span that raises still closes: depth and total
+    with pytest.raises(KeyError):
+        with telemetry.span("test.outer", total="test.outer_us"):
+            raise KeyError("in the body")
+    assert telemetry._main_depth == 0
+    assert _moved(names, before)["main.span_us"] >= after["main.span_us"]
+
+
+def _feed_epoch(tmp_path):
+    path = tmp_path / "feed.libsvm"
+    path.write_text("".join(
+        f"{i % 2} {i % 7}:1.5 {7 + i % 5}:0.5\n" for i in range(1500)))
+    it = dt.DeviceStagingIter(str(path), batch_size=64, nnz_bucket=128,
+                              num_workers=2)
+    assert sum(int(b.num_rows) for b in it) == 1500
+
+
+def _page_pass(tmp_path):
+    from dmlc_core_tpu.data import PagePrefetcher
+    import jax.numpy as jnp
+
+    def source():       # slower than its consumer: every page is waited for
+        for i in range(3):
+            time.sleep(0.01)
+            yield np.full((8, 3), i, np.uint8)
+
+    with PagePrefetcher(source, passes=1, page_rows=8, num_features=3,
+                        depth=1) as feed:
+        for _index, _rows, page in feed.pages():
+            feed.release(page, jnp.zeros(()))
+
+
+def _entry_sort(tmp_path):
+    import jax.numpy as jnp
+    from dmlc_core_tpu.models import GBDT
+    rng = np.random.default_rng(0)
+    n = 200
+    model = GBDT(num_features=6, num_trees=1, max_depth=2, num_bins=8,
+                 missing_aware=True, histogram="pallas")
+    layout = model._sparse_fit_layout(
+        jnp.asarray(np.sort(rng.integers(0, 40, n)), jnp.int32),
+        jnp.asarray(rng.integers(0, 6, n), jnp.int32),
+        jnp.asarray(rng.integers(1, 8, n), jnp.int32),
+        jnp.ones(n, bool), rows=40)
+    assert layout is not None
+
+
+@pytest.mark.parametrize("span, counter, drive, exact", [
+    ("feed.wait", "h2d.consumer_wait_us", _feed_epoch, True),
+    # the stager's last wait, which finds the stream's end, has no batch to
+    # be a span of: the counter holds it besides
+    ("h2d.host_wait", "h2d.wait_us", _feed_epoch, False),
+    ("h2d.emit_wait", "h2d.emit_wait_us", _feed_epoch, True),
+    ("page.wait", "page.wait_us", _page_pass, True),
+    ("page.h2d", "page.h2d_busy_us", _page_pass, True),
+    ("gbdt.entry_sort", "gbdt.entry_sort_us", _entry_sort, True),
+])
+def test_a_rewired_site_moves_its_counter_by_its_spans(tmp_path, span,
+                                                        counter, drive, exact):
+    """The six places that timed one stretch twice: the counter each kept
+    by hand is its span's ``total=`` now and reads what the ring reads."""
+    if not telemetry.enabled():
+        pytest.skip("counters are compiled out")
+    before = telemetry.counter_get(counter)
+    telemetry.trace_start()
+    try:
+        drive(tmp_path)
+    finally:
+        telemetry.trace_stop()
+    moved = telemetry.counter_get(counter) - before
+    durs = [e["dur"] for e in telemetry.trace_dump()["traceEvents"]
+            if e["name"] == span]
+    assert durs and moved > 0
+    if exact:
+        assert moved == sum(durs)
+    else:
+        assert moved >= sum(durs)
+
+
+def test_registry_at_start_holds_what_came_before_the_trace():
+    if not telemetry.enabled():
+        pytest.skip("counters are compiled out")
+    telemetry.trace_stop()
+    with telemetry.span("test.setup_phase", total="test.setup_phase_us"):
+        time.sleep(0.001)
+    telemetry.counter_add("test.bumped_before", 3)
+    held = telemetry.counter_get("test.bumped_before")
+    late = telemetry.counter_get("test.bumped_after")
+    telemetry.trace_start()
+    try:
+        telemetry.counter_add("test.bumped_before", 5)
+        telemetry.counter_add("test.bumped_after", 7)
+        at_start = telemetry.trace_dump()["otherData"]["registry_at_start"]
+    finally:
+        telemetry.trace_stop()
+    assert at_start["test.bumped_before"] == held
+    assert at_start.get("test.bumped_after", 0) == late
+    assert at_start["test.setup_phase_us"] >= 1000
+    assert at_start == telemetry.trace_dump()["otherData"]["registry_at_start"]
+    assert all(isinstance(v, int) for v in at_start.values())   # counters only
+
+
+def test_the_ring_beside_a_profiler_keeps_the_registry_of_its_start(
+        monkeypatch):
+    """The benchmark's case: set-up, then a profiler session; the first span
+    that sees the session starts the ring and keeps the snapshot — with what
+    closed before it, without itself."""
+    if not telemetry.enabled():
+        pytest.skip("counters are compiled out")
+    import jax      # noqa: F401  (telemetry follows jax only where it is loaded)
+    from jax._src import profiler as jax_profiler
+    telemetry.trace_stop()
+    with telemetry.span("test.in_setup", total="test.in_setup_us"):
+        time.sleep(0.001)
+    in_setup = telemetry.counter_get("test.in_setup_us")
+    in_window = telemetry.counter_get("test.in_window_us")
+    monkeypatch.setattr(jax_profiler._profile_state, "profile_session",
+                        object())
+    try:
+        with telemetry.span("test.in_window", total="test.in_window_us"):
+            time.sleep(0.001)
+        assert telemetry._ring_on and telemetry._ring_ours
+        at_start = telemetry.trace_dump()["otherData"]["registry_at_start"]
+    finally:
+        monkeypatch.setattr(jax_profiler._profile_state, "profile_session",
+                            None)
+        with telemetry.span("test.after_window"):
+            pass
+    assert at_start["test.in_setup_us"] == in_setup
+    assert at_start.get("test.in_window_us", 0) == in_window
+    assert telemetry.counter_get("test.in_window_us") >= in_window + 1000
