@@ -43,7 +43,8 @@ from .. import telemetry
 from .common import count_predict_retrace
 from ..ops import pallas_segment
 from ..ops.pallas_segment import (HIST_NODE_LIMIT, SPARSE_HIST_NODE_LIMIT,
-                                  entry_values, histogram_gh,
+                                  entry_values, hist_dead_key_tiles,
+                                  histogram_gh,
                                   histogram_gh_sparse_kernel,
                                   layout_bin_engages, push_to_rows,
                                   route_push_engages, run_spans, segment_sum,
@@ -974,6 +975,16 @@ class GBDT:
             return "pallas"
         return "xla"
 
+    def _dead_key_tiles(self, depth: int) -> int:
+        """Key tiles of padding that ONE dense kernel call for the level at
+        ``depth`` is planned with and leaves out (``hist_dead_key_tiles`` at
+        the level's built columns); 0 where the level's backend is not the
+        kernel.  What the counter ``gbdt.hist_dead_key_tiles`` sums."""
+        if self._hist_impl(2 ** depth) != "pallas":
+            return 0
+        return hist_dead_key_tiles(self.num_features, self.num_bins,
+                                   _built_columns(depth))
+
     def level_backends(self, sparse: bool = False) -> list:
         """The histogram backend ("pallas" or "xla") each level of a fit
         resolves to on this process's devices, root level first —
@@ -1268,19 +1279,29 @@ class GBDT:
         """The span ``gbdt.tree`` around one boosting round's ``trees`` calls
         of ``build_tree``, which also counts the node histograms those trees
         build and derive (``gbdt.hist_nodes_built``, ``gbdt.hist_nodes_derived``:
-        `_built_columns`); under a mesh plan it also counts the reductions
-        those calls run (``MeshPlan.counting``), under the name of the fit
-        that made ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one
-        tree program each)."""
+        `_built_columns`) and, for the resident dense builder (``GBDT.fit``:
+        one kernel call a level and shard), the padding key tiles those
+        calls leave out (``gbdt.hist_dead_key_tiles``: `_dead_key_tiles`;
+        `fit_paged` counts its own, a page visit at a time); under a mesh
+        plan it also counts the reductions those calls run
+        (``MeshPlan.counting``), under the name of the fit that made
+        ``build_tree`` (``GBDT.fit``, ``GBDT.fit_batch``, ...: one tree
+        program each)."""
         span = telemetry.span("gbdt.tree")
         built = trees * sum(map(_built_columns, range(self.max_depth)))
         counter_add("gbdt.hist_nodes_built", built)
         counter_add("gbdt.hist_nodes_derived",
                     trees * (2 ** self.max_depth - 1) - built)
-        if self.mesh_plan is None:
-            return span
+        plan = self.mesh_plan
         kind = getattr(build_tree, "__qualname__", "").split(".<locals>")[0]
-        return self.mesh_plan.counting(kind, trees, around=span)
+        if kind == "GBDT.fit":
+            counter_add("gbdt.hist_dead_key_tiles",
+                        trees * (1 if plan is None else plan.num_shards)
+                        * sum(map(self._dead_key_tiles,
+                                  range(self.max_depth))))
+        if plan is None:
+            return span
+        return plan.counting(kind, trees, around=span)
 
     def _boost(self, label: jax.Array, w: jax.Array, build_tree,
                eval_margin=None, eval_label=None, eval_weight=None,
@@ -2739,6 +2760,9 @@ class GBDT:
                         visited, streamed = visited + 1, streamed + held
                 counter_add("gbdt.page_passes", 1)
                 counter_add("gbdt.rows_streamed", streamed)
+                if depth < self.max_depth:
+                    counter_add("gbdt.hist_dead_key_tiles",
+                                visited * self._dead_key_tiles(depth))
                 if (visited, streamed) != (n_pages, rows):
                     raise ValueError(
                         f"fit_paged: a pass gave {visited} pages of "

@@ -270,7 +270,9 @@ class LeafwiseGBDT(GBDT):
         on one device.  The counters ``gbdt.expansions``,
         ``gbdt.hist_rows_visited``, ``gbdt.leaves`` and ``gbdt.depth_max``
         are device scalars a tree, added once the last tree is built: the
-        call returns when the fit has finished."""
+        call returns when the fit has finished.  With them, from the plan
+        and the expansions, ``gbdt.hist_dead_key_tiles`` (`GBDT._tree_span`
+        tells it for the depth-wise builder)."""
         label = label.astype(jnp.float32)
         w = (jnp.ones_like(label) if weight is None
              else weight.astype(jnp.float32))
@@ -296,6 +298,12 @@ class LeafwiseGBDT(GBDT):
         counter_add("gbdt.hist_rows_visited", int(grown[1]))
         counter_add("gbdt.leaves", int(grown[2]))
         counter_add("gbdt.depth_max", int(grown[3]))
+        # a segment's histogram (the root's, then one an expansion) is at
+        # least one kernel call of one node column, as the root level's is;
+        # the whole chunks of a segment past `_SEGMENT_CHUNK` rows are calls
+        # the host cannot count
+        counter_add("gbdt.hist_dead_key_tiles",
+                    (len(stats) + int(grown[0])) * self._dead_key_tiles(0))
         return params
 
     def fit_batch(self, *args, **kwargs):

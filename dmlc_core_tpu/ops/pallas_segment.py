@@ -243,51 +243,67 @@ def _hist_kernel(plan, bins_ref, rel_ref, gh_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    rt = rel_ref.shape[1]
-    # Padding rows carry rel == n_pad (matches no node column) AND gh == 0:
-    # whatever bins the ragged last block holds there is inert.
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, rt), 0)
-    mask = jnp.broadcast_to(rel_ref[...], (n_pad, rt)) == node_ids
-    a_t = jnp.concatenate(
-        [jnp.where(mask, jnp.broadcast_to(gh_ref[i:i + 1, :], (n_pad, rt)),
-                   0.0) for i in range(6)], axis=0).astype(jnp.bfloat16)
-    lanes = min(nb, w)
-    loc = jax.lax.broadcasted_iota(jnp.int32, (lanes, rt), 0)
+    def step(tiles):
+        """A^T once, then the step's first ``tiles`` key tiles."""
+        rt = rel_ref.shape[1]
+        # Padding rows carry rel == n_pad (matches no node column) AND gh ==
+        # 0: whatever bins the ragged last block holds there is inert.
+        node_ids = jax.lax.broadcasted_iota(jnp.int32, (n_pad, rt), 0)
+        mask = jnp.broadcast_to(rel_ref[...], (n_pad, rt)) == node_ids
+        a_t = jnp.concatenate(
+            [jnp.where(mask,
+                       jnp.broadcast_to(gh_ref[i:i + 1, :], (n_pad, rt)), 0.0)
+             for i in range(6)], axis=0).astype(jnp.bfloat16)
+        lanes = min(nb, w)
+        loc = jax.lax.broadcasted_iota(jnp.int32, (lanes, rt), 0)
 
-    def one_hot(feature, offset):
-        # bins_ref is every feature or an 8-feature block (see in_specs);
-        # a padding feature past the last reads some row: its keys are cut
-        row = bins_ref[pl.ds(feature % plan.fb, 1), :]          # [1, rt]
-        hit = loc == jnp.broadcast_to(row, (lanes, rt)) - offset
-        return jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)
+        def one_hot(feature, offset):
+            # bins_ref is every feature or an 8-feature block (see
+            # in_specs); a padding feature past the last, in a tile that
+            # also holds live ones, reads some row: its keys are cut
+            row = bins_ref[pl.ds(feature % plan.fb, 1), :]      # [1, rt]
+            hit = loc == jnp.broadcast_to(row, (lanes, rt)) - offset
+            return jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)
 
-    dot = functools.partial(jax.lax.dot_general,
-                            dimension_numbers=(((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    m = 2 * n_pad
-    for j in range(plan.tiles):
-        kt = first_tile + j
-        if q == 1:      # tile kt: features kt * fpt + fl, one after another
-            b_t = jnp.concatenate(
-                [one_hot(kt * fpt + fl, 0) for fl in range(fpt)], axis=0)
-        else:           # tile kt: slice kt % q of feature kt // q
-            b_t = one_hot(kt // q, (kt % q) * w)
-        if plan.parts == 3:
-            acc = dot(a_t, b_t)
-        else:
-            acc = sum(dot(a_t[p * m:(p + 1) * m], b_t) for p in range(3))
-        out_ref[:, j * w:(j + 1) * w] += acc
+        dot = functools.partial(jax.lax.dot_general,
+                                dimension_numbers=(((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        m = 2 * n_pad
+        for j in range(tiles):
+            kt = first_tile + j
+            if q == 1:  # tile kt: features kt * fpt + fl, one after another
+                b_t = jnp.concatenate(
+                    [one_hot(kt * fpt + fl, 0) for fl in range(fpt)], axis=0)
+            else:       # tile kt: slice kt % q of feature kt // q
+                b_t = one_hot(kt // q, (kt % q) * w)
+            if plan.parts == 3:
+                acc = dot(a_t, b_t)
+            else:
+                acc = sum(dot(a_t[p * m:(p + 1) * m], b_t) for p in range(3))
+            out_ref[:, j * w:(j + 1) * w] += acc
+
+    # The last group may be short: its tiles from ``live_kt`` on hold no
+    # feature, and its steps do nothing for them (no one-hot, no dot, no
+    # add: their out lanes keep `_init`'s zeros and are cut by the caller).
+    # The WHOLE step is under one of two branches, not the tail of its tile
+    # loop: a branch inside the loop cost every step of the full groups more
+    # than the last group's saved (PERF.md, PR 51).  A plan without such
+    # tiles has no branch.
+    dead = plan.num_kt - plan.live_kt
+    if not dead:
+        step(plan.tiles)
+    else:
+        last = pl.program_id(0) == pl.num_programs(0) - 1
+        pl.when(jnp.logical_not(last))(lambda: step(plan.tiles))
+        pl.when(last)(lambda: step(plan.tiles - dead))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_nodes", "num_bins", "interpret"))
-def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
-                         n_nodes: int, num_bins: int,
-                         interpret: bool) -> jax.Array:
-    """bins_t: [F, rows] int32; rel: [rows] int32 node ids; gh: [rows, 2].
-    Returns [n_nodes, F, num_bins, 2]."""
-    F, rows = bins_t.shape
-    p = _hist_plan(F, num_bins, n_nodes)
+def _hist_key_lanes(p: "_HistPlan", bins_t: jax.Array, rel: jax.Array,
+                    gh: jax.Array, interpret: bool) -> jax.Array:
+    """`_hist_kernel` over the grid of plan ``p``: float32
+    ``[parts * 2 * n_pad, num_kt * w]``, every key lane the plan has, those
+    of its padding tiles (zeros) too."""
+    rows = bins_t.shape[1]
     k_pad = p.num_kt * p.w
     m_pad = 2 * p.parts * p.n_pad
     rows_pad = pl.cdiv(max(rows, 1), _HIST_ROW_TILE) * _HIST_ROW_TILE
@@ -301,7 +317,7 @@ def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
         # the block that holds step g's features: its first tile's first
         return ((g * p.tiles * p.fpt // p.q) // p.fb, rt)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_hist_kernel, p),
         grid=(p.num_kt // p.tiles, rows_pad // _HIST_ROW_TILE),
         in_specs=[
@@ -314,8 +330,20 @@ def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
         interpret=interpret,
         name=DENSE_HIST_KERNEL,
     )(bins_t, rel_p, gh_p)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_nodes", "num_bins", "interpret"))
+def _histogram_gh_pallas(bins_t: jax.Array, rel: jax.Array, gh: jax.Array,
+                         n_nodes: int, num_bins: int,
+                         interpret: bool) -> jax.Array:
+    """bins_t: [F, rows] int32; rel: [rows] int32 node ids; gh: [rows, 2].
+    Returns [n_nodes, F, num_bins, 2]."""
+    F = bins_t.shape[0]
+    p = _hist_plan(F, num_bins, n_nodes)
+    out = _hist_key_lanes(p, bins_t, rel, gh, interpret)
     with jax.named_scope("ops.hist_layout"):
-        return (out.reshape(p.parts, 2, p.n_pad, k_pad // p.nb, p.nb).sum(0)
+        return (out.reshape(p.parts, 2, p.n_pad, -1, p.nb).sum(0)
                 [:, :n_nodes, :F, :num_bins]
                 .transpose(1, 2, 3, 0))                 # [n, F, B, 2]
 
@@ -1378,7 +1406,8 @@ class _HistPlan:
     w: int        # key lanes a tile
     fpt: int      # whole features a tile (when q == 1)
     q: int        # tiles a feature (when fpt == 1)
-    num_kt: int   # key tiles in all
+    num_kt: int   # key tiles in all, whole groups of ``tiles``
+    live_kt: int  # the first of them that hold a feature; the rest do no work
     n_pad: int    # node columns, padded to 8
     parts: int    # bfloat16 parts that are rows of one dot: 3, else 1
     tiles: int    # key tiles a grid step: all of them, else a power of 2
@@ -1407,11 +1436,13 @@ def _hist_plan(num_features: int, num_bins: int, n_nodes: int) -> _HistPlan:
     a power of two, because bins then stream in 8-feature blocks (the
     smallest legal sublane tile): a group's features never straddle a
     block, and the kernel indexes inside it with pl.ds.  ``num_kt`` is
-    rounded up to whole groups; the padding tiles' keys are sliced off."""
+    rounded up to whole groups; the padding tiles past ``live_kt`` (67
+    features of 256 bins: 5 of 72) make the last group short: the kernel
+    does nothing for them, and their keys, zeros, are sliced off."""
     nb = max(1 << max(num_bins - 1, 1).bit_length(), _HIST_MIN_STRIDE)
     w = min(max(nb, 256), 512)
     fpt, q = (w // nb, 1) if nb <= w else (1, nb // w)
-    num_kt = pl.cdiv(num_features * nb, w)
+    num_kt = live_kt = pl.cdiv(num_features * nb, w)
     n_pad = pl.cdiv(n_nodes, 8) * 8
     parts = 3 if n_pad <= _HIST_STACK_NODES else 1
 
@@ -1427,8 +1458,17 @@ def _hist_plan(num_features: int, num_bins: int, n_nodes: int) -> _HistPlan:
         while tiles > 1 and not fits(tiles):
             tiles //= 2
         num_kt = pl.cdiv(num_kt, tiles) * tiles
-    return _HistPlan(nb=nb, w=w, fpt=fpt, q=q, num_kt=num_kt, n_pad=n_pad,
-                     parts=parts, tiles=tiles, fb=fb)
+    return _HistPlan(nb=nb, w=w, fpt=fpt, q=q, num_kt=num_kt,
+                     live_kt=live_kt, n_pad=n_pad, parts=parts, tiles=tiles,
+                     fb=fb)
+
+
+def hist_dead_key_tiles(num_features: int, num_bins: int,
+                        n_nodes: int) -> int:
+    """Padding key tiles one dense kernel call of these shapes is planned
+    with and does no work for (`_hist_plan`: ``num_kt - live_kt``)."""
+    p = _hist_plan(num_features, num_bins, n_nodes)
+    return p.num_kt - p.live_kt
 
 
 def _split_bf16x3(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:

@@ -39,7 +39,7 @@ def test_rehearsal_passes_and_says_what_it_is(tmp_path):
     assert all(p["ok"] for p in summary["phases"].values())
     assert summary["phases"]["mesh"]["skipped"]
     # every kernel ran, and said it was interpreted (off the chip it must be)
-    assert len(summary["kernels"]) == 12
+    assert len(summary["kernels"]) == 14
     assert all(k["interpret"] for k in summary["kernels"])
     # the last four: the entry lookup against XLA's gather and the entries'
     # push against its scatter-add, bit for bit
@@ -59,6 +59,12 @@ def test_rehearsal_passes_and_says_what_it_is(tmp_path):
     binning = summary["kernels"][11]
     assert binning["kernel"] == "layout_bin" and binning["exact"]
     assert binning["checked"] > binning["runs"]
+    # then the dense kernel where its last group of key tiles is short
+    short = summary["kernels"][12:]
+    assert [(k["kernel"], k["n_nodes"], k["dead_key_tiles"]) for k in short
+            ] == [("histogram_gh(short_group)", 8, 5),
+                  ("histogram_gh(short_group)", 64, 5)]
+    assert all(k["rel_err"] <= 7e-7 for k in short)
     # data and outputs stay under --out, and the large inputs are removed
     assert sorted(p.name for p in (tmp_path / "out").iterdir()) == [
         "forest.ckpt", "summary.json"]

@@ -218,6 +218,24 @@ def test_histogram_rows_visited_is_rows_plus_smaller_children():
     assert visited < model.num_trees * rows * (1 + np.log2(L))
 
 
+@pytest.mark.parametrize("histogram, F, dead", [
+    ("pallas", 67, 5), ("pallas", 24, 0), ("xla", 67, 0)])
+def test_dead_key_tiles_are_told_a_segment(histogram, F, dead):
+    """``gbdt.hist_dead_key_tiles``: the padding key tiles the dense kernel's
+    plan has at the fit's width (67 features of 256 bins: 5 of 72; none
+    where every tile holds a feature) once a segment histogram, the root's
+    and one an expansion; nothing where no kernel runs."""
+    rows, B, L = 700, 256, 4
+    bins, y = make_data(51, rows, F, B)
+    model = leafwise(F, B, L, num_trees=1, min_child_weight=3.0,
+                     histogram=histogram)
+    before = telemetry.snapshot()
+    model.fit(jnp.asarray(bins), jnp.asarray(y))
+    moved = telemetry.counters_delta(before, telemetry.snapshot())
+    assert moved["gbdt.expansions"] == L - 1
+    assert moved.get("gbdt.hist_dead_key_tiles", 0) == dead * L
+
+
 def test_constraints_hold_and_growth_stops_on_an_empty_frontier():
     """A minimum hessian mass that a few hundred rows cannot meet twice:
     growth stops short of ``max_leaves`` with no child under the minimum,

@@ -29,7 +29,8 @@ EXACT = ("feature", "threshold", "default_right", "trees_used")
 CLOSE = {"split_gain": 3e-5, "split_cover": 1e-5, "leaf": 3e-5, "base": 1e-5}
 
 
-def seeded_rows(rows: int, features: int = FEATURES, seed: int = 0):
+def seeded_rows(rows: int, features: int = FEATURES, seed: int = 0,
+                bins: int = BINS):
     """Binned rows with absent cells and a label that a few thresholds
     make; an absent cell counts as 0, so default directions matter.  (No
     term on absence itself: "absent right, every cut left" and "absent
@@ -41,7 +42,7 @@ def seeded_rows(rows: int, features: int = FEATURES, seed: int = 0):
     y = ((x[:, 0] > 0.2) ^ (np.nan_to_num(x[:, 3]) < -0.3)
          | (np.nan_to_num(x[:, 5]) > 0.8) & (x[:, 1] > 0)
          | (rng.random(rows) < 0.05)).astype(np.float32)
-    binner = QuantileBinner(num_bins=BINS, missing_aware=True)
+    binner = QuantileBinner(num_bins=bins, missing_aware=True)
     return np.asarray(binner.fit_transform(x)), y
 
 
@@ -258,6 +259,33 @@ def test_page_visit_through_the_kernel_grows_the_same_forest():
     paged = model.fit_paged(pages_of(bins, 1024), jnp.asarray(y),
                             page_rows=1024)
     assert_same_forest(paged, resident)
+
+
+@pytest.mark.parametrize("histogram, dead", [("pallas", 5), ("xla", 0)])
+def test_paged_fit_at_a_short_last_group_of_key_tiles(histogram, dead):
+    """The paged cell's plan (67 features of 256 bins: 72 key tiles in nine
+    groups, 5 of them padding, which the kernel leaves out): the paged fit
+    still grows the resident fit's forest, and both tell the tiles their
+    kernel calls left out: 5 a call, one call a level and page (a level of
+    the resident tree); none where no kernel runs."""
+    rows, page_rows, depth = 2500, 1024, 3
+    bins, y = seeded_rows(rows, features=67, seed=51, bins=256)
+    model = model_of(features=67, num_trees=1, max_depth=depth, num_bins=256,
+                     histogram=histogram)
+
+    def told(fit):
+        before = telemetry.counter_get("gbdt.hist_dead_key_tiles")
+        forest = fit()
+        return forest, telemetry.counter_get(
+            "gbdt.hist_dead_key_tiles") - before
+
+    resident, by_fit = told(lambda: model.fit(jnp.asarray(bins),
+                                              jnp.asarray(y)))
+    paged, by_pages = told(lambda: model.fit_paged(
+        pages_of(bins, page_rows), jnp.asarray(y), page_rows=page_rows))
+    assert_same_forest(paged, resident)
+    assert by_fit == dead * depth
+    assert by_pages == dead * depth * 3         # three pages a pass
 
 
 def reference():

@@ -464,6 +464,8 @@ HIST_F64_CASES = [
     (64, 28, 256, 1200), (64, 5, 16, 700), (128, 28, 256, 1100),
     (128, 5, 1024, 300), (512, 5, 16, 2050), (512, 28, 256, 1200),
     (512, 3, 2048, 130), (40, 3, 300, 999), (24, 7, 2, 300),
+    # a short last group: 67 key tiles in nine groups of 8, 5 tiles dead
+    (64, 67, 256, 1200), (8, 67, 256, 2100),
 ]
 
 
@@ -477,6 +479,51 @@ def test_dense_histogram_kernel_is_float32_exact_against_float64(
                        n_nodes, num_bins, force="pallas")
     assert got.shape == want.shape and got.dtype == jnp.float32
     assert _worst(got, want) < HIST_F64_LIMIT
+
+
+@pytest.mark.parametrize("F,num_bins,live,num_kt", [
+    (67, 256, 67, 72), (130, 256, 130, 136), (130, 16, 33, 34),
+    (28, 256, 28, 28), (13, 256, 13, 13), (2000, 256, 2000, 2000)])
+def test_dense_histogram_plan_counts_its_live_key_tiles(F, num_bins, live,
+                                                        num_kt):
+    """The plan says which of its key tiles hold a feature: whole groups on
+    the grid (``num_kt``), of which the first ``live_kt`` are real; at every
+    node count the same, since the groups are of features."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    for n_nodes in (1, 8, 16, 32, 64):
+        p = ps._hist_plan(F, num_bins, n_nodes)
+        dead = ps.hist_dead_key_tiles(F, num_bins, n_nodes)
+        assert (p.live_kt, p.num_kt) == (live, num_kt), n_nodes
+        assert p.live_kt + dead == p.num_kt and p.num_kt % p.tiles == 0
+        assert p.live_kt == -(-F * p.nb // p.w) and 0 <= dead < p.tiles
+
+
+@pytest.mark.parametrize("n_nodes,F,num_bins,rows", [
+    (8, 67, 256, 1100), (64, 67, 256, 1100), (8, 130, 16, 1100)])
+def test_dense_histogram_kernel_leaves_dead_key_tiles_alone(n_nodes, F,
+                                                            num_bins, rows):
+    """The kernel's own output, before the caller's slice: every lane of a
+    key tile that holds no feature is exactly zero, whatever the bins' last
+    block holds past the last feature (here codes like any other: a kernel
+    that works through its padding tiles sums them into those lanes; on a
+    chip the block's tail is whatever the buffer held), and the lanes that
+    are kept are the float64 sums."""
+    from dmlc_core_tpu.ops import pallas_segment as ps
+    p = ps._hist_plan(F, num_bins, n_nodes)
+    assert p.live_kt < p.num_kt
+    whole = p.num_kt * p.fpt // p.q         # feature rows of whole groups
+    bins, rel, gh = _hist_case(rows, whole, num_bins, n_nodes,
+                               seed=51 + n_nodes)
+    raw = np.asarray(ps._hist_key_lanes(
+        p, jnp.asarray(bins.T), jnp.asarray(rel), jnp.asarray(gh), True))
+    bins = bins[:, :F]
+    assert raw.shape == (2 * p.parts * p.n_pad, p.num_kt * p.w)
+    assert np.abs(raw[:, :p.live_kt * p.w]).max() > 0
+    assert not raw[:, p.live_kt * p.w:].any()
+    kept = (raw.reshape(p.parts, 2, p.n_pad, -1, p.nb).sum(0)
+            [:, :n_nodes, :F, :num_bins].transpose(1, 2, 3, 0))
+    want = _hist_f64(bins, rel, gh, n_nodes, num_bins)
+    assert _worst(kept, want) < HIST_F64_LIMIT
 
 
 @pytest.mark.parametrize("step_bytes,tiles", [(6 << 20, 28), (1 << 20, 8),
