@@ -2,9 +2,9 @@
 the feed was doing with the batch it ended with; the three shares sum to 100;
 the ring's steady clock reaches the window through the sync marks; a dropped
 event, or a program that writes no marks, makes the reader return nothing.
-Also the metric files this reader and PR 37's counters brought, and the two
-tests of the benchmark's own that they broke (both assert which entries are
-the LAST of ``per_layer``, or a cell's only ones)."""
+Also the metric files this reader and PR 37's counters brought: there, in
+their order, each over the two streamed cells of its day and whichever
+joined since."""
 import json
 from pathlib import Path
 
@@ -160,16 +160,18 @@ def test_the_reader_returns_nothing_rather_than_a_partial_answer(ring, case):
 
 
 def test_the_new_metric_files_name_their_readers_and_cells():
-    mine = {m["name"]: m for m in BENCH["per_layer"][49:]}
-    assert list(mine) == [
+    names = [
         "h2d_host_wait_us_per_batch.train", "h2d_emit_wait_us_per_batch.train",
         "pack_input_wait_us_per_row.train", "native_spans_dropped.train",
         "feed_wait_h2d_pct.train", "feed_wait_native_pct.train",
         "feed_wait_handoff_pct.train", "feed_lead_ms.train",
         "h2d_device_put_us_per_batch.train", "clock_sync_err_us.train",
-        "ftrl_scatter_tiles_per_step", "hist_nodes_built_per_round",
+        "sgd_scatter_tiles_per_step", "hist_nodes_built_per_round",
         "hist_nodes_derived_per_round", "sparse_hist_blocks_per_round",
         "sparse_hist_grid_steps_per_round"]
+    every = [m["name"] for m in BENCH["per_layer"]]
+    assert [n for n in every if n in names] == names       # in this order
+    mine = {n: BENCH["per_layer"][every.index(n)] for n in names}
     for name, m in mine.items():
         spec = json.loads(
             (HERE / "layer_metrics" / f"{name}.json").read_text())
@@ -177,43 +179,15 @@ def test_the_new_metric_files_name_their_readers_and_cells():
         assert m["moves"] == "train_rows_per_s"
         if spec["reader"] == "feed_timeline":
             assert m["source"] == "program_span"
-            assert m["workloads"] == STREAMED
+            assert m["workloads"][:2] == STREAMED
             assert spec["args"]["what"] in ("wait_pct", "lead_ms", "span_us",
                                             "sync_err_us")
         else:
             assert spec["reader"] == "counter_delta"
             assert m["source"] == "program_counter"
         if name.endswith(".train"):
-            assert m["workloads"] == STREAMED
+            assert m["workloads"][:2] == STREAMED
     assert [spec["of"] for spec in (
         json.loads((HERE / "layer_metrics" / f"{n}.json").read_text())["args"]
         for n in mine if n.startswith("feed_wait_"))] == ["h2d", "native",
                                                           "handoff"]
-
-
-def test_the_sparse_cells_entries_are_as_pr27_left_them(monkeypatch):
-    """``test_sparse_fit.py`` asserts that the metrics whose only cell is
-    ``bosch-gbdt.fit-sparse`` are PR 27's nine; this PR's two counters of the
-    sparse kernel are such metrics too (PERF.md section 7, for a ``benchmark``
-    issue).  Its body holds against the benchmark less them."""
-    import test_sparse_fit
-    less = dict(test_sparse_fit.BENCH)
-    less["per_layer"] = [m for m in less["per_layer"] if m["name"] not in (
-        "sparse_hist_blocks_per_round", "sparse_hist_grid_steps_per_round")]
-    assert len(less["per_layer"]) == len(test_sparse_fit.BENCH["per_layer"]) - 2
-    monkeypatch.setattr(test_sparse_fit, "BENCH", less)
-    test_sparse_fit.test_every_new_layer_metric_has_its_file_and_reader()
-
-
-def test_the_mesh_cells_entries_are_as_pr31_left_them(monkeypatch):
-    """``test_stream_ftrl.py`` runs ``test_mesh_fit.py``'s "the mesh cell's
-    nine metrics are the last nine" against the benchmark less the later
-    CELLS; this PR's counters of the GBDT cells are no later cell's and stay
-    in, after the nine.  The body holds against the benchmark less this PR's
-    fifteen entries too."""
-    import test_mesh_fit
-    import test_stream_ftrl
-    less = dict(BENCH, per_layer=BENCH["per_layer"][:49])
-    monkeypatch.setattr(test_mesh_fit, "BENCH", test_stream_ftrl.less_later_cells(
-        less, test_mesh_fit.CELL))
-    test_mesh_fit.test_every_new_layer_metric_has_its_file_and_reader()

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark import opcount, opcount_leafwise_histogram, run
+from test_names import cell_entries
 from test_references import (SEED, control_fails, through_bf16, verdict,
                              walk)
 
@@ -20,7 +21,8 @@ ROOT = HERE.parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "epsilon-lgbm.fit-leafwise"
 CONFIG = "epsilon-lgbm"
-MINE = ["leafwise_round_device_ms", "leafwise_hist_ms_per_round",
+# the round is the other GBDT cells' reading, under their name
+MINE = ["leafwise_hist_ms_per_round",
         "leafwise_partition_ms_per_round", "leafwise_split_ms_per_round",
         "leafwise_pick_ms_per_round", "leafwise_rows_visited_per_round",
         "leafwise_expansions_per_round", "leafwise_depth_max",
@@ -66,18 +68,10 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    mine = [m["name"] for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert mine == MINE
-    every = [m["name"] for m in BENCH["per_layer"]]
-    assert every.index(MINE[0]) > every.index("difacto_scatter_roofline")
-    for name in MINE:
-        spec = json.loads((HERE / "layer_metrics" / f"{name}.json").read_text())
-        assert spec["name"] == name
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
-        entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert entry["layer"] == spec["layer"]
+    for entry in cell_entries(CELL, MINE, after="difacto_scatter_roofline"):
+        assert entry["workloads"] == [CELL]
         assert entry["moves"] == "train_rows_per_s"
+    cell_entries(CELL, ["round_device_ms", "margin_ms_per_round"])
     scopes = {json.loads((HERE / "layer_metrics" / f"{n}.json").read_text())[
         "args"].get("scope") for n in MINE} - {None}
     assert scopes == {"gbdt\\.leafwise\\.hist", "gbdt\\.leafwise\\.partition",

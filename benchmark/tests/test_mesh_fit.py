@@ -2,7 +2,9 @@
 the names resolve to their files, the generator's rows are a function of
 (seed, shard) with the source's columns, the roofline's work is one chip's,
 the skew reader reads a trace's chips, the rehearsal walks on a CPU mesh of
-four devices, and ``check`` breaks when the timed call is broken."""
+four devices, ``check`` breaks when the timed call is broken, and the cell's
+entries in ``BENCHMARK.json`` are there in their order (membership and order,
+never that they are the last)."""
 import json
 import os
 from pathlib import Path
@@ -21,12 +23,18 @@ import pytest  # noqa: E402
 
 from benchmark import (harness, opcount, opcount_mesh_histogram,  # noqa: E402
                        run, trace_reduce)
+from test_names import cell_entries  # noqa: E402
 
 HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "airline-gbdt.fit-mesh4"
 SEED = 2 ** 31 + 31
+# the tree's round and its parts, the readings the resident tree has too
+SHARED = ["hist_ms_per_round", "round_device_ms", "route_ms_per_round",
+          "leaf_ms_per_round", "boost_ms_per_round"]
+MINE = ["mesh_hist_roofline", "allreduce_ms_per_round",
+        "collective_bytes_per_round", "chip_busy_skew_pct.train"]
 
 
 def mesh_fit():
@@ -45,7 +53,7 @@ def test_the_cell_and_its_configuration_resolve():
     cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     config = next(c for c in BENCH["configs"] if c["name"] == "airline-gbdt")
     assert cell["config"] == "airline-gbdt" and cell["chips"] == 4
-    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [CELL]
+    assert CELL in [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
     spec = json.loads((HERE / "workloads" / f"{CELL}.json").read_text())
     assert spec["generator"] == "mesh_fit"
     assert spec["reference"] == "airline-gbdt"
@@ -63,7 +71,9 @@ def test_the_cell_and_its_configuration_resolve():
     assert "chips" not in p         # only the builder's control sets it
     rate = next(m for m in BENCH["end_to_end"]
                 if m["name"] == "train_rows_per_s")
-    assert rate["workloads"][-1] == CELL
+    # after the sparse cell's, whatever follows
+    assert rate["workloads"].index(CELL) > rate["workloads"].index(
+        "bosch-gbdt.fit-sparse")
     assert set(data["tolerance"]["limits"]) == {
         "base_abs_err", "gain_rel_err", "cover_rel_err", "leaf_rel_err",
         "split_regret", "trees_missing", "root_cover_rel_err"}
@@ -71,20 +81,12 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == [
-        "mesh_round_device_ms", "mesh_hist_ms_per_round",
-        "mesh_hist_roofline", "allreduce_ms_per_round",
-        "mesh_leaf_ms_per_round", "mesh_route_ms_per_round",
-        "mesh_boost_ms_per_round", "collective_bytes_per_round",
-        "chip_busy_skew_pct.train"]
-    assert mine == BENCH["per_layer"][-9:]
-    for m in mine:
-        spec = json.loads(
-            (HERE / "layer_metrics" / f"{m['name']}.json").read_text())
-        assert spec["name"] == m["name"] and spec["layer"] == m["layer"]
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
-        assert m["moves"] == "train_rows_per_s"
+    # the mesh's own come after the sparse cell's
+    for m in cell_entries(CELL, MINE, after="sparse_boost_ms_per_round"):
+        assert m["workloads"][0] == CELL and m["moves"] == "train_rows_per_s"
+    for m in cell_entries(CELL, SHARED):
+        assert m["workloads"].index(CELL) > m["workloads"].index(
+            "higgs-gbdt.fit-resident")
     roofline = json.loads(
         (HERE / "layer_metrics" / "mesh_hist_roofline.json").read_text())
     assert roofline["args"]["opcount"] == \

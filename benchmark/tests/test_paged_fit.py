@@ -2,9 +2,10 @@
 ``criteo-xgb-extmem.fit-paged``: the names resolve to their files, the
 generator's pages are a function of (seed, page) with the source's three
 kinds of columns, the binner's sample is rows the fit sees, the roofline's
-work is the algorithm's whatever the pages, the rehearsal walks, and
+work is the algorithm's whatever the pages, the rehearsal walks,
 ``check`` breaks, by the limit that names the fault, when the paged fit is
-broken."""
+broken, and the cell's entries in ``BENCHMARK.json`` are there in their order
+(membership and order, never that they are the last or a cell's only ones)."""
 import json
 import re
 from pathlib import Path
@@ -13,18 +14,19 @@ import numpy as np
 import pytest
 
 from benchmark import harness, opcount, opcount_paged_histogram, run
+from test_names import cell_entries
 
 HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CONFIG, CELL = "criteo-xgb-extmem", "criteo-xgb-extmem.fit-paged"
 SEED = 2 ** 31 + 48
-MINE = ["paged_round_device_ms", "paged_hist_ms_per_round",
-        "paged_hist_roofline", "paged_route_ms_per_round",
+# the readings the resident tree has too, under its names
+SHARED = ["hist_ms_per_round", "round_device_ms", "margin_ms_per_round"]
+MINE = ["paged_hist_roofline", "paged_route_ms_per_round",
         "paged_accumulate_ms_per_round", "paged_boost_ms_per_round",
-        "paged_margin_ms_per_round", "page_h2d_us_per_page",
-        "page_h2d_bytes_per_round", "page_wait_pct.train",
-        "page_passes_per_round"]
+        "page_h2d_us_per_page", "page_h2d_bytes_per_round",
+        "page_wait_pct.train", "page_passes_per_round"]
 LIMITS = {"base_abs_err", "gain_rel_err", "cover_rel_err", "leaf_rel_err",
           "root_cover_rel_err", "split_regret", "trees_missing",
           "rows_streamed_mismatch", "page_bytes_mismatch",
@@ -79,26 +81,18 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert [n for n in names if n in MINE] == MINE          # in this order
-    assert min(names.index(n) for n in MINE) > names.index(
-        "route_push_per_round")
-    for name in MINE:
-        m = BENCH["per_layer"][names.index(name)]
+    for m in cell_entries(CELL, MINE, after="route_push_per_round"):
         assert m["workloads"] == [CELL] and m["moves"] == "train_rows_per_s"
-        spec = json.loads(
-            (HERE / "layer_metrics" / f"{name}.json").read_text())
-        assert spec["name"] == name and spec["layer"] == m["layer"]
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
+    for m in cell_entries(CELL, SHARED):
+        # joined, after the resident cell whose names they are
+        assert m["workloads"].index(CELL) > m["workloads"].index(
+            "higgs-gbdt.fit-resident")
     roof = json.loads(
         (HERE / "layer_metrics" / "paged_hist_roofline.json").read_text())
     assert roof["args"]["pattern"] == "^%_histogram_gh_pallas"
     module, function = roof["args"]["opcount"].split(":")
     assert (HERE / f"{module}.py").is_file()
     assert callable(getattr(opcount_paged_histogram, function))
-    # no cell of another configuration lists one of these
-    assert not [m["name"] for m in BENCH["per_layer"]
-                if CELL in m.get("workloads", []) and m["name"] not in MINE]
 
 
 def test_the_rooflines_work_is_the_algorithms_whatever_the_pages():

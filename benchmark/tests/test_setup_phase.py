@@ -16,10 +16,11 @@ BENCH = json.loads((HERE.parent / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
 GBDT = [c for c in CELLS if re.search("gbdt|lgbm|xgb", c)]
 STREAMED = [c for c in CELLS if "stream" in c]
+# None: no ``workloads`` key, so every cell that reports ``setup_s`` reads it
 MINE = {
-    "setup_program_s": CELLS, "setup_compile_trace_s": CELLS,
-    "setup_compile_backend_s": CELLS, "setup_compile_fetch_s": CELLS,
-    "setup_cache_misses": CELLS, "setup_binner_s": GBDT,
+    "setup_program_s": None, "setup_compile_trace_s": None,
+    "setup_compile_backend_s": None, "setup_compile_fetch_s": None,
+    "setup_cache_misses": None, "setup_binner_s": GBDT,
     "setup_init_s": STREAMED, "setup_warmup_s": GBDT,
     "setup_stage_s": ["bosch-gbdt.fit-sparse"],
 }
@@ -78,14 +79,15 @@ def test_the_metric_files_name_the_reader_their_counters_and_their_cells():
     contract = doc[doc.index("## Metric name contract"):
                    doc.index("## Stall attribution")]
     entries = {m["name"]: m for m in BENCH["per_layer"]}
-    assert list(entries)[-len(MINE):] == list(MINE)     # appended, in order
+    assert [n for n in entries if n in MINE] == list(MINE)      # in order
     for name, cells in MINE.items():
         spec = json.loads(
             (HERE / "layer_metrics" / f"{name}.json").read_text())
         entry = entries[name]
         assert spec["name"] == name and spec["reader"] == "setup_phase"
         assert spec["layer"] == entry["layer"]
-        assert entry["moves"] == "setup_s" and entry["workloads"] == cells
+        assert entry["moves"] == "setup_s"
+        assert entry.get("workloads") == cells
         counters = spec["args"]["num"] + spec["args"].get("den", [])
         assert counters
         for counter in counters:

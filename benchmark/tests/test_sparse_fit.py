@@ -1,8 +1,9 @@
 """The ``bosch-gbdt`` configuration and its cell ``bosch-gbdt.fit-sparse``:
 the names resolve to their files, the generator's rows and file are a
 function of the seed and come back through the parser as drawn, the opcount
-is what its docstring says, the rehearsal walks, and ``check`` breaks when
-the timed call is broken."""
+is what its docstring says, the rehearsal walks, ``check`` breaks when the
+timed call is broken, and the cell's entries in ``BENCHMARK.json`` are there
+in their order (membership and order, never that they are the last)."""
 import json
 from pathlib import Path
 
@@ -10,12 +11,18 @@ import numpy as np
 import pytest
 
 from benchmark import harness, opcount, opcount_sparse_histogram, run
+from test_names import cell_entries
 
 HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "bosch-gbdt.fit-sparse"
 SEED = 2 ** 31 + 27
+# the tree's round and the parts the dense tree has too, then PR 27's own
+MINE = ["round_device_ms", "route_ms_per_round", "split_ms_per_round",
+        "sparse_hist_ms_per_round", "sparse_hist_roofline",
+        "entry_gather_ms_per_round", "sparse_leaf_ms_per_round",
+        "sparse_prepare_ms_per_round", "sparse_boost_ms_per_round"]
 
 
 def sparse_fit():
@@ -53,18 +60,7 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
-    assert {m["name"] for m in mine} == {
-        "sparse_round_device_ms", "sparse_hist_ms_per_round",
-        "sparse_hist_roofline", "entry_gather_ms_per_round",
-        "sparse_route_ms_per_round", "sparse_split_ms_per_round",
-        "sparse_leaf_ms_per_round", "sparse_prepare_ms_per_round",
-        "sparse_boost_ms_per_round"}
-    for m in mine:
-        spec = json.loads(
-            (HERE / "layer_metrics" / f"{m['name']}.json").read_text())
-        assert spec["name"] == m["name"] and spec["layer"] == m["layer"]
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
+    for m in cell_entries(CELL, MINE):
         assert m["moves"] == "train_rows_per_s"
     roofline = json.loads(
         (HERE / "layer_metrics" / "sparse_hist_roofline.json").read_text())

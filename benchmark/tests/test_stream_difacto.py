@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from benchmark import opcount, opcount_rows_scatter, run
+from test_names import cell_entries
 from test_references import SEED, control_fails, verdict, walk
 
 HERE = Path(__file__).resolve().parents[1]
@@ -20,11 +21,14 @@ ROOT = HERE.parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "criteo-tb-difacto.stream-train"
 CONFIG = "criteo-tb-difacto"
-MINE = ["difacto_step_device_ms", "difacto_gather_ms_per_step",
-        "difacto_margins_ms_per_step", "difacto_unique_ms_per_step",
-        "difacto_update_ms_per_step", "difacto_scatter_ms_per_step",
-        "difacto_touched_rows_per_step", "difacto_active_rows_per_step",
-        "difacto_scatter_tiles_per_step", "difacto_scatter_roofline"]
+# the touched-rows step's readings, which the FTRL cell's (and since PR 42 the
+# FFM cell's) step shares under the spans' own names, then the three this
+# cell's gate and wide kernel brought
+STEP = ["sgd_step_device_ms", "sgd_unique_ms_per_step",
+        "sgd_gather_ms_per_step", "sgd_scatter_ms_per_step",
+        "sgd_touched_rows_per_step", "sgd_scatter_tiles_per_step"]
+MINE = ["sgd_margins_ms_per_step", "sgd_update_ms_per_step",
+        "sgd_active_rows_per_step", "difacto_scatter_roofline"]
 FEED = ["parse_us_per_row.train", "feed_wait_pct.train",
         "feed_wait_us_per_row.train", "h2d_host_wait_us_per_batch.train",
         "h2d_emit_wait_us_per_batch.train", "pack_input_wait_us_per_row.train",
@@ -67,18 +71,12 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    mine = [m["name"] for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert mine == MINE
-    every = [m["name"] for m in BENCH["per_layer"]]
-    assert every.index(MINE[0]) > every.index("ftrl_scatter_tiles_per_step")
-    for name in MINE:
-        spec = json.loads((HERE / "layer_metrics" / f"{name}.json").read_text())
-        assert spec["name"] == name
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
-        entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert entry["layer"] == spec["layer"]
+    for entry in cell_entries(CELL, MINE, after="sgd_scatter_tiles_per_step"):
+        assert entry["workloads"][0] == CELL        # this cell brought them
         assert entry["moves"] == "train_rows_per_s"
+    for entry in cell_entries(CELL, STEP):
+        assert entry["workloads"].index(CELL) > entry["workloads"].index(
+            "criteo-tb-ftrl.stream-train")
     for name in FEED:
         entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
         assert entry["workloads"].index(CELL) > entry["workloads"].index(

@@ -23,6 +23,7 @@ import pytest  # noqa: E402
 
 from benchmark import (harness, opcount, opcount_sharded_rows,  # noqa: E402
                        run)
+from test_names import cell_entries  # noqa: E402
 from test_references import SEED, control_fails, verdict, walk  # noqa: E402
 
 HERE = Path(__file__).resolve().parents[1]
@@ -31,12 +32,14 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL = "criteo-tb-difacto-ps4.stream-train-mesh4"
 CONFIG = "criteo-tb-difacto-ps4"
 SIBLING = "criteo-tb-difacto"
-MINE = ["ps4_step_device_ms", "ps4_exchange_ms_per_step",
-        "ps4_owner_merge_ms_per_step", "ps4_unique_ms_per_step",
-        "ps4_gather_ms_per_step", "ps4_margins_ms_per_step",
-        "ps4_update_ms_per_step", "ps4_scatter_ms_per_step",
-        "ps4_exchange_bytes_per_step", "ps4_touched_rows_per_step",
-        "ps4_owner_rows_per_step", "ps4_active_rows_per_step",
+# the step's readings that the one-chip sibling's step has too, under the
+# spans' own names, then what the exchange and the owner brought
+STEP = ["sgd_step_device_ms", "sgd_unique_ms_per_step",
+        "sgd_gather_ms_per_step", "sgd_scatter_ms_per_step",
+        "sgd_touched_rows_per_step", "sgd_margins_ms_per_step",
+        "sgd_update_ms_per_step", "sgd_active_rows_per_step"]
+MINE = ["ps4_exchange_ms_per_step", "ps4_owner_merge_ms_per_step",
+        "ps4_exchange_bytes_per_step", "ps4_owner_rows_per_step",
         "ps4_exchange_overflow_per_step", "ps4_scatter_roofline"]
 JOINED = ["parse_us_per_row.train", "feed_wait_pct.train",
           "feed_wait_us_per_row.train", "h2d_host_wait_us_per_batch.train",
@@ -103,19 +106,13 @@ def test_the_cell_and_its_configuration_resolve():
 
 
 def test_every_new_layer_metric_has_its_file_and_reader():
-    mine = [m["name"] for m in BENCH["per_layer"]
-            if m.get("workloads") == [CELL]]
-    assert mine == MINE
-    every = [m["name"] for m in BENCH["per_layer"]]
-    assert every.index(MINE[0]) > every.index("difacto_scatter_roofline")
     layers = {m["layer"] for m in BENCH["per_layer"] if m["name"] not in MINE}
-    for name in MINE:
-        spec = json.loads((HERE / "layer_metrics" / f"{name}.json").read_text())
-        assert spec["name"] == name
-        assert (HERE / "readers" / f"{spec['reader']}.py").is_file()
-        entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert entry["layer"] == spec["layer"] and entry["layer"] in layers
+    for entry in cell_entries(CELL, MINE, after="difacto_scatter_roofline"):
+        assert entry["workloads"] == [CELL] and entry["layer"] in layers
         assert entry["moves"] == "train_rows_per_s"
+    for entry in cell_entries(CELL, STEP):
+        assert entry["workloads"].index(CELL) > entry["workloads"].index(
+            f"{SIBLING}.stream-train")
     # the feed's metrics and the chips' skew hold for a batch laid over four
     # chips: the cell joins their lists, after the cells that were there
     for name in JOINED:

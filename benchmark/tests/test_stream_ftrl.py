@@ -1,11 +1,7 @@
 """The ``stream_ftrl`` generator and the ``criteo-tb-ftrl`` reference at the
 cell's rehearsal size: the walk is sound, its control is not, a state
 rounded through bfloat16 fails, a batch delivered twice is noticed, and the
-file is what the seed says.  Also the two tests of ``test_mesh_fit.py`` that
-this cell's entries broke (``benchmark/conftest.py``), against the benchmark
-as PR 31 left it."""
-import copy
-
+file is what the seed says."""
 import ml_dtypes
 import numpy as np
 import pytest
@@ -13,35 +9,6 @@ import pytest
 from test_references import SEED, control_fails, verdict, walk
 
 CELL = "criteo-tb-ftrl.stream-train"
-
-
-def less_later_cells(bench: dict, cell: str) -> dict:
-    """``BENCHMARK.json`` less every cell listed after ``cell`` and what only
-    those cells brought: their configurations, their names in the metrics'
-    ``workloads``, the metrics nothing else reports."""
-    names = [w["name"] for w in bench["workloads"]]
-    later = set(names[names.index(cell) + 1:])
-    out = copy.deepcopy(bench)
-    out["workloads"] = [w for w in out["workloads"] if w["name"] not in later]
-    kept = {w["config"] for w in out["workloads"]}
-    out["configs"] = [c for c in out["configs"] if c["name"] in kept]
-    for m in out["end_to_end"] + out["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [w for w in m["workloads"] if w not in later]
-    out["per_layer"] = [m for m in out["per_layer"]
-                        if m.get("workloads") != []]
-    return out
-
-
-@pytest.mark.parametrize("name", (
-    "test_the_cell_and_its_configuration_resolve",
-    "test_every_new_layer_metric_has_its_file_and_reader"))
-def test_the_mesh_cells_entries_are_as_pr31_left_them(monkeypatch, name):
-    import test_mesh_fit
-    assert CELL in [w["name"] for w in test_mesh_fit.BENCH["workloads"]]
-    monkeypatch.setattr(test_mesh_fit, "BENCH", less_later_cells(
-        test_mesh_fit.BENCH, test_mesh_fit.CELL))
-    getattr(test_mesh_fit, name)()
 
 
 @pytest.mark.parametrize("seed", (SEED, 99))

@@ -165,8 +165,12 @@ def test_recorded_busy_union_equals_trace_reduce(path):
     assert mine == sorted(trace.chips[0])
 
 
-def metric_args(name: str) -> dict:
-    return json.loads((TESTDATA.parent / "layer_metrics" / f"{name}.json")
+def metric_args(metric) -> dict:
+    """A layer metric's ``args`` by its name; ``args`` given as they are
+    stand for a scope of the recorded program that no metric reads."""
+    if isinstance(metric, dict):
+        return metric
+    return json.loads((TESTDATA.parent / "layer_metrics" / f"{metric}.json")
                       .read_text())["args"]
 
 
@@ -186,7 +190,9 @@ RECORDED = [
     (GBDT, "leaf_ms_per_round", 0.000933495),
     (GBDT, "boost_ms_per_round", 0.000246712),
     (FFM, "ffm_row_ids_ms_per_step", 0.044489405),
-    (FFM, "ffm_gather_ms_per_step", 0.04633146),
+    # scoring's and the dense step's scope; its metric went with PR 52
+    (FFM, {"scope": "ffm\\.gather", "per": "steps", "scale": 1000.0},
+     0.04633146),
     (FFM, "ffm_reduce_ms_per_step", 0.132409872),
     (FFM, "ffm_loss_ms_per_step", 2.044e-06),
     (FFM, "ffm_update_ms_per_step", 0.001768117),
@@ -194,7 +200,8 @@ RECORDED = [
 
 
 @pytest.mark.parametrize("path,metric,seconds", RECORDED,
-                         ids=[m for _, m, _ in RECORDED])
+                         ids=[m if isinstance(m, str) else m["scope"]
+                              for _, m, _ in RECORDED])
 def test_recorded_time_per_scope(path, metric, seconds):
     args = metric_args(metric)
     trace = tr.reduce(path)
